@@ -1,0 +1,789 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the two normal entry points once, at the full width of models the
+repo has, in ONE process on one TPU (random weights from a seed, data from
+a seed, no network, no git, no child process that needs the chip):
+
+- train      BERT-base MLM through paddle.Model(...).prepare(amp "O2").fit
+- serve      GPT-2 124M (bf16) behind ServeLoop.start(), ragged greedy
+             requests from client threads, plus a teacher-forced
+             paged-kernel vs paged_attention_ref logits check
+- kernels    each Pallas kernel compiled by Mosaic against its jnp
+             reference, fwd and bwd where it has one
+- multichip  (>= 4 devices) the train path through fleet on dp=4 and
+             dp=2 x tp=2, and the LocalSGD shard_map step; on fewer
+             devices an explicit SKIP
+
+Every phase prints PASS/FAIL with wall time split into compile and run,
+peak device memory, and the pallas.* counters with reasons. Any FAIL, any
+uncaught exception, or a platform other than "tpu" exits non-zero. The
+last stdout line of a passing run is one JSON object:
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+The phases are plain functions of a `Sizes`; tests/test_chip_smoke.py calls
+them at toy size on the CPU (kernels interpreted). This script has no
+"run on CPU" switch: off-TPU `main()` refuses.
+"""
+from __future__ import annotations
+
+import dataclasses
+import faulthandler
+import json
+import sys
+import threading
+import time
+import traceback
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+SEED = 0
+DTYPE = jnp.bfloat16   # what both models compute in on the chip
+# normalized max error |got - ref|_max / |ref|_max a bf16 kernel may show
+# against its f32 jnp reference on the same bf16 inputs (bf16 eps is 2^-8
+# ~ 3.9e-3; probabilities and outputs are each rounded once)
+KERNEL_TOL = 2e-2
+# same measure for last-position logits of the whole 12-layer model, paged
+# kernel on vs paged_attention_ref (both bf16; rounding compounds by layer)
+LOGITS_TOL = 5e-2
+# relative gap allowed between the one-chip and the sharded final loss
+# (same global batches, dropout off; bf16 reductions reorder under GSPMD)
+MULTICHIP_LOSS_TOL = 2e-2
+# every serve request must be back by then, compiles included: a scheduler
+# thread that died leaves its requests waiting, and the smoke must end
+SERVE_DEADLINE_S = 600.0
+
+
+@dataclasses.dataclass
+class Sizes:
+    """Everything a phase needs to know about how big to run."""
+    bert: object                 # BertConfig
+    train_batch: int
+    train_seq: int
+    train_steps: int
+    train_lr: float
+    gpt: object                  # GPTConfig
+    serve_requests: int
+    serve_max_active: int
+    serve_kv_blocks: int
+    serve_prompt_bands: tuple    # ((lo, hi), ...) inclusive
+    serve_new_tokens: tuple      # (lo, hi) inclusive
+    serve_clients: int
+    serve_generate_checks: int   # requests compared to net.generate
+    serve_expect_hits: tuple     # kernels that must engage in serve
+    forced_prompts: tuple        # teacher-forced check: real prompt lens
+    forced_bucket: int           #   padded to this prefill bucket
+    flash_shape: tuple           # (b, h, s, d)
+    ce_shape: tuple              # (n, hidden, vocab)
+    decode_shape: tuple          # (b, h, L, d)
+    paged_checks: tuple          # (query rows, block size or None=picker)
+    multichip_layers: int
+    multichip_steps: int
+
+    @staticmethod
+    def full():
+        from paddle_tpu.text.models.bert import BertConfig
+        from paddle_tpu.text.models.gpt import GPTConfig
+        return Sizes(
+            bert=BertConfig.bert_base(), train_batch=32, train_seq=128,
+            train_steps=40, train_lr=1e-4,
+            gpt=GPTConfig(), serve_requests=16, serve_max_active=8,
+            serve_kv_blocks=96,
+            # two bands, not one length per power of two: every prefill
+            # bucket is a compile
+            serve_prompt_bands=((16, 64), (256, 512)),
+            serve_new_tokens=(32, 128), serve_clients=4,
+            serve_generate_checks=4,
+            serve_expect_hits=("paged_decode_attention",),
+            forced_prompts=(37, 120, 200, 256), forced_bucket=256,
+            flash_shape=(2, 12, 1024, 64), ce_shape=(4096, 768, 30522),
+            decode_shape=(8, 12, 1024, 64),
+            # a decode beat, a prefill chunk, and the smallest block
+            # FLAGS_serve_block_size admits: 8 rows is under bf16's
+            # (16, 128) tile, which Mosaic compiles all the same
+            paged_checks=((1, None), (64, None), (1, 8)),
+            multichip_layers=4, multichip_steps=8)
+
+    @staticmethod
+    def toy():
+        from paddle_tpu.text.models.bert import BertConfig
+        from paddle_tpu.text.models.gpt import GPTConfig
+        return Sizes(
+            bert=BertConfig.tiny(), train_batch=8, train_seq=16,
+            train_steps=12, train_lr=1e-2,
+            gpt=GPTConfig.tiny(), serve_requests=6, serve_max_active=2,
+            serve_kv_blocks=24, serve_prompt_bands=((3, 8), (17, 30)),
+            serve_new_tokens=(3, 6), serve_clients=2,
+            serve_generate_checks=1, serve_expect_hits=(),
+            forced_prompts=(5, 14), forced_bucket=16,
+            flash_shape=(1, 2, 128, 16), ce_shape=(64, 32, 200),
+            decode_shape=(2, 2, 64, 16), paged_checks=((1, None),),
+            multichip_layers=1, multichip_steps=3)
+
+
+# --------------------------------------------------------------------------
+# accounting: compile seconds and persistent-cache traffic from jax's own
+# monitoring events, so "compile" is what jax says it is
+# --------------------------------------------------------------------------
+
+_COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                   "/jax/core/compile/backend_compile_duration")
+_acct = {"compile_s": 0.0, "backend_compiles": 0, "cache_hits": 0,
+         "cache_misses": 0}
+_acct_lock = threading.Lock()
+_listening = False
+
+
+def _on_duration(event, duration, **_):
+    if event in _COMPILE_EVENTS:
+        with _acct_lock:
+            _acct["compile_s"] += duration
+            if event == _COMPILE_EVENTS[2]:
+                _acct["backend_compiles"] += 1
+
+
+def _on_event(event, **_):
+    if event == "/jax/compilation_cache/cache_hits":
+        with _acct_lock:
+            _acct["cache_hits"] += 1
+    elif event == "/jax/compilation_cache/cache_misses":
+        with _acct_lock:
+            _acct["cache_misses"] += 1
+
+
+def _listen():
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
+        _listening = True
+
+
+def _acct_snapshot():
+    with _acct_lock:
+        return dict(_acct)
+
+
+def _pallas_counters():
+    from paddle_tpu.core import monitor
+    return {k: int(v) for k, v in sorted(monitor.stats("pallas.").items())}
+
+
+def _block_sizes():
+    """Measured autotune winners (the only block sizes that can differ
+    from run to run; everything else is the static heuristic)."""
+    from paddle_tpu.ops.pallas import autotune
+    return {"|".join(str(x) for x in k): list(v)
+            for k, v in sorted(autotune.table_snapshot().items())}
+
+
+def _rel_err(got, ref):
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    if not np.isfinite(got).all():
+        return float("inf")
+    return float(np.abs(got - ref).max() / max(np.abs(ref).max(), 1e-30))
+
+
+def run_phase(name, fn, *args):
+    """Run one phase; print its START line first (a SIGABRT inside Mosaic
+    kills the process, and the last START line names the culprit), then a
+    PASS/FAIL line. Returns the phase's result dict with "ok" set."""
+    from paddle_tpu import memory
+    from paddle_tpu.core import monitor
+    _listen()
+    monitor.reset(prefix="pallas.")
+    print(f"PHASE {name} START", flush=True)
+    before = _acct_snapshot()
+    t0 = time.perf_counter()
+    try:
+        result = fn(*args)
+    except Exception:  # the smoke must report every phase, then fail
+        traceback.print_exc()
+        sys.stderr.flush()
+        result = {"failures": ["uncaught exception (traceback on stderr)"]}
+    wall = time.perf_counter() - t0
+    after = _acct_snapshot()
+    counters = _pallas_counters()
+    fallbacks = {k: v for k, v in counters.items()
+                 if k.startswith("pallas.fallback.") and v}
+    if fallbacks:
+        result["failures"].append(f"pallas fallbacks: {fallbacks}")
+    result["ok"] = not result["failures"]
+    compile_s = after["compile_s"] - before["compile_s"]
+    peak = memory.max_memory_allocated()  # 0 where PJRT tracks no peak
+    status = result.get("skip") or ("PASS" if result["ok"] else "FAIL")
+    print(f"PHASE {name} {status} wall={wall:.1f}s compile={compile_s:.1f}s "
+          f"run={max(wall - compile_s, 0.0):.1f}s "
+          f"backend_compiles={after['backend_compiles'] - before['backend_compiles']} "
+          f"cache_hits={after['cache_hits'] - before['cache_hits']} "
+          f"cache_writes={after['cache_misses'] - before['cache_misses']} "
+          f"peak_bytes_in_use_so_far={peak or 'n/a'}",
+          flush=True)
+    for key, value in result.items():
+        if key not in ("failures", "ok", "skip"):
+            print(f"  {name}.{key}: {value}", flush=True)
+    print(f"  {name}.pallas_counters: {counters}", flush=True)
+    print(f"  {name}.autotuned_blocks: {_block_sizes()}", flush=True)
+    for failure in result["failures"]:
+        print(f"  {name} FAILURE: {failure}", flush=True)
+    return result
+
+
+# --------------------------------------------------------------------------
+# train: BERT MLM through Model.prepare(amp O2).fit
+# --------------------------------------------------------------------------
+
+def _bert_model(cfg, sizes, strategy=None):
+    """Seeded BERT behind paddle.Model, prepared for bf16 O2 + AdamW. With
+    a fleet `strategy` the optimizer is fleet-wrapped (the engine reads
+    e.g. LocalSGD from it) and amp comes from the strategy."""
+    import paddle_tpu as paddle
+    from paddle_tpu.text.models.bert import Bert, BertPretrainingCriterion
+
+    paddle.seed(SEED)
+    net = Bert(cfg)
+    model = paddle.Model(net)
+    opt = paddle.optimizer.AdamW(learning_rate=sizes.train_lr,
+                                 parameters=net.parameters())
+    # Model.fit's contract is loss(net(inputs), labels): it reaches
+    # BertPretrainingCriterion over materialized logits. The fused MLM
+    # head needs the labels as a network INPUT, which fit does not do;
+    # the kernels phase compiles the fused-CE kernel instead.
+    criterion = BertPretrainingCriterion(cfg.vocab_size)
+    if strategy is None:
+        return model.prepare(opt, criterion, amp_configs="O2")
+    from paddle_tpu.distributed import fleet
+    return model.prepare(fleet.distributed_optimizer(opt, strategy),
+                         criterion)
+
+
+def _fit(model, sizes, steps):
+    """`steps` batches of seeded MLM data through Model.fit. Returns
+    (per-step losses, backend compiles after step 1)."""
+    from paddle_tpu.hapi.callbacks import Callback
+    from paddle_tpu.text.datasets import LMDataset
+
+    class Recorder(Callback):
+        def __init__(self):
+            super().__init__()
+            self.losses = []
+            self.compiles_at_step1 = None
+
+        def on_train_batch_end(self, step, logs=None):
+            self.losses.append(float(logs["loss"]))  # syncs this step
+            if self.compiles_at_step1 is None:
+                self.compiles_at_step1 = _acct_snapshot()["backend_compiles"]
+
+    data = LMDataset(vocab_size=model.network.config.vocab_size,
+                     seq_len=sizes.train_seq, n=steps * sizes.train_batch,
+                     mode="mlm", seed=SEED)
+    rec = Recorder()
+    # num_workers=0: forked loader workers under a live TPU client are
+    # untested (docs/chip_runs.md)
+    model.fit(data, batch_size=sizes.train_batch, epochs=1, shuffle=False,
+              drop_last=True, num_workers=0, verbose=0, callbacks=[rec])
+    late = _acct_snapshot()["backend_compiles"] - rec.compiles_at_step1
+    return rec.losses, late
+
+
+def train_phase(sizes):
+    failures = []
+    model = _bert_model(sizes.bert, sizes)
+    losses, late_compiles = _fit(model, sizes, sizes.train_steps)
+    k = max(1, len(losses) // 8)
+    start, end = float(np.mean(losses[:k])), float(np.mean(losses[-k:]))
+    if len(losses) != sizes.train_steps:
+        failures.append(f"ran {len(losses)} steps, wanted "
+                        f"{sizes.train_steps}")
+    if not np.isfinite(losses).all():
+        failures.append(f"non-finite loss: {losses}")
+    elif not end < start:
+        failures.append(f"loss did not fall: {start:.4f} -> {end:.4f}")
+    step_variants = model._engine._train_fn._cache_size()
+    if step_variants != 1:
+        failures.append(f"train step compiled {step_variants} times")
+    if late_compiles:
+        failures.append(f"{late_compiles} backend compiles after step 1")
+    want = jax.devices()[0].platform
+    named = dict(model.network.named_parameters())
+    params = list(named.values())
+    off = [n for n, p in named.items()
+           if {d.platform for d in p._value.devices()} != {want}]
+    if off:
+        failures.append(f"parameters not on {want}: {off[:3]}")
+    return {
+        "failures": failures,
+        "loss_head": "BertPretrainingCriterion (materialized logits)",
+        "params": int(sum(p.size for p in params)),
+        "param_dtypes": sorted({str(p._value.dtype) for p in params}),
+        "loss_start": round(start, 4), "loss_end": round(end, 4),
+        "steps": len(losses), "compiles_after_step1": late_compiles,
+    }
+
+
+# --------------------------------------------------------------------------
+# serve: GPT behind ServeLoop.start(), client threads, teacher-forced check
+# --------------------------------------------------------------------------
+
+def _bf16_gpt(cfg):
+    import paddle_tpu as paddle
+    from paddle_tpu.text.models.gpt import GPT
+    paddle.seed(SEED)
+    net = GPT(cfg)
+    net.eval()
+    paddle.amp.decorate(net, level="O2", dtype="bfloat16")
+    return net
+
+
+def _forced_logits(net, sizes, block_size):
+    """Teacher-force one bucket-padded prefill and one decode beat through
+    net._forward_paged twice — paged kernel on, then off (the counted
+    flag_off gate onto paged_attention_ref) — and return the normalized
+    max logits error of each."""
+    import paddle_tpu as paddle
+    from paddle_tpu.core import tape
+    from paddle_tpu.nn.kv_pool import KVBlockPool, PagedKVCache
+
+    cfg = net.config
+    lens = np.asarray(sizes.forced_prompts, np.int32)
+    b, bucket = len(lens), sizes.forced_bucket
+    per_slot = -(-(bucket + 1) // block_size)
+    pool = KVBlockPool(b * per_slot, block_size)
+    tables = np.asarray([pool.alloc(per_slot) for _ in range(b)], np.int32)
+    heads, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
+    rng = np.random.RandomState(SEED + 1)
+    ids = np.zeros((b, bucket), np.int32)
+    for i, n in enumerate(lens):
+        ids[i, :n] = rng.randint(1, cfg.vocab_size, n)
+    params, buffers = net.functional_state()
+
+    def forward(params, arenas, tokens, lengths, last_index):
+        with tape.no_grad():
+            net.load_functional_state(params, buffers)
+            caches = [PagedKVCache(k, v, jnp.asarray(tables), lengths)
+                      for k, v in arenas]
+            logits, new = net._forward_paged(tokens, caches,
+                                             last_index=last_index)
+        return logits, [(c.k, c.v) for c in new]
+
+    def both_paths(arenas, tokens, lengths, last_index):
+        out = {}
+        for kernel_on in (True, False):
+            paddle.set_flags({"FLAGS_use_paged_attention": kernel_on})
+            try:
+                # the flag is read at trace time and jit caches by function
+                # identity: a fresh lambda per path forces a fresh trace
+                out[kernel_on] = jax.jit(lambda *a: forward(*a))(
+                    params, arenas, tokens, lengths, last_index)
+            finally:
+                paddle.set_flags({"FLAGS_use_paged_attention": True})
+                net.load_functional_state(params, buffers)
+        return out
+
+    arenas = pool.arenas(cfg.num_layers, heads, hd, DTYPE)
+    pre = both_paths(arenas, jnp.asarray(ids), jnp.zeros((b,), jnp.int32),
+                     jnp.asarray(lens - 1))
+    nxt = jnp.argmax(pre[False][0], axis=-1).astype(jnp.int32)
+    dec = both_paths(pre[False][1], nxt[:, None], jnp.asarray(lens), None)
+    return {"prefill": _rel_err(pre[True][0], pre[False][0]),
+            "decode": _rel_err(dec[True][0], dec[False][0])}
+
+
+def serve_phase(sizes):
+    import paddle_tpu as paddle
+    from paddle_tpu.core import monitor
+    from paddle_tpu.inference import ServeConfig, ServeLoop
+
+    failures = []
+    cfg = sizes.gpt
+    net = _bf16_gpt(cfg)
+    monitor.reset(prefix="serve.")
+    loop = ServeLoop(net, ServeConfig(
+        max_active=sizes.serve_max_active, kv_blocks=sizes.serve_kv_blocks,
+        max_seq_len=cfg.max_seq_len))
+    block_size = loop.stats()["block_size"]
+
+    rng = np.random.RandomState(SEED)
+    n = sizes.serve_requests
+    prompts, news = [], []
+    for i in range(n):
+        lo, hi = sizes.serve_prompt_bands[i % len(sizes.serve_prompt_bands)]
+        prompts.append(rng.randint(1, cfg.vocab_size,
+                                   rng.randint(lo, hi + 1)).astype(np.int64))
+        news.append(int(rng.randint(sizes.serve_new_tokens[0],
+                                    sizes.serve_new_tokens[1] + 1)))
+    outs, errors = [None] * n, []
+    deadline = time.monotonic() + SERVE_DEADLINE_S
+
+    def client(base):
+        reqs = [(i, loop.submit(prompts[i], max_new_tokens=news[i]))
+                for i in range(base, n, sizes.serve_clients)]
+        for i, req in reqs:
+            try:
+                outs[i] = req.result(
+                    timeout=max(1.0, deadline - time.monotonic()))
+            except Exception as e:  # every request's error is a finding
+                errors.append(f"request {i}: {type(e).__name__}: {e}")
+
+    loop.start()
+    try:
+        threads = [threading.Thread(target=client, args=(c,))
+                   for c in range(sizes.serve_clients)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        loop.stop(timeout=120)
+
+    failures += errors
+    bad = [i for i, o in enumerate(outs)
+           if o is not None and len(o) != news[i]]
+    if bad:
+        failures.append(f"requests with wrong token count: {bad}")
+    completed = int(monitor.stat_get("serve.requests_completed"))
+    if completed != n:
+        failures.append(f"serve.requests_completed={completed}, "
+                        f"submitted {n}")
+    counters = _pallas_counters()
+    for kernel in sizes.serve_expect_hits:
+        if not counters.get(f"pallas.hit.{kernel}"):
+            failures.append(f"kernel {kernel} never engaged in serve")
+
+    forced = _forced_logits(net, sizes, block_size)
+    if not _pallas_counters().get(
+            "pallas.gate_reject.paged_decode_attention.flag_off"):
+        failures.append("teacher-forced check never traced the "
+                        "paged_attention_ref path")
+    for beat, err in forced.items():
+        if not err <= LOGITS_TOL:
+            failures.append(f"teacher-forced {beat} logits: kernel vs "
+                            f"paged_attention_ref err {err:.3g} > "
+                            f"{LOGITS_TOL}")
+
+    # reported, not gated: bf16 argmax ties may flip between kernels, and
+    # a stream never rejoins after its first flipped token
+    agree = []
+    for i in [i for i in range(n) if outs[i] is not None][
+            :sizes.serve_generate_checks]:
+        ref = net.generate(paddle.to_tensor(prompts[i][None]),
+                           max_new_tokens=news[i], temperature=0)
+        ref = np.asarray(ref._value)[0, len(prompts[i]):]
+        differ = np.nonzero(ref != outs[i])[0]
+        agree.append(f"{differ[0] if len(differ) else len(ref)}/{len(ref)}")
+
+    buckets = sorted({loop._bucket(len(p)) for p in prompts})
+    return {
+        "failures": failures, "requests": n, "completed": completed,
+        "tokens_generated": int(monitor.stat_get("serve.tokens_generated")),
+        "preempted": int(monitor.stat_get("serve.preempted")),
+        "backpressure_waits":
+            int(monitor.stat_get("serve.backpressure_waits")),
+        "pool_block_size": block_size, "prefill_buckets": buckets,
+        "prompt_lens": [len(p) for p in prompts], "new_tokens": news,
+        "forced_logits_err": {k: float(f"{v:.3g}")
+                              for k, v in forced.items()},
+        "logits_tol": LOGITS_TOL,
+        "tokens_agreeing_with_generate_before_first_flip": agree,
+    }
+
+
+# --------------------------------------------------------------------------
+# kernels: each Pallas kernel against its jnp reference
+# --------------------------------------------------------------------------
+
+def _f32(*xs):
+    return [x.astype(jnp.float32) for x in xs]
+
+
+def _check_flash(sizes, causal):
+    from paddle_tpu.nn import functional as F
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    b, h, s, d = sizes.flash_shape
+    ks = jax.random.split(jax.random.PRNGKey(SEED), 4)
+    q, k, v, w = (jax.random.normal(kk, (b, h, s, d), jnp.float32)
+                  .astype(DTYPE) for kk in ks)
+    bias = None
+    if not causal:  # padding mask: the tail quarter of row 0 is padding
+        live = np.ones((b, s), bool)
+        live[0, 3 * s // 4:] = False
+        bias = jnp.where(jnp.asarray(live), 0.0, -1e9).astype(jnp.float32)
+    scale = d ** -0.5
+
+    def kernel(q, k, v):
+        out = flash_attention(q, k, v, bias=bias, causal=causal)
+        return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32)), out
+
+    def ref(q, k, v):
+        mask = None if bias is None else bias[:, None, None, :]
+        out = F._sdpa.raw(q, k, v, mask, scale, causal)
+        return jnp.sum(out * w.astype(jnp.float32)), out
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        kernel, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    (_, out_r), grads_r = jax.jit(jax.value_and_grad(
+        ref, argnums=(0, 1, 2), has_aux=True))(*_f32(q, k, v))
+    errs = {"out": _rel_err(out, out_r)}
+    errs.update({n: _rel_err(g, r)
+                 for n, g, r in zip(("dq", "dk", "dv"), grads, grads_r)})
+    return errs
+
+
+def _check_fused_ce(sizes):
+    from paddle_tpu.nn import functional as F
+    from paddle_tpu.ops.pallas.fused_ce import fused_linear_cross_entropy
+
+    n, hidden, vocab = sizes.ce_shape
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 1), 4)
+    h = jax.random.normal(ks[0], (n, hidden), jnp.float32).astype(DTYPE)
+    w = (0.05 * jax.random.normal(ks[1], (vocab, hidden), jnp.float32)) \
+        .astype(DTYPE)
+    bvec = (0.1 * jax.random.normal(ks[2], (vocab,), jnp.float32)) \
+        .astype(DTYPE)
+    y = jax.random.randint(ks[3], (n,), 0, vocab, jnp.int32)
+    y = jnp.where(jnp.arange(n) % 7 == 0, -100, y)  # MLM-style ignores
+
+    def kernel(h, w, bvec):
+        losses = fused_linear_cross_entropy(h, w, bvec, y)
+        return jnp.sum(losses), losses
+
+    def ref(h, w, bvec):
+        losses = F._ce_head_fallback.raw(h, w, bvec, y, -100)
+        return jnp.sum(losses), losses
+
+    (_, loss), grads = jax.jit(jax.value_and_grad(
+        kernel, argnums=(0, 1, 2), has_aux=True))(h, w, bvec)
+    (_, loss_r), grads_r = jax.jit(jax.value_and_grad(
+        ref, argnums=(0, 1, 2), has_aux=True))(*_f32(h, w, bvec))
+    errs = {"loss": _rel_err(loss, loss_r)}
+    errs.update({n_: _rel_err(g, r)
+                 for n_, g, r in zip(("dh", "dw", "db"), grads, grads_r)})
+    return errs
+
+
+def _check_decode(sizes):
+    from paddle_tpu.nn.layer.transformer import _static_cache_attention
+    from paddle_tpu.ops.pallas.decode_attention import decode_attention
+
+    b, h, L, d = sizes.decode_shape
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 2), 3)
+    q = jax.random.normal(ks[0], (b, h, 1, d), jnp.float32).astype(DTYPE)
+    kc, vc = (jax.random.normal(kk, (b, h, L, d), jnp.float32).astype(DTYPE)
+              for kk in ks[1:])
+    # ragged fills, from one token to the full cache
+    index = jnp.asarray(np.linspace(0, L - 1, b).astype(np.int32))
+    scale = d ** -0.5
+    out = jax.jit(lambda q, kc, vc, i: decode_attention(q, kc, vc, i))(
+        q, kc, vc, index)
+
+    def one(q1, k1, v1, i):  # the reference takes one scalar index
+        return _static_cache_attention(q1[None], k1[None], v1[None], i,
+                                       scale, 0.0, False)[0]
+
+    out_r = jax.jit(jax.vmap(one))(*_f32(q, kc, vc), index)
+    return {"out": _rel_err(out, out_r)}
+
+
+def _check_paged(sizes, chunk, block_size=None):
+    """The arena shape ServeLoop builds: [blocks + 1, h, block_size, d]
+    shared by serve_max_active slots of up to gpt.max_seq_len tokens, at
+    the block size ServeLoop's own picker gives (or the one named)."""
+    from paddle_tpu.nn.kv_pool import paged_attention_ref, pick_block_size
+    from paddle_tpu.ops.pallas.decode_attention import paged_decode_attention
+
+    cfg = sizes.gpt
+    b, h = sizes.serve_max_active, cfg.num_heads
+    d = cfg.hidden_size // h
+    if block_size is None:
+        block_size = pick_block_size(cfg.max_seq_len, h, d,
+                                     dtype=DTYPE)
+    nb = cfg.max_seq_len // block_size       # block-table width
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 3), 3)
+    shape = (b * nb + 1, h, block_size, d)
+    ka, va = (jax.random.normal(kk, shape, jnp.float32).astype(DTYPE)
+              for kk in ks[:2])
+    q = jax.random.normal(ks[2], (b, h, chunk, d), jnp.float32).astype(DTYPE)
+    rng = np.random.RandomState(SEED + 3)
+    tables = (rng.permutation(b * nb) + 1).reshape(b, nb).astype(np.int32)
+    lengths = np.linspace(0, cfg.max_seq_len - chunk, b).astype(np.int32)
+    scale = d ** -0.5
+    out = jax.jit(lambda *a: paged_decode_attention(*a, scale))(
+        q, ka, va, jnp.asarray(tables), jnp.asarray(lengths))
+    out_r = jax.jit(lambda *a: paged_attention_ref(*a, scale))(
+        *_f32(q, ka, va), jnp.asarray(tables), jnp.asarray(lengths))
+    return {"out": _rel_err(out, out_r), "block_size": block_size}
+
+
+def kernel_checks(sizes):
+    """name -> thunk returning {tensor: normalized max error}."""
+    checks = {
+        "flash_causal": lambda: _check_flash(sizes, causal=True),
+        "flash_padding_bias": lambda: _check_flash(sizes, causal=False),
+        "fused_ce": lambda: _check_fused_ce(sizes),
+        "decode": lambda: _check_decode(sizes),
+    }
+    for chunk, block in sizes.paged_checks:
+        checks[f"paged_decode_s{chunk}_block{block or 'picked'}"] = \
+            lambda c=chunk, b=block: _check_paged(sizes, c, b)
+    return checks
+
+
+def _default_blocks(sizes):
+    """The static-heuristic block sizes the checks above run with: under
+    jit autotune.lookup takes its default unless the table has a measured
+    entry (printed per phase as autotuned_blocks)."""
+    from paddle_tpu.ops.pallas import fused_ce
+    from paddle_tpu.ops.pallas.flash_attention import _ceil_to, _pick_block
+    s, (n, _, vocab), cache_len = (sizes.flash_shape[2], sizes.ce_shape,
+                                   sizes.decode_shape[2])
+    v_pad = _ceil_to(vocab, 128)
+    return {"flash_bq_bk": [_pick_block(s), _pick_block(s)],
+            "fused_ce_bn_bv": [fused_ce._pick(n, 512),
+                               next(x for x in (512, 256, 128)
+                                    if v_pad % x == 0)],
+            "decode_bk": _pick_block(cache_len, 128)}
+
+
+def kernels_phase(sizes):
+    failures, errs = [], {}
+    for name, check in kernel_checks(sizes).items():
+        print(f"  kernels: START {name}", flush=True)
+        try:
+            got = check()
+        except Exception as e:  # report every kernel, then fail
+            traceback.print_exc()
+            failures.append(f"{name}: {type(e).__name__}: {str(e)[:300]}")
+            continue
+        block_size = got.pop("block_size", None)
+        errs[name] = {k: float(f"{v:.3g}") for k, v in got.items()}
+        if block_size is not None:
+            errs[name]["block_size"] = block_size
+        if not max(got.values()) <= KERNEL_TOL:
+            failures.append(f"{name}: err {errs[name]} > {KERNEL_TOL}")
+    return {"failures": failures, "errors_vs_jnp_reference": errs,
+            "tolerance": KERNEL_TOL, "default_blocks": _default_blocks(sizes),
+            "interpreted": jax.default_backend() != "tpu"}
+
+
+# --------------------------------------------------------------------------
+# multichip: the train path through fleet, sharded
+# --------------------------------------------------------------------------
+
+def multichip_phase(sizes):
+    """dp=4 and dp=2 x tp=2 meshes under the hapi sharded step, and fleet's
+    LocalSGD shard_map step, against a one-device run of the same global
+    batches (dropout off: the hardware bit generator is not
+    sharding-invariant)."""
+    from paddle_tpu import memory
+    from paddle_tpu.distributed import fleet
+    from paddle_tpu.distributed import mesh as mesh_mod
+
+    devices = jax.devices()
+    if len(devices) < 4:
+        return {"failures": [], "skip": f"SKIP ({len(devices)} device"
+                                        f"{'s' if len(devices) != 1 else ''})"}
+    failures, report = [], {"loss_tol": MULTICHIP_LOSS_TOL}
+    cfg = dataclasses.replace(
+        sizes.bert, num_hidden_layers=sizes.multichip_layers,
+        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0)
+    steps = sizes.multichip_steps
+
+    try:
+        mesh_mod.reset_mesh()
+        ref = _fit(_bert_model(cfg, sizes), sizes, steps)[0][-1]
+        report["one_chip_loss"] = round(ref, 4)
+
+        for tag, shape in (("dp4", {"dp": 4}),
+                           ("dp2_tp2", {"dp": 2, "tp": 2})):
+            print(f"  multichip: START {tag}", flush=True)
+            mesh_mod.reset_mesh()
+            mesh_mod.init_mesh(shape)  # what fleet.init declares
+            model = _bert_model(cfg, sizes)
+            losses, late = _fit(model, sizes, steps)
+            loss = losses[-1]
+            report[f"{tag}_loss"] = round(loss, 4)
+            if late or model._engine._train_fn._cache_size() != 1:
+                failures.append(
+                    f"{tag}: {late} backend compiles after step 1, "
+                    f"{model._engine._train_fn._cache_size()} step variants")
+            gap = abs(loss - ref) / abs(ref)
+            if not gap <= MULTICHIP_LOSS_TOL:
+                failures.append(f"{tag}: loss {loss:.4f} vs one chip "
+                                f"{ref:.4f} (gap {gap:.3g})")
+            params = [p._value for p in model.network.parameters()]
+            spread = min(len(v.sharding.device_set) for v in params)
+            if spread != 4:
+                failures.append(f"{tag}: a parameter lives on {spread} "
+                                "devices")
+            split = sum(not v.sharding.is_fully_replicated for v in params)
+            report[f"{tag}_sharded_params"] = split
+            if "tp" in shape and not split:
+                failures.append(f"{tag}: no parameter is tp-sharded")
+            # PJRT's bytes_in_use on the chip; live-array bytes on the CPU
+            in_use = [memory.memory_allocated(d) for d in devices[:4]]
+            report[f"{tag}_bytes_in_use"] = in_use
+            floor = sum(v.nbytes for v in params) // 8
+            if not all(b > floor for b in in_use):
+                failures.append(f"{tag}: a device holds under {floor} "
+                                f"bytes: {in_use}")
+
+        # fit's shard_map path (hapi _build_localsgd_fn)
+        print("  multichip: START localsgd_shard_map", flush=True)
+        mesh_mod.reset_mesh()
+        mesh_mod.init_mesh({"dp": 4})
+        strategy = fleet.DistributedStrategy()
+        strategy.localsgd = True
+        strategy.localsgd_configs = {"k_steps": 2}
+        # O2 through the strategy (its amp_configs default to pure bf16):
+        # that is what seats f32 master weights in the wrapped optimizer
+        strategy.amp = True
+        loss = _fit(_bert_model(cfg, sizes, strategy), sizes, 4)[0][-1]
+        report["localsgd_loss"] = round(loss, 4)
+        if not np.isfinite(loss):
+            failures.append("localsgd: non-finite loss")
+    finally:
+        mesh_mod.reset_mesh()
+    report["failures"] = failures
+    return report
+
+
+# --------------------------------------------------------------------------
+
+def main():
+    import paddle_tpu  # noqa: F401 — places the compile cache
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    print(f"jax={jax.__version__} platform={dev.platform} "
+          f"device_kind={dev.device_kind!r} device_count={device['count']}",
+          flush=True)
+    if dev.platform != "tpu":
+        print(f"chip_smoke: refusing to run on platform {dev.platform!r}: "
+              "this script proves the program on a TPU and has no CPU mode "
+              "(tests/test_chip_smoke.py runs the phases at toy size)",
+              file=sys.stderr)
+        return 2
+    print(f"compile_cache_dir={jax.config.jax_compilation_cache_dir}",
+          flush=True)
+    sizes = Sizes.full()
+    results = {name: run_phase(name, phase, sizes) for name, phase in (
+        ("train", train_phase), ("serve", serve_phase),
+        ("kernels", kernels_phase), ("multichip", multichip_phase))}
+    ok = all(r["ok"] for r in results.values())
+    if not ok:
+        print("chip_smoke: FAILED phases: "
+              + ", ".join(n for n, r in results.items() if not r["ok"]),
+              file=sys.stderr)
+        return 1
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    faulthandler.enable()  # a Mosaic SIGABRT leaves a Python traceback
+    sys.exit(main())

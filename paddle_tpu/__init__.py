@@ -19,6 +19,17 @@ import jax as _jax
 # the compute path prefers bf16 on the MXU (ops/linalg.py).
 _jax.config.update("jax_enable_x64", True)
 
+# Persistent compilation cache, placeable from outside: where
+# JAX_COMPILATION_CACHE_DIR is set JAX reads it itself and nothing here
+# names another directory; otherwise a fixed path beside the package (the
+# path is part of the cache key, so a directory that moves never hits).
+import os as _os
+
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update("jax_compilation_cache_dir", _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+        ".jax_cache"))
+
 from .core.dtype import (bfloat16, bool_, complex128, complex64, float16,  # noqa: F401
                          float32, float64, int16, int32, int64, int8, uint8)
 from .core.dtype import bool_ as bool  # noqa: F401,A001

@@ -214,12 +214,6 @@ define_flag("FLAGS_pallas_force_compile", False,
             "off-TPU: tools/hlo_evidence.py uses this to AOT-lower bench "
             "graphs for a TPU target on a dev box. Such programs lower "
             "and cost-analyze fine but only *run* on real TPU hardware")
-define_flag("FLAGS_pallas_strict", False,
-            "re-raise Pallas kernel failures instead of demoting to the "
-            "jnp fallback (kernel development; the default False keeps a "
-            "kernel crash from ever aborting a training/bench run — each "
-            "demotion bumps pallas.fallback.{kernel}.{reason} in "
-            "core/monitor)")
 
 # --- continuous-batching decode serving (inference/serving.py,
 # --- nn/kv_pool.py, ops/pallas/decode_attention.py paged kernel) --------

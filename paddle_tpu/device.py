@@ -92,4 +92,4 @@ def is_compiled_with_xpu() -> bool:
 
 
 def is_compiled_with_tpu() -> bool:
-    return True
+    return jax.default_backend() == "tpu"

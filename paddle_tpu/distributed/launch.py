@@ -3,8 +3,12 @@
 Analog of reference python/paddle/distributed/launch.py + utils.py
 (get_cluster :297, start_local_trainers :424 setting the PADDLE_* env
 contract and watching children). On TPU, one process per HOST (not per
-chip): jax's single-controller runtime drives all local chips, so
-single-host launches collapse to exec'ing the script with rank 0 env.
+chip): jax's single-controller runtime drives all local chips through the
+mesh, so single-host launches collapse to exec'ing the script with rank 0
+env. A chip belongs to one process at a time and nothing here hands a
+rank its own chip: `nproc_per_node > 1` is for CPU tests and for
+describing multi-host jobs, never for splitting one host's chips — on a
+four-chip host every child would claim every chip.
 """
 from __future__ import annotations
 
@@ -26,7 +30,6 @@ def _build_env(rank, nranks, endpoints):
         "PADDLE_TRAINER_ENDPOINTS": ",".join(endpoints),
         "PADDLE_CURRENT_ENDPOINT": endpoints[rank],
         "PADDLE_RANK_IN_NODE": str(rank),
-        "FLAGS_selected_tpus": str(rank),
     })
     return env
 

@@ -91,25 +91,12 @@ def axis_tiers(mesh_or_shape) -> Dict[str, dict]:
 
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None, check=False):
-    """Version-portable `shard_map`: `jax.shard_map` where it exists
-    (newer jax; `check_vma=`), `jax.experimental.shard_map.shard_map`
-    otherwise (`check_rep=`). The replication check defaults OFF — the
-    pipeline/MoE SPMD programs here intermix psum/ppermute/all_to_all in
-    ways the checker's older releases reject spuriously."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        try:
-            return fn(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=check)
-        except TypeError:
-            return fn(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check)
-    except TypeError:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """`jax.shard_map` with the varying-manual-axes check defaulting OFF —
+    the pipeline/MoE SPMD programs here intermix psum/ppermute/all_to_all
+    in ways the checker rejects spuriously."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
+
 
 _lock = threading.Lock()
 _meshes: Dict[str, Mesh] = {}
@@ -255,6 +242,13 @@ def auto_mesh() -> Mesh:
     return m
 
 
+def _axis_env():
+    """The current trace context's bound mesh axes (jax keeps this
+    accessor private; jax 0.9.0 has no public equivalent)."""
+    from jax._src.core import get_axis_env
+    return get_axis_env()
+
+
 def mesh_axis_size(axis: str, name: str = None) -> int:
     """Size of a mesh axis. Inside an SPMD region (shard_map trace)
     the BOUND axis size is authoritative — the registry may hold a
@@ -263,65 +257,20 @@ def mesh_axis_size(axis: str, name: str = None) -> int:
     reading the registry there silently degraded the pipeline to a
     single stage). Falls back to the registered mesh when the axis is
     not bound in the current trace."""
-    try:
-        from jax._src.core import get_axis_env
-        env = get_axis_env()
-        if axis in tuple(env.axis_names()):
-            return int(env.axis_size(axis))
-    except Exception:
-        pass  # private accessor moved / axis unbound: registry fallback
+    env = _axis_env()
+    if axis in env.axis_names():
+        return int(env.axis_size(axis))
     m = get_mesh(name)
     if m is None or axis not in m.axis_names:
         return 1
     return m.shape[axis]
 
 
-def _axis_env_names():
-    """Bound mesh-axis names of the current trace context, via the
-    private jax accessor (the fast path; raises ImportError/AttributeError
-    on jax versions that moved it — callers must fall back, NOT swallow)."""
-    from jax._src.core import get_axis_env
-    return tuple(get_axis_env().axis_names())
-
-
-def _axis_bound_probe(axis: str) -> bool:
-    """Public-API fallback: `lax.psum(axis)` is legal exactly when `axis`
-    is bound here, and `jax.eval_shape` asks that question abstractly
-    (no op enters the enclosing trace). An unbound name raises NameError;
-    anything else jax raises for a malformed probe also means 'not a
-    bound SPMD axis'."""
-    import jax.numpy as jnp
-    try:
-        jax.eval_shape(lambda: jax.lax.psum(jnp.zeros((), jnp.float32),
-                                            axis))
-        return True
-    except NameError:
-        return False
-    except Exception:
-        return False
-
-
 def in_spmd_region(axis: str = None) -> bool:
-    """True when tracing inside shard_map where `axis` is bound —
-    i.e. lax.psum(axis) is legal here.
-
-    Prefers the private jax axis-env accessor; when a jax version moves
-    it, degrades to a public-API probe (eval_shape over lax.psum) that
-    still answers correctly for named axes. With axis=None the fallback
-    probes every registered mesh's axes (plus the conventional five) —
-    a correct answer for any axis this framework could have bound."""
-    try:
-        names = _axis_env_names()
-    except (ImportError, AttributeError):
-        if axis is not None:
-            return _axis_bound_probe(axis)
-        with _lock:
-            candidates = {a for m in _meshes.values() for a in m.axis_names}
-        candidates |= {"dp", "tp", "pp", "sp", "ep"}
-        return any(_axis_bound_probe(a) for a in sorted(candidates))
-    if axis is None:
-        return bool(names)
-    return axis in names
+    """True when tracing inside shard_map where `axis` (or, with
+    axis=None, any axis) is bound — i.e. lax.psum(axis) is legal here."""
+    names = _axis_env().axis_names()
+    return bool(names) if axis is None else axis in names
 
 
 def named_sharding(spec: PartitionSpec, name: str = None) -> NamedSharding:

@@ -228,12 +228,14 @@ class _CompiledEngine:
         return step
 
     def _build_train_fn(self, example_in=(), example_lab=()):
+        """(jitted step, mesh layout of (params, buffers, slots) or None
+        when no mesh is active)."""
         step = self._make_train_step()
         amp_cfg = self.model._amp_configs
         scaler = amp_cfg.get("scaler") if amp_cfg else None
         plan = self._sharding_plan()
         if plan is None:
-            return jax.jit(step, donate_argnums=(0, 1, 2))
+            return jax.jit(step, donate_argnums=(0, 1, 2)), None
         # distributed: partition the whole step via GSPMD
         opt_state = self.model._optimizer._slots
         slot_sh = {k: {s: plan["param"][k] for s in opt_state.get(k, {})}
@@ -261,12 +263,16 @@ class _CompiledEngine:
                 return plan["batch"]
             return jax.tree_util.tree_map(leaf_sh, tuple(example))
 
+        # new params/slots come back in the layout they go in with: left
+        # to the partitioner, a tp mesh may return e.g. the vocab bias
+        # P('tp'), which the next step's in_shardings then refuse
         return jax.jit(
             step,
             in_shardings=(plan["param"], buffers_sh, slot_sh, plan["repl"],
                           plan["repl"], plan["repl"], data_sh(example_in),
                           data_sh(example_lab), scale_sh),
-            donate_argnums=(0, 1, 2))
+            out_shardings=(None, None, None, plan["param"], slot_sh, None),
+            donate_argnums=(0, 1, 2)), (plan["param"], buffers_sh, slot_sh)
 
     # ---- LocalSGD (strategy.localsgd / adaptive_localsgd) ------------------
     def _localsgd_cfg(self):
@@ -512,7 +518,14 @@ class _CompiledEngine:
             if self._train_fn is None:
                 from .. import profiler as _prof
                 with _prof.RecordEvent("hapi/build_train_fn"):
-                    self._train_fn = self._build_train_fn(raw_in, raw_lab)
+                    self._train_fn, state_sh = self._build_train_fn(
+                        raw_in, raw_lab)
+                if state_sh is not None:
+                    # seat the host-built state in its mesh layout once:
+                    # step 1 then has step 2's signature, and the step
+                    # compiles once instead of twice
+                    params, buffers, slots = jax.device_put(
+                        (params, buffers, slots), state_sh)
             amp_cfg = self.model._amp_configs
             scaler = amp_cfg.get("scaler") if amp_cfg else None
             scale_state = scaler.scale_state() if scaler is not None else {}

@@ -93,7 +93,9 @@ class ServeConfig:
     eos_token_id: int = None   # default; per-request override wins
     max_inflight: int = 0      # decode pipeline depth (0 = executor flag)
 
-    def resolve(self, net):
+    def resolve(self, net, dtype):
+        """`dtype` is the arena dtype: the auto block size is measured
+        (and keyed) with it."""
         from ..core import flags as _flags
         cfg = net.config
         max_active = int(self.max_active
@@ -107,7 +109,8 @@ class ServeConfig:
         else:
             from ..nn.kv_pool import pick_block_size
             block_size = pick_block_size(
-                max_seq, cfg.num_heads, cfg.hidden_size // cfg.num_heads)
+                max_seq, cfg.num_heads, cfg.hidden_size // cfg.num_heads,
+                dtype=dtype)
         max_inflight = int(self.max_inflight
                            or _flags.flag("FLAGS_executor_max_inflight"))
         return max_active, kv_blocks, block_size, max_seq, \
@@ -299,17 +302,17 @@ class ServeLoop:
         self.config = config or ServeConfig(**overrides)
         if overrides and config is not None:
             raise ValueError("pass either a ServeConfig or kwargs")
+        self._params, self._buffers = net.functional_state()
+        self._dtype = jnp.bfloat16 if any(
+            v.dtype == jnp.bfloat16 for v in self._params.values()) \
+            else jnp.float32
         (self._A, n_blocks, self._bs, self._cap,
-         self._max_inflight) = self.config.resolve(net)
+         self._max_inflight) = self.config.resolve(net, self._dtype)
         cfg = net.config
         if net.training:
             net.eval()  # decode kernels are eval-only; serving never drops
         self._pool = KVBlockPool(n_blocks, self._bs)
         self._MB = -(-self._cap // self._bs)     # block-table width
-        self._params, self._buffers = net.functional_state()
-        self._dtype = jnp.bfloat16 if any(
-            v.dtype == jnp.bfloat16 for v in self._params.values()) \
-            else jnp.float32
         heads, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
         self._arenas = self._pool.arenas(cfg.num_layers, heads, hd,
                                          self._dtype)

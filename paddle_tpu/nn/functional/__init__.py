@@ -123,10 +123,11 @@ def _flash_eligible(query, key, value, attn_mask):
         return gate_reject("flash_attention", "flag_off")
     if not _pallas_backend_ok("FLAGS_flash_attention_interpret"):
         return gate_reject("flash_attention", "backend")
-    # profitability dispatch (measured on v5e): at short seq XLA's fused
-    # attention wins — per-grid-step overhead dominates the kernel; the
-    # kernel's O(s) memory + blockwise matmuls win in the long-context
-    # regime. FLAGS_flash_min_seq=0 forces the kernel on.
+    # profitability dispatch (a heuristic: not measured on current code):
+    # at short seq XLA's fused attention is expected to win — per-grid-
+    # step overhead dominates the kernel; the kernel's O(s) memory +
+    # blockwise matmuls pay in the long-context regime.
+    # FLAGS_flash_min_seq=0 forces the kernel on.
     min_seq = int(_flags.flag("FLAGS_flash_min_seq"))
     if min_seq and key.shape[-2] < min_seq:
         return gate_reject("flash_attention", "min_seq")
@@ -148,17 +149,16 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  training=True):
     """Fused attention core. On TPU this routes through the Pallas
     flash-attention kernel (paddle_tpu.ops.pallas.flash_attention): O(s)
-    attention memory, blockwise online softmax on the MXU. The jnp fallback
-    (_sdpa) covers general mask shapes, non-TPU backends (where XLA fuses
-    the softmax chain), and any kernel failure — run_guarded demotes a
-    crashed kernel to _sdpa instead of aborting the step."""
+    attention memory, blockwise online softmax on the MXU. The jnp
+    reference (_sdpa) covers what the gate rejects: general mask shapes,
+    short sequences, non-TPU backends (where XLA fuses the softmax
+    chain). An admitted kernel that fails raises."""
     sc = scale if scale is not None else query.shape[-1] ** -0.5
     if _flash_eligible(query, key, value, attn_mask):
         from ...ops.pallas import run_guarded
         out = run_guarded(
             "flash_attention",
-            lambda: _flash_sdpa(query, key, value, attn_mask, sc, is_causal),
-            lambda: _sdpa(query, key, value, attn_mask, sc, is_causal))
+            lambda: _flash_sdpa(query, key, value, attn_mask, sc, is_causal))
     else:
         out = _sdpa(query, key, value, attn_mask, sc, is_causal)
     if dropout_p > 0.0 and training:
@@ -213,9 +213,7 @@ def fused_linear_cross_entropy(hidden, weight, bias=None, labels=None,
     if use_kernel:
         losses = run_guarded(
             "fused_ce",
-            lambda: _fused_ce_op(h2, weight, bias, y, int(ignore_index)),
-            lambda: _ce_head_fallback(h2, weight, bias, y,
-                                      int(ignore_index)))
+            lambda: _fused_ce_op(h2, weight, bias, y, int(ignore_index)))
     else:
         losses = _ce_head_fallback(h2, weight, bias, y, int(ignore_index))
     if reduction == "none":

@@ -22,9 +22,9 @@ fix, TPU-native:
 Attention over the paged layout dispatches to the block-table Pallas
 kernel (ops/pallas/decode_attention.paged_decode_attention — lengths AND
 block tables ride the scalar-prefetch path, so per-step KV bytes scale
-with live blocks, not max_seq_len) behind the same gate + run_guarded
-discipline as every other kernel; `paged_attention_ref` is the jnp
-fallback and the parity oracle.
+with live blocks, not max_seq_len) behind the same counted gate as every
+other kernel; `paged_attention_ref` is the jnp path the gate rejects onto
+and the parity oracle.
 """
 from __future__ import annotations
 
@@ -234,17 +234,14 @@ def _paged_kernel_eligible(q, k_arena, training):
 
 def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,
                     training=False):
-    """Gated + crash-guarded paged attention: the Pallas block-table
-    kernel when eligible, `paged_attention_ref` otherwise (and on any
-    kernel failure, via ops/pallas.run_guarded)."""
+    """Gated paged attention: the Pallas block-table kernel when
+    eligible, `paged_attention_ref` when the gate rejects."""
     if _paged_kernel_eligible(q, k_arena, training):
         from ..ops.pallas import run_guarded
         from ..ops.pallas.decode_attention import paged_decode_attention
         return run_guarded(
             "paged_decode_attention",
             lambda: paged_decode_attention(q, k_arena, v_arena,
-                                           block_tables, lengths, scale),
-            lambda: paged_attention_ref(q, k_arena, v_arena, block_tables,
-                                        lengths, scale))
+                                           block_tables, lengths, scale))
     return paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
                                scale)

@@ -202,10 +202,7 @@ class MultiHeadAttention(Layer):
                 from ...ops.pallas import decode_attention, run_guarded
                 out = run_guarded(
                     "decode_attention",
-                    lambda: decode_attention(qv, kc, vc, idx, scale),
-                    lambda: _static_cache_attention(
-                        qv, kc, vc, idx, scale, self.dropout,
-                        self.training))
+                    lambda: decode_attention(qv, kc, vc, idx, scale))
             else:
                 out = _static_cache_attention(
                     qv, kc, vc, idx, scale, self.dropout, self.training)

@@ -13,20 +13,18 @@ hand-written *_grad kernels).
 Kernels run compiled on TPU and in Pallas interpreter mode elsewhere, so the
 same code paths are testable on the CPU mesh (tests/conftest.py).
 
-Every dispatch site goes through `run_guarded`: a kernel that fails to
-trace/compile/run demotes to its jnp fallback and bumps
-`pallas.fallback.{kernel}.{reason}` in core/monitor instead of aborting the
-step — a Mosaic crash must never poison a bench or training run (the
-BENCH_r03 failure mode, where both kernels crashed out and the whole run
-silently measured the fallback paths). Eligibility-gate rejections bump
-`pallas.gate_reject.{kernel}.{reason}` so bench output can report *why* a
-kernel didn't engage; engagements bump `pallas.hit.{kernel}`. The counters
-count call-site engagements (once per trace under jit), not per-step
-executions.
+Every dispatch site goes through `run_guarded`, which counts the
+engagement (`pallas.hit.{kernel}`) and leaves a span; a kernel whose gate
+admitted the call runs or raises — a demotion to the jnp reference would
+let a run measure a different program than it meant to and still exit 0.
+The eligibility gates are the one way onto the
+reference path: each rejection bumps `pallas.gate_reject.{kernel}.{reason}`
+so output can report *why* a kernel didn't engage. The counters count
+call-site engagements (once per trace under jit), not per-step executions.
+Under jit a Mosaic refusal surfaces when the OUTER step compiles, outside
+this function, as that step's error.
 """
 from __future__ import annotations
-
-import warnings
 
 from .flash_attention import flash_attention  # noqa: F401
 from .decode_attention import decode_attention  # noqa: F401
@@ -44,35 +42,19 @@ def gate_reject(kernel: str, reason: str):
     return False
 
 
-def run_guarded(kernel: str, thunk, fallback):
-    """Run a Pallas kernel thunk; on ANY failure demote to the jnp
-    fallback thunk, bumping pallas.fallback.{kernel}.{exception-type}.
-    FLAGS_pallas_strict re-raises instead (kernel development / tests
-    that assert on the error itself). Every dispatch leaves a span with
-    its outcome (hit / fallback+reason) in the trace ring, so a fallback
-    storm shows up in a flight-recorder dump with per-call timing, not
-    just a final counter value."""
-    from ...core import flags as _flags
+def run_guarded(kernel: str, thunk):
+    """Run a Pallas kernel thunk, bumping pallas.hit.{kernel}; whatever it
+    raises propagates. Every dispatch leaves a span with its outcome
+    (hit / error+reason) in the trace ring."""
     from ...core import monitor, trace
     sp = trace.begin(f"pallas/{kernel}")
     try:
         out = thunk()
     except Exception as e:
-        strict = _flags.flag("FLAGS_pallas_strict")
-        # strict mode re-raises without running the fallback — the span
-        # must not claim a fallback the counters won't show
-        sp.attrs["outcome"] = "error" if strict else "fallback"
+        sp.attrs["outcome"] = "error"
         sp.attrs["reason"] = type(e).__name__
         trace.end(sp)
-        if strict:
-            raise
-        monitor.stat_add(f"pallas.fallback.{kernel}.{type(e).__name__}")
-        warnings.warn(
-            f"Pallas kernel '{kernel}' failed ({type(e).__name__}: {e}); "
-            "demoted to the jnp fallback for this call. See "
-            "monitor.stats('pallas.') and docs/pallas_kernels.md.",
-            RuntimeWarning, stacklevel=2)
-        return fallback()
+        raise
     sp.attrs["outcome"] = "hit"
     trace.end(sp)
     monitor.stat_add(f"pallas.hit.{kernel}")

@@ -18,7 +18,8 @@ Resolution order at a call site (all kernels follow it):
    fleet job pays the measurement once per shape family, not once per
    process;
 4. measure-and-record — only when measuring is meaningful (compiled TPU
-   backend, or `FLAGS_pallas_autotune_force` for interpreter-mode tests);
+   backend, or `FLAGS_pallas_autotune_force` for interpreter-mode tests)
+   and the call is eager: under a trace the heuristic default is taken;
 5. otherwise the caller's heuristic default (what `_pick_block` chose
    before this module existed).
 
@@ -138,6 +139,16 @@ def _should_measure():
     return jax.default_backend() == "tpu"
 
 
+def _tracing():
+    """Under jit/scan every jnp value is a tracer — including the
+    "concrete" zeros a measure() builds — so timing there would time
+    nested tracing (block_until_ready on a tracer is a no-op) and pick
+    block sizes by trace-time noise."""
+    import jax
+    import jax.numpy as jnp
+    return isinstance(jnp.zeros(()), jax.core.Tracer)
+
+
 def lookup(kernel, shape_key, dtype, candidates, measure, default):
     """Resolve block params for one kernel call.
 
@@ -159,7 +170,8 @@ def lookup(kernel, shape_key, dtype, candidates, measure, default):
         if hit in [tuple(c) for c in candidates]:
             return hit
         return default
-    if not _should_measure() or measure is None or len(candidates) <= 1:
+    if not _should_measure() or measure is None or len(candidates) <= 1 \
+            or _tracing():
         return default
     best, best_t = None, None
     for cand in candidates:

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from .flash_attention import (NEG_INF, _ceil_to, _cparams, _interpret,
+from .flash_attention import (NEG_INF, _Z, _ceil_to, _cparams, _interpret,
                               _pick_block, _vmem)
 
 __all__ = ["decode_attention", "supported",
@@ -113,7 +113,7 @@ def _call(q, kc, vc, lengths, scale, bk):
     nk = kc.shape[2] // bk
 
     def q_map(ib, ih, ik, len_ref):
-        return (ib, ih, 0, 0)
+        return (ib, ih, _Z, _Z)
 
     def kv_map(ib, ih, ik, len_ref):
         # clamp to the last live block: a revisited block index skips the
@@ -121,7 +121,7 @@ def _call(q, kc, vc, lengths, scale, bk):
         # (np.int32 scalars: see _decode_attn_kernel)
         last = jnp.maximum(len_ref[ib] - np.int32(1),
                            np.int32(0)) // np.int32(bk)
-        return (ib, ih, jnp.minimum(ik, last), 0)
+        return (ib, ih, jnp.minimum(ik, last), _Z)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -257,7 +257,7 @@ def _paged_call(q, k_arena, v_arena, block_tables, lengths, scale):
     nb = block_tables.shape[1]
 
     def q_map(ib, ih, ik, len_ref, bt_ref):
-        return (ib, ih, 0, 0)
+        return (ib, ih, _Z, _Z)
 
     def kv_map(ib, ih, ik, len_ref, bt_ref):
         # gather ONLY live physical blocks: past the last live logical
@@ -269,7 +269,7 @@ def _paged_call(q, k_arena, v_arena, block_tables, lengths, scale):
             jnp.maximum(len_ref[ib] - np.int32(1),
                         np.int32(0)) // np.int32(bs),
             np.int32(nb - 1))
-        return (bt_ref[ib, jnp.minimum(ik, last)], ih, 0, 0)
+        return (bt_ref[ib, jnp.minimum(ik, last)], ih, _Z, _Z)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

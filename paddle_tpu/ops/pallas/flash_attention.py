@@ -41,8 +41,8 @@ def _pick_block(s: int, target: int = None, flag: str = None):
     """Largest block size <= target that divides s, no smaller than 8 (the
     f32 sublane tile); None means "not kernel-friendly, use the jnp path".
     target=None: FLAGS_flash_block_* override, else auto — 256 once the
-    sequence is long enough to amortize (measured on v5e: s=2048 fwd+dq
-    3.70ms at blk 256 vs 5.41ms at blk 128)."""
+    sequence is long enough to amortize the grid (a heuristic: not
+    measured on current code)."""
     if target is None:
         cfg = 0
         if flag is not None:
@@ -215,16 +215,9 @@ def _cparams(*semantics):
     """Mosaic grid semantics: 'parallel' dims can be reordered/pipelined by
     the compiler, 'arbitrary' marks the sequential reduction dim (the
     revisiting accumulator pattern). Without this Mosaic assumes every dim
-    is arbitrary and cannot overlap the next block's DMA with compute.
-
-    The params class was renamed across jax releases (TPUCompilerParams ->
-    CompilerParams); resolve whichever this build ships — the old
-    single-name lookup was itself a Pallas crash mode (AttributeError at
-    every kernel call on mismatched jax)."""
+    is arbitrary and cannot overlap the next block's DMA with compute."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=semantics)
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 # --------------------------------------------------------------------------
@@ -532,16 +525,20 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
     if scale is None:
         scale = d ** -0.5
     off = sk - sq  # causal alignment of the ORIGINAL shapes
-    sq_p, sk_p = _ceil_to(sq, 8), _ceil_to(sk, 8)
+    # padded key columns must never win the softmax: mask via bias —
+    # except under causal with no bias, where the original-shape
+    # diagonal (off = sk - sq) already caps every real row at
+    # col <= sk-1, so manufacturing a bias would only add the
+    # per-head bias materialization and kernel loads for nothing
+    with_bias = bias is not None or (not causal and sk % 8 != 0)
+    # the bias rides in (1, 1, bk) blocks whose lane dim Mosaic wants
+    # 128-divisible, so with a bias keys pad to the lane tile, not just
+    # the 8-row sublane tile
+    sq_p, sk_p = _ceil_to(sq, 8), _ceil_to(sk, 128 if with_bias else 8)
     if bias is not None:
         bias = bias.astype(jnp.float32)
     if sk_p != sk:
-        # padded key columns must never win the softmax: mask via bias —
-        # except under causal with no bias, where the original-shape
-        # diagonal (off = sk - sq) already caps every real row at
-        # col <= sk-1, so manufacturing a bias would only add the
-        # per-head bias materialization and kernel loads for nothing
-        if bias is not None or not causal:
+        if with_bias:
             if bias is None:
                 bias = jnp.zeros((b, sk), jnp.float32)
             bias = jnp.pad(bias, ((0, 0), (0, sk_p - sk)),

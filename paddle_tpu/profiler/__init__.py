@@ -208,7 +208,4 @@ def cost_analysis(jitted_fn, *args, **kwargs):
     passes (details/op_handle_base events + monitor StatRegistry)."""
     lowered = jitted_fn.lower(*args, **kwargs)
     compiled = lowered.compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns one dict per device
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    return dict(compiled.cost_analysis() or {})

@@ -15,12 +15,9 @@ os.environ.setdefault("PADDLE_TPU_VERIFY_PASSES", "1")
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    # newer jax spells the host-device spoof as a config option; older
-    # builds only understand the XLA_FLAGS form set above
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    pass
+# this process takes the config option; the XLA_FLAGS form above is what
+# child processes started by tests inherit
+jax.config.update("jax_num_cpu_devices", 8)
 
 import pytest  # noqa: E402
 

@@ -389,7 +389,6 @@ def test_meshguard_with_mesh_still_works():
 
 def _probe_spmd_region():
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh
     import jax.numpy as jnp
     mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
@@ -401,30 +400,14 @@ def _probe_spmd_region():
         seen["any"] = mesh_mod.in_spmd_region()
         return jnp.zeros(())
 
-    jax.jit(shard_map(f, mesh=mesh, in_specs=(), out_specs=P()))()
+    jax.jit(mesh_mod.shard_map(f, mesh=mesh, in_specs=(), out_specs=P()))()
     return seen
 
 
-def test_in_spmd_region_private_path():
+def test_in_spmd_region():
     assert not mesh_mod.in_spmd_region("dp")  # outside any shard_map
     seen = _probe_spmd_region()
     assert seen == {"dp": True, "zz": False, "any": True}
-
-
-def test_in_spmd_region_public_fallback(monkeypatch):
-    """When the private jax accessor vanishes (version drift), the
-    public-API probe must still answer CORRECTLY — not silently False."""
-    def gone():
-        raise ImportError("jax moved the private axis env")
-
-    monkeypatch.setattr(mesh_mod, "_axis_env_names", gone)
-    mesh_mod.init_mesh({"dp": 1}, name="fb_test")  # feeds axis=None probe
-    try:
-        assert not mesh_mod.in_spmd_region("dp")
-        seen = _probe_spmd_region()
-        assert seen == {"dp": True, "zz": False, "any": True}
-    finally:
-        mesh_mod.reset_mesh("fb_test")
 
 
 def test_pipeline_schedule_collectives():

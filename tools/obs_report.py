@@ -167,7 +167,9 @@ def ps_health(metrics) -> str:
 
 def pallas_rates(metrics) -> str:
     """Per-kernel engagement: pallas.hit.K / pallas.fallback.K.reason /
-    pallas.gate_reject.K.reason -> hit/fallback/reject counts + rate."""
+    pallas.gate_reject.K.reason -> hit/fallback/reject counts + rate.
+    (Nothing emits pallas.fallback.* any more; dumps recorded before the
+    demotion was removed still carry it.)"""
     per = defaultdict(lambda: {"hit": 0.0, "fallback": 0.0,
                                "gate_reject": 0.0, "reasons": []})
     for name, v in metrics.get("values", {}).items():
