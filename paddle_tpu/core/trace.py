@@ -25,12 +25,25 @@ the same reason — PAPERS.md):
   full capture buffer while `start()`ed, exported with
   `export_chrome_trace` (chrome://tracing / Perfetto).
 
-This absorbs profiler.RecordEvent: RecordEvent is now a thin span wrapper
-and finished spans are mirrored into the profiler's event table while the
-host profiler is enabled, so `profiler.summary()` covers every span site
-for free. Span overhead is two perf_counter calls and a deque append —
-cheap enough to leave on at per-step granularity (NOT per-op; per-op
-annotations stay behind FLAGS_enable_profiler, as before).
+- **One clock with the device**: `begin()` also opens a
+  `jax.profiler.TraceAnnotation` carrying the span's name and scalar
+  attributes, and `end()` closes it. While a `jax.profiler` session runs
+  (`profiler.xplane_trace(dir)`, `ProfilerCallback(xplane_dir=...)`, the
+  benchmark's `--trace 1`) every span therefore also lands in the
+  xplane's `/host:CPU` plane, on the line of the thread that opened it
+  and on the profiler's clock, where it can be laid against the device's
+  idle gaps (benchmark/lib/host_spans.py reads them back). With no
+  session the annotation is a no-op (about a microsecond a span).
+
+This is the one span API of the package: `fit`'s steps (`fit/*`,
+`hapi/build_train_fn`), the serve scheduler's beats (`serve/*`), the
+loader (`io/*`) and the pipeline runner all use `trace.span`.
+profiler.RecordEvent is a thin span wrapper kept for the per-op sites
+that stay behind FLAGS_enable_profiler; finished spans are mirrored into
+the profiler's event table while the host profiler is enabled, so
+`profiler.summary()` covers every span site for free. Span overhead is
+two perf_counter calls, the annotation and a deque append — cheap enough
+to leave on at per-step granularity (NOT per-op).
 """
 from __future__ import annotations
 
@@ -41,6 +54,8 @@ import os
 import threading
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from . import flags as _flags
 
@@ -126,7 +141,7 @@ class Span:
     """
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "t0", "t1",
-                 "tid", "thread", "attrs", "flows")
+                 "tid", "thread", "attrs", "flows", "_annotation")
 
     def __init__(self, name, trace_id, parent_id, attrs):
         self.name = name
@@ -138,6 +153,14 @@ class Span:
         th = threading.current_thread()
         self.tid = th.ident
         self.thread = th.name
+        # the span on the profiler's timeline: attributes that fit an
+        # event stat (numbers, short strings) ride along, the rest stay
+        # in the ring only
+        self._annotation = _TraceAnnotation(name, **{
+            k: v for k, v in self.attrs.items()
+            if isinstance(v, (int, float))
+            or (isinstance(v, str) and len(v) <= 64)})
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         self.t1 = None
 
@@ -222,6 +245,8 @@ def end(sp: Span, discard: bool = False):
     if sp is None or sp.t1 is not None:
         return
     sp.t1 = time.perf_counter()
+    sp._annotation.__exit__(None, None, None)
+    sp._annotation = None
     st = _stack()
     if st and st[-1] is sp:
         st.pop()

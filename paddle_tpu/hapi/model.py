@@ -34,6 +34,9 @@ from .callbacks import config_callbacks
 __all__ = ["Model", "InputSpec"]
 
 
+_NO_BATCH = object()   # what next() gives once the loader is exhausted
+
+
 class _LazyLoss:
     """`logs["loss"]` placeholder in the async fit loop
     (docs/async_executor.md): materializes the EXACT loss of its own step
@@ -516,8 +519,9 @@ class _CompiledEngine:
         if not accumulating:
             # fast path: forward+backward+update fused in one XLA program
             if self._train_fn is None:
-                from .. import profiler as _prof
-                with _prof.RecordEvent("hapi/build_train_fn"):
+                from ..core import trace as _trace
+                # a rebuild (and with it a recompile) shows as this span
+                with _trace.span("hapi/build_train_fn"):
                     self._train_fn, state_sh = self._build_train_fn(
                         raw_in, raw_lab)
                 if state_sh is not None:
@@ -530,16 +534,12 @@ class _CompiledEngine:
             scaler = amp_cfg.get("scaler") if amp_cfg else None
             scale_state = scaler.scale_state() if scaler is not None else {}
             opt._step_count += 1
-            from .. import profiler as _prof
-            from ..core import monitor as _monitor
-            _monitor.stat_add("hapi/train_steps")
-            with _prof.RecordEvent("hapi/train_step"):
-                lval, outs, new_bufs, new_params, new_slots, scale_state = \
-                    self._train_fn(
-                        params, buffers, slots,
-                        jnp.asarray(opt.get_lr(), jnp.float32),
-                        jnp.asarray(opt._step_count, jnp.int32),
-                        _rng.next_key(), raw_in, raw_lab, scale_state)
+            lval, outs, new_bufs, new_params, new_slots, scale_state = \
+                self._train_fn(
+                    params, buffers, slots,
+                    jnp.asarray(opt.get_lr(), jnp.float32),
+                    jnp.asarray(opt._step_count, jnp.int32),
+                    _rng.next_key(), raw_in, raw_lab, scale_state)
             if scaler is not None:
                 scaler.load_scale_state(scale_state)
             from ..core import flags as _flags
@@ -986,8 +986,10 @@ class Model:
 
     def _run_one_epoch(self, loader, cbks, mode, num_iters=None, accum=1,
                        epoch=0, skip_steps=0, step_offset=0, log_freq=10):
+        import itertools
         from collections import deque
         from ..core import flags as _flags
+        from ..core import trace as _trace
         for m in self._metrics:
             m.reset()
         logs = {}
@@ -1012,53 +1014,77 @@ class Model:
             while window and ((through is not None
                                and window[0].step <= through)
                               or len(window) > inflight):
-                window.popleft()._materialize()
+                # host blocked on the device
+                with _trace.span("fit/drain", step=window[0].step):
+                    window.popleft()._materialize()
 
         from ..distributed import elastic as _elastic
-        for step, batch in enumerate(loader, start=step_offset):
-            if step < skip_steps:
-                continue  # resumed mid-epoch: fast-forward consumed batches
-            cbks.on_batch_begin(mode, step, logs)
-            inputs, labels = self._split_batch(batch)
-            update = accum <= 1 or (step + 1) % accum == 0
-            lval, outs = self._engine.train_batch(inputs, labels,
-                                                  update=update)
-            if self._lr_sched_step_on_batch():
-                self._optimizer._learning_rate.step()
-            if async_loop:
-                lazy = _LazyLoss(step, lval, drain)
-                window.append(lazy)
-                if (step + 1) % max(log_freq, 1) == 0:
-                    drain(through=step)  # boundary: window fully retired
+        # One `fit/step` span per iteration; its children are the step's
+        # host phases: fit/next_batch (the loader, pulled by hand so the
+        # pull is inside the span), fit/callbacks (begin, then end),
+        # fit/dispatch (the engine's train_batch) and fit/drain (above).
+        batches = iter(loader)
+        for step in itertools.count(step_offset):
+            step_span = _trace.begin("fit/step", step=step)
+            try:
+                with _trace.span("fit/next_batch"):
+                    batch = next(batches, _NO_BATCH)
+                if batch is _NO_BATCH or step < skip_steps:
+                    _trace.end(step_span, discard=True)  # not a step
+                    if batch is _NO_BATCH:
+                        break
+                    continue  # resumed mid-epoch: fast-forward
+                with _trace.span("fit/callbacks"):
+                    cbks.on_batch_begin(mode, step, logs)
+                inputs, labels = self._split_batch(batch)
+                update = accum <= 1 or (step + 1) % accum == 0
+                with _trace.span("fit/dispatch"):
+                    lval, outs = self._engine.train_batch(inputs, labels,
+                                                          update=update)
+                if self._lr_sched_step_on_batch():
+                    self._optimizer._learning_rate.step()
+                if async_loop:
+                    lazy = _LazyLoss(step, lval, drain)
+                    window.append(lazy)
+                    if (step + 1) % max(log_freq, 1) == 0:
+                        drain(through=step)  # boundary: window retired
+                    else:
+                        drain()  # retire past the window bound only
+                    logs["loss"] = lazy  # exact for whoever reads it
                 else:
-                    drain()  # retire past the window bound only
-                logs["loss"] = lazy  # exact for whoever reads it
-            else:
-                logs["loss"] = float(np.asarray(lval))
-            logs["batch_size"] = np.asarray(inputs[0]).shape[0]
-            metric_logs = self._update_metrics(outs, labels)
-            logs.update(metric_logs)
-            if mode == "train":
-                _elastic.notify_step()  # StallMonitor/Heartbeat pulse
-            if acp is not None and mode == "train":
-                # account the completed batch BEFORE callbacks: a SIGTERM
-                # raised from a callback must capture this step as done
-                self._global_step = getattr(self, "_global_step", 0) + 1
-                self._acp_pos = (epoch, step)
-                data_state = None
-                if hasattr(loader, "state_dict"):
-                    try:
-                        data_state = loader.state_dict()
-                    except Exception:
-                        data_state = None
-                # batch-end snapshot for the PreemptionGuard capture:
-                # consistent with _acp_pos/_global_step by construction
-                self._acp_data_state = data_state
-                acp.maybe_save(self, epoch, step, self._global_step,
-                               data_state=data_state)
-            cbks.on_batch_end(mode, step, logs)
-            if num_iters is not None and step + 1 >= num_iters:
-                break
+                    with _trace.span("fit/drain", step=step):
+                        logs["loss"] = float(np.asarray(lval))
+                logs["batch_size"] = np.asarray(inputs[0]).shape[0]
+                metric_logs = self._update_metrics(outs, labels)
+                logs.update(metric_logs)
+                if mode == "train":
+                    _elastic.notify_step()  # StallMonitor/Heartbeat pulse
+                if acp is not None and mode == "train":
+                    # account the completed batch BEFORE callbacks: a
+                    # SIGTERM raised from a callback must capture this
+                    # step as done
+                    self._global_step = getattr(self, "_global_step", 0) + 1
+                    self._acp_pos = (epoch, step)
+                    data_state = None
+                    if hasattr(loader, "state_dict"):
+                        try:
+                            data_state = loader.state_dict()
+                        except Exception:
+                            data_state = None
+                    # batch-end snapshot for the PreemptionGuard capture:
+                    # consistent with _acp_pos/_global_step by construction
+                    self._acp_data_state = data_state
+                    acp.maybe_save(self, epoch, step, self._global_step,
+                                   data_state=data_state)
+                with _trace.span("fit/callbacks"):
+                    cbks.on_batch_end(mode, step, logs)
+                if num_iters is not None and step + 1 >= num_iters:
+                    break
+            except BaseException as e:
+                step_span.attrs.setdefault("error", type(e).__name__)
+                raise
+            finally:
+                _trace.end(step_span)
         if window:  # epoch boundary: materialize the tail
             drain(through=window[-1].step)
         if async_loop and isinstance(logs.get("loss"), _LazyLoss):

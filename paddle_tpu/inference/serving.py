@@ -46,13 +46,25 @@ Two seams close the serve→train→serve loop (docs/online_learning.md):
   dropped) and each in-flight stream finishes on the version pinned at
   its first admission.
 
-Observability: spans `serve/{admit,prefill,decode_step,retire,evict,
-hot_swap}` with a per-request flow chain, gauges `serve.{queue_depth,
-active_slots,kv_pool_used_blocks,kv_pool_free_blocks,model_version}`,
-counters `serve.{preempted,tokens_generated,requests_completed,
-requests_errored,hot_swaps,completion_log_errors}`, histograms
-`serve/ttft_ms` and `serve/token_ms` — rendered by tools/obs_report.py's
-serving section and snapshotted by BENCH_MODE=serve.
+Observability: one `serve/tick` span per scheduler beat (attributes
+`beat`, `active`, `queued`) whose children are the beat's phases —
+`serve/settle` (its child `serve/settle_wait` is the host blocked on the
+device; the rest of `serve/settle` is token bookkeeping), `serve/admit`
+> `serve/prefill`, `serve/grow`, `serve/upload` (this beat's lengths,
+block tables and keys built and sent to the device), `serve/decode_step`
+(the dispatch) — plus `serve/wait_work` while the scheduler thread has
+nothing to do, and `serve/{retire,evict,hot_swap}`; request spans carry
+`req=<rid>` and a per-request flow chain. Every span is also on the
+timeline of any running `jax.profiler` session (core/trace.py). Stamps
+`ServeRequest.{t_submit,t_admit,t_first,t_tokens,t_done}`. `stats()`
+counts, cumulative: `steps`, `decode_tokens`, `prefill_dispatches`,
+`prefill_tokens`, `admitted`, `queue_wait_s` (mirrored as `serve.*`
+gauges beside `serve.{queue_depth,active_slots,kv_pool_used_blocks,
+kv_pool_free_blocks,model_version}`). Counters `serve.{preempted,
+tokens_generated,requests_completed,requests_errored,hot_swaps,
+completion_log_errors}`, histograms `serve/ttft_ms` and
+`serve/token_ms` — rendered by tools/obs_report.py's serving section
+and snapshotted by BENCH_MODE=serve.
 """
 from __future__ import annotations
 
@@ -69,7 +81,9 @@ __all__ = ["ServeConfig", "ServeRequest", "ServeLoop",
 
 GAUGES = ("serve.queue_depth", "serve.active_slots",
           "serve.kv_pool_used_blocks", "serve.kv_pool_free_blocks",
-          "serve.model_version")
+          "serve.model_version", "serve.decode_tokens",
+          "serve.prefill_dispatches", "serve.prefill_tokens",
+          "serve.admitted", "serve.queue_wait_s")
 COUNTERS = ("serve.preempted", "serve.tokens_generated",
             "serve.requests_completed", "serve.requests_errored",
             "serve.hot_swaps", "serve.completion_log_errors",
@@ -136,7 +150,9 @@ class ServeRequest:
         self.preemptions = 0
         self.snapshot_version = None  # model version pinned at 1st admit
         self.t_submit = time.perf_counter()
+        self.t_admit = None      # left the queue for a slot (first time)
         self.t_first = None      # first generated token materialized
+        self.t_tokens = []       # one stamp per entry of `out`
         self.t_done = None
         self._done = threading.Event()
 
@@ -181,7 +197,9 @@ class ServeRequest:
             "version": self.snapshot_version,
             "preemptions": int(self.preemptions),
             "t_submit": self.t_submit,
+            "t_admit": self.t_admit,
             "t_first": self.t_first,
+            "t_tokens": list(self.t_tokens),
             "t_done": self.t_done,
             "ttft_s": self.ttft_s,
             "per_token_s": self.per_token_s,
@@ -341,6 +359,12 @@ class ServeLoop:
         self._version = 0
         self._admit_seq = 0
         self._step_count = 0
+        # cumulative counts of the scheduler's work, read through stats()
+        self._decode_tokens = 0       # tokens appended from decode beats
+        self._prefill_dispatches = 0  # re-prefill after preemption too
+        self._prefill_tokens = 0      # prompt tokens sent to prefill
+        self._admitted = 0            # first admissions
+        self._queue_wait_s = 0.0      # sum of t_admit - t_submit
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
         self._thread = None
@@ -419,6 +443,11 @@ class ServeLoop:
             "kv_pool_used_blocks": self._pool.used_blocks,
             "kv_pool_free_blocks": self._pool.free_blocks,
             "steps": self._step_count,
+            "decode_tokens": self._decode_tokens,
+            "prefill_dispatches": self._prefill_dispatches,
+            "prefill_tokens": self._prefill_tokens,
+            "admitted": self._admitted,
+            "queue_wait_s": self._queue_wait_s,
             "block_size": self._bs,
             "max_active": self._A,
             "model_version": self.model_version,
@@ -458,10 +487,14 @@ class ServeLoop:
                     or any(s is not None for s in self._slots))
 
     def _serve_forever(self):
+        from ..core import trace as _trace
         while True:
             with self._work:
-                while not self._has_work() and not self._stopping:
-                    self._work.wait(timeout=0.05)
+                if not self._has_work() and not self._stopping:
+                    # an idle device under this span is "no request"
+                    with _trace.span("serve/wait_work"):
+                        while not self._has_work() and not self._stopping:
+                            self._work.wait(timeout=0.05)
                 if self._stopping and not self._has_work():
                     return
             self._tick()
@@ -469,7 +502,15 @@ class ServeLoop:
     def _tick(self):
         """One scheduler beat: settle enough of the pipeline to bound
         the window, admit, grow/preempt, dispatch the next fused decode
-        step (N+1 overlapping the settle of step N)."""
+        step (N+1 overlapping the settle of step N). Every phase is a
+        child span of the beat's `serve/tick`."""
+        from ..core import trace as _trace
+        with _trace.span("serve/tick", beat=self._step_count,
+                         active=sum(s is not None for s in self._slots),
+                         queued=len(self._queue)):
+            self._tick_phases()
+
+    def _tick_phases(self):
         # testing/faults.py ("serve", "beat") boundary: a scripted STALL
         # here models a hung scheduler beat (the latency fault the SLO
         # drill scripts a TTFT breach against). Transport-shaped chaos
@@ -567,6 +608,10 @@ class ServeLoop:
                              blocks=len(blocks)) as sp:
                 sp.flow(self._flow_base + req.rid, "s")
                 import jax
+                if req.t_admit is None:
+                    req.t_admit = time.perf_counter()
+                    self._admitted += 1
+                    self._queue_wait_s += req.t_admit - req.t_submit
                 if req.snapshot_version is None:
                     req.snapshot_version = self.model_version
                 self._version += 1
@@ -625,6 +670,8 @@ class ServeLoop:
                                                  req=req.rid)
         if carry is not None:
             self._arenas, self._tokens = carry
+        self._prefill_dispatches += 1
+        self._prefill_tokens += s_real
         slot.length = s_real
         self._pending.append(("prefill", handles, req, idx,
                               slot.version))
@@ -643,23 +690,25 @@ class ServeLoop:
         """Every active slot writes its next token at position `length`
         this step; make sure the covering block exists, evicting the
         youngest stream when the pool is dry (oldest always wins)."""
-        order = sorted((i for i, s in enumerate(self._slots)
-                        if s is not None),
-                       key=lambda i: self._slots[i].admit_seq)
-        for idx in order:
-            slot = self._slots[idx]
-            if slot is None:          # evicted by an earlier iteration
-                continue
-            need_blk = slot.length // self._bs
-            while need_blk >= len(slot.blocks):
-                got = self._pool.alloc(1)
-                if got is not None:
-                    slot.blocks.extend(got)
+        from ..core import trace as _trace
+        with _trace.span("serve/grow"):
+            order = sorted((i for i, s in enumerate(self._slots)
+                            if s is not None),
+                           key=lambda i: self._slots[i].admit_seq)
+            for idx in order:
+                slot = self._slots[idx]
+                if slot is None:      # evicted by an earlier iteration
                     continue
-                victim = self._youngest_active()
-                self._preempt(victim)
-                if victim == idx:
-                    break             # preempted ourselves; slot is gone
+                need_blk = slot.length // self._bs
+                while need_blk >= len(slot.blocks):
+                    got = self._pool.alloc(1)
+                    if got is not None:
+                        slot.blocks.extend(got)
+                        continue
+                    victim = self._youngest_active()
+                    self._preempt(victim)
+                    if victim == idx:
+                        break         # preempted ourselves; slot is gone
 
     def _preempt(self, idx):
         from ..core import monitor as _monitor
@@ -686,17 +735,22 @@ class ServeLoop:
 
         from ..core import trace as _trace
         A, MB = self._A, self._MB
-        lengths = np.zeros((A,), np.int32)
-        bt = np.zeros((A, MB), np.int32)
-        keys = np.zeros((A, 2), np.uint32)
-        snapshot = []
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue
-            lengths[i] = s.length
-            bt[i, :len(s.blocks)] = s.blocks
-            keys[i] = s.key
-            snapshot.append((i, s.req, s.version))
+        # this beat's host state, rebuilt and re-sent every beat: the
+        # per-beat upload has its own span so its cost has its own number
+        with _trace.span("serve/upload"):
+            lengths = np.zeros((A,), np.int32)
+            bt = np.zeros((A, MB), np.int32)
+            keys = np.zeros((A, 2), np.uint32)
+            snapshot = []
+            for i, s in enumerate(self._slots):
+                if s is None:
+                    continue
+                lengths[i] = s.length
+                bt[i, :len(s.blocks)] = s.blocks
+                keys[i] = s.key
+                snapshot.append((i, s.req, s.version))
+            bt_d, lengths_d, keys_d = (jnp.asarray(bt), jnp.asarray(lengths),
+                                       jnp.asarray(keys))
         step_idx = self._step_count
         self._step_count += 1
         with _trace.span("serve/decode_step", step=step_idx,
@@ -706,8 +760,7 @@ class ServeLoop:
                 arenas, nxt = self._call_traced(
                     self._step_jit, ("decode",),
                     self._params, self._buffers, self._arenas,
-                    jnp.asarray(bt), jnp.asarray(lengths), self._tokens,
-                    jnp.asarray(keys))
+                    bt_d, lengths_d, self._tokens, keys_d)
                 return (arenas, nxt), [nxt]
 
             carry, handles = self._driver.submit(thunk, kind="decode",
@@ -720,28 +773,35 @@ class ServeLoop:
 
     # -- settlement / retirement --------------------------------------------
     def _settle_one(self):
+        """Materialise the oldest in-flight step and book its tokens.
+        `serve/settle_wait` is the host blocked on the device; what is
+        left of `serve/settle` is host work (appends, retirements)."""
+        from ..core import trace as _trace
         from ..static.pipeline_runner import PipelineStepError
         entry = self._pending.popleft()
-        try:
-            toks = np.asarray(entry[1][0])
-        except PipelineStepError as exc:
-            self._fail_inflight(exc)
-            return
-        now = time.perf_counter()
-        if entry[0] == "prefill":
-            _kind, _h, req, idx, version = entry
-            slot = self._slots[idx]
-            if slot is None or slot.version != version:
-                return               # preempted before its first token
-            self._append_token(idx, slot, int(toks), now, first=True)
-            return
-        _kind, _h, snapshot = entry
-        for idx, req, version in snapshot:
-            slot = self._slots[idx]
-            if slot is None or slot.version != version \
-                    or slot.req is not req:
-                continue             # retired/preempted mid-flight
-            self._append_token(idx, slot, int(toks[idx]), now)
+        with _trace.span("serve/settle", kind=entry[0]):
+            try:
+                with _trace.span("serve/settle_wait", kind=entry[0]):
+                    toks = np.asarray(entry[1][0])
+            except PipelineStepError as exc:
+                self._fail_inflight(exc)
+                return
+            now = time.perf_counter()
+            if entry[0] == "prefill":
+                _kind, _h, req, idx, version = entry
+                slot = self._slots[idx]
+                if slot is None or slot.version != version:
+                    return           # preempted before its first token
+                self._append_token(idx, slot, int(toks), now, first=True)
+                return
+            _kind, _h, snapshot = entry
+            for idx, req, version in snapshot:
+                slot = self._slots[idx]
+                if slot is None or slot.version != version \
+                        or slot.req is not req:
+                    continue             # retired/preempted mid-flight
+                self._decode_tokens += 1
+                self._append_token(idx, slot, int(toks[idx]), now)
 
     def _append_token(self, idx, slot, token, now, first=False):
         from ..core import monitor as _monitor
@@ -749,6 +809,7 @@ class ServeLoop:
         if first and req.t_first is None and not req.out:
             req.t_first = now
         req.out.append(token)
+        req.t_tokens.append(now)
         _monitor.stat_add("serve.tokens_generated")
         if (req.eos_token_id is not None and token == req.eos_token_id) \
                 or len(req.out) >= req.max_new_tokens:
@@ -820,4 +881,9 @@ class ServeLoop:
             "serve.kv_pool_used_blocks": self._pool.used_blocks,
             "serve.kv_pool_free_blocks": self._pool.free_blocks,
             "serve.model_version": self.model_version,
+            "serve.decode_tokens": self._decode_tokens,
+            "serve.prefill_dispatches": self._prefill_dispatches,
+            "serve.prefill_tokens": self._prefill_tokens,
+            "serve.admitted": self._admitted,
+            "serve.queue_wait_s": self._queue_wait_s,
         })

@@ -4,6 +4,7 @@ small model trained a few iterations, loss must drop, save/load roundtrip).
 """
 import numpy as np
 import pytest
+from span_util import self_times
 
 import paddle_tpu as paddle
 from paddle_tpu import Model, nn, optimizer
@@ -199,3 +200,88 @@ def test_model_engine_mode_independent():
         paddle.disable_static()
         if prev_mesh is not None:
             mesh_mod.set_mesh(prev_mesh)
+
+
+# --------------------------------------------------------------------------
+# fit's host phases as spans (core/trace.py): fit/step and its children
+# --------------------------------------------------------------------------
+
+def _traced_fit(n_batches=3, **fit_kw):
+    from paddle_tpu.core import trace
+    paddle.seed(0)
+    rng = np.random.RandomState(0)
+    X = rng.rand(16 * n_batches, 4).astype("float32")
+    Y = X @ rng.rand(4, 1).astype("float32")
+    net = nn.Linear(4, 1)
+    model = Model(net)
+    model.prepare(optimizer=optimizer.SGD(learning_rate=0.1,
+                                          parameters=net.parameters()),
+                  loss=nn.MSELoss())
+    trace.reset()
+    model.fit(TensorDataset([X, Y]), batch_size=16, epochs=1, verbose=0,
+              shuffle=False, **fit_kw)
+    return trace.recent()
+
+
+def test_fit_three_steps_leave_step_spans_with_their_phases():
+    spans = _traced_fit(3)
+    steps = [sp for sp in spans if sp.name == "fit/step"]
+    assert [sp.attrs["step"] for sp in steps] == [0, 1, 2]
+    selfs = self_times(spans)
+    for step in steps:
+        kids = [sp.name for sp in spans if sp.parent_id == step.span_id]
+        assert kids[:3] == ["fit/next_batch", "fit/callbacks",
+                            "fit/dispatch"]
+        assert kids[-1] == "fit/callbacks" and kids.count(
+            "fit/callbacks") == 2
+        assert set(kids[3:-1]) <= {"fit/drain"}
+        inside = [sp for sp in spans
+                  if step.t0 <= sp.t0 and sp.t1 <= step.t1]
+        assert sum(selfs[sp.span_id] for sp in inside) == pytest.approx(
+            step.t1 - step.t0, rel=0.01)
+    # the loader's own spans sit under the pull, the (one) build of the
+    # train step under the first dispatch
+    by_id = {sp.span_id: sp for sp in spans}
+    produce = [sp for sp in spans if sp.name == "io/produce_batch"]
+    assert produce and all(
+        by_id[sp.parent_id].name == "fit/next_batch" for sp in produce)
+    (build,) = [sp for sp in spans if sp.name == "hapi/build_train_fn"]
+    first_dispatch = next(sp for sp in spans if sp.name == "fit/dispatch")
+    assert first_dispatch.t0 <= build.t0 and build.t1 <= first_dispatch.t1
+    # every step's loss is drained exactly once, in order; with the
+    # default in-flight window of two the tail drains at the epoch's end
+    drains = [sp for sp in spans if sp.name == "fit/drain"]
+    assert [sp.attrs["step"] for sp in drains] == [0, 1, 2]
+    assert drains[-1].parent_id is None
+    # the iteration that found the loader empty is not a step
+    assert len([sp for sp in spans if sp.name == "fit/next_batch"]) == 4
+
+
+def test_fit_synchronous_loop_drains_inside_every_step():
+    """Gradient accumulation turns the in-flight window off: the loop
+    reads each loss at once, and that blocking read is the step's own
+    fit/drain."""
+    spans = _traced_fit(2, accumulate_grad_batches=2)
+    steps = [sp for sp in spans if sp.name == "fit/step"]
+    assert len(steps) == 2
+    for step in steps:
+        kids = [sp for sp in spans if sp.parent_id == step.span_id]
+        drains = [sp for sp in kids if sp.name == "fit/drain"]
+        assert len(drains) == 1
+        assert drains[0].attrs["step"] == step.attrs["step"]
+
+
+def test_fit_step_span_records_a_callbacks_error_and_closes():
+    from paddle_tpu.core import trace
+    from paddle_tpu.hapi.callbacks import Callback
+
+    class Boom(Callback):
+        def on_train_batch_end(self, step, logs=None):
+            if step == 1:
+                raise KeyError("boom")
+
+    with pytest.raises(KeyError):
+        _traced_fit(3, callbacks=[Boom()])
+    assert trace.open_spans() == [] and trace.current() is None
+    steps = [sp for sp in trace.recent() if sp.name == "fit/step"]
+    assert [sp.attrs.get("error") for sp in steps] == [None, "KeyError"]

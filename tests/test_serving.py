@@ -15,6 +15,7 @@ import threading
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from span_util import self_times
 
 import paddle_tpu as paddle
 from paddle_tpu.core import monitor, trace
@@ -321,6 +322,153 @@ def test_serve_spans_and_gauges(net):
     assert monitor.stat_get("serve.requests_completed") == 2
     # latency histograms feed bench's serve snapshot
     assert monitor.histogram_summary("serve/ttft_ms")["count"] == 2
+
+
+# --------------------------------------------------------------------------
+# a beat's phases, a request's stamps, the scheduler's counts
+# --------------------------------------------------------------------------
+
+def _toy_loop(net, **kw):
+    cfg = dict(max_active=4, kv_blocks=32, block_size=16, max_seq_len=64)
+    cfg.update(kw)
+    return ServeLoop(net, ServeConfig(**cfg))
+
+
+def test_every_beat_is_a_tick_whose_phases_add_up(net):
+    trace.reset()
+    rng = np.random.RandomState(11)
+    prompts = [rng.randint(1, 1024, (n,)).astype(np.int64)
+               for n in (5, 9, 6)]
+    loop = _toy_loop(net)
+    loop.serve(prompts, max_new_tokens=5)
+    spans = trace.recent()
+    ticks = [sp for sp in spans if sp.name == "serve/tick"]
+    assert len(ticks) >= loop.stats()["steps"] == 5
+    assert [t.attrs["beat"] for t in ticks[:5]] == [0, 1, 2, 3, 4]
+    assert ticks[0].attrs["queued"] == 3 and ticks[0].attrs["active"] == 0
+    assert ticks[1].attrs["active"] == 3 and ticks[1].attrs["queued"] == 0
+    selfs = self_times(spans)
+    for tick in ticks:
+        kids = [sp for sp in spans if sp.parent_id == tick.span_id]
+        names = [sp.name for sp in kids]
+        if "serve/decode_step" in names:
+            # a dispatching beat: grow, upload, dispatch, in that order,
+            # after the admissions (first beat) or the settles (later)
+            assert names[-3:] == ["serve/grow", "serve/upload",
+                                  "serve/decode_step"]
+            assert set(names[:-3]) <= {"serve/settle", "serve/admit"}
+        if tick.attrs["beat"] >= 1:
+            assert "serve/settle" in names
+        # phases lie inside the beat and one after the other
+        for a, b in zip(kids, kids[1:]):
+            assert tick.t0 <= a.t0 and a.t1 <= b.t0 and b.t1 <= tick.t1
+        # ... so self times add up to the beat
+        inside = [sp for sp in spans
+                  if tick.t0 <= sp.t0 and sp.t1 <= tick.t1]
+        total = sum(selfs[sp.span_id] for sp in inside)
+        assert total == pytest.approx(tick.t1 - tick.t0, rel=0.01)
+    # waiting is a child of settling, so the two can be told apart
+    by_id = {sp.span_id: sp for sp in spans}
+    waits = [sp for sp in spans if sp.name == "serve/settle_wait"]
+    assert waits and all(by_id[w.parent_id].name == "serve/settle"
+                         and w.attrs["kind"] in ("prefill", "decode")
+                         for w in waits)
+    retire = next(sp for sp in spans if sp.name == "serve/retire")
+    assert by_id[retire.parent_id].name == "serve/settle"
+
+
+def test_request_stamps_order_and_completion_record(net):
+    records = []
+    loop = ServeLoop(net, ServeConfig(max_active=1, kv_blocks=16,
+                                      block_size=16, max_seq_len=64),
+                     on_complete=records.append)
+    rng = np.random.RandomState(12)
+    reqs = [loop.submit(rng.randint(1, 1024, (6,)).astype(np.int64),
+                        max_new_tokens=4) for _ in range(2)]
+    loop.run_until_idle()
+    for req in reqs:
+        assert len(req.t_tokens) == len(req.out) == 4
+        assert req.t_submit <= req.t_admit <= req.t_first
+        assert req.t_tokens[0] == req.t_first
+        assert req.t_tokens == sorted(req.t_tokens)
+        assert req.t_tokens[-1] <= req.t_done
+    # one slot: the second request waits for the first to retire
+    assert reqs[1].t_admit >= reqs[0].t_done
+    rec = {r["rid"]: r for r in records}[reqs[1].rid]
+    assert rec["t_admit"] == reqs[1].t_admit
+    assert rec["t_tokens"] == reqs[1].t_tokens
+    assert rec["t_tokens"] is not reqs[1].t_tokens   # a copy, host floats
+
+
+def test_stats_count_the_schedulers_work(net):
+    monitor.reset(prefix="serve.")
+    rng = np.random.RandomState(13)
+    lens = (5, 9, 6, 7, 8)
+    loop = _toy_loop(net, max_active=2)      # five requests, two slots
+    before = loop.stats()
+    reqs = [loop.submit(rng.randint(1, 1024, (n,)).astype(np.int64),
+                        max_new_tokens=6) for n in lens]
+    loop.run_until_idle()
+    after = loop.stats()
+    d = {k: after[k] - before[k] for k in (
+        "steps", "decode_tokens", "prefill_dispatches", "prefill_tokens",
+        "admitted", "queue_wait_s")}
+    generated = sum(len(r.out) for r in reqs)
+    assert generated == 30 == monitor.stat_get("serve.tokens_generated")
+    assert monitor.stat_get("serve.preempted") == 0
+    # a token comes from a prefill (the first) or from a decode beat
+    assert d["decode_tokens"] + d["prefill_dispatches"] == generated
+    assert d["prefill_dispatches"] == d["admitted"] == len(lens)
+    assert d["prefill_tokens"] == sum(lens)
+    assert d["decode_tokens"] <= d["steps"] * after["max_active"]
+    assert d["queue_wait_s"] == pytest.approx(
+        sum(r.t_admit - r.t_submit for r in reqs))
+    # the same numbers as gauges, for whoever reads the monitor
+    for name in ("decode_tokens", "prefill_dispatches", "prefill_tokens",
+                 "admitted", "queue_wait_s"):
+        assert monitor.stat_get(f"serve.{name}") == after[name]
+
+
+def test_preemption_reprefills_but_admits_once(net):
+    monitor.reset(prefix="serve.")
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(1, 1024, (6,)).astype(np.int64)
+               for _ in range(3)]
+    loop = _toy_loop(net, kv_blocks=3, block_size=8, max_seq_len=16)
+    reqs = [loop.submit(p, max_new_tokens=8) for p in prompts]
+    loop.run_until_idle()
+    st = loop.stats()
+    preempted = int(monitor.stat_get("serve.preempted"))
+    assert preempted > 0
+    assert st["admitted"] == 3                   # first admissions only
+    assert st["prefill_dispatches"] == 3 + preempted
+    assert st["prefill_tokens"] > sum(p.size for p in prompts)
+    for req in reqs:
+        assert len(req.t_tokens) == len(req.out) == 8
+        assert req.t_admit <= req.t_first        # not moved by a replay
+    assert st["queue_wait_s"] == pytest.approx(
+        sum(r.t_admit - r.t_submit for r in reqs))
+
+
+def test_idle_scheduler_thread_sits_in_wait_work(net):
+    import time
+    trace.reset()
+    loop = _toy_loop(net).start()
+    try:
+        time.sleep(0.12)                          # nothing to do yet
+        out = loop.submit(np.arange(1, 7), max_new_tokens=3).result(
+            timeout=120)
+    finally:
+        loop.stop(timeout=60)
+    assert len(out) == 3
+    spans = trace.recent()
+    waits = [sp for sp in spans if sp.name == "serve/wait_work"]
+    ticks = [sp for sp in spans if sp.name == "serve/tick"]
+    assert waits and waits[0].duration_ms >= 100 and ticks
+    assert waits[0].thread == ticks[0].thread == "serve-loop"
+    # waiting and beating alternate on the one thread, never nested
+    assert all(t.parent_id is None for t in ticks)
+    assert all(w.t1 <= t.t0 or t.t1 <= w.t0 for w in waits for t in ticks)
 
 
 # --------------------------------------------------------------------------

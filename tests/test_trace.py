@@ -167,3 +167,145 @@ def test_record_event_absorbed_into_tracer():
     finally:
         prof.stop_profiler()
     prof.reset_profiler()
+
+
+# --------------------------------------------------------------------------
+# the bridge: every span is also a jax.profiler.TraceAnnotation
+# --------------------------------------------------------------------------
+
+def _host_events(trace_dir):
+    """{name: [(start_ns, end_ns, {stat: value}, line index)]} of the
+    xplane's /host:CPU plane under `trace_dir`."""
+    import glob
+
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for k, line in enumerate(plane.lines):
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats), k))
+    return out
+
+
+@pytest.fixture
+def profiler_session(tmp_path):
+    """A jax.profiler session with the options the benchmark uses
+    (benchmark/lib/profiler.py); yields a function that stops it and
+    returns the host plane's events."""
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    options.enable_hlo_proto = False
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    stopped = []
+
+    def stop():
+        jax.profiler.stop_trace()
+        stopped.append(True)
+        return _host_events(tmp_path)
+
+    yield stop
+    if not stopped:
+        jax.profiler.stop_trace()
+
+
+def test_span_lands_on_the_profiler_timeline(profiler_session):
+    """Inside a profiler session a span is an event of /host:CPU with
+    its name, start, duration and scalar attributes; a child lies inside
+    its parent on the same thread's line."""
+    import time
+    with trace.span("bridge/outer", beat=7, share=0.5, kind="decode",
+                    blob=[1, 2], long="x" * 65) as outer:
+        time.sleep(0.002)
+        with trace.span("bridge/inner", req=3):
+            time.sleep(0.001)
+    events = profiler_session()
+    (o0, o1, ostats, oline), = events["bridge/outer"]
+    (i0, i1, istats, iline), = events["bridge/inner"]
+    assert ostats == {"beat": 7, "share": 0.5, "kind": "decode"}
+    assert istats == {"req": 3}
+    assert oline == iline and o0 <= i0 and i1 <= o1
+    assert i1 - i0 >= 1e6 and o1 - o0 >= 3e6       # ns: the sleeps
+    # the two clocks agree on the duration to well under a millisecond
+    assert abs((o1 - o0) * 1e-6 - outer.duration_ms) < 0.5
+    # and the ring holds the same spans, attributes whole
+    ring = {s.name: s for s in trace.recent()}
+    assert ring["bridge/outer"].attrs["blob"] == [1, 2]
+    assert ring["bridge/inner"].parent_id == outer.span_id
+
+
+def test_bridge_spans_of_two_threads_sit_on_two_lines(profiler_session):
+    def worker():
+        with trace.span("bridge/worker"):
+            pass
+
+    with trace.span("bridge/main"):
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join(timeout=10)
+    assert not t.is_alive()
+    events = profiler_session()
+    assert events["bridge/main"][0][3] != events["bridge/worker"][0][3]
+
+
+def test_discard_double_end_and_cross_order_close_the_annotation(
+        profiler_session):
+    """Every way end() tolerates leaves no annotation open: the profiler
+    gets one closed event per span, the discarded one included (the ring
+    is what `discard` spares)."""
+    a = trace.begin("bridge/a")
+    b = trace.begin("bridge/b")
+    trace.end(a)                     # out of order: a before its child b
+    trace.end(b)
+    trace.end(b)                     # second end: a no-op
+    d = trace.begin("bridge/discarded")
+    trace.end(d, discard=True)
+    det = trace.begin("bridge/detached", _attach=False)
+    trace.end(det)
+    assert all(sp._annotation is None for sp in (a, b, d, det))
+    assert trace.open_spans() == [] and trace.current() is None
+    events = profiler_session()
+    for name in ("bridge/a", "bridge/b", "bridge/discarded",
+                 "bridge/detached"):
+        assert len(events[name]) == 1, name
+    assert "bridge/discarded" not in {s.name for s in trace.recent()}
+
+
+def test_without_a_session_the_ring_behaves_as_before():
+    """No profiler running: the annotation is a no-op and spans reach
+    the ring, the capture buffer and recent() exactly as they did."""
+    trace.start()
+    with trace.span("quiet/outer", n=1) as outer:
+        with trace.span("quiet/inner") as inner:
+            pass
+    spans = trace.stop()
+    assert [s.name for s in spans] == ["quiet/inner", "quiet/outer"]
+    assert [s.name for s in trace.recent(2)] == ["quiet/inner",
+                                                 "quiet/outer"]
+    assert inner.parent_id == outer.span_id and outer.attrs == {"n": 1}
+    assert outer._annotation is None and inner._annotation is None
+
+
+def test_core_trace_is_the_only_span_api_of_the_package():
+    """No direct TraceAnnotation (or a second tracer) elsewhere in
+    paddle_tpu/: the bridge in core/trace.py is the one place."""
+    import os
+    import re
+    root = os.path.dirname(os.path.abspath(paddle.__file__))
+    hits = []
+    for dirpath, _dirs, files in os.walk(root):
+        for f in files:
+            path = os.path.join(dirpath, f)
+            if f.endswith(".py") and not path.endswith(
+                    os.path.join("core", "trace.py")):
+                with open(path) as fh:
+                    if re.search(r"TraceAnnotation\(|TraceMe\(", fh.read()):
+                        hits.append(os.path.relpath(path, root))
+    assert hits == []

@@ -200,19 +200,23 @@ def pallas_rates(metrics) -> str:
 # against inference/serving.py GAUGES/COUNTERS so the two cannot drift
 SERVE_GAUGES = ("serve.queue_depth", "serve.active_slots",
                 "serve.kv_pool_used_blocks", "serve.kv_pool_free_blocks",
-                "serve.model_version")
+                "serve.model_version", "serve.decode_tokens",
+                "serve.prefill_dispatches", "serve.prefill_tokens",
+                "serve.admitted", "serve.queue_wait_s")
 SERVE_COUNTERS = ("serve.preempted", "serve.tokens_generated",
                   "serve.requests_completed", "serve.requests_errored",
                   "serve.hot_swaps", "serve.completion_log_errors",
                   "serve.backpressure_waits")
-_SERVE_SPANS = ("serve/admit", "serve/prefill", "serve/decode_step",
+_SERVE_SPANS = ("serve/tick", "serve/wait_work", "serve/settle",
+                "serve/settle_wait", "serve/admit", "serve/prefill",
+                "serve/grow", "serve/upload", "serve/decode_step",
                 "serve/retire", "serve/evict", "serve/hot_swap")
 
 
 def serving_section(metrics, spans) -> str:
     """Continuous-batching serve tier: pool/queue gauges, stream
     counters, TTFT/per-token latency histograms, and the per-phase span
-    table (admit/prefill/decode_step/retire/evict)."""
+    table (one serve/tick per beat and its phases)."""
     values = metrics.get("values", {})
     rows = [[k, values[k]] for k in SERVE_GAUGES + SERVE_COUNTERS
             if k in values]
