@@ -393,6 +393,59 @@ def _forced_logits(net, sizes, block_size):
             "decode": _rel_err(dec[True][0], dec[False][0])}
 
 
+def arena_relayouts(hlo_text, arena_shape):
+    """The copy / transpose instructions of arena shape in a compiled
+    program's optimized HLO."""
+    import re
+    dims = ",".join(str(n) for n in arena_shape)
+    return re.findall(r"= \w+\[%s\]\S* (copy|transpose)\(" % dims, hlo_text)
+
+
+def _serve_program_memory(loop, bucket):
+    """Lower and compile ServeLoop's own decode step and one prefill
+    bucket (the persistent cache has both after the run above) and read
+    what a compiled program does to the KV arenas: its temp bytes beside
+    one arena's, and the copy / transpose instructions of arena shape in
+    its optimized HLO. The arenas have one device layout (nn/kv_pool.py):
+    a relayout, or a temp the size of an arena, means XLA is copying them
+    again. `head_bytes` is the one large temp a serve program does hold:
+    the tied embedding, transposed for the head's matmul."""
+    A, MB = loop._A, loop._MB
+    arena = loop._arenas[0][0]
+    arena_bytes = arena.size * arena.dtype.itemsize
+    wte = loop.net.wte.weight._value
+    head_bytes = wte.size * wte.dtype.itemsize
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    state = (loop._params, loop._buffers, loop._arenas)
+    i32, u32 = jnp.int32, jnp.uint32
+    lowered = {
+        "decode": lambda: loop._step_jit.lower(
+            *state, spec((A, MB), i32), spec((A,), i32), loop._tokens,
+            spec((A, 2), u32)),
+        f"prefill{bucket}": lambda: loop._prefill_jit.lower(
+            *state, loop._tokens, spec((1, MB), i32), spec((1, bucket), i32),
+            spec((), i32), spec((2,), u32), spec((), i32)),
+    }
+    out = {}
+    try:
+        for name, lower in lowered.items():
+            compiled = lower().compile()
+            out[name] = {
+                "temp_bytes":
+                    int(compiled.memory_analysis().temp_size_in_bytes),
+                "arena_bytes": int(arena_bytes),
+                "head_bytes": int(head_bytes),
+                "arena_relayouts": len(arena_relayouts(compiled.as_text(),
+                                                       arena.shape)),
+            }
+    finally:  # tracing rebinds the live layers' parameters to tracers
+        loop.net.load_functional_state(loop._params, loop._buffers)
+    return out
+
+
 def serve_phase(sizes):
     import paddle_tpu as paddle
     from paddle_tpu.core import monitor
@@ -454,6 +507,21 @@ def serve_phase(sizes):
         if not counters.get(f"pallas.hit.{kernel}"):
             failures.append(f"kernel {kernel} never engaged in serve")
 
+    buckets = sorted({loop._bucket(len(p)) for p in prompts})
+    programs = _serve_program_memory(loop, buckets[-1])
+    for name, mem in programs.items():
+        # a property of the TPU's compiler: the toy run on the CPU
+        # (tests/test_chip_smoke.py) reports the numbers and gates nothing
+        if jax.default_backend() == "tpu" and (
+                mem["temp_bytes"] - mem["head_bytes"] >= mem["arena_bytes"]
+                or mem["arena_relayouts"]):
+            failures.append(
+                f"compiled {name} program relays out the KV arena: "
+                f"temp {mem['temp_bytes']} B (head {mem['head_bytes']} B, "
+                f"one arena {mem['arena_bytes']} B), "
+                f"{mem['arena_relayouts']} "
+                "copy/transpose instructions of arena shape")
+
     forced = _forced_logits(net, sizes, block_size)
     if not _pallas_counters().get(
             "pallas.gate_reject.paged_decode_attention.flag_off"):
@@ -476,9 +544,9 @@ def serve_phase(sizes):
         differ = np.nonzero(ref != outs[i])[0]
         agree.append(f"{differ[0] if len(differ) else len(ref)}/{len(ref)}")
 
-    buckets = sorted({loop._bucket(len(p)) for p in prompts})
     return {
         "failures": failures, "requests": n, "completed": completed,
+        "programs": programs,
         "tokens_generated": int(monitor.stat_get("serve.tokens_generated")),
         "preempted": int(monitor.stat_get("serve.preempted")),
         "backpressure_waits":
@@ -590,10 +658,11 @@ def _check_decode(sizes):
 
 
 def _check_paged(sizes, chunk, block_size=None):
-    """The arena shape ServeLoop builds: [blocks + 1, h, block_size, d]
-    shared by serve_max_active slots of up to gpt.max_seq_len tokens, at
-    the block size ServeLoop's own picker gives (or the one named)."""
-    from paddle_tpu.nn.kv_pool import paged_attention_ref, pick_block_size
+    """The arenas ServeLoop builds (KVBlockPool.arenas), shared by
+    serve_max_active slots of up to gpt.max_seq_len tokens, at the block
+    size ServeLoop's own picker gives (or the one named)."""
+    from paddle_tpu.nn.kv_pool import (KVBlockPool, paged_attention_ref,
+                                       pick_block_size)
     from paddle_tpu.ops.pallas.decode_attention import paged_decode_attention
 
     cfg = sizes.gpt
@@ -604,7 +673,7 @@ def _check_paged(sizes, chunk, block_size=None):
                                      dtype=DTYPE)
     nb = cfg.max_seq_len // block_size       # block-table width
     ks = jax.random.split(jax.random.PRNGKey(SEED + 3), 3)
-    shape = (b * nb + 1, h, block_size, d)
+    shape = KVBlockPool(b * nb, block_size).arena_shape(h, d)
     ka, va = (jax.random.normal(kk, shape, jnp.float32).astype(DTYPE)
               for kk in ks[:2])
     q = jax.random.normal(ks[2], (b, h, chunk, d), jnp.float32).astype(DTYPE)

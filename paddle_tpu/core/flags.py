@@ -223,14 +223,18 @@ define_flag("FLAGS_use_paged_attention", True,
             "paged_decode_attention): per-request block tables ride the "
             "scalar-prefetch path next to the ragged lengths, so a "
             "decode step's KV reads scale with each request's LIVE "
-            "blocks, not max_seq_len. Off, the serve loop runs the jnp "
-            "gather fallback (nn/kv_pool.paged_attention_ref)")
+            "blocks, not max_seq_len; the decode step's token write "
+            "(paged_write_token) rides the same flag. Off, the serve loop "
+            "runs the jnp gather fallback (nn/kv_pool.paged_attention_ref) "
+            "and write_kv's XLA loop")
 define_flag("FLAGS_serve_block_size", 0,
             "tokens per physical KV-pool block (nn/kv_pool.KVBlockPool); "
             "0 = auto: the paged-decode autotune table on TPU, else the "
             "128-column heuristic. Must be a multiple of the 8-row "
-            "sublane tile. Smaller blocks waste less pool memory per "
-            "short request; larger blocks amortize kernel grid overhead")
+            "sublane tile; multiples of 128 (a lane tile) keep the arenas "
+            "free of layout copies. Smaller blocks waste less pool memory "
+            "per short request; larger blocks amortize kernel grid "
+            "overhead")
 define_flag("FLAGS_serve_kv_blocks", 512,
             "physical blocks in the serving KV pool (per layer, k+v "
             "arenas); the pool is the admission currency — waiting "

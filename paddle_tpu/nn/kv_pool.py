@@ -8,7 +8,7 @@ fixed-batch slab burns max_seq_len slots of HBM per row whether the row
 holds a 2000-token context or an idle slot. This module is the vLLM-style
 fix, TPU-native:
 
-- **arena**: one physical [n_blocks + 1, h, block_size, d] buffer per
+- **arena**: one physical [n_blocks + 1, h, d, block_size] buffer per
   layer per k/v (`PagedKVCache`). Physical block 0 is RESERVED as the
   trash block — writes from masked/inactive rows and table entries past a
   request's allocation all land there, so the kernel's index maps never
@@ -25,6 +25,21 @@ block tables ride the scalar-prefetch path, so per-step KV bytes scale
 with live blocks, not max_seq_len) behind the same counted gate as every
 other kernel; `paged_attention_ref` is the jnp path the gate rejects onto
 and the parity oracle.
+
+Why tokens are the arena's MINOR dimension. Three components meet on one
+buffer, and each has a physical layout it insists on: the pool's array
+gets XLA's default TPU layout, which puts whichever of the two minor
+dimensions fills the 128 lanes there; the Mosaic kernel takes its operands
+row-major; an XLA scatter wants the scattered dimensions major. With
+[.., block_size, d] and d = 64 those were three different layouts, and
+every serve program copied every arena three times (PR 26: 76 % of a
+decode beat). [.., d, block_size] with block_size a multiple of 128 is
+row-major by default, is what the kernel asks for, and `write_kv` rewrites
+whole blocks of it in place — never a scatter — so a compiled serve
+program holds no copy and no temp of arena size
+(tests/test_chip_smoke.py compiles for v5e and asserts it). The shape is
+private to this module and the kernels: everything else goes through
+`KVBlockPool.arenas`, `PagedKVCache`, `write_kv` and `paged_attention`.
 """
 from __future__ import annotations
 
@@ -42,20 +57,20 @@ TRASH_BLOCK = 0  # physical row 0 of every arena; never allocated
 
 class PagedKVCache(typing.NamedTuple):
     """One layer's paged decode cache. `k`/`v` are the physical arenas
-    [n_blocks + 1, h, block_size, d] (row 0 = trash); `block_tables`
+    [n_blocks + 1, h, d, block_size] (row 0 = trash); `block_tables`
     [b, max_blocks] i32 maps each request-slot's logical blocks to
     physical rows (unallocated entries 0); `lengths` [b] i32 counts the
     tokens already written per slot. A pytree — jit/scan-able, and the
     block_tables/lengths leaves are shared by reference across layers."""
 
-    k: object             # [n_blocks + 1, h, block_size, d]
-    v: object             # [n_blocks + 1, h, block_size, d]
+    k: object             # [n_blocks + 1, h, d, block_size]
+    v: object             # [n_blocks + 1, h, d, block_size]
     block_tables: object  # [b, max_blocks] i32
     lengths: object       # [b] i32
 
     @property
     def block_size(self):
-        return int(self.k.shape[2])
+        return int(self.k.shape[3])
 
 
 def pick_block_size(max_seq_len, heads, head_dim, dtype="float32",
@@ -64,7 +79,8 @@ def pick_block_size(max_seq_len, heads, head_dim, dtype="float32",
     override, else the decode-attention autotune table (measured on TPU,
     disk-cached — same (kernel, shape-bucket, dtype) key family as the
     contiguous kernel), else the 128-column heuristic clamped to the
-    sequence budget. Always a multiple of the 8-row sublane tile."""
+    sequence budget. Always a multiple of 8; the table offers only lane
+    multiples (128, 256), which is where the arena is copy-free."""
     from ..core import flags as _flags
     from ..ops.pallas import autotune
     from ..ops.pallas.flash_attention import _ceil_to, _pick_block
@@ -81,7 +97,7 @@ def pick_block_size(max_seq_len, heads, head_dim, dtype="float32",
         (bs_,) = params
         nb = max(L // bs_, 1)
         h, d = int(heads), int(head_dim)
-        ka = jnp.zeros((nb + 1, h, bs_, d), dtype)
+        ka = jnp.zeros(KVBlockPool(nb, bs_).arena_shape(h, d), dtype)
         q = jnp.zeros((batch, h, 8, d), dtype)
         bt = jnp.tile(jnp.arange(1, nb + 1, dtype=jnp.int32), (batch, 1))
         lens = jnp.full((batch,), nb * bs_, jnp.int32)
@@ -90,7 +106,7 @@ def pick_block_size(max_seq_len, heads, head_dim, dtype="float32",
             a, k_, v_, b_, ln, float(d) ** -0.5))
         return autotune.time_thunk(lambda: fn(q, ka, ka, bt, lens))
 
-    cands = [(x,) for x in (256, 128, 64) if L % x == 0]
+    cands = [(x,) for x in (256, 128) if L % x == 0]
     if len(cands) <= 1:
         return default
     return autotune.lookup(
@@ -150,12 +166,17 @@ class KVBlockPool:
                 raise ValueError(f"double free of block {b}")
             self._free.append(b)
 
+    def arena_shape(self, heads, head_dim):
+        """[n_blocks + 1, h, d, block_size]: tokens in the lanes (see
+        the module docstring)."""
+        return (self.n_blocks + 1, int(heads), int(head_dim),
+                self.block_size)
+
     def arenas(self, layers, heads, head_dim, dtype=jnp.float32):
         """Fresh zeroed k/v arena pairs, one per layer:
-        [(k, v), ...] each [n_blocks + 1, h, block_size, d] (row 0 =
-        trash). Zeros, not empty: a fresh pool must attend to nothing."""
-        shape = (self.n_blocks + 1, int(heads), self.block_size,
-                 int(head_dim))
+        [(k, v), ...] each `arena_shape` (row 0 = trash). Zeros, not
+        empty: a fresh pool must attend to nothing."""
+        shape = self.arena_shape(heads, head_dim)
         return [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
                 for _ in range(int(layers))]
 
@@ -165,23 +186,83 @@ class KVBlockPool:
 # --------------------------------------------------------------------------
 
 def write_kv(arena, block_tables, lengths, new_kv):
-    """Scatter a chunk's k (or v) into the paged arena. `new_kv` is
-    [b, s, h, d] — the s new tokens per slot land at logical positions
+    """Write a chunk's k (or v) into the paged arena, in place. `new_kv`
+    is [b, s, h, d] — the s new tokens per slot land at logical positions
     lengths[i]..lengths[i]+s-1. Positions past a slot's table (or rows
     the scheduler parked with an all-zero table) redirect to the trash
-    block, so masked/padded rows can never corrupt another request."""
-    b, s = new_kv.shape[0], new_kv.shape[1]
-    bs = arena.shape[2]
-    nb = block_tables.shape[1]
-    pos = (jnp.asarray(lengths, jnp.int32)[:, None]
-           + jnp.arange(s, dtype=jnp.int32)[None])        # [b, s]
-    blk_raw = pos // bs
-    blk = jnp.minimum(blk_raw, nb - 1)
-    phys = jnp.take_along_axis(jnp.asarray(block_tables, jnp.int32),
-                               blk, axis=1)               # [b, s]
-    phys = jnp.where(blk_raw < nb, phys, TRASH_BLOCK)
-    off = pos % bs
-    return arena.at[phys, :, off].set(new_kv.astype(arena.dtype))
+    block, so masked/padded rows can never corrupt another request.
+
+    The arena is only ever updated a whole [1, h, d, block_size] block
+    at a time, in place (an XLA scatter, or an update one token wide,
+    asks for a layout of its own and copies the arena to get it). s == 1,
+    the decode step, goes to the Pallas writer when its gate admits
+    (ops/pallas/decode_attention.paged_write_token). Everything else is
+    a loop over (slot, block the chunk reaches — at most `touched` per
+    slot): read the block, take the chunk's tokens where they fall in
+    it, keep its own contents elsewhere, `dynamic_update_slice` it back.
+    A loop, not unrolled: a serve program holds two writes per layer."""
+    bt = jnp.asarray(block_tables, jnp.int32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    if new_kv.shape[1] == 1 and _write_kernel_eligible(arena):
+        from ..ops.pallas import run_guarded
+        return run_guarded("paged_write_token",
+                           lambda: _write_token(arena, bt, lens, new_kv))
+    return _write_blocks(arena, bt, lens, new_kv)
+
+
+def _phys_row(bt, i, blk):
+    """Physical row of slot i's logical block `blk`: the trash block
+    past the table."""
+    nb = bt.shape[1]
+    return jnp.where(blk < nb, bt[i, jnp.minimum(blk, nb - 1)],
+                     jnp.int32(TRASH_BLOCK))
+
+
+def _tokens_to_lanes(arena, new_kv):
+    """[b, s, h, d] -> [b, h, d, s] in the arena's dtype."""
+    return jnp.transpose(new_kv.astype(arena.dtype), (0, 2, 3, 1))
+
+
+def _write_token(arena, bt, lens, new_kv):
+    from ..ops.pallas.decode_attention import paged_write_token
+    bs = arena.shape[3]
+    slots = jnp.arange(new_kv.shape[0], dtype=jnp.int32)
+    return paged_write_token(arena, _phys_row(bt, slots, lens // bs),
+                             lens % bs, _tokens_to_lanes(arena, new_kv))
+
+
+# jitted, like the kernels' calls in ops/pallas/decode_attention.py, so
+# that the layers of a serve program trace and lower one loop, not 96
+@jax.jit
+def _write_blocks(arena, bt, lens, new_kv):
+    b, s, h, d = new_kv.shape
+    bs = arena.shape[3]
+    zero = jnp.int32(0)
+    # a block of margin on either side, so that any block's window
+    # [blk*bs - lengths[i], +bs) of the chunk is a plain slice
+    chunk = jnp.pad(_tokens_to_lanes(arena, new_kv),
+                    ((0, 0), (0, 0), (0, 0), (bs, bs)))
+    touched = (s + bs - 2) // bs + 1   # blocks a run of s tokens can reach
+    lane = jnp.arange(bs, dtype=jnp.int32)
+
+    def write_block(n, a):
+        n = jnp.asarray(n, jnp.int32)  # the loop counts in int64 under x64
+        i, j = n // touched, n % touched
+        start = lens[i]
+        blk = start // bs + j
+        row = _phys_row(bt, i, blk)
+        first = blk * bs - start       # chunk index of the block's lane 0
+        new = jax.lax.dynamic_slice(
+            chunk, (i, zero, zero, jnp.clip(first, -bs, s) + bs),
+            (1, h, d, bs))
+        old = jax.lax.dynamic_slice(a, (row, zero, zero, zero),
+                                    (1, h, d, bs))
+        tok = first + lane
+        return jax.lax.dynamic_update_slice(
+            a, jnp.where((tok >= 0) & (tok < s), new, old),
+            (row, zero, zero, zero))
+
+    return jax.lax.fori_loop(0, b * touched, write_block, arena)
 
 
 def paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
@@ -191,14 +272,14 @@ def paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
     softmax as _static_cache_attention, with per-row live lengths. Row r
     of slot i attends logical cols <= lengths[i] + r."""
     b, h, s, d = q.shape
-    bs = k_arena.shape[2]
+    bs = k_arena.shape[3]
     bt = jnp.asarray(block_tables, jnp.int32)
     nb = bt.shape[1]
     L = nb * bs
 
     def gather(arena):
-        g = jnp.take(arena, bt, axis=0)          # [b, nb, h, bs, d]
-        return jnp.moveaxis(g, 2, 1).reshape(b, h, L, d)
+        g = jnp.take(arena, bt, axis=0)          # [b, nb, h, d, bs]
+        return jnp.transpose(g, (0, 2, 1, 4, 3)).reshape(b, h, L, d)
 
     kc, vc = gather(k_arena), gather(v_arena)
     lens = jnp.asarray(lengths, jnp.int32)
@@ -212,24 +293,38 @@ def paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
     return jnp.einsum("bhsl,bhld->bhsd", p, vc).astype(q.dtype)
 
 
-def _paged_kernel_eligible(q, k_arena, training):
-    """Gate for the block-table Pallas kernel; every rejection bumps
-    pallas.gate_reject.paged_decode_attention.{reason} so bench/serve
-    output can say why the pool path ran on jnp."""
+def _paged_gate(kernel, training, supported):
+    """Gate shared by the pool's two Pallas kernels; every rejection
+    bumps pallas.gate_reject.{kernel}.{reason} so bench/serve output can
+    say why the pool path ran on jnp. `supported` is a thunk."""
     from ..core import flags as _flags
     from ..ops.pallas import gate_reject
     if not _flags.flag("FLAGS_use_paged_attention"):
-        return gate_reject("paged_decode_attention", "flag_off")
+        return gate_reject(kernel, "flag_off")
     from . import functional as F
     if not F._pallas_backend_ok():
-        return gate_reject("paged_decode_attention", "backend")
+        return gate_reject(kernel, "backend")
     if training:
         # eval-only, like the contiguous decode kernel (no dropout/vjp)
-        return gate_reject("paged_decode_attention", "training")
-    from ..ops.pallas.decode_attention import paged_supported
-    if not paged_supported(tuple(q.shape), tuple(k_arena.shape)):
-        return gate_reject("paged_decode_attention", "shape")
+        return gate_reject(kernel, "training")
+    if not supported():
+        return gate_reject(kernel, "shape")
     return True
+
+
+def _paged_kernel_eligible(q, k_arena, training):
+    from ..ops.pallas.decode_attention import paged_supported
+    return _paged_gate(
+        "paged_decode_attention", training,
+        lambda: paged_supported(tuple(q.shape), tuple(k_arena.shape)))
+
+
+def _write_kernel_eligible(arena):
+    from ..ops.pallas.decode_attention import paged_write_supported
+    return _paged_gate(
+        "paged_write_token", False,
+        lambda: paged_write_supported(tuple(arena.shape),
+                                      arena.dtype.itemsize))
 
 
 def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,

@@ -40,7 +40,8 @@ from .flash_attention import (NEG_INF, _Z, _ceil_to, _cparams, _interpret,
                               _pick_block, _vmem)
 
 __all__ = ["decode_attention", "supported",
-           "paged_decode_attention", "paged_supported"]
+           "paged_decode_attention", "paged_supported",
+           "paged_write_token", "paged_write_supported"]
 
 
 def _decode_attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -176,15 +177,24 @@ def _pick_bk(shape, dtype, scale, measure_builder):
 # The contiguous kernel above assumes each batch row owns a private
 # [L, d] cache slab. The continuous-batching serve loop
 # (inference/serving.py) instead shares ONE physical arena
-# [n_blocks, h, block_size, d] across every in-flight request
+# [n_blocks, h, d, block_size] across every in-flight request
 # (nn/kv_pool.py): request i's logical block j lives at physical row
-# block_tables[i, j]. The only change the indirection needs is in the
-# K/V BlockSpec index maps — the block table rides the scalar-prefetch
-# path next to the ragged lengths, so the index map gathers the LIVE
-# physical block for (batch, logical-block) and clamps past the last
-# live one exactly like the contiguous kernel. Per-step HBM traffic
-# therefore scales with ceil(live_len/bs) blocks per request, never
-# with max_seq_len, and never with the arena size.
+# block_tables[i, j]. The indirection lives in the K/V BlockSpec index
+# maps — the block table rides the scalar-prefetch path next to the
+# ragged lengths, so the index map gathers the LIVE physical block for
+# (batch, logical-block) and clamps past the last live one exactly like
+# the contiguous kernel. Per-step HBM traffic therefore scales with
+# ceil(live_len/bs) blocks per request, never with max_seq_len, and
+# never with the arena size.
+#
+# Layout contract: a K/V block is [d, block_size], TOKENS IN THE LANES.
+# A Mosaic custom call takes its operands row-major; XLA's default TPU
+# layout of a 4-d bf16 array is row-major only when the minor dimension
+# fills the 128 lanes. With d (64) minor, XLA put block_size in the
+# lanes instead, the kernel's operand and the pool's buffer disagreed,
+# and every call copied every arena (PR 26: 76 % of a decode beat). With
+# block_size minor and a multiple of 128 the pool's buffer IS the
+# kernel's operand; nn/kv_pool.write_kv updates it in place.
 
 def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
                               m_scr, l_scr, acc_scr, *, scale, bs, nb, s):
@@ -210,8 +220,8 @@ def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ik <= last)
     def _compute():
         q = q_ref[0, 0]                        # [s, d]
-        k = k_ref[0, 0]                        # [bs, d]
-        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        kt = k_ref[0, 0]                       # [d, bs]
+        sc = jax.lax.dot_general(q, kt, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
         row = jax.lax.broadcasted_iota(jnp.int32, (s, bs), 0)
         col = ik * bs + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 1)
@@ -221,8 +231,9 @@ def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(sc - m_new)                # [s, bs] f32
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        # p [s, bs] against vT [d, bs]: both contract their lane dim
         pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0, 0],
-                                 (((1,), (0,)), ((), ())),
+                                 (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = m_new
@@ -235,12 +246,12 @@ def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_supported(q_shape, arena_shape) -> bool:
     """Static predicate: can the paged kernel serve q [b, h, s, d] over
-    an arena [n_blocks, h, block_size, d]? block_size is fixed by the
+    an arena [n_blocks, h, d, block_size]? block_size is fixed by the
     pool layout, so it must already be a sublane-tile multiple."""
     if len(q_shape) != 4 or len(arena_shape) != 4:
         return False
     b, h, s, d = q_shape
-    nb_phys, hl, bs, dl = arena_shape
+    nb_phys, hl, dl, bs = arena_shape
     if (hl, dl) != (h, d):
         return False
     if d > 256 or s < 1 or s > 256:
@@ -249,11 +260,27 @@ def paged_supported(q_shape, arena_shape) -> bool:
 
 
 def _paged_call(q, k_arena, v_arena, block_tables, lengths, scale):
-    """The pallas_call for already-tile-padded q. The arena is NOT
-    padded or copied — indirection is the whole point."""
+    """The pallas_call for already-tile-padded q over arenas
+    [n_blocks, h, d, block_size]. With block_size a multiple of 128 the
+    compiled program hands the pool's buffers to the kernel as they are
+    (tests/test_chip_smoke.py holds that: no copy, no temp of arena
+    size); other multiples of 8 are correct, and XLA relays them out."""
+    return _paged_call_once(q, k_arena, v_arena, block_tables, lengths,
+                            scale=float(scale), interpret=_interpret())
+
+
+# The two calls below are jitted so that a program with many identical
+# layers traces and lowers each of them once (XLA inlines the calls):
+# un-jitted, each of GPT-2 XL's serve programs spent seconds re-tracing 48
+# identical kernels, all of it set-up time (PR 26). What they read from
+# flags is a static argument, so a cached trace never outlives a flag.
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_call_once(q, k_arena, v_arena, block_tables, lengths, *, scale,
+                     interpret):
     from jax.experimental.pallas import tpu as pltpu
     b, h, s_p, d = q.shape
-    bs = k_arena.shape[2]
+    bs = k_arena.shape[3]
     nb = block_tables.shape[1]
 
     def q_map(ib, ih, ik, len_ref, bt_ref):
@@ -276,8 +303,8 @@ def _paged_call(q, k_arena, v_arena, block_tables, lengths, scale):
         grid=(b, h, nb),
         in_specs=[
             pl.BlockSpec((1, 1, s_p, d), q_map),
-            pl.BlockSpec((1, 1, bs, d), kv_map),
-            pl.BlockSpec((1, 1, bs, d), kv_map),
+            pl.BlockSpec((1, 1, d, bs), kv_map),
+            pl.BlockSpec((1, 1, d, bs), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, s_p, d), q_map),
         scratch_shapes=[
@@ -287,13 +314,13 @@ def _paged_call(q, k_arena, v_arena, block_tables, lengths, scale):
         ],
     )
     kernel = functools.partial(_paged_decode_attn_kernel,
-                               scale=float(scale), bs=bs, nb=nb, s=s_p)
+                               scale=scale, bs=bs, nb=nb, s=s_p)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_p, d), q.dtype),
         compiler_params=_cparams("parallel", "parallel", "arbitrary"),
-        interpret=_interpret(),
+        interpret=interpret,
     )(lengths, block_tables, q, k_arena, v_arena)
 
 
@@ -301,21 +328,21 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
                            scale=None):
     """Attention of q [b, h, s, d] over a PAGED cache: per-request block
     tables [b, max_blocks] of physical block ids into shared arenas
-    k_arena/v_arena [n_blocks, h, block_size, d]. `lengths` [b] is each
+    k_arena/v_arena [n_blocks, h, d, block_size]. `lengths` [b] is each
     request's cache fill count BEFORE this chunk (the chunk's k/v must
-    already be scattered into the arena — nn/kv_pool.write_kv). Row r of
+    already be written into the arena — nn/kv_pool.write_kv). Row r of
     batch i attends to logical cache cols <= lengths[i] + r. Block-table
     entries past the allocation MUST be 0 (the pool's reserved trash
     block): padded query rows reach past the live end and the index map
     must land on a valid physical row. Eval-only (no vjp); returns
     [b, h, s, d] in q's dtype."""
     b, h, s, d = q.shape
-    if v_arena.shape != k_arena.shape or k_arena.shape[3] != d \
+    if v_arena.shape != k_arena.shape or k_arena.shape[2] != d \
             or k_arena.shape[1] != h:
         raise ValueError(
             f"paged_decode_attention: arena shapes k{tuple(k_arena.shape)} "
             f"v{tuple(v_arena.shape)} don't match q{tuple(q.shape)}")
-    bs = k_arena.shape[2]
+    bs = k_arena.shape[3]
     if bs % 8 != 0 or bs < 8:
         raise ValueError(
             f"paged_decode_attention: block_size {bs} must be a multiple "
@@ -339,6 +366,71 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
     out = _paged_call(q, k_arena, v_arena, bt, lens, scale)
     out = out.astype(out_dtype)
     return out[:, :, :s] if s_p != s else out
+
+
+# The decode step's write: one token per slot into the same arena, as a
+# kernel because XLA's form of it (read the slot's block, select, write it
+# back, in a loop over slots) runs the slots one after another — 0.26 ms a
+# layer at 32 slots on a v5e against 0.08 ms here, where the grid's
+# pipeline fetches slot i+1's block while slot i's is written back (PR 26).
+
+def _paged_write_kernel(row_ref, off_ref, col_ref, blk_ref, out_ref):
+    """Grid (b,): step i holds slot i's block [1, h, d, bs], aliased in
+    and out, and puts the slot's token [1, h, d, 1] in lane off[i]."""
+    off = off_ref[pl.program_id(0)]
+    lane = jax.lax.broadcasted_iota(jnp.int32, blk_ref.shape[1:], 2)
+    out_ref[0] = jnp.where(lane == off, col_ref[0], blk_ref[0])
+
+
+# a step holds the block twice in and twice out (double buffering) plus
+# the lane-padded token: keep that well inside Mosaic's scoped VMEM
+_WRITE_BLOCK_BYTES = 2 << 20
+
+
+def paged_write_supported(arena_shape, itemsize) -> bool:
+    """Static predicate: does a slot's whole block fit the writer's VMEM
+    budget? [n_blocks, h, d, block_size] arenas only."""
+    if len(arena_shape) != 4:
+        return False
+    _, h, d, bs = arena_shape
+    return bs % 8 == 0 and \
+        h * d * max(bs, 128) * int(itemsize) <= _WRITE_BLOCK_BYTES
+
+
+def paged_write_token(arena, rows, offsets, cols):
+    """arena [n_blocks, h, d, bs] with cols[i] ([b, h, d, 1], the arena's
+    dtype) written to lane offsets[i] of physical row rows[i] (both [b]
+    i32), in place. Two slots may share a row only if it is the trash
+    block: a step reads its block before the step before it has written
+    its own back."""
+    return _paged_write_once(arena, rows, offsets, cols,
+                             interpret=_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_write_once(arena, rows, offsets, cols, *, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    _, h, d, bs = arena.shape
+    b = cols.shape[0]
+
+    def col_map(i, row_ref, off_ref):
+        return (i, _Z, _Z, _Z)
+
+    def blk_map(i, row_ref, off_ref):
+        return (row_ref[i], _Z, _Z, _Z)
+
+    blk = pl.BlockSpec((1, h, d, bs), blk_map)
+    return pl.pallas_call(
+        _paged_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b,),
+            in_specs=[pl.BlockSpec((1, h, d, 1), col_map), blk],
+            out_specs=blk),
+        out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
+        input_output_aliases={3: 0},    # operands: rows, offsets, cols, arena
+        compiler_params=_cparams("arbitrary"),
+        interpret=interpret,
+    )(rows, offsets, cols, arena)
 
 
 def decode_attention(q, kc, vc, index, scale=None, block_k=None):

@@ -102,7 +102,7 @@ def _compile_kernels_for_v5e():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     from paddle_tpu.ops.pallas.decode_attention import (
-        decode_attention, paged_decode_attention)
+        decode_attention, paged_decode_attention, paged_write_token)
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.ops.pallas.fused_ce import fused_linear_cross_entropy
     try:
@@ -140,26 +140,105 @@ def _compile_kernels_for_v5e():
     cache = ((2, 4, 512, 64), bf16)
     compile_for_v5e(decode_attention, ((2, 4, 1, 64), bf16), cache, cache,
                     ((2,), i32))
-    arena = ((9, 4, 64, 64), bf16)
-    compile_for_v5e(paged_decode_attention, ((2, 4, 8, 64), bf16), arena,
-                    arena, ((2, 4), i32), ((2,), i32))
+    for block in (128, 16):  # the serving size; a tests-only size
+        arena = ((9, 4, 64, block), bf16)
+        compile_for_v5e(paged_decode_attention, ((2, 4, 8, 64), bf16),
+                        arena, arena, ((2, 4), i32), ((2,), i32))
+        compile_for_v5e(paged_write_token, arena, ((2,), i32), ((2,), i32),
+                        ((2, 4, 64, 1), bf16))
     print("MOSAIC-OK")
 
 
-def test_kernels_compile_under_mosaic_for_v5e():
-    """What interpret mode cannot see is Mosaic itself — 64-bit index
-    maps, layouts, block shapes, VMEM. Results still need the chip. Runs in
-    a CPU child: libtpu's threads must not live in this process, which
-    later forks DataLoader workers."""
+def _run_in_cpu_child(body, ok):
+    """Run this module's `body()` in a CPU child: libtpu's threads must
+    not live in this process, which later forks DataLoader workers."""
     here = os.path.dirname(os.path.abspath(__file__))
     code = (f"import sys; sys.path[:0] = [{here!r}, {os.path.dirname(here)!r}]"
-            "; import test_chip_smoke as t; t._compile_kernels_for_v5e()")
+            f"; import test_chip_smoke as t; t.{body}()")
     r = subprocess.run([sys.executable, "-c", code],
                        env=dict(os.environ, JAX_PLATFORMS="cpu"),
                        capture_output=True, text=True, timeout=300)
     if "NO-TOPOLOGY" in r.stdout:
         pytest.skip(r.stdout.strip())
-    assert "MOSAIC-OK" in r.stdout, r.stdout + r.stderr[-3000:]
+    assert ok in r.stdout, r.stdout + r.stderr[-3000:]
+    return r.stdout
+
+
+def test_kernels_compile_under_mosaic_for_v5e():
+    """What interpret mode cannot see is Mosaic itself — 64-bit index
+    maps, layouts, block shapes, VMEM. Results still need the chip."""
+    _run_in_cpu_child("_compile_kernels_for_v5e", "MOSAIC-OK")
+
+
+def _compile_paged_steps_for_v5e():
+    """Child-process body of the test below: two layers of write-then-
+    attend over donated arenas of the benchmark's pool shape, as a decode
+    step and as a prefill, compiled for v5e; one JSON line a step says
+    what the compiled program does to a buffer of arena shape."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.nn.kv_pool import KVBlockPool, paged_attention, write_kv
+    try:
+        device = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
+    except Exception as e:  # environment without a usable libtpu
+        print(f"NO-TOPOLOGY {type(e).__name__}: {e}")
+        return
+    sharding = SingleDeviceSharding(device)
+    heads, d, table, bf16 = 25, 64, 8, jnp.bfloat16
+    arena = KVBlockPool(224, 128).arena_shape(heads, d)
+
+    def step(arenas, tables, lengths, q, k, v):
+        out = []
+        for ka, va in arenas:
+            ka = write_kv(ka, tables, lengths, k)
+            va = write_kv(va, tables, lengths, v)
+            q = paged_attention(q, ka, va, tables, lengths, d ** -0.5)
+            out.append((ka, va))
+        return out, q
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    paddle.set_flags({"FLAGS_pallas_force_compile": True})
+    for b, s in ((32, 1), (1, 256)):
+        monitor.reset(prefix="pallas.")
+        kv = spec((b, s, heads, d), bf16)
+        compiled = jax.jit(step, donate_argnums=(0,)).trace(
+            [(spec(arena, bf16),) * 2] * 2, spec((b, table), jnp.int32),
+            spec((b,), jnp.int32), spec((b, heads, s, d), bf16), kv, kv,
+        ).lower(lowering_platforms=("tpu",)).compile()
+        text = compiled.as_text()
+        hits = monitor.stats("pallas.hit.")
+        print("STEP " + json.dumps({
+            "s": s,
+            "arena_in_hlo": "[%s]" % ",".join(map(str, arena)) in text,
+            "relayouts": chip_smoke.arena_relayouts(text, arena),
+            "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+            "arena_bytes": int(np.prod(arena)) * 2,
+            "attn": hits.get("pallas.hit.paged_decode_attention", 0),
+            "writer": hits.get("pallas.hit.paged_write_token", 0)}))
+    print("PAGED-STEPS-DONE")
+
+
+def test_paged_serve_steps_hold_no_arena_copy_for_v5e():
+    """The paged arena has ONE device layout: the pool's default layout,
+    write_kv's in-place updates and the paged kernel's operand constraint
+    agree, so a compiled decode step and a compiled prefill hold no copy
+    or transpose of arena shape and no temp of arena size (PR 26: with
+    [n, h, block, d] arenas each program copied each arena three times,
+    76 % of a decode beat). A property of the compiled program, so it is
+    held here, ahead of time for v5e, not sampled at run time."""
+    out = _run_in_cpu_child("_compile_paged_steps_for_v5e",
+                            "PAGED-STEPS-DONE")
+    decode, prefill = (json.loads(line[5:]) for line in out.splitlines()
+                       if line.startswith("STEP "))
+    for step in (decode, prefill):
+        assert step["arena_in_hlo"] and step["relayouts"] == [], step
+        assert step["temp_bytes"] < step["arena_bytes"] // 10, step
+        assert step["attn"] == 2, step
+    assert (decode["s"], decode["writer"]) == (1, 4)      # the Pallas writer
+    assert (prefill["s"], prefill["writer"]) == (256, 0)  # the XLA loop
 
 
 def test_autotune_lookup_never_measures_under_trace():

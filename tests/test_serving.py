@@ -88,19 +88,21 @@ def test_pool_rejects_bad_block_size():
 # block-table kernel parity vs the jnp fallback
 # --------------------------------------------------------------------------
 
+@pytest.mark.parametrize("bs", [8, 16, 128])
 @pytest.mark.parametrize("s", [1, 8])
-def test_paged_kernel_parity_fill_levels(interpret, s):
+def test_paged_kernel_parity_fill_levels(interpret, s, bs):
     """Several fill levels across slots — from one partial block to a
-    full table — kernel vs gather fallback."""
+    full table — kernel vs gather fallback, at block sizes under, at and
+    over a lane tile's sublane count and at the lane width itself."""
     from paddle_tpu.ops.pallas.decode_attention import (
         paged_decode_attention, paged_supported)
     rng = np.random.RandomState(0)
-    b, h, d, bs, MB, NB = 4, 2, 16, 16, 4, 14
+    b, h, d, MB, NB = 4, 2, 16, 4, 14
     pool = KVBlockPool(NB, bs)
-    ka = jnp.zeros((NB + 1, h, bs, d), jnp.float32)
-    va = jnp.zeros((NB + 1, h, bs, d), jnp.float32)
+    (ka, va), = pool.arenas(1, h, d)
     bt = np.zeros((b, MB), np.int32)
-    fills = [9, 16, 37, 64]          # 1 part, 1 full, 3 part, 4 full blocks
+    # 1 part (if it holds the chunk), 1 full, 3 part, 4 full blocks
+    fills = [max(bs // 2 + 1, s), bs, 2 * bs + 5, 4 * bs]
     for i, ln in enumerate(fills):
         blocks = pool.alloc(pool.blocks_for(ln))
         bt[i, :len(blocks)] = blocks
@@ -119,6 +121,84 @@ def test_paged_kernel_parity_fill_levels(interpret, s):
                                atol=2e-5)
 
 
+def _write_kv_numpy(arena, bt, lens, new):
+    """Token-at-a-time reference for write_kv over the pool's arena
+    [n, h, d, block_size]: position p of slot i lives in lane p % bs of
+    row bt[i, p // bs]; past the table it is the trash block's."""
+    out = np.array(arena)
+    bs, nb = out.shape[3], bt.shape[1]
+    for i in range(new.shape[0]):
+        for t in range(new.shape[1]):
+            p = int(lens[i]) + t
+            row = bt[i, p // bs] if p // bs < nb else 0
+            out[row, :, :, p % bs] = new[i, t]
+    return out
+
+
+# (block_size, table width, lengths, chunk, block tables or None = one
+# run of blocks per slot); bs 128 is the serving size, 16 keeps the
+# multi-slot and past-the-table cases small
+_WRITE_CASES = {
+    "s1_offset0": (128, 2, [0, 128], 1, None),
+    "s1_offset127": (128, 2, [127, 255], 1, None),
+    "s1_many_slots": (16, 3, [0, 15, 16, 47, 5], 1, None),
+    "s8_from0": (128, 2, [0], 8, None),
+    "s64_from0": (128, 2, [0], 64, None),
+    "s256_from0": (128, 2, [0], 256, None),
+    "s64_four_slots": (128, 1, [0, 0, 0, 0], 64, None),
+    "mid_block": (16, 4, [5, 23], 20, None),
+    "mid_block_to_boundary": (16, 4, [9], 7, None),
+    "s1_past_table": (16, 2, [32, 31], 1, None),
+    "chunk_runs_past_table": (16, 2, [20], 30, None),
+    "zero_table_s1": (16, 2, [3, 0], 1, np.zeros((2, 2), np.int32)),
+    "zero_table_chunk": (16, 2, [3], 24, np.zeros((1, 2), np.int32)),
+}
+
+
+def _check_write_case(case):
+    bs, nb, lens, s, bt = _WRITE_CASES[case]
+    b, h, d = len(lens), 2, 8
+    pool = KVBlockPool(b * nb + 2, bs)
+    trash_only = bt is not None
+    if bt is None:
+        bt = np.asarray([pool.alloc(nb) for _ in range(b)], np.int32)
+    rng = np.random.RandomState(1)
+    (arena, _), = pool.arenas(1, h, d)
+    arena = np.asarray(arena) + rng.randn(*arena.shape).astype(np.float32)
+    new = rng.randn(b, s, h, d).astype(np.float32)
+    lens = np.asarray(lens, np.int32)
+    got = np.asarray(write_kv(jnp.asarray(arena), jnp.asarray(bt),
+                              jnp.asarray(lens), jnp.asarray(new)))
+    want = _write_kv_numpy(arena, bt, lens, new)
+    np.testing.assert_array_equal(got[1:], want[1:])
+    if trash_only:
+        np.testing.assert_array_equal(got[1:], arena[1:])
+    else:
+        assert not np.array_equal(got[1:], arena[1:])
+
+
+@pytest.mark.parametrize("case", sorted(_WRITE_CASES))
+def test_write_kv_matches_numpy_reference(case):
+    """write_kv (its XLA form: the writer kernel's gate rejects on the
+    CPU) against a token-at-a-time numpy writer: single tokens at a
+    block's first and last lane, chunks from length 0 and from inside a
+    block, positions past the table and parked (all-zero) tables — which
+    may touch the trash block and nothing else."""
+    monitor.reset(prefix="pallas.")
+    _check_write_case(case)
+    assert monitor.stat_get("pallas.hit.paged_write_token") == 0
+
+
+@pytest.mark.parametrize(
+    "case", sorted(c for c in _WRITE_CASES if _WRITE_CASES[c][3] == 1))
+def test_write_kv_token_kernel_matches_numpy_reference(interpret, case):
+    """The decode step's single-token write through the Pallas writer
+    (interpreted), same cases, same reference."""
+    monitor.reset(prefix="pallas.")
+    _check_write_case(case)
+    assert monitor.stat_get("pallas.hit.paged_write_token") == 1
+
+
 def test_mha_paged_matches_static_cache_bitwise():
     """The MHA PagedKVCache branch (jnp path) must be BITWISE equal to
     the StaticKVCache path across a prefill + decode sequence — the
@@ -133,9 +213,9 @@ def test_mha_paged_matches_static_cache_bitwise():
     bt = np.zeros((b, MB), np.int32)
     for i in range(b):
         bt[i, :] = pool.alloc(MB)
-    paged = PagedKVCache(jnp.zeros((NB + 1, 2, bs, 16), jnp.float32),
-                         jnp.zeros((NB + 1, 2, bs, 16), jnp.float32),
-                         jnp.asarray(bt), jnp.zeros((b,), jnp.int32))
+    (ka, va), = pool.arenas(1, 2, 16)
+    paged = PagedKVCache(ka, va, jnp.asarray(bt),
+                         jnp.zeros((b,), jnp.int32))
     rng = np.random.RandomState(5)
     for chunk in (7, 1, 1, 1):
         x = paddle.to_tensor(rng.randn(b, chunk, 32).astype(np.float32))

@@ -291,6 +291,7 @@ def lower_serve_decode_step(cfg, use_kernel=True):
 
     import paddle_tpu as paddle
     from paddle_tpu.inference.serving import build_decode_step
+    from paddle_tpu.nn.kv_pool import KVBlockPool
     from paddle_tpu.text.models.gpt import GPT, GPTConfig
 
     A, bs = cfg["slots"], cfg["block_size"]
@@ -307,8 +308,8 @@ def lower_serve_decode_step(cfg, use_kernel=True):
     params, buffers = net.functional_state()
     heads = gcfg.num_heads
     hd = gcfg.hidden_size // heads
-    arena = jax.ShapeDtypeStruct((nb + 1, heads, bs, hd), jnp.float32)
-    arenas = [(arena, arena) for _ in range(gcfg.num_layers)]
+    arenas = jax.eval_shape(lambda: KVBlockPool(nb, bs).arenas(
+        gcfg.num_layers, heads, hd, jnp.float32))
     bt = jax.ShapeDtypeStruct((A, mb), jnp.int32)
     lens = jax.ShapeDtypeStruct((A,), jnp.int32)
     toks = jax.ShapeDtypeStruct((A,), jnp.int32)
@@ -774,7 +775,9 @@ def self_check():
                         f"bench shape (b={b}, L={L})")
     sA, sbs, snb = SERVE_CFG["slots"], SERVE_CFG["block_size"], \
         SERVE_CFG["blocks"]
-    if not da.paged_supported((sA, 12, 1, 64), (snb + 1, 12, sbs, 64)):
+    from paddle_tpu.nn.kv_pool import KVBlockPool
+    if not da.paged_supported((sA, 12, 1, 64),
+                              KVBlockPool(snb, sbs).arena_shape(12, 64)):
         problems.append("hlo_evidence: paged-decode gate rejects the "
                         f"serve config (slots={sA}, bs={sbs})")
     n_tok_gpt = LONGSEQ_CFG["batch"] * s
