@@ -15,7 +15,8 @@ field the scheduler reads at each token) so that `stop()` returns at once.
 
 Traffic keys: name, arrival, tenants, seed_burst, lead_in_s, deadline_s,
 unfinished_is_failure, sample_every_s, trace_seconds (with --trace 1 the
-profiler covers that many seconds right after the window, load still on).
+profiler covers that many seconds right after the window, load still on),
+and for a saturating mix knee_rps and headroom (`knee_line`).
 `plan()` / `measure()` are what benchmark/sweep.py repeats per rate.
 """
 from __future__ import annotations
@@ -28,7 +29,7 @@ import numpy as np
 from benchmark.lib import accounting, profiler
 from benchmark.lib.build import build_net
 from benchmark.lib.forced_check import forced_logits
-from benchmark.lib.stats import samples_beyond
+from benchmark.lib.stats import longest_still_s, samples_beyond, slot_fill
 from benchmark.lib.workload import build_schedule
 
 COUNTERS = ("serve.tokens_generated", "serve.requests_completed",
@@ -137,7 +138,7 @@ def measure(loop, schedule, mix, seconds, trace_dir=None):
         samples = []
         t_end = open_["t"] + seconds
         while time.perf_counter() < t_end:
-            samples.append(loop.stats())
+            samples.append(dict(loop.stats(), t=time.perf_counter()))
             time.sleep(max(0.0, min(every, t_end - time.perf_counter())))
         close = {"t": time.perf_counter(), "counters": _counters(),
                  "steps": loop.stats()["steps"],
@@ -195,23 +196,59 @@ def plan(config, mix, seed, seconds):
                           int(kwargs["max_seq_len"]), horizon)
 
 
+def knee_line(mix, samples, max_active):
+    """For a mix that declares `headroom` (offered at that many times its
+    knee, so that completed tokens/s is capacity): the log line that says
+    whether the window ran above the knee, from the share of decode slots
+    that produced a token. Under 90 % the server drained what was offered,
+    the cell reads offered load, and the next issue re-rates the mix
+    (benchmark/README.md). It decides nothing: a program that got faster
+    must not fail its own cell. None for a mix without `headroom`, or
+    when the program's `stats()` does not count decode tokens."""
+    fill = slot_fill(samples, max_active)
+    if "headroom" not in mix or fill is None:
+        return None
+    verdict = ("above its knee" if fill >= 0.9 else
+               "below its knee: re-rate the mix (benchmark/README.md)")
+    return (f"serve_open_loop: mix {mix['name']} declares {mix['headroom']} "
+            f"x its knee of {mix.get('knee_rps')}/s: decode_tokens / (steps "
+            f"x max_active) = {100.0 * fill:.2f} % over the window, {verdict}")
+
+
+def load_line(m):
+    """The log line that tells a stalled run from a slow program: the
+    window's beats, the longest time the scheduler's `steps` stood still
+    and the longest gap between two of the driver's samples (one or two
+    sampling intervals in a sound run, seconds where the serve loop or the
+    whole process was held), the generator's worst lateness over the
+    requests due in the window and how many it submitted over 100 ms late.
+    It decides nothing."""
+    late = [(r["t_submit"] - r["t_due"]) * 1e3 for r in m["rows"]
+            if r["t_submit"]]
+    still, gap = longest_still_s(m["samples"]) or (float("nan"),) * 2
+    return (f"serve_open_loop: load: {m['close']['steps'] - m['open']['steps']}"
+            f" beats in {m['window_s']:.3f} s, longest without a beat "
+            f"{still:.3f} s, longest gap between samples {gap:.3f} s, "
+            f"generator latest {max(late, default=0.0):.1f} ms with "
+            f"{sum(x > 100.0 for x in late)} over 100 ms")
+
+
 def check(config, net, loop, m, vocab):
-    """Why the run is not correct: an empty list when it is."""
+    """(why the run is not correct: an empty list when it is; every number
+    compared beside its limit, {name: [value, limit]})."""
     from paddle_tpu.core import monitor
     why = []
     errors = [r for r in m["rows"] if r["error"]]
     if errors:
         why.append(f"{len(errors)} requests failed, first: "
                    f"{errors[0]['error']}")
-    for r in m["rows"]:
-        if r["finished"] and not r["error"]:
-            out = r["out"]
-            if len(out) != r["n_out_wanted"] or not all(
-                    0 <= t < vocab for t in out):
-                why.append(f"request {r['index']}: {len(out)} tokens, wanted "
-                           f"{r['n_out_wanted']}, or ids outside the "
-                           "vocabulary")
-                break
+    malformed = [r for r in m["rows"] if r["finished"] and not r["error"]
+                 and (len(r["out"]) != r["n_out_wanted"]
+                      or not all(0 <= t < vocab for t in r["out"]))]
+    if malformed:
+        r = malformed[0]
+        why.append(f"request {r['index']}: {len(r['out'])} tokens, wanted "
+                   f"{r['n_out_wanted']}, or ids outside the vocabulary")
     if m["compiles_in_window"]:
         why.append(f"{m['compiles_in_window']} compiles inside the window")
     fc = config["forced_check"]
@@ -226,7 +263,12 @@ def check(config, net, loop, m, vocab):
         if not err <= float(fc["tol"]):
             why.append(f"teacher-forced {beat} logits: kernel vs "
                        f"paged_attention_ref err {err:.3g} > {fc['tol']}")
-    return why, errs
+    compared = {"requests_errored": [len(errors), 0],
+                "outputs_malformed": [len(malformed), 0],
+                "compiles_in_window": [m["compiles_in_window"], 0]}
+    compared.update({f"forced_{beat}_logits_err": [err, float(fc["tol"])]
+                     for beat, err in errs.items()})
+    return why, compared
 
 
 def run(cell):
@@ -249,7 +291,7 @@ def run(cell):
     finally:
         loop.stop(timeout=120)
     stats = loop.stats()
-    why, forced = check(config, net, loop, m, vocab)
+    why, compared = check(config, net, loop, m, vocab)
     rows = m["rows"]
     failed = sum(bool(r["error"]) or (
         bool(mix.get("unfinished_is_failure")) and not r["finished"])
@@ -259,7 +301,11 @@ def run(cell):
     print(f"serve_open_loop: buckets {buckets}, block_size "
           f"{stats['block_size']}, {len(rows)} due in {m['window_s']:.3f} s, "
           f"{sum(r['finished'] for r in rows)} finished, window counters "
-          f"{delta}, forced logits err {forced}", flush=True)
+          f"{delta}, compared {compared}", flush=True)
+    line = knee_line(mix, m["samples"], stats["max_active"])
+    if line:
+        print(line, flush=True)
+    print(load_line(m), flush=True)
     for what, xs in (("ttft_ms", [(r["t_first"] - r["t_due"]) * 1e3
                                   for r in rows if r["t_first"]]),
                      ("late_ms", [(r["t_submit"] - r["t_due"]) * 1e3
@@ -269,7 +315,7 @@ def run(cell):
               f"{[round(x, 1) for x in sorted(xs)]}", flush=True)
     return {
         "correct": not why and not failed, "why_incorrect": why,
-        "attempted": len(rows), "failed": failed,
+        "compared": compared, "attempted": len(rows), "failed": failed,
         "setup_s": m["open"]["t"] - cell.t_process_start,
         "window_s": m["window_s"], "chips": 1, "rows": rows,
         "counters": delta,

@@ -123,16 +123,19 @@ def fit_window(model, config, traffic, seed, seconds, chips, trace_dir=None):
 
 
 def check(model, cb, chips, platform):
-    """Why the run is not correct: an empty list when it is."""
+    """(why the run is not correct: an empty list when it is; the losses;
+    every number compared beside its limit, {name: [value, limit]})."""
     import jax
     why = []
     losses = [float(x) for x in cb.losses]   # all materialised by now
     k = max(1, len(losses) // 10)
-    if not all(math.isfinite(x) for x in losses):
+    first, last = sum(losses[:k]) / k, sum(losses[-k:]) / k
+    nonfinite = sum(not math.isfinite(x) for x in losses)
+    if nonfinite:
         why.append("non-finite loss")
-    elif not sum(losses[-k:]) / k < sum(losses[:k]) / k:
-        why.append(f"loss did not fall: first tenth "
-                   f"{sum(losses[:k]) / k:.4f}, last {sum(losses[-k:]) / k:.4f}")
+    elif not last < first:
+        why.append(f"loss did not fall: first tenth {first:.4f}, last "
+                   f"{last:.4f}")
     variants = model._engine._train_fn._cache_size()
     if variants != 1:
         why.append(f"train step compiled in {variants} variants")
@@ -148,7 +151,11 @@ def check(model, cb, chips, platform):
                 for d in jax.devices()[:chips]]
         if not all(held):
             why.append(f"a device holds no bytes: {held}")
-    return why, losses
+    compared = {"losses_nonfinite": [nonfinite, 0],
+                "loss_last_tenth_under_first": [last, first],
+                "step_variants": [variants, 1],
+                "compiles_in_window": [cb.compiles_in_window, 0]}
+    return why, losses, compared
 
 
 def run(cell):
@@ -162,7 +169,8 @@ def run(cell):
     model = build_model(config, traffic, cell.seed)
     cb = fit_window(model, config, traffic, cell.seed, cell.seconds, chips,
                     cell.trace_dir if cell.trace else None)
-    why, losses = check(model, cb, chips, jax.devices()[0].platform)
+    why, losses, compared = check(model, cb, chips,
+                                  jax.devices()[0].platform)
     steps = cb.step_close - cb.step_open
     window = losses[cb.step_open:cb.step_close]
     failed = sum(not math.isfinite(x) for x in window)
@@ -172,7 +180,7 @@ def run(cell):
           flush=True)
     return {
         "correct": not why and not failed, "why_incorrect": why,
-        "attempted": steps, "failed": failed,
+        "compared": compared, "attempted": steps, "failed": failed,
         "setup_s": cb.t_open - cell.t_process_start,
         "window_s": window_s, "chips": chips, "steps": steps,
         "tokens": steps * int(traffic["per_chip_batch"]) * chips

@@ -5,18 +5,14 @@ the scheduler's own `decode_tokens` (tokens appended from decode beats) over
 from completions. Like every per-layer metric PR 25 added it is reported by
 the traced run only; nothing is reported from a program whose `stats()` has
 no `decode_tokens`."""
+from benchmark.lib.stats import slot_fill
+
 LAYER, UNIT, SOURCE, MOVES = ("serve scheduler", "%", "program_counter",
                               "serve_tokens_per_s")
 
 
 def read(obs):
-    samples = obs.get("samples")
-    if ("trace_modules" not in obs or not samples
-            or "decode_tokens" not in samples[0]):
+    if "trace_modules" not in obs:
         return None
-    first, last = samples[0], samples[-1]
-    beats = last["steps"] - first["steps"]
-    if beats <= 0:
-        return None
-    return (100.0 * (last["decode_tokens"] - first["decode_tokens"])
-            / (beats * obs["max_active"]))
+    fill = slot_fill(obs.get("samples"), obs["max_active"])
+    return None if fill is None else 100.0 * fill

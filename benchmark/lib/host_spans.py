@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 import sys
 
 from benchmark.lib import trace_reduce as tr
@@ -155,21 +156,15 @@ def program_gaps(program_events):
             if s1 > e0]
 
 
-def attribute_gaps(program_events, host_events, n=10):
-    """Name each idle interval between consecutive programs on a device by
-    what the host was doing in it: the innermost (shortest) program span
-    that covers most of the gap; where none covers half of it, the span
-    that covers the largest part; "no program span" where none touches it.
-    Returns `([[label, seconds], ...], share)`: the `n` longest gaps, and
-    the share in [0, 1] of all such idle time that lies under some program
-    span (None when the device was never idle between programs)."""
-    gaps = program_gaps(program_events)
-    idle = sum(e - s for s, e in gaps)
-    if not gaps or idle <= 0:
-        return [], None
+def _label_gaps(program_events, host_events):
+    """([(label, start_ns, end_ns), ...] in device order, ns of idle time
+    under some program span) for the idle intervals between consecutive
+    programs: each labelled with the innermost (shortest) program span
+    that covers most of it; where none covers half of it, the span that
+    covers the largest part; "no program span" where none touches it."""
     spans = sorted((s, s + d, name) for name, s, d, *_ in host_events)
     labelled, named = [], 0.0
-    for g0, g1 in gaps:
+    for g0, g1 in program_gaps(program_events):
         # (covers most of the gap, then: shorter span | larger overlap)
         best, best_key = NO_SPAN, None
         for s, e, name in spans:
@@ -183,9 +178,38 @@ def attribute_gaps(program_events, host_events, n=10):
             if best_key is None or key > best_key:
                 best, best_key = name, key
         named += covered_ns(((s, e) for s, e, _ in spans), g0, g1)
-        labelled.append([best, (g1 - g0) * 1e-9])
-    labelled.sort(key=lambda x: -x[1])
-    return labelled[:n], named / idle
+        labelled.append((best, g0, g1))
+    return labelled, named
+
+
+def attribute_gaps(program_events, host_events, n=10):
+    """Name each idle interval between consecutive programs on a device by
+    what the host was doing in it (`_label_gaps`). Returns `([[label,
+    seconds], ...], share)`: the `n` longest gaps, and the share in [0, 1]
+    of all such idle time that lies under some program span (None when the
+    device was never idle between programs)."""
+    labelled, named = _label_gaps(program_events, host_events)
+    idle = sum(g1 - g0 for _, g0, g1 in labelled)
+    if idle <= 0:
+        return [], None
+    ranked = sorted(([label, (g1 - g0) * 1e-9] for label, g0, g1
+                     in labelled), key=lambda x: -x[1])
+    return ranked[:n], named / idle
+
+
+def breakdown_gaps(program_events, host_events, n=10):
+    """The result line's `breakdown.idle_gaps`: the `n` longest idle
+    intervals between programs as `["<span> after <program> before
+    <program>", seconds]`, the program span first so that it survives
+    where the label is cut short."""
+    # the profiler prints a program as `jit_prefill(<fingerprint>)`
+    plain = lambda name: re.sub(r"\(\d+\)$", "", name)  # noqa: E731
+    ends = {s + d: plain(name) for name, s, d in program_events}
+    starts = {s: plain(name) for name, s, _d in program_events}
+    labelled, _ = _label_gaps(program_events, host_events)
+    ranked = sorted(labelled, key=lambda g: g[1] - g[2])[:n]
+    return [[f"{label} after {ends[g0]} before {starts[g1]}",
+             (g1 - g0) * 1e-9] for label, g0, g1 in ranked]
 
 
 def idle_named_share(obs, path=None, n=10):

@@ -129,9 +129,10 @@ def top_ops(events, n=10, key=op_stem):
 
 def idle_gaps(events, n=10):
     """The n longest intervals with no operation on the device, as
-    [[label, seconds], ...]. Until the program puts its host phases on the
-    profiler's clock (ROADMAP S2) a gap can only be named by the operations
-    on either side of it; what the host did in it is not available."""
+    [[label, seconds], ...], each named by the operations on either side
+    of it. The gaps between programs, which the host causes, are named by
+    the program's own spans in host_spans.py; run.py falls back on this
+    where a trace has no program line."""
     merged = _union((s, s + d) for _, s, d in events)
     ends_at = {s + d: name for name, s, d in events}
     starts_at = {s: name for name, s, _d in events}
@@ -140,17 +141,6 @@ def idle_gaps(events, n=10):
     return [[f"host span: not available (after {op_stem(ends_at[e0])}, "
              f"before {op_stem(starts_at[s1])})", length * 1e-9]
             for length, e0, s1 in gaps[:n]]
-
-
-def share_of_busy(events, pattern):
-    """Device time of the events whose name matches `pattern` over device
-    busy time, in [0, 1]. None when nothing matches."""
-    rx = re.compile(pattern)
-    hit = [(s, s + d) for name, s, d in events if rx.search(name)]
-    if not hit:
-        return None
-    busy = busy_s(events)
-    return sum(e - s for s, e in _union(hit)) * 1e-9 / busy
 
 
 def exposed_collective_s(events):

@@ -11,7 +11,8 @@ each read one metric from what the driver observed. Nothing here names a
 cell, a model or a mix: a new one is new files plus one entry.
 
 The last stdout line is one JSON object: correct, attempted, failed,
-metrics, device (and, with --trace 1, breakdown). With --trace 0 the
+metrics, device (with --trace 1, breakdown) and last `compared`: every
+number that decided `correct`, `[value, limit]`. With --trace 0 the
 metrics are the cell's end-to-end metrics, with --trace 1 its per-layer
 metrics. Any platform but "tpu", or fewer chips than the cell asks for,
 exits 2 and prints no result; there is no CPU mode (benchmark/tests/ calls
@@ -143,6 +144,7 @@ def read_metrics(cell, obs, group, kind):
 
 def trace_observations(cell, obs):
     """Reduce the profiler's trace to what readers and `device` need."""
+    from benchmark.lib import host_spans
     from benchmark.lib import trace_reduce as tr
     path = tr.find_xplane(cell.trace_dir)
     if path is None:
@@ -163,9 +165,14 @@ def trace_observations(cell, obs):
         print(f"trace: program {name}: {s:.4f} s", flush=True)
     obs["trace_busy_s"] = sum(tr.busy_s(ops[d]) for d in used) / len(used)
     obs["trace_window_s"] = max(tr.span_s(ops[d]) for d in used)
-    first = ops[used[0]]
-    return {"device_ops": tr.top_ops(first, 10),
-            "idle_gaps": tr.idle_gaps(first, 10)}
+    # the idle time between programs, each gap under the program's own
+    # span that covers it; between single operations (and without a
+    # program line) only the operations on either side are known
+    programs = modules.get(used[0], [])
+    host = [e for line in host_spans.host_lines(path).values() for e in line]
+    return {"device_ops": tr.top_ops(ops[used[0]], 10),
+            "idle_gaps": host_spans.breakdown_gaps(programs, host, 10)
+            or tr.idle_gaps(ops[used[0]], 10)}
 
 
 def main(argv=None):
@@ -208,6 +215,13 @@ def main(argv=None):
           flush=True)
     for why in obs.get("why_incorrect", []):
         print(f"INCORRECT: {why}", flush=True)
+    # every number `correct` compared, beside its limit: the last lines of
+    # stderr and the last key of the result, which is what the driver's
+    # record keeps of a run that was not correct
+    line["compared"] = obs.get("compared", {})
+    for name, (value, limit) in line["compared"].items():
+        print(f"compared: {name} {value!r} limit {limit!r}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

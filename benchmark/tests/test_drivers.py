@@ -51,11 +51,17 @@ def test_train_fit_toy(name, chips):
 
 @pytest.mark.parametrize("name,mix,rate", [("gpt2xl_chat", "chat_sat", 40.0),
                                            ("gpt2xl_doc", "doc_p80", 8.0)])
-def test_serve_open_loop_toy(name, mix, rate):
+def test_serve_open_loop_toy(name, mix, rate, capsys):
     cell = toy.cell(name, toy.gpt_toy(), toy.serve_mix_toy(mix, rate),
                     seconds=2.0)
     obs = runner.load_module("drivers", "serve_open_loop").run(cell)
     assert obs["correct"], obs["why_incorrect"]
+    # the toy drains 40/s: a mix that declares headroom over its knee is
+    # told so; a mix that declares none (doc) gets no such line
+    knee = [ln for ln in capsys.readouterr().out.splitlines()
+            if "its knee" in ln]
+    assert len(knee) == ("headroom" in cell.traffic)
+    assert all("below its knee" in ln for ln in knee)
     assert obs["failed"] == 0 and obs["attempted"] == len(obs["rows"]) > 0
     assert obs["compiles_in_window"] == 0
     assert obs["counters"]["serve.tokens_generated"] > 0
@@ -78,6 +84,53 @@ def test_serve_open_loop_toy(name, mix, rate):
             assert runner.load_module(kind, reader).read(obs) > 0
         assert runner.load_module(
             "layer_metrics", "preempt_per_100req").read(obs) == 0
+
+
+def test_a_saturated_toy_runs_above_its_knee(capsys):
+    """Four slots, outputs of 20-40 tokens, 60 requests/s: more than the
+    toy can hold, so every slot decodes on every beat and the driver's
+    line does not ask for a re-rate; `correct` does not depend on it."""
+    cell = toy.cell("gpt2xl_chat", toy.gpt_toy(),
+                    toy.serve_mix_toy("chat_sat", 60.0, new=(20, 40)),
+                    seconds=2.0)
+    obs = runner.load_module("drivers", "serve_open_loop").run(cell)
+    assert obs["correct"], obs["why_incorrect"]
+    assert obs["attempted"] > sum(r["finished"] for r in obs["rows"])
+    out = capsys.readouterr().out.splitlines()
+    knee = [ln for ln in out if "its knee" in ln]
+    assert len(knee) == 1 and "above its knee" in knee[0]
+    assert "below its knee" not in knee[0] and "re-rate" not in knee[0]
+    load = [ln for ln in out if ln.startswith("serve_open_loop: load:")]
+    assert len(load) == 1 and f"{obs['steps']} beats in" in load[0]
+    assert "nan" not in load[0]
+
+
+def test_the_load_line_tells_a_stalled_run_from_a_slow_program():
+    """The scheduler's count standing still over several samples, or two
+    samples seconds apart, is a stall; beats that are merely long are not.
+    The driver's line carries both and the generator's worst lateness."""
+    from benchmark.lib.stats import longest_still_s
+    drv = runner.load_module("drivers", "serve_open_loop")
+    sound = [{"t": 0.1 * i, "steps": i} for i in range(50)]
+    slow = [{"t": 0.1 * i, "steps": i // 2} for i in range(50)]
+    stalled = [{"t": 0.1 * i, "steps": min(i, 10) + max(0, i - 40)}
+               for i in range(50)]
+    frozen = sound[:10] + [{"t": s["t"] + 3.0, "steps": s["steps"]}
+                           for s in sound[10:]]
+    assert longest_still_s(sound) == pytest.approx((0.0, 0.1))
+    assert longest_still_s(slow) == pytest.approx((0.1, 0.1))
+    assert longest_still_s(stalled) == pytest.approx((3.0, 0.1))
+    assert longest_still_s(frozen) == pytest.approx((0.0, 3.1))
+    assert longest_still_s([{"steps": 1}, {"steps": 2}]) is None
+    m = {"samples": stalled, "window_s": 5.0,
+         "open": {"steps": 0}, "close": {"steps": 19},
+         "rows": [{"t_due": 1.0, "t_submit": 1.001},
+                  {"t_due": 2.0, "t_submit": 3.8},
+                  {"t_due": 3.0, "t_submit": None}]}
+    line = drv.load_line(m)
+    assert "19 beats in 5.000 s" in line
+    assert "longest without a beat 3.000 s" in line
+    assert "generator latest 1800.0 ms with 1 over 100 ms" in line
 
 
 def test_traced_runs_write_a_trace_after_the_window(tmp_path):

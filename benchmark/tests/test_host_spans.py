@@ -109,6 +109,20 @@ def test_gaps_between_programs_get_the_innermost_covering_span():
     # a device that was never idle between programs has no share
     assert hs.attribute_gaps(PROGRAMS[:1], BEATS) == ([], None)
     assert hs.attribute_gaps([], BEATS) == ([], None)
+    assert hs.breakdown_gaps(PROGRAMS[:1], BEATS) == []
+    # the profiler's `jit_decode_step(<fingerprint>)` is cut to the name
+    stamped = [[f"{name}(1344576564943856)", s, d] for name, s, d in PROGRAMS]
+    assert hs.breakdown_gaps(stamped, BEATS, 1) == [
+        ["serve/settle_wait after jit_decode_step before jit_decode_step",
+         pytest.approx(300e-9)]]
+
+
+FIRST_GAP = {
+    "chat_three_beats":
+        "no program span after jit_decode_step before jit_decode_step",
+    "v5e_gpt2xl_chat_admissions":
+        "serve/admit after jit__threefry_seed before "
+        "jit_convert_element_type"}
 
 
 @pytest.mark.parametrize("name", sorted(
@@ -128,6 +142,11 @@ def test_recorded_beats(name):
     assert [[label, round(s * 1e3, 3)] for label, s in labelled] == \
         rec["expect"]["gaps_ms"]
     assert 100 * share == pytest.approx(rec["expect"]["named_share"])
+    # the result line's breakdown.idle_gaps: the same gaps, the span first
+    # (the ledger cuts a label short), then the programs on either side
+    gaps = hs.breakdown_gaps(rec["programs"], host, len(labelled))
+    assert [[g[0].split(" after ")[0], g[1]] for g in gaps] == labelled
+    assert gaps[0][0] == FIRST_GAP[name]
     assert hs.work_ms(hs.phase_ms(rec["host"], "serve/tick"),
                       ("serve/settle_wait", "serve/retire_wait")) \
         == pytest.approx(rec["expect"]["tick_host_ms"])

@@ -101,3 +101,32 @@ def test_each_metric_has_a_reader_that_agrees(m):
         assert reader.LAYER == m["layer"] and reader.MOVES == m["moves"]
     assert reader.read({"rows": [], "attempted": 0, "setup_s": 1.0}) in (
         None, 1.0)   # nothing to read -> nothing reported
+
+
+@pytest.mark.parametrize("traffic", sorted(
+    f[:-5] for f in os.listdir(os.path.join(BENCH_DIR, "traffic"))
+    if f.endswith(".json")))
+def test_a_saturating_mix_is_rated_above_its_knee(traffic):
+    """A mix that declares `headroom` is offered at that many times the
+    knee it names (rounded to 0.5/s), open loop at a constant rate, and
+    its cell reports `decode_slot_fill`, the number that says when the
+    knee has moved (README: Re-rate a saturating mix). A mix that declares
+    none makes no such claim and the driver prints no verdict on it."""
+    from benchmark.drivers.serve_open_loop import knee_line
+    mix = runner.load_json(BENCH_DIR, "traffic", traffic + ".json")
+    if "headroom" not in mix:
+        assert knee_line(mix, [], 32) is None
+        return
+    assert mix["arrival"]["kind"] == "poisson"
+    assert mix["knee_rps"] > 0 and mix["headroom"] >= 1.25
+    assert mix["arrival"]["rate"] >= mix["headroom"] * mix["knee_rps"] - 0.25
+    assert mix["arrival"]["rate"] <= mix["headroom"] * mix["knee_rps"] + 0.25
+    cells = [w["name"] for w in BENCH["workloads"] if w["traffic"] == traffic]
+    fill = next(m for m in BENCH["per_layer"]
+                if m["name"] == "decode_slot_fill")
+    assert cells and all(c in fill["workloads"] for c in cells)
+    samples = [{"steps": 0, "decode_tokens": 0},
+               {"steps": 100, "decode_tokens": 3100}]
+    assert "above its knee" in knee_line(mix, samples, 32)
+    samples[1]["decode_tokens"] = 2800
+    assert "below its knee" in knee_line(mix, samples, 32)

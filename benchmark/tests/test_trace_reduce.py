@@ -1,11 +1,13 @@
 """The reduction from trace events to numbers, on hand-made events and on
-the small recorded trace kept beside this file (tests/data/, cut from a
-TPU v5e run of PR 23: what `device_lines` returned, as JSON)."""
+the small recorded traces kept beside this file (tests/data/, cut from TPU
+v5e runs of PR 23 and, with both serve kernels, PR 28: what `device_lines`
+returned, as JSON)."""
 import json
 import os
 
 import pytest
 
+from benchmark.layer_metrics import paged_attn_share
 from benchmark.lib import trace_reduce as tr
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
@@ -32,10 +34,29 @@ def test_top_ops_group_by_stem_and_gaps_are_ranked():
     assert "after all-reduce, before custom-call" in gaps[0][0]
 
 
-def test_kernel_share_and_exposed_collectives():
-    assert tr.share_of_busy(EVENTS, r"^custom-call") == pytest.approx(
-        100 / 320)
-    assert tr.share_of_busy(EVENTS, r"^no-such-op") is None
+def kernel_share(ops, pattern):
+    """The paged_attn_share reader on one device's op list, as a share."""
+    got = paged_attn_share.read({"kernel_patterns": {"paged_attn": pattern},
+                                 "trace_ops": {0: ops}})
+    return None if got is None else got / 100.0
+
+
+def test_kernel_share_and_exposed_collectives(capsys):
+    assert kernel_share(EVENTS, r"^custom-call") == pytest.approx(100 / 320)
+    assert kernel_share(EVENTS, r"^no-such-op") is None
+    assert paged_attn_share.read({"trace_ops": {0: EVENTS}}) is None
+    # the KV writer is a kernel of its own: its seconds are printed, the
+    # share is the attention kernel's alone; a trace with nothing but the
+    # writer reports nothing
+    kernel = "custom-call[tpu_custom_call] "
+    both = EVENTS + [[kernel + "_paged_call_once.3", 600, 60],
+                     [kernel + "_paged_write_once.5", 700, 20]]
+    capsys.readouterr()
+    assert kernel_share(both, r"^custom-call") == pytest.approx(160 / 400)
+    assert capsys.readouterr().out == (
+        "trace: kernel seconds: paged attention 0.0000, KV writer "
+        "(_paged_write_once) 0.0000, of 0.0000 busy\n")
+    assert kernel_share(EVENTS[:2] + both[-1:], r"^custom-call") is None
     # all-reduce.3 runs alone for 50 ns, all-reduce.4 for all its 20 ns
     assert tr.exposed_collective_s(EVENTS) == pytest.approx(70e-9)
     assert tr.exposed_collective_s(EVENTS[:1]) is None
@@ -76,7 +97,7 @@ def test_xplane_of_a_cpu_run_has_no_device_plane(tmp_path):
 
 @pytest.mark.parametrize("name", sorted(
     f[:-5] for f in os.listdir(DATA) if f.endswith(".json")))
-def test_recorded_trace(name):
+def test_recorded_trace(name, capsys):
     with open(os.path.join(DATA, name + ".json")) as f:
         rec = json.load(f)
     ops = rec["ops"]
@@ -85,7 +106,10 @@ def test_recorded_trace(name):
     assert 0 < tr.busy_s(ops) <= tr.span_s(ops)
     assert tr.top_ops(ops, 3)[0][0] == rec["expect"]["top_stem"]
     for pattern, share in rec["expect"].get("shares", {}).items():
-        assert tr.share_of_busy(ops, pattern) == pytest.approx(share)
+        assert kernel_share(ops, pattern) == pytest.approx(share)
+    if "kernel_seconds_line" in rec["expect"]:  # both kernels, told apart
+        assert capsys.readouterr().out.endswith(
+            rec["expect"]["kernel_seconds_line"])
     if "exposed_collective_s" in rec["expect"]:
         assert tr.exposed_collective_s(ops) == pytest.approx(
             rec["expect"]["exposed_collective_s"])

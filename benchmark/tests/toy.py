@@ -41,12 +41,12 @@ def gpt_toy():
     return cfg
 
 
-def serve_mix_toy(name, rate):
+def serve_mix_toy(name, rate, new=(2, 6)):
     mix = copy.deepcopy(load("traffic", name))
     mix["arrival"]["rate"] = rate
     ten = mix["tenants"][0]
     ten["prompt"].update(median=12, lo=5, hi=30)
-    ten["new"] = {"kind": "uniform", "lo": 2, "hi": 6}
+    ten["new"] = {"kind": "uniform", "lo": new[0], "hi": new[1]}
     if "seed_burst" in mix:
         mix["seed_burst"]["count"] = 4
     mix.update(lead_in_s=0.5, deadline_s=min(mix["deadline_s"], 10.0),
