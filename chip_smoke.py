@@ -81,11 +81,14 @@ class Sizes:
     paged_checks: tuple          # (query rows, block size or None=picker)
     multichip_layers: int
     multichip_steps: int
+    latent: object               # KimiK2Config: the latent-cache programs
+    latent_serve: tuple          # (slots, blocks, block, max_seq, bucket)
 
     @staticmethod
     def full():
         from paddle_tpu.text.models.bert import BertConfig
         from paddle_tpu.text.models.gpt import GPTConfig
+        from paddle_tpu.text.models.kimi_k2 import KimiK2Config
         return Sizes(
             bert=BertConfig.bert_base(), train_batch=32, train_seq=128,
             train_steps=40, train_lr=1e-4,
@@ -104,12 +107,23 @@ class Sizes:
             # FLAGS_serve_block_size admits: 8 rows is under bf16's
             # (16, 128) tile, which Mosaic compiles all the same
             paged_checks=((1, None), (64, None), (1, 8)),
-            multichip_layers=4, multichip_steps=8)
+            multichip_layers=4, multichip_steps=8,
+            # the benchmark's share of Kimi-K2.7-Code at its published
+            # widths, one dense and one expert layer, its pool and its
+            # largest prefill bucket
+            latent=KimiK2Config(
+                vocab_size=20480, num_layers=2, experts_held=(0, 12),
+                max_seq_len=3072, dtype="bfloat16", rope_scaling={
+                    "type": "yarn", "factor": 64, "beta_fast": 32,
+                    "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                    "original_max_position_embeddings": 4096}),
+            latent_serve=(64, 1024, 128, 3072, 2048))
 
     @staticmethod
     def toy():
         from paddle_tpu.text.models.bert import BertConfig
         from paddle_tpu.text.models.gpt import GPTConfig
+        from paddle_tpu.text.models.kimi_k2 import KimiK2Config
         return Sizes(
             bert=BertConfig.tiny(), train_batch=8, train_seq=16,
             train_steps=12, train_lr=1e-2,
@@ -120,7 +134,10 @@ class Sizes:
             forced_prompts=(5, 14), forced_bucket=16,
             flash_shape=(1, 2, 128, 16), ce_shape=(64, 32, 200),
             decode_shape=(2, 2, 64, 16), paged_checks=((1, None),),
-            multichip_layers=1, multichip_steps=3)
+            multichip_layers=1, multichip_steps=3,
+            latent=KimiK2Config.tiny(num_layers=2, experts_held=(4, 8),
+                                     dtype="bfloat16"),
+            latent_serve=(2, 8, 16, 64, 32))
 
 
 # --------------------------------------------------------------------------
@@ -413,8 +430,9 @@ def _serve_program_memory(loop, bucket):
     A, MB = loop._A, loop._MB
     arena = loop._arenas[0][0]
     arena_bytes = arena.size * arena.dtype.itemsize
-    wte = loop.net.wte.weight._value
-    head_bytes = wte.size * wte.dtype.itemsize
+    wte = getattr(loop.net, "wte", None)   # an untied head holds no such temp
+    head_bytes = 0 if wte is None else \
+        wte.weight._value.size * wte.weight._value.dtype.itemsize
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype)
@@ -444,6 +462,25 @@ def _serve_program_memory(loop, bucket):
     finally:  # tracing rebinds the live layers' parameters to tracers
         loop.net.load_functional_state(loop._params, loop._buffers)
     return out
+
+
+def latent_serve_programs(sizes):
+    """`_serve_program_memory` for a net that caches ONE latent a token
+    (text/models/kimi_k2.py, at `sizes.latent`'s widths behind a
+    ServeLoop of `sizes.latent_serve`): its decode step and its largest
+    prefill bucket. The pool's one layout has to hold for the one-head
+    arena too: no copy or transpose of arena shape. The temp is reported
+    and not held to an arena's size: plain-XLA latent attention gathers
+    a slot's blocks, a temp of table width x block bytes a slot."""
+    from paddle_tpu.inference import ServeConfig, ServeLoop
+    from paddle_tpu.text.models.kimi_k2 import KimiK2
+    slots, blocks, block, max_seq, bucket = sizes.latent_serve
+    net = KimiK2(sizes.latent)
+    net.eval()
+    loop = ServeLoop(net, ServeConfig(
+        max_active=slots, kv_blocks=blocks, block_size=block,
+        max_seq_len=max_seq))
+    return _serve_program_memory(loop, bucket)
 
 
 def serve_phase(sizes):
@@ -522,6 +559,14 @@ def serve_phase(sizes):
                 f"{mem['arena_relayouts']} "
                 "copy/transpose instructions of arena shape")
 
+    programs_latent = latent_serve_programs(sizes)
+    for name, mem in programs_latent.items():
+        if jax.default_backend() == "tpu" and mem["arena_relayouts"]:
+            failures.append(
+                f"compiled latent-cache {name} program relays out the "
+                f"arena: {mem['arena_relayouts']} copy/transpose "
+                "instructions of arena shape")
+
     forced = _forced_logits(net, sizes, block_size)
     if not _pallas_counters().get(
             "pallas.gate_reject.paged_decode_attention.flag_off"):
@@ -547,6 +592,7 @@ def serve_phase(sizes):
     return {
         "failures": failures, "requests": n, "completed": completed,
         "programs": programs,
+        "programs_latent": programs_latent,
         "tokens_generated": int(monitor.stat_get("serve.tokens_generated")),
         "preempted": int(monitor.stat_get("serve.preempted")),
         "backpressure_waits":

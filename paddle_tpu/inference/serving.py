@@ -58,8 +58,9 @@ nothing to do, and `serve/{retire,evict,hot_swap}`; request spans carry
 timeline of any running `jax.profiler` session (core/trace.py). Stamps
 `ServeRequest.{t_submit,t_admit,t_first,t_tokens,t_done}`. `stats()`
 counts, cumulative: `steps`, `decode_tokens`, `prefill_dispatches`,
-`prefill_tokens`, `admitted`, `queue_wait_s` (mirrored as `serve.*`
-gauges beside `serve.{queue_depth,active_slots,kv_pool_used_blocks,
+`prefill_tokens`, `admitted`, `queue_wait_s`, and from a net with expert
+layers `MOE_STATS` (mirrored as `serve.*` gauges beside
+`serve.{queue_depth,active_slots,kv_pool_used_blocks,
 kv_pool_free_blocks,model_version}`). Counters `serve.{preempted,
 tokens_generated,requests_completed,requests_errored,hot_swaps,
 completion_log_errors}`, histograms `serve/ttft_ms` and
@@ -79,11 +80,20 @@ import numpy as np
 __all__ = ["ServeConfig", "ServeRequest", "ServeLoop",
            "build_decode_step"]
 
+# what the expert layers of a served net counted (nets without any report
+# none): tokens routed, (token, expert) pairs that fell on a held expert,
+# held experts that got at least one pair and the most pairs on one
+# expert, the last two summed over layer-steps; decode beats and prefills
+# apart, `moe_decode_layer_steps` to divide the decode sums by
+MOE_STATS = tuple(f"moe_{kind}_{what}" for kind in ("decode", "prefill")
+                  for what in ("tokens", "pairs_held", "experts_touched",
+                               "peak_pairs")) + ("moe_decode_layer_steps",)
 GAUGES = ("serve.queue_depth", "serve.active_slots",
           "serve.kv_pool_used_blocks", "serve.kv_pool_free_blocks",
           "serve.model_version", "serve.decode_tokens",
           "serve.prefill_dispatches", "serve.prefill_tokens",
-          "serve.admitted", "serve.queue_wait_s")
+          "serve.admitted", "serve.queue_wait_s") \
+    + tuple(f"serve.{name}" for name in MOE_STATS)
 COUNTERS = ("serve.preempted", "serve.tokens_generated",
             "serve.requests_completed", "serve.requests_errored",
             "serve.hot_swaps", "serve.completion_log_errors",
@@ -112,6 +122,10 @@ class ServeConfig:
         (and keyed) with it."""
         from ..core import flags as _flags
         cfg = net.config
+        # the widest arena of what the net caches sizes the block
+        heads, dim = max((a for layer in net.paged_cache_spec()
+                          for a in layer.arenas),
+                         key=lambda a: a[0] * a[1])
         max_active = int(self.max_active
                          or _flags.flag("FLAGS_serve_max_active"))
         kv_blocks = int(self.kv_blocks
@@ -122,9 +136,7 @@ class ServeConfig:
             block_size = int(self.block_size)
         else:
             from ..nn.kv_pool import pick_block_size
-            block_size = pick_block_size(
-                max_seq, cfg.num_heads, cfg.hidden_size // cfg.num_heads,
-                dtype=dtype)
+            block_size = pick_block_size(max_seq, heads, dim, dtype=dtype)
         max_inflight = int(self.max_inflight
                            or _flags.flag("FLAGS_executor_max_inflight"))
         return max_active, kv_blocks, block_size, max_seq, \
@@ -233,56 +245,61 @@ def _sampler(temperature, top_k):
 def build_decode_step(net, temperature=0.0, top_k=None):
     """The UN-jitted fused decode step: every active stream advances one
     token. (params, buffers, arenas, block_tables, lengths, tokens,
-    keys) -> (new_arenas, next_tokens). Exposed at module level so
+    keys) -> (new_arenas, next_tokens), then whatever the net's
+    `_forward_paged` returns past its caches (a net with expert layers:
+    the pairs each held expert got). `arenas` is what
+    `KVBlockPool.arenas_for(net.paged_cache_spec())` lays out: each
+    layer gets its own tuple. Exposed at module level so
     tools/hlo_evidence.py can AOT-lower the PRODUCTION step — the
     evidence cannot drift from the loop."""
     import jax.numpy as jnp
 
     from ..core import tape as _tape
-    from ..nn.kv_pool import PagedKVCache
+    from ..nn.kv_pool import cache_arenas, paged_caches
 
     samp = _sampler(temperature, top_k)
+    spec = net.paged_cache_spec()
 
     def decode_step(params, buffers, arenas, block_tables, lengths,
                     tokens, keys):
         with _tape.no_grad():
             net.load_functional_state(params, buffers)
-            caches = [PagedKVCache(k, v, block_tables, lengths)
-                      for (k, v) in arenas]
-            logits, new_caches = net._forward_paged(tokens[:, None],
-                                                    caches)
+            logits, new_caches, *counted = net._forward_paged(
+                tokens[:, None], paged_caches(spec, arenas, block_tables,
+                                              lengths))
             nxt = samp(logits, keys, lengths + jnp.int32(1))
-        return [(c.k, c.v) for c in new_caches], nxt
+        return (cache_arenas(new_caches), nxt, *counted)
 
     return decode_step
 
 
 def _build_prefill(net, temperature, top_k):
     """The UN-jitted bucketed prefill: one request's (padded) prompt
-    writes its k/v into the pool blocks and samples the first token,
-    which is also spliced into the fused batch's token carry at `slot`.
-    (params, buffers, arenas, tokens, bt_row, ids, real_len, key, slot)
-    -> ((new_arenas, new_tokens), first_token)."""
+    writes what each layer caches into the pool blocks and samples the
+    first token, which is also spliced into the fused batch's token
+    carry at `slot`. (params, buffers, arenas, tokens, bt_row, ids,
+    real_len, key, slot) -> ((new_arenas, new_tokens), first_token),
+    then what `_forward_paged` counted, as in `build_decode_step`."""
     import jax.numpy as jnp
 
     from ..core import tape as _tape
-    from ..nn.kv_pool import PagedKVCache
+    from ..nn.kv_pool import cache_arenas, paged_caches
 
     samp = _sampler(temperature, top_k)
+    spec = net.paged_cache_spec()
 
     def prefill(params, buffers, arenas, tokens, bt_row, ids, real_len,
                 key, slot):
         with _tape.no_grad():
             net.load_functional_state(params, buffers)
-            caches = [PagedKVCache(k, v, bt_row, jnp.zeros((1,),
-                                                           jnp.int32))
-                      for (k, v) in arenas]
-            logits, new_caches = net._forward_paged(
+            caches = paged_caches(spec, arenas, bt_row,
+                                  jnp.zeros((1,), jnp.int32))
+            logits, new_caches, *counted = net._forward_paged(
                 ids, caches, last_index=jnp.reshape(real_len, (1,)) - 1)
             first = samp(logits, key[None], jnp.reshape(real_len,
                                                         (1,)))[0]
             tokens = tokens.at[slot].set(first)
-        return ([(c.k, c.v) for c in new_caches], tokens), first
+        return ((cache_arenas(new_caches), tokens), first, *counted)
 
     return prefill
 
@@ -314,7 +331,7 @@ class ServeLoop:
 
         from ..core import flags as _flags  # noqa: F401 (resolve below)
         from ..nn.kv_pool import KVBlockPool
-        from ..static.pipeline_runner import _FLOW_NS, InflightDriver
+        from ..static.pipeline_runner import _FLOW_NS
 
         self.net = net
         self.config = config or ServeConfig(**overrides)
@@ -326,17 +343,11 @@ class ServeLoop:
             else jnp.float32
         (self._A, n_blocks, self._bs, self._cap,
          self._max_inflight) = self.config.resolve(net, self._dtype)
-        cfg = net.config
         if net.training:
             net.eval()  # decode kernels are eval-only; serving never drops
         self._pool = KVBlockPool(n_blocks, self._bs)
         self._MB = -(-self._cap // self._bs)     # block-table width
-        heads, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
-        self._arenas = self._pool.arenas(cfg.num_layers, heads, hd,
-                                         self._dtype)
-        self._tokens = jnp.zeros((self._A,), jnp.int32)
-        self._driver = InflightDriver("serve",
-                                      max_inflight=self._max_inflight)
+        self._fresh_device_state()
         self._flow_base = next(_FLOW_NS) << 42  # per-request flow chain
 
         step = build_decode_step(net, self.config.temperature,
@@ -365,6 +376,7 @@ class ServeLoop:
         self._prefill_tokens = 0      # prompt tokens sent to prefill
         self._admitted = 0            # first admissions
         self._queue_wait_s = 0.0      # sum of t_admit - t_submit
+        self._moe = {}                # MOE_STATS, once a step reports them
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
         self._thread = None
@@ -452,6 +464,7 @@ class ServeLoop:
             "max_active": self._A,
             "model_version": self.model_version,
             "swap_staged": self._staged_swap is not None,
+            **self._moe,
         }
 
     def publish_weights(self, version, updates):
@@ -658,13 +671,13 @@ class ServeLoop:
             sp.flow(self._flow_base + req.rid, "t")
 
             def thunk():
-                carry, first = self._call_traced(
+                carry, first, *counted = self._call_traced(
                     self._prefill_jit, ("prefill", bucket),
                     self._params, self._buffers, self._arenas,
                     self._tokens, jnp.asarray(bt_row), jnp.asarray(ids),
                     jnp.int32(s_real), jnp.asarray(slot.key),
                     jnp.int32(idx))
-                return carry, [first]
+                return carry, [first, *counted]
 
             carry, handles = self._driver.submit(thunk, kind="prefill",
                                                  req=req.rid)
@@ -674,7 +687,7 @@ class ServeLoop:
         self._prefill_tokens += s_real
         slot.length = s_real
         self._pending.append(("prefill", handles, req, idx,
-                              slot.version))
+                              slot.version, s_real))
 
     # -- growth / preemption -------------------------------------------------
     def _youngest_active(self):
@@ -757,11 +770,11 @@ class ServeLoop:
                          active=len(snapshot)):
 
             def thunk():
-                arenas, nxt = self._call_traced(
+                arenas, nxt, *counted = self._call_traced(
                     self._step_jit, ("decode",),
                     self._params, self._buffers, self._arenas,
                     bt_d, lengths_d, self._tokens, keys_d)
-                return (arenas, nxt), [nxt]
+                return (arenas, nxt), [nxt, *counted]
 
             carry, handles = self._driver.submit(thunk, kind="decode",
                                                  active=len(snapshot))
@@ -788,13 +801,15 @@ class ServeLoop:
                 return
             now = time.perf_counter()
             if entry[0] == "prefill":
-                _kind, _h, req, idx, version = entry
+                _kind, handles, req, idx, version, n_tokens = entry
+                self._count_served("prefill", handles, n_tokens)
                 slot = self._slots[idx]
                 if slot is None or slot.version != version:
                     return           # preempted before its first token
                 self._append_token(idx, slot, int(toks), now, first=True)
                 return
-            _kind, _h, snapshot = entry
+            _kind, handles, snapshot = entry
+            self._count_served("decode", handles, len(snapshot))
             for idx, req, version in snapshot:
                 slot = self._slots[idx]
                 if slot is None or slot.version != version \
@@ -802,6 +817,19 @@ class ServeLoop:
                     continue             # retired/preempted mid-flight
                 self._decode_tokens += 1
                 self._append_token(idx, slot, int(toks[idx]), now)
+
+    def _count_served(self, kind, handles, n_tokens):
+        """Add up what a settled step's program returned past its tokens
+        (a net with expert layers: what they counted). The net names the
+        counters (`serve_counters`); read here, where the tokens have
+        just been read, so the device is not waited for again."""
+        if len(handles) < 2:
+            return                       # a net that counts nothing
+        moe = self._moe or dict.fromkeys(MOE_STATS, 0)
+        for name, n in self.net.serve_counters(kind, handles[1:],
+                                               n_tokens).items():
+            moe[name] += n
+        self._moe = moe
 
     def _append_token(self, idx, slot, token, now, first=False):
         from ..core import monitor as _monitor
@@ -849,10 +877,7 @@ class ServeLoop:
         """A decode/prefill step died (XLA-level, past run_guarded): the
         donated device chain is poisoned. Fail every in-flight stream,
         rebuild the device state, keep serving the queue."""
-        import jax.numpy as jnp
-
         from ..core import monitor as _monitor
-        from ..static.pipeline_runner import InflightDriver
         self._pending.clear()
         for i, slot in enumerate(self._slots):
             if slot is None:
@@ -863,10 +888,16 @@ class ServeLoop:
             self._pool.free(slot.blocks)
             self._slots[i] = None
             _monitor.stat_add("serve.requests_errored")
-        cfg = self.net.config
-        heads, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
-        self._arenas = self._pool.arenas(cfg.num_layers, heads, hd,
-                                         self._dtype)
+        self._fresh_device_state()
+
+    def _fresh_device_state(self):
+        """Zeroed arenas of what the net says it caches, the token carry
+        and a driver with nothing in flight."""
+        import jax.numpy as jnp
+
+        from ..static.pipeline_runner import InflightDriver
+        self._arenas = self._pool.arenas_for(self.net.paged_cache_spec(),
+                                             self._dtype)
         self._tokens = jnp.zeros((self._A,), jnp.int32)
         self._driver = InflightDriver("serve",
                                       max_inflight=self._max_inflight)
@@ -886,4 +917,5 @@ class ServeLoop:
             "serve.prefill_tokens": self._prefill_tokens,
             "serve.admitted": self._admitted,
             "serve.queue_wait_s": self._queue_wait_s,
+            **{f"serve.{k}": v for k, v in self._moe.items()},
         })

@@ -43,14 +43,17 @@ private to this module and the kernels: everything else goes through
 """
 from __future__ import annotations
 
+import functools
 import typing
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PagedKVCache", "KVBlockPool", "paged_attention",
-           "paged_attention_ref", "write_kv", "pick_block_size"]
+__all__ = ["PagedKVCache", "PagedLatentCache", "CacheSpec",
+           "KVBlockPool", "paged_caches", "cache_arenas", "paged_attention",
+           "paged_attention_ref", "latent_paged_attention", "write_kv",
+           "pick_block_size"]
 
 TRASH_BLOCK = 0  # physical row 0 of every arena; never allocated
 
@@ -71,6 +74,47 @@ class PagedKVCache(typing.NamedTuple):
     @property
     def block_size(self):
         return int(self.k.shape[3])
+
+
+class PagedLatentCache(typing.NamedTuple):
+    """One layer's paged cache of ONE vector a token: a latent-attention
+    (MLA) layer caches the compressed key-value and the rotated key side
+    by side, `dim` = 512 + 64 wide, shared by every query head. `kv` is
+    the arena [n_blocks + 1, 1, dim, block_size] — the pool's layout
+    with one head, written by `write_kv` like any other arena."""
+
+    kv: object            # [n_blocks + 1, 1, dim, block_size]
+    block_tables: object  # [b, max_blocks] i32
+    lengths: object       # [b] i32
+
+    @property
+    def block_size(self):
+        return int(self.kv.shape[3])
+
+
+class CacheSpec(typing.NamedTuple):
+    """What one layer of a served net caches, as the net's
+    `paged_cache_spec()` says it: `cache`, the type its `_forward_paged`
+    reads (the arenas in order, then `block_tables`, `lengths`), and
+    `arenas`, the (heads, dim) of each arena — ((h, d), (h, d)) for
+    per-head keys and values, ((1, dim),) for a latent."""
+
+    cache: type
+    arenas: tuple
+
+
+def paged_caches(spec, arenas, block_tables, lengths):
+    """[(arena, ...) per layer] -> one cache per layer, of the type the
+    layer's `CacheSpec` names, over shared slot state. The inverse is
+    `cache_arenas`."""
+    return [layer.cache(*a, block_tables, lengths)
+            for layer, a in zip(spec, arenas)]
+
+
+def cache_arenas(caches):
+    """The arenas of each layer's cache, as `KVBlockPool.arenas_for`
+    lays them out (and as a serve program donates them)."""
+    return [tuple(c[:-2]) for c in caches]
 
 
 def pick_block_size(max_seq_len, heads, head_dim, dtype="float32",
@@ -172,13 +216,19 @@ class KVBlockPool:
         return (self.n_blocks + 1, int(heads), int(head_dim),
                 self.block_size)
 
+    def arenas_for(self, spec, dtype=jnp.float32):
+        """Fresh zeroed arenas for a net's `paged_cache_spec()` (one
+        `CacheSpec` a layer): [(arena, ...), ...] each `arena_shape`
+        (row 0 = trash). Zeros, not empty: a fresh pool must attend to
+        nothing."""
+        return [tuple(jnp.zeros(self.arena_shape(h, d), dtype)
+                      for h, d in layer.arenas) for layer in spec]
+
     def arenas(self, layers, heads, head_dim, dtype=jnp.float32):
-        """Fresh zeroed k/v arena pairs, one per layer:
-        [(k, v), ...] each `arena_shape` (row 0 = trash). Zeros, not
-        empty: a fresh pool must attend to nothing."""
-        shape = self.arena_shape(heads, head_dim)
-        return [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-                for _ in range(int(layers))]
+        """`arenas_for` k/v pairs of one shape: [(k, v), ...]."""
+        return self.arenas_for(
+            [CacheSpec(PagedKVCache, ((heads, head_dim),) * 2)]
+            * int(layers), dtype)
 
 
 # --------------------------------------------------------------------------
@@ -291,6 +341,42 @@ def paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
     scores = jnp.where(live[:, None], scores, -1e9)
     p = jax.nn.softmax(scores, axis=-1).astype(vc.dtype)
     return jnp.einsum("bhsl,bhld->bhsd", p, vc).astype(q.dtype)
+
+
+def latent_paged_attention(q, arena, block_tables, lengths, scale,
+                           value_dim):
+    """Attention of q [b, h, s, dim] — every head over the ONE cached
+    vector a token of a latent arena [n, 1, dim, bs]: keys are the whole
+    `dim`-wide rows, values their first `value_dim` entries (MLA's
+    absorbed form: the key and value projections live in q and in what
+    is done with the result). Row r of slot i attends logical cols
+    <= lengths[i] + r. Returns [b, h, s, value_dim] in q's dtype. Plain
+    XLA: gather the slot's blocks, two einsums with the tokens left in
+    the lanes, softmax in float32."""
+    return _latent_attn_paged(
+        q, arena, jnp.asarray(block_tables, jnp.int32),
+        jnp.asarray(lengths, jnp.int32), scale=float(scale),
+        value_dim=int(value_dim))
+
+
+# jitted under a name of its own, so that a device trace can tell its
+# fusions from the rest of a serve program
+@functools.partial(jax.jit, static_argnames=("scale", "value_dim"))
+def _latent_attn_paged(q, arena, bt, lens, *, scale, value_dim):
+    b, h, s, d = q.shape
+    bs, nb = arena.shape[3], bt.shape[1]
+    g = jnp.take(arena[:, 0], bt, axis=0)                 # [b, nb, d, bs]
+    scores = jnp.einsum("bhsd,bndt->bhsnt", q.astype(g.dtype), g,
+                        preferred_element_type=jnp.float32) * scale
+    col = jnp.arange(nb * bs, dtype=jnp.int32).reshape(nb, bs)
+    row = lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None]    # [b, s]
+    live = col[None, None] <= row[:, :, None, None]        # [b, s, nb, bs]
+    scores = jnp.where(live[:, None], scores, -1e9)
+    p = jax.nn.softmax(scores.reshape(b, h, s, nb * bs), axis=-1)
+    p = p.reshape(scores.shape).astype(g.dtype)
+    ctx = jnp.einsum("bhsnt,bndt->bhsd", p, g[:, :, :value_dim],
+                     preferred_element_type=jnp.float32)
+    return ctx.astype(q.dtype)
 
 
 def _paged_gate(kernel, training, supported):

@@ -8,3 +8,4 @@ from .activation import *   # noqa: F401,F403
 from .loss import *         # noqa: F401,F403
 from .rnn import *          # noqa: F401,F403
 from .transformer import *  # noqa: F401,F403
+from .experts import *      # noqa: F401,F403

@@ -1,0 +1,166 @@
+"""A sparse expert layer that is told which experts it holds.
+
+`distributed/moe.py` is the training-side Switch layer: top-1, softmax,
+a capacity past which tokens are dropped, experts sharded over a mesh
+axis. This is the layer that serving runs (DeepSeek-V3 / Kimi-K2 style):
+
+- **router over every expert**: sigmoid scores, float32, `num_experts`
+  wide; the `top_k` experts of a token are those with the highest
+  `score + bias` (the load-balancing bias, `e_score_correction_bias`),
+  its weights are the scores themselves, normalised over the chosen
+  `top_k` and scaled by `routed_scaling_factor`;
+- **a held share**: `held = (first, count)` names the contiguous range of
+  experts whose weights live here — one chip's share of an
+  expert-parallel deployment. Routing, top-k and the normalisation are
+  over all `num_experts`; the sum runs over the chosen experts that are
+  held. What the absent experts would add is left out (on a deployment
+  it arrives through the exchange between chips; here nothing stands in
+  for it). The shared expert is computed in full. With `held` = all the
+  layer is the whole layer;
+- **dropless, static shapes**: no capacity. The (token, expert) pairs
+  that are held are sorted by expert and cut into row blocks of
+  `block_rows(tokens)` rows, each block of one expert; a loop runs over
+  the blocks IN USE, so the matrix products cost what the routing asks
+  for, whatever it asks. The rule that bounds the loop: with `T` tokens,
+  `P = T * top_k` pairs at most are held, an expert's rows are padded to
+  a whole block, so at most `ceil(P / M) + count` blocks of `M` rows
+  exist; the expectation is `T * top_k * count / num_experts` pairs.
+
+`routed` also returns how many pairs each held expert got, which is what
+the serving counters (`serve.moe_*`) are made of.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from .. import initializer as I
+from .layers import Layer
+
+__all__ = ["RoutedExperts"]
+
+
+def block_rows(tokens):
+    """Rows of one block of the grouped expert products: the MXU's 128
+    at prefill sizes, the whole batch (to a 16-row bf16 tile) below."""
+    return min(128, -(-int(tokens) // 16) * 16)
+
+
+def _swiglu(x, gate, up, down):
+    g = jnp.dot(x, gate, preferred_element_type=jnp.float32)
+    u = jnp.dot(x, up, preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(g) * u).astype(x.dtype)
+    return jnp.dot(a, down, preferred_element_type=jnp.float32)
+
+
+# jitted under a name of its own, so that a device trace can tell the
+# routed products from the rest of a serve program
+@jax.jit
+def _routed_expert_ffn(x, idx, weights, valid, gate, up, down, first):
+    """Σ over the held experts a token chose of weight * SwiGLU_e(x).
+    x [T, H]; idx [T, K] i32 expert ids over the router's width; weights
+    [T, K] f32; valid [T] bool (a pad row routes nowhere); gate/up
+    [n, H, I], down [n, I, H]: experts first..first+n-1.
+    -> (y [T, H] f32, pairs per held expert [n] i32)."""
+    T, K = idx.shape
+    n = gate.shape[0]
+    M = block_rows(T)
+    P = T * K
+    local = idx - jnp.asarray(first, jnp.int32)
+    held = (local >= 0) & (local < n) & valid[:, None]
+    key = jnp.where(held, local, n).reshape(P)             # n = not here
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    order = jnp.concatenate([order, jnp.zeros((M,), jnp.int32)])
+    counts = jnp.sum(key[:, None] == jnp.arange(n, dtype=jnp.int32)[None],
+                     axis=0, dtype=jnp.int32)              # [n]
+    blocks = (counts + (M - 1)) // M                       # per expert
+    last_block = jnp.cumsum(blocks)
+    group_start = jnp.cumsum(counts) - counts              # in sorted order
+    w_flat = weights.reshape(P)
+    lane = jnp.arange(M, dtype=jnp.int32)
+
+    def one_block(j, y):
+        j = jnp.asarray(j, jnp.int32)
+        e = jnp.minimum(jnp.searchsorted(last_block, j, side="right"),
+                        n - 1).astype(jnp.int32)
+        r0 = (j - (last_block[e] - blocks[e])) * M
+        pairs = jax.lax.dynamic_slice(order, (group_start[e] + r0,), (M,))
+        live = lane < counts[e] - r0
+        tok = jnp.where(live, pairs // K, 0)
+        w = jnp.where(live, w_flat[pairs], 0.0)
+        out = _swiglu(x[tok], gate[e], up[e], down[e])
+        return y.at[tok].add(out * w[:, None])
+
+    y = jax.lax.fori_loop(0, last_block[-1], one_block,
+                          jnp.zeros(x.shape, jnp.float32))
+    return y, counts
+
+
+class RoutedExperts(Layer):
+    """See the module docstring. `forward(x)` takes [..., hidden] (Tensor
+    or array) and returns the same kind; `routed(x, valid)` is the
+    array-level call a served model uses: ([T, hidden] in x's dtype,
+    [count] i32 pairs per held expert)."""
+
+    def __init__(self, hidden_size, expert_width, num_experts, top_k,
+                 held=None, routed_scaling_factor=1.0, shared_width=0,
+                 dtype="float32", init_std=0.02):
+        super().__init__(dtype=dtype)
+        first, count = (0, num_experts) if held is None else held
+        if not (0 <= first and count >= 1
+                and first + count <= num_experts and top_k <= num_experts):
+            raise ValueError(f"held range {held} outside the router's "
+                             f"{num_experts} experts")
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.first, self.count = int(first), int(count)
+        self.scaling = float(routed_scaling_factor)
+        normal = I.Normal(0.0, init_std)
+
+        def param(*shape):
+            return self.create_parameter(list(shape),
+                                         default_initializer=normal)
+
+        H, W = int(hidden_size), int(expert_width)
+        self.router_weight = param(H, num_experts)
+        # the selection bias (`e_score_correction_bias`): float32 always
+        self.router_bias = self.create_parameter(
+            [num_experts], dtype="float32", is_bias=True)
+        self.gate, self.up = param(count, H, W), param(count, H, W)
+        self.down = param(count, W, H)
+        self.shared_width = int(shared_width)
+        if shared_width:
+            self.shared_gate = param(H, shared_width)
+            self.shared_up = param(H, shared_width)
+            self.shared_down = param(shared_width, H)
+
+    def route(self, x):
+        """x [T, H] -> (expert ids [T, top_k] i32 over all experts,
+        weights [T, top_k] f32). Selection on score + bias; weights from
+        the scores alone, normalised over the chosen, scaled."""
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32),
+            self.router_weight._value.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, idx = jax.lax.top_k(scores + self.router_bias._value, self.top_k)
+        chosen = jnp.take_along_axis(scores, idx, axis=-1)
+        weights = self.scaling * chosen / jnp.sum(chosen, axis=-1,
+                                                  keepdims=True)
+        return idx.astype(jnp.int32), weights
+
+    def routed(self, x, valid=None):
+        if valid is None:
+            valid = jnp.ones((x.shape[0],), bool)
+        idx, weights = self.route(x)
+        y, counts = _routed_expert_ffn(
+            x, idx, weights, valid, self.gate._value, self.up._value,
+            self.down._value, self.first)
+        if self.shared_width:
+            y = y + _swiglu(x, self.shared_gate._value,
+                            self.shared_up._value, self.shared_down._value)
+        return y.astype(x.dtype), counts
+
+    def forward(self, x):
+        from ...core.tensor import Tensor
+        v = x._value if isinstance(x, Tensor) else jnp.asarray(x)
+        y = self.routed(v.reshape(-1, v.shape[-1]))[0].reshape(v.shape)
+        return Tensor(y, _internal=True) if isinstance(x, Tensor) else y

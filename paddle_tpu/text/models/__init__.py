@@ -1,2 +1,3 @@
 from .bert import Bert, BertConfig  # noqa: F401
 from .gpt import GPT, GPTConfig  # noqa: F401
+from .kimi_k2 import KimiK2, KimiK2Config  # noqa: F401

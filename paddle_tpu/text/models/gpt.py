@@ -119,6 +119,16 @@ class GPT(nn.Layer):
         logits = ops.matmul(x[:, -1], self.wte.weight, transpose_y=True)
         return logits._value, new_caches
 
+    def paged_cache_spec(self):
+        """What ServeLoop's pool holds for this net, one `CacheSpec` a
+        layer: a `PagedKVCache` over two arenas, keys and values, per
+        head."""
+        from ...nn.kv_pool import CacheSpec, PagedKVCache
+        cfg = self.config
+        per_head = (cfg.num_heads, cfg.hidden_size // cfg.num_heads)
+        return [CacheSpec(PagedKVCache, (per_head, per_head))] \
+            * cfg.num_layers
+
     def _forward_paged(self, input_ids, caches, last_index=None):
         """One paged decode/prefill pass over the serving tier's shared
         block arena (nn/kv_pool.py). input_ids [b, s] (Tensor or jnp);

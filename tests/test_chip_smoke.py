@@ -9,6 +9,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import paddle_tpu as paddle
@@ -239,6 +240,95 @@ def test_paged_serve_steps_hold_no_arena_copy_for_v5e():
         assert step["attn"] == 2, step
     assert (decode["s"], decode["writer"]) == (1, 4)      # the Pallas writer
     assert (prefill["s"], prefill["writer"]) == (256, 0)  # the XLA loop
+
+
+def _compile_latent_steps_for_v5e():
+    """Child-process body of the test below: ServeLoop's own decode step
+    and bucket-2048 prefill of the Kimi-K2 share at its published widths
+    (`chip_smoke.Sizes.full().latent`: one dense and one expert layer, 12
+    of 384 experts held), compiled for v5e over the benchmark's pool of
+    one-head latent arenas; one JSON line a program."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.inference.serving import (_build_prefill,
+                                              build_decode_step)
+    from paddle_tpu.nn import initializer
+    from paddle_tpu.nn.kv_pool import KVBlockPool
+    from paddle_tpu.text.models import KimiK2
+    try:
+        device = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
+    except Exception as e:  # environment without a usable libtpu
+        print(f"NO-TOPOLOGY {type(e).__name__}: {e}")
+        return
+    sharding = SingleDeviceSharding(device)
+    # only shapes are compiled: 1.5 B parameters need not be drawn
+    initializer.Normal.__call__ = \
+        lambda self, shape, dtype="float32": jnp.zeros(tuple(shape), dtype)
+    sizes = chip_smoke.Sizes.full()
+    slots, blocks, block, max_seq, bucket = sizes.latent_serve
+    net = KimiK2(sizes.latent)
+    net.eval()
+    params, buffers = net.functional_state()
+    pool, width = KVBlockPool(blocks, block), max_seq // block
+    (arena,) = net.paged_cache_spec()[0].arenas
+    arena = pool.arena_shape(*arena)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def like(tree):
+        return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
+
+    i32, u32 = jnp.int32, jnp.uint32
+    state = (like(params), like(buffers), like(jax.eval_shape(
+        lambda: pool.arenas_for(net.paged_cache_spec(), jnp.bfloat16))))
+    programs = {
+        1: (build_decode_step(net),
+            (spec((slots, width), i32), spec((slots,), i32),
+             spec((slots,), i32), spec((slots, 2), u32))),
+        bucket: (_build_prefill(net, 0.0, None),
+                 (spec((slots,), i32), spec((1, width), i32),
+                  spec((1, bucket), i32), spec((), i32), spec((2,), u32),
+                  spec((), i32)))}
+    paddle.set_flags({"FLAGS_pallas_force_compile": True})
+    for s, (fn, rest) in programs.items():
+        monitor.reset(prefix="pallas.")
+        compiled = jax.jit(fn, donate_argnums=(2,)).trace(
+            *state, *rest).lower(lowering_platforms=("tpu",)).compile()
+        net.load_functional_state(params, buffers)
+        text, mem = compiled.as_text(), compiled.memory_analysis()
+        print("STEP " + json.dumps({
+            "s": s,
+            "arena_in_hlo": "[%s]" % ",".join(map(str, arena)) in text,
+            "relayouts": chip_smoke.arena_relayouts(text, arena),
+            "temp_bytes": mem.temp_size_in_bytes,
+            "alias_bytes": mem.alias_size_in_bytes,
+            "arena_bytes": int(np.prod(arena)) * 2,
+            "writer": monitor.stats("pallas.hit.").get(
+                "pallas.hit.paged_write_token", 0)}))
+    print("LATENT-STEPS-DONE")
+
+
+def test_latent_serve_steps_hold_no_arena_copy_for_v5e():
+    """The pool's one layout holds for a one-head latent arena
+    [blocks+1, 1, 576, 128] too: the Kimi-K2 share's decode step (the
+    Pallas token writer, once a layer) and its bucket-2048 prefill (the
+    in-place block loop) hold no copy or transpose of arena shape, and
+    the donated arenas come back aliased. Unlike GPT's steps these are
+    whole model programs, and plain-XLA latent attention gathers a
+    slot's blocks, so their temps are activations and that gather: held
+    to what fits beside 9.7 GB of weights (the un-blocked scores of a
+    2048-token prompt alone would be 1.07 GB), not to an arena's size."""
+    out = _run_in_cpu_child("_compile_latent_steps_for_v5e",
+                            "LATENT-STEPS-DONE")
+    decode, prefill = (json.loads(line[5:]) for line in out.splitlines()
+                       if line.startswith("STEP "))
+    for step in (decode, prefill):
+        assert step["arena_in_hlo"] and step["relayouts"] == [], step
+        assert step["alias_bytes"] >= 2 * step["arena_bytes"], step
+        assert step["temp_bytes"] < 1.5e9, step
+    assert (decode["s"], decode["writer"]) == (1, 2)      # the Pallas writer
+    assert (prefill["s"], prefill["writer"]) == (2048, 0)  # the XLA loop
 
 
 def test_autotune_lookup_never_measures_under_trace():

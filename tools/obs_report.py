@@ -202,7 +202,15 @@ SERVE_GAUGES = ("serve.queue_depth", "serve.active_slots",
                 "serve.kv_pool_used_blocks", "serve.kv_pool_free_blocks",
                 "serve.model_version", "serve.decode_tokens",
                 "serve.prefill_dispatches", "serve.prefill_tokens",
-                "serve.admitted", "serve.queue_wait_s")
+                "serve.admitted", "serve.queue_wait_s",
+                # a net with expert layers only (serving.MOE_STATS)
+                "serve.moe_decode_tokens", "serve.moe_decode_pairs_held",
+                "serve.moe_decode_experts_touched",
+                "serve.moe_decode_peak_pairs", "serve.moe_prefill_tokens",
+                "serve.moe_prefill_pairs_held",
+                "serve.moe_prefill_experts_touched",
+                "serve.moe_prefill_peak_pairs",
+                "serve.moe_decode_layer_steps")
 SERVE_COUNTERS = ("serve.preempted", "serve.tokens_generated",
                   "serve.requests_completed", "serve.requests_errored",
                   "serve.hot_swaps", "serve.completion_log_errors",
