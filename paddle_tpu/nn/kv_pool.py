@@ -402,7 +402,8 @@ def _paged_kernel_eligible(q, k_arena, training):
     from ..ops.pallas.decode_attention import paged_supported
     return _paged_gate(
         "paged_decode_attention", training,
-        lambda: paged_supported(tuple(q.shape), tuple(k_arena.shape)))
+        lambda: paged_supported(tuple(q.shape), tuple(k_arena.shape),
+                                k_arena.dtype.itemsize))
 
 
 def _write_kernel_eligible(arena):
@@ -419,10 +420,21 @@ def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,
     eligible, `paged_attention_ref` when the gate rejects."""
     if _paged_kernel_eligible(q, k_arena, training):
         from ..ops.pallas import run_guarded
-        from ..ops.pallas.decode_attention import paged_decode_attention
+        from ..core import monitor
+        from ..ops.pallas.decode_attention import (paged_cut,
+                                                   paged_decode_attention)
+        # which cut this program compiles with, on the kernel's span and
+        # as a pair of gauges per (slots, query rows) for a dump to read:
+        # b32s1 is a decode step of 32 slots, b1s256 a prefill
+        cut = paged_cut(tuple(q.shape), tuple(k_arena.shape),
+                        block_tables.shape[1], k_arena.dtype.itemsize)
+        monitor.stat_set_many({
+            f"pallas.paged_decode_attention.{name}.b{q.shape[0]}"
+            f"s{q.shape[2]}": value for name, value in cut.items()})
         return run_guarded(
             "paged_decode_attention",
             lambda: paged_decode_attention(q, k_arena, v_arena,
-                                           block_tables, lengths, scale))
+                                           block_tables, lengths, scale),
+            **cut)
     return paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
                                scale)

@@ -42,12 +42,12 @@ def gate_reject(kernel: str, reason: str):
     return False
 
 
-def run_guarded(kernel: str, thunk):
+def run_guarded(kernel: str, thunk, **attrs):
     """Run a Pallas kernel thunk, bumping pallas.hit.{kernel}; whatever it
     raises propagates. Every dispatch leaves a span with its outcome
-    (hit / error+reason) in the trace ring."""
+    (hit / error+reason) and the caller's `attrs` in the trace ring."""
     from ...core import monitor, trace
-    sp = trace.begin(f"pallas/{kernel}")
+    sp = trace.begin(f"pallas/{kernel}", **attrs)
     try:
         out = thunk()
     except Exception as e:

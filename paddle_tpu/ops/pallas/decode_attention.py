@@ -198,11 +198,17 @@ def _pick_bk(shape, dtype, scale, measure_builder):
 
 def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
                               m_scr, l_scr, acc_scr, *, scale, bs, nb, s):
-    """Grid (b, h, nb); nb = logical blocks per request (sequential
-    accumulator dim). len_ref is the [b] live-length vector (index + s
-    per batch, like the contiguous kernel); bt_ref [b, nb] maps logical
-    to physical arena blocks (consumed by the index maps — unused here
-    beyond documentation: logical col ids already encode causality)."""
+    """Grid (b, h // ht, nb); nb = logical blocks per request (sequential
+    accumulator dim). One step holds ONE logical block of one request for
+    a tile of ht heads — q/out [1, ht, s, d], K/V [1, ht, d, bs] — and
+    does the heads as batched products (Mosaic unrolls them into
+    straight-line code; a fori_loop over the same 2-D body was 5x slower
+    at 25 heads, PR 31). The arithmetic of a head, and its order over the
+    blocks, are those of one head a step. len_ref is the [b] live-length
+    vector (index + s per batch, like the contiguous kernel); bt_ref
+    [b, nb] maps logical to physical arena blocks (consumed by the index
+    maps — unused here beyond documentation: logical col ids already
+    encode causality)."""
     ib, ik = pl.program_id(0), pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -219,21 +225,21 @@ def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(ik <= last)
     def _compute():
-        q = q_ref[0, 0]                        # [s, d]
-        kt = k_ref[0, 0]                       # [d, bs]
-        sc = jax.lax.dot_general(q, kt, (((1,), (0,)), ((), ())),
+        q = q_ref[0]                           # [ht, s, d]
+        kt = k_ref[0]                          # [ht, d, bs]
+        sc = jax.lax.dot_general(q, kt, (((2,), (1,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32) * scale
         row = jax.lax.broadcasted_iota(jnp.int32, (s, bs), 0)
         col = ik * bs + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 1)
-        sc = jnp.where(col <= index + row, sc, np.float32(NEG_INF))
-        m_prev = m_scr[:]                      # [s, 1]
+        sc = jnp.where((col <= index + row)[None], sc, np.float32(NEG_INF))
+        m_prev = m_scr[:]                      # [ht, s, 1]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sc - m_new)                # [s, bs] f32
+        p = jnp.exp(sc - m_new)                # [ht, s, bs] f32
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # p [s, bs] against vT [d, bs]: both contract their lane dim
-        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0, 0],
-                                 (((1,), (1,)), ((), ())),
+        # p [ht, s, bs] against vT [ht, d, bs]: both contract their lanes
+        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
+                                 (((2,), (2,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = m_new
@@ -241,13 +247,47 @@ def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ik == nb - 1)
     def _flush():
         denom = jnp.maximum(l_scr[:], 1e-30)   # padded rows stay finite
-        o_ref[0, 0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
 
 
-def paged_supported(q_shape, arena_shape) -> bool:
+# What one grid step of the paged kernel may plan to hold in VMEM: the
+# pipeline's two buffers of its K, V, q and out blocks, the softmax state
+# and the scores of its heads. Mosaic's scoped VMEM on a v5e is 16 MiB by
+# default; the rest is room for what the compiler spills.
+_ATTN_VMEM_BYTES = 12 << 20
+
+
+def _paged_step_bytes(ht, s_p, d, bs, itemsize):
+    """VMEM bytes of one grid step over `ht` heads, as Mosaic lays the
+    blocks out: the minor dimension padded to the 128 lanes."""
+    d_l, bs_l = _ceil_to(d, 128), _ceil_to(bs, 128)
+    kv = 2 * 2 * ht * d * bs_l * itemsize          # K, V, double-buffered
+    qo = 2 * 2 * ht * s_p * d_l * itemsize         # q, out, double-buffered
+    state = ht * s_p * (128 + 128 + d_l) * 4       # m, l, acc in float32
+    scores = 3 * ht * s_p * bs_l * 4               # sc, p and a temporary
+    return kv + qo + state + scores
+
+
+def paged_heads_per_step(h, s_p, d, bs, itemsize,
+                         budget=_ATTN_VMEM_BYTES) -> int:
+    """The paged kernel's head tile: the largest divisor of `h` whose
+    grid step fits `budget` bytes of VMEM, 0 where not even one head
+    does. From the shape alone: GPT-2 XL's decode step (h 25, 8 padded
+    query rows, d 64, block 128, bf16) takes all 25 heads of a block in
+    one step, its 128- and 256-row prefills 5."""
+    for ht in range(int(h), 0, -1):
+        if h % ht == 0 and \
+                _paged_step_bytes(ht, s_p, d, bs, itemsize) <= budget:
+            return ht
+    return 0
+
+
+def paged_supported(q_shape, arena_shape, itemsize=4) -> bool:
     """Static predicate: can the paged kernel serve q [b, h, s, d] over
-    an arena [n_blocks, h, d, block_size]? block_size is fixed by the
-    pool layout, so it must already be a sublane-tile multiple."""
+    an arena [n_blocks, h, d, block_size] of `itemsize`-byte elements?
+    block_size is fixed by the pool layout, so it must already be a
+    sublane-tile multiple; one head of one block, with its query rows
+    and softmax state, has to fit the kernel's VMEM budget."""
     if len(q_shape) != 4 or len(arena_shape) != 4:
         return False
     b, h, s, d = q_shape
@@ -256,7 +296,18 @@ def paged_supported(q_shape, arena_shape) -> bool:
         return False
     if d > 256 or s < 1 or s > 256:
         return False
-    return bs >= 8 and bs % 8 == 0 and bs <= 1024 and nb_phys >= 1
+    if bs < 8 or bs % 8 != 0 or nb_phys < 1:
+        return False
+    return paged_heads_per_step(h, _ceil_to(s, 8), d, bs, itemsize) > 0
+
+
+def paged_cut(q_shape, arena_shape, table_blocks, itemsize) -> dict:
+    """How a supported call is cut into grid steps: `heads_per_step`, and
+    `grid_steps` = b x head tiles x the table's logical blocks."""
+    b, h, s, d = q_shape
+    ht = paged_heads_per_step(h, _ceil_to(s, 8), d, arena_shape[3], itemsize)
+    return {"heads_per_step": ht,
+            "grid_steps": b * (h // ht) * int(table_blocks)}
 
 
 def _paged_call(q, k_arena, v_arena, block_tables, lengths, scale):
@@ -282,6 +333,11 @@ def _paged_call_once(q, k_arena, v_arena, block_tables, lengths, *, scale,
     b, h, s_p, d = q.shape
     bs = k_arena.shape[3]
     nb = block_tables.shape[1]
+    # the cut into grid steps: a step pays ~0.3 us whatever it holds, so
+    # it holds as many of a block's heads as fit (one head a step made
+    # GPT-2 XL's decode 6400 steps a layer of 16 KB each: 1.6 ms, all of
+    # it step overhead, against 0.17 ms for 256 steps of 400 KB; PR 31)
+    ht = paged_heads_per_step(h, s_p, d, bs, k_arena.dtype.itemsize)
 
     def q_map(ib, ih, ik, len_ref, bt_ref):
         return (ib, ih, _Z, _Z)
@@ -300,17 +356,17 @@ def _paged_call_once(q, k_arena, v_arena, block_tables, lengths, *, scale,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, nb),
+        grid=(b, h // ht, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, s_p, d), q_map),
-            pl.BlockSpec((1, 1, d, bs), kv_map),
-            pl.BlockSpec((1, 1, d, bs), kv_map),
+            pl.BlockSpec((1, ht, s_p, d), q_map),
+            pl.BlockSpec((1, ht, d, bs), kv_map),
+            pl.BlockSpec((1, ht, d, bs), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, s_p, d), q_map),
+        out_specs=pl.BlockSpec((1, ht, s_p, d), q_map),
         scratch_shapes=[
-            _vmem((s_p, 1), jnp.float32),
-            _vmem((s_p, 1), jnp.float32),
-            _vmem((s_p, d), jnp.float32),
+            _vmem((ht, s_p, 1), jnp.float32),
+            _vmem((ht, s_p, 1), jnp.float32),
+            _vmem((ht, s_p, d), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_decode_attn_kernel,

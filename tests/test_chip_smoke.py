@@ -103,7 +103,8 @@ def _compile_kernels_for_v5e():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     from paddle_tpu.ops.pallas.decode_attention import (
-        decode_attention, paged_decode_attention, paged_write_token)
+        decode_attention, paged_cut, paged_decode_attention,
+        paged_write_token)
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.ops.pallas.fused_ce import fused_linear_cross_entropy
     try:
@@ -147,6 +148,14 @@ def _compile_kernels_for_v5e():
                         arena, arena, ((2, 4), i32), ((2,), i32))
         compile_for_v5e(paged_write_token, arena, ((2,), i32), ((2,), i32),
                         ((2, 4, 64, 1), bf16))
+    # GPT-2 XL's pool: a block's 25 heads, [1, 25, 64, 128], in one grid
+    # step of the decode step; 5 head tiles under a 256-row prefill
+    arena = ((225, 25, 64, 128), bf16)
+    for (b, s), heads_per_step in (((32, 1), 25), ((1, 256), 5)):
+        compile_for_v5e(paged_decode_attention, ((b, 25, s, 64), bf16),
+                        arena, arena, ((b, 8), i32), ((b,), i32))
+        cut = paged_cut((b, 25, s, 64), arena[0], 8, 2)
+        assert cut["heads_per_step"] == heads_per_step, (b, s, cut)
     print("MOSAIC-OK")
 
 
@@ -218,7 +227,11 @@ def _compile_paged_steps_for_v5e():
             "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
             "arena_bytes": int(np.prod(arena)) * 2,
             "attn": hits.get("pallas.hit.paged_decode_attention", 0),
-            "writer": hits.get("pallas.hit.paged_write_token", 0)}))
+            "writer": hits.get("pallas.hit.paged_write_token", 0),
+            "heads_per_step": monitor.stat_get(
+                f"pallas.paged_decode_attention.heads_per_step.b{b}s{s}"),
+            "grid_steps": monitor.stat_get(
+                f"pallas.paged_decode_attention.grid_steps.b{b}s{s}")}))
     print("PAGED-STEPS-DONE")
 
 
@@ -240,6 +253,10 @@ def test_paged_serve_steps_hold_no_arena_copy_for_v5e():
         assert step["attn"] == 2, step
     assert (decode["s"], decode["writer"]) == (1, 4)      # the Pallas writer
     assert (prefill["s"], prefill["writer"]) == (256, 0)  # the XLA loop
+    # a block's 25 heads in one grid step of the decode step: 32 slots x 8
+    # logical blocks; a 256-row prefill fits 5 heads a step
+    assert (decode["heads_per_step"], decode["grid_steps"]) == (25, 256)
+    assert (prefill["heads_per_step"], prefill["grid_steps"]) == (5, 40)
 
 
 def _compile_latent_steps_for_v5e():

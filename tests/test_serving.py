@@ -10,6 +10,8 @@ fill levels, pool-exhaustion backpressure then admission-on-retire, and
 an injected kernel crash failing the in-flight requests (no demotion)
 while the serve loop itself survives.
 """
+import os
+import sys
 import threading
 
 import jax.numpy as jnp
@@ -23,6 +25,10 @@ from paddle_tpu.inference import ServeConfig, ServeLoop
 from paddle_tpu.nn.kv_pool import (KVBlockPool, PagedKVCache,
                                    paged_attention_ref, write_kv)
 from paddle_tpu.text.models.gpt import GPT, GPTConfig
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "tools"))
+import obs_report  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -88,37 +94,79 @@ def test_pool_rejects_bad_block_size():
 # block-table kernel parity vs the jnp fallback
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("bs", [8, 16, 128])
-@pytest.mark.parametrize("s", [1, 8])
-def test_paged_kernel_parity_fill_levels(interpret, s, bs):
-    """Several fill levels across slots — from one partial block to a
-    full table — kernel vs gather fallback, at block sizes under, at and
-    over a lane tile's sublane count and at the lane width itself."""
+@pytest.mark.parametrize("h,d,s,bs", [
+    # two heads at block sizes under, at and over a lane tile's sublane
+    # count and at the lane width itself
+    (2, 16, 1, 8), (2, 16, 1, 16), (2, 16, 1, 128),
+    (2, 16, 8, 8), (2, 16, 8, 16), (2, 16, 8, 128),
+    # head counts that are no power of two, all in one grid step
+    (5, 64, 1, 128), (25, 64, 1, 128), (25, 64, 8, 16),
+    # 256 query rows of 25 heads do not fit the VMEM budget: 5 head tiles
+    (25, 64, 256, 128),
+])
+def test_paged_kernel_parity_fill_levels(interpret, h, d, s, bs):
+    """Several fill levels across slots — an empty slot, one block partly
+    full, one full, a partly full last block, a full table — kernel vs
+    gather fallback. Every table but the full one ends in the trash
+    block; the empty slot's is nothing else."""
     from paddle_tpu.ops.pallas.decode_attention import (
-        paged_decode_attention, paged_supported)
+        paged_cut, paged_decode_attention, paged_supported)
     rng = np.random.RandomState(0)
-    b, h, d, MB, NB = 4, 2, 16, 4, 14
+    MB, NB = 4, 14
     pool = KVBlockPool(NB, bs)
     (ka, va), = pool.arenas(1, h, d)
+    # 1 part (if it holds the chunk), 1 full, 3 part, 4 full blocks, none
+    fills = [max(bs // 2 + 1, s), max(bs, s), max(2 * bs + 5, s), 4 * bs, 0]
+    b = len(fills)
     bt = np.zeros((b, MB), np.int32)
-    # 1 part (if it holds the chunk), 1 full, 3 part, 4 full blocks
-    fills = [max(bs // 2 + 1, s), bs, 2 * bs + 5, 4 * bs]
     for i, ln in enumerate(fills):
         blocks = pool.alloc(pool.blocks_for(ln))
         bt[i, :len(blocks)] = blocks
     bt = jnp.asarray(bt)
     for i, ln in enumerate(fills):
+        if not ln:
+            continue
         ka = write_kv(ka, bt[i:i + 1], jnp.zeros((1,), jnp.int32),
                       jnp.asarray(rng.randn(1, ln, h, d), jnp.float32))
         va = write_kv(va, bt[i:i + 1], jnp.zeros((1,), jnp.int32),
                       jnp.asarray(rng.randn(1, ln, h, d), jnp.float32))
-    assert paged_supported((b, h, s, d), tuple(ka.shape))
+    assert paged_supported((b, h, s, d), tuple(ka.shape), ka.dtype.itemsize)
+    cut = paged_cut((b, h, s, d), tuple(ka.shape), MB, ka.dtype.itemsize)
+    assert h // cut["heads_per_step"] == (5 if s == 256 else 1)
     q = jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
-    lens = jnp.asarray([ln - s for ln in fills], jnp.int32)
+    lens = jnp.asarray([max(ln - s, 0) for ln in fills], jnp.int32)
     out = paged_decode_attention(q, ka, va, bt, lens)
     ref = paged_attention_ref(q, ka, va, bt, lens, d ** -0.5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5)
+
+
+@pytest.mark.parametrize("h", [25, 12, 16, 7])
+def test_paged_head_tile_comes_from_the_shape(h):
+    """The paged kernel's head tile: a divisor of h, never smaller under a
+    larger budget, every head of a block for a decode step at GPT-2 XL's
+    widths; where not even one head fits, the gate says no."""
+    from paddle_tpu.ops.pallas.decode_attention import (
+        _ATTN_VMEM_BYTES, _paged_step_bytes, paged_cut,
+        paged_heads_per_step, paged_supported)
+    shape = (256, 64, 128, 2)                   # s_p, d, block, bf16
+    budgets = [kib << 10 for kib in (64, 256, 1024, 4096, 12288, 65536)]
+    picked = [paged_heads_per_step(h, *shape, budget=x) for x in budgets]
+    assert picked == sorted(picked) and picked[0] == 0 and picked[-1] == h
+    for ht, budget in zip(picked, budgets):
+        fits = [n for n in range(1, h + 1) if h % n == 0
+                and _paged_step_bytes(n, *shape) <= budget]
+        assert ht == max(fits, default=0)
+    assert paged_heads_per_step(h, 8, 64, 128, 2) == h
+    assert paged_heads_per_step(25, 256, 64, 128, 2) == 5
+    assert paged_cut((32, 25, 1, 64), (225, 25, 64, 128), 8, 2) == \
+        {"heads_per_step": 25, "grid_steps": 256}
+    assert paged_cut((1, 25, 256, 64), (225, 25, 64, 128), 8, 2) == \
+        {"heads_per_step": 5, "grid_steps": 40}
+    # one head of a 2048-token float32 block at d 256 is over the budget
+    assert _paged_step_bytes(1, 256, 256, 2048, 4) > _ATTN_VMEM_BYTES
+    assert not paged_supported((1, h, 256, 256), (4, h, 256, 2048), 4)
+    assert paged_supported((1, h, 256, 256), (4, h, 256, 1024), 4)
 
 
 def _write_kv_numpy(arena, bt, lens, new):
@@ -375,9 +423,23 @@ def test_paged_kernel_engages_in_serve(net, interpret):
     p = rng.randint(1, 1024, (7,)).astype(np.int64)
     loop = ServeLoop(net, ServeConfig(max_active=2, kv_blocks=16,
                                       block_size=16, max_seq_len=64))
+    trace.reset()
     out = loop.serve([p], max_new_tokens=4)[0]
     np.testing.assert_array_equal(out, _ref_generate(net, p, 4))
     assert monitor.stat_get("pallas.hit.paged_decode_attention") > 0
+    # the decode step says how it was cut into grid steps: every head of
+    # a block in one step, 2 slots x 4 logical blocks; as gauges, on the
+    # kernel's span, and in the report of a dump
+    heads = GPTConfig.tiny().num_heads
+    cut = {"heads_per_step": heads, "grid_steps": 2 * 4}
+    for name, value in cut.items():
+        assert monitor.stat_get(
+            f"pallas.paged_decode_attention.{name}.b2s1") == value
+    spans = [sp.attrs for sp in trace.recent()
+             if sp.name == "pallas/paged_decode_attention"]
+    assert any(cut.items() <= attrs.items() for attrs in spans), spans
+    assert f"cut:b2s1={heads}heads/stepx8steps" in obs_report.pallas_rates(
+        {"values": monitor.stats("pallas.")})
 
 
 def test_serve_spans_and_gauges(net):

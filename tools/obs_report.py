@@ -169,9 +169,13 @@ def pallas_rates(metrics) -> str:
     """Per-kernel engagement: pallas.hit.K / pallas.fallback.K.reason /
     pallas.gate_reject.K.reason -> hit/fallback/reject counts + rate.
     (Nothing emits pallas.fallback.* any more; dumps recorded before the
-    demotion was removed still carry it.)"""
+    demotion was removed still carry it.) A kernel that says how its
+    programs were cut into grid steps (pallas.K.heads_per_step.bMsN and
+    .grid_steps.bMsN, M slots of N query rows: the paged kernel) has it
+    in detail."""
     per = defaultdict(lambda: {"hit": 0.0, "fallback": 0.0,
                                "gate_reject": 0.0, "reasons": []})
+    cuts = defaultdict(dict)
     for name, v in metrics.get("values", {}).items():
         if not name.startswith("pallas."):
             continue
@@ -183,6 +187,13 @@ def pallas_rates(metrics) -> str:
             per[parts[2]][kind] += v
             per[parts[2]]["reasons"].append(
                 f"{kind}:{'.'.join(parts[3:])}={int(v)}")
+        elif len(parts) == 4 and parts[2] in ("heads_per_step",
+                                              "grid_steps"):
+            cuts[kind, parts[3]][parts[2]] = int(v)
+    for (k, shape), cut in sorted(cuts.items()):
+        per[k]["reasons"].append(
+            f"cut:{shape}={cut.get('heads_per_step', '?')}heads/step"
+            f"x{cut.get('grid_steps', '?')}steps")
     rows = []
     for k in sorted(per):
         d = per[k]
