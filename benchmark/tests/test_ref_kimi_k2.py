@@ -133,10 +133,9 @@ def test_driver_toy_is_correct_and_reports_the_cells_metrics(capsys):
     assert set(e2e) == {"serve_tokens_per_s", "setup_s"}
     # without a trace the device_trace and program_span readers report
     # nothing; the expert counters are read from the window's samples
-    assert set(layer) == {"gen_late_p95_ms", "beat_ms", "decode_occupancy",
-                          "kv_used_share", "chat_ttft_p50_ms",
-                          "chat_tpot_p50_ms", "compiles_in_window",
-                          "moe_expert_peak_over_mean"}
+    assert set(layer) == {"gen_late_p95_ms", "beat_ms", "kv_used_share",
+                          "chat_ttft_p50_ms", "chat_tpot_p50_ms",
+                          "compiles_in_window", "moe_expert_peak_over_mean"}
     assert 1.0 <= layer["moe_expert_peak_over_mean"]["value"] <= 8.0
 
 
