@@ -438,7 +438,7 @@ define_flag("PADDLE_ONLINE_STALENESS_BATCHES", 4,
 # --- tools/cluster_obs_drill.py) ---
 define_flag("PADDLE_TELEMETRY_HUB", "",
             "host:port of a TelemetryHub. When set, processes that opt "
-            "in (drills, bench.py snapshot emitters, anything that "
+            "in (drills, metric snapshot emitters, anything that "
             "starts a TelemetryShipper) ship metric deltas / span "
             "batches there; empty (the default) means fully local "
             "observability, no network")

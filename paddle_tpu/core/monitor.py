@@ -24,7 +24,7 @@ pybind/global_value_getter_setter.cc), grown into a typed registry:
 
 Concurrency: ONE lock guards every structure, and `reset(prefix=...)`
 clears values, types, series, and histograms in a single critical
-section. That atomicity is load-bearing for benches: bench.py resets
+section. That atomicity is load-bearing: a report resets a prefix like
 `pallas.`/`executor/` between modes while pipeline prefetch and
 communicator send threads are still writing — a reset that cleared the
 value map and the series map in separate lock acquisitions would let a

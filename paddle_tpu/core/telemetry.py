@@ -625,7 +625,7 @@ class TelemetryShipper:
 # --------------------------------------------------------------------------
 
 def fetch_snapshot(endpoint=None, timeout=5.0):
-    """One-shot aggregated hub snapshot (bench.py's fleet section).
+    """One-shot aggregated hub snapshot (a report's fleet section).
     Raises on an unreachable hub — callers own their degrade policy."""
     rpc = _rpc()
     endpoint = endpoint or _flag("PADDLE_TELEMETRY_HUB")

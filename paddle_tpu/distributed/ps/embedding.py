@@ -41,7 +41,7 @@ defeats conflict tracking exactly as it would defeat any cache.
 Overlap accounting (`stats()` / `overlap_ratio`): per-pull wall time is
 measured on the background thread, exposed wait at `get()` on the
 caller — `1 - wait/pull` is the fraction of PS latency the dense step
-absorbed (`bench.py BENCH_MODE=sparse` reports it).
+absorbed (`tools/ps_load_test.py` reports it).
 """
 from __future__ import annotations
 

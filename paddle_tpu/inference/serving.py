@@ -65,7 +65,7 @@ kv_pool_free_blocks,model_version}`). Counters `serve.{preempted,
 tokens_generated,requests_completed,requests_errored,hot_swaps,
 completion_log_errors}`, histograms `serve/ttft_ms` and
 `serve/token_ms` — rendered by tools/obs_report.py's serving section
-and snapshotted by BENCH_MODE=serve.
+and by `benchmark/`'s serve drivers.
 """
 from __future__ import annotations
 

@@ -288,3 +288,17 @@ def test_tool_registry_completeness_detected():
 
 def test_tool_registry_repo_is_complete():
     assert framework_lint.check_tool_registry() == []
+
+
+def test_doc_command_to_a_retired_entry_point_detected():
+    assert framework_lint.check_doc_commands() == []      # the repo itself
+    with tempfile.TemporaryDirectory() as tmp:
+        os.makedirs(os.path.join(tmp, "docs"))
+        for name, text in (("README.md", "run `python3 kept.py --x`\n"),
+                           ("BASELINE.md", "no command here\n"),
+                           ("docs/ops.md", "then `ENV=1 python gone.py`\n"),
+                           ("kept.py", "")):
+            with open(os.path.join(tmp, name), "w") as f:
+                f.write(text)
+        problems = framework_lint.check_doc_commands(tmp)
+    assert len(problems) == 1 and "docs/ops.md:1" in problems[0], problems

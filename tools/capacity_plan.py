@@ -28,7 +28,7 @@ FLAGS_capacity_p99_band_pct. The achieved headroom is written to
 HLO_EVIDENCE.json `graphs.capacity_validation.band_headroom_x` and
 gated >= 1.0 by framework_lint.check_perf_floors.
 
-Flag/doc/bench pins live in self_check (TOOL_CROSS_CHECKS).
+Flag/doc pins live in self_check (TOOL_CROSS_CHECKS).
 """
 from __future__ import annotations
 
@@ -249,7 +249,7 @@ def _validate_once(prof, band50, band99):
 # ---------------------------------------------------------------------------
 
 def self_check():
-    """Pin flag defaults <-> this tool's knobs <-> docs <-> bench <->
+    """Pin flag defaults <-> this tool's knobs <-> docs <->
     committed evidence. Run by framework_lint.check_registered_tools."""
     problems = []
     from paddle_tpu.core import flags as _flags
@@ -286,35 +286,13 @@ def self_check():
         with open(doc) as f:
             text = f.read()
         for tok in ("capacity_plan", "--validate", "band_headroom_x",
-                    "BENCH_MODE=traffic", "splitmix64",
+                    "splitmix64",
                     *CAPACITY_FLAG_DEFAULTS, *TRAFFIC_FLAG_DEFAULTS):
             if tok not in text:
                 problems.append(
                     f"capacity_plan: docs/traffic_lab.md lost {tok!r}")
     except OSError as e:
         problems.append(f"capacity_plan: cannot read {doc}: {e}")
-
-    # bench env knobs: the traffic mode line reads these defaults
-    import re
-    bench_src = os.path.join(REPO, "bench.py")
-    try:
-        with open(bench_src) as f:
-            btext = f.read()
-        for env, want in (("BENCH_TRAFFIC_REQUESTS", 96),
-                          ("BENCH_TRAFFIC_RATE", 40),
-                          ("BENCH_TRAFFIC_NEW", 8),
-                          ("BENCH_TRAFFIC_CLIENTS", 4)):
-            pat = r'os\.environ\.get\("%s",\s*([0-9]+)\)' % env
-            m = re.search(pat, btext)
-            if not m:
-                problems.append(
-                    f"capacity_plan: bench.py lost the {env} knob")
-            elif int(m.group(1)) != want:
-                problems.append(
-                    f"capacity_plan: bench.py {env} default "
-                    f"{m.group(1)} != pinned {want}")
-    except OSError as e:
-        problems.append(f"capacity_plan: cannot read bench.py: {e}")
 
     # committed evidence: bands recorded there must be the flag bands,
     # and the perf floor gates headroom >= 1.0 (framework_lint)

@@ -360,10 +360,9 @@ def check_concretization(ops_dir=OPS_DIR):
 # gate. Each module lives in tools/, exposes `self_check()` returning a
 # list of violation strings, and `main(argv)` for standalone use.
 TOOL_CROSS_CHECKS = ["spmd_lint", "spmd_plan", "hlo_evidence",
-                     "pipeline_lint", "obs_report", "ps_load_test",
-                     "elastic_drill", "serve_load_test",
-                     "pp_schedule_report", "online_drill",
-                     "cluster_obs_drill", "capacity_plan"]
+                     "obs_report", "ps_load_test", "elastic_drill",
+                     "serve_load_test", "pp_schedule_report",
+                     "online_drill", "cluster_obs_drill", "capacity_plan"]
 
 
 def check_tool_registry(tools_dir=None):
@@ -535,6 +534,28 @@ def check_doc_flags(docs_dir=DOCS_DIR):
     return problems
 
 
+_DOC_COMMAND = re.compile(r"\bpython3? +([\w./-]+\.py)\b")
+
+
+def check_doc_commands(root=REPO):
+    """Every `python[3] <path>.py` that README.md, BASELINE.md or docs/*.md
+    quotes must name a file of the tree (the driver's checkout holds what
+    git tracks): no doc sends its reader to a retired entry point."""
+    names = ["README.md", "BASELINE.md"] + sorted(
+        f"docs/{f}" for f in os.listdir(os.path.join(root, "docs"))
+        if f.endswith(".md"))
+    problems = []
+    for name in names:
+        with open(os.path.join(root, name)) as f:
+            for lineno, line in enumerate(f, 1):
+                for path in _DOC_COMMAND.findall(line):
+                    if not os.path.isfile(os.path.join(root, path)):
+                        problems.append(
+                            f"{name}:{lineno} tells the reader to run "
+                            f"`{path}`, which the repository does not have")
+    return problems
+
+
 # ---------------------------------------------------------------------------
 # check 6: the traffic lab must stay deterministic
 # ---------------------------------------------------------------------------
@@ -638,6 +659,7 @@ def run_lint(spec_path=SPEC_PATH, versions_path=VERSIONS_PATH,
     problems += check_tool_registry()
     problems += check_registered_tools()
     problems += check_doc_flags()
+    problems += check_doc_commands()
     problems += check_traffic_determinism()
     return problems
 

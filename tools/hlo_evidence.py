@@ -6,7 +6,7 @@ runs (`python chip_smoke.py` on the chip is). The same optimize-inside-
 the-compiler-stack / verify-at-the-HLO posture as EQuARX
 (arXiv:2506.17615):
 
-1. AOT-lowers the bench graphs for a TPU target on any dev box
+1. AOT-lowers the graphs below for a TPU target on any dev box
    (`jax.jit(f).trace(...).lower(lowering_platforms=("tpu",))` — Mosaic
    lowering needs no TPU, only *running* does; FLAGS_pallas_force_compile
    keeps the kernels out of interpreter mode off-TPU);
@@ -21,8 +21,8 @@ the-compiler-stack / verify-at-the-HLO posture as EQuARX
    math and stated as such);
 4. writes HLO_EVIDENCE.json.
 
-Graphs lowered (configs mirror bench.py; framework_lint's
-TOOL_CROSS_CHECKS runs self_check() so the two can't drift):
+Graphs lowered (the shapes at which kernel presence is asserted, nothing
+more; framework_lint's TOOL_CROSS_CHECKS runs self_check() on them):
 
 - bert_train_step   — BERT-base MLM fused-CE head, b32 s128 bf16
                       (fused-CE fwd+bwd custom calls; flash gated off by
@@ -30,8 +30,8 @@ TOOL_CROSS_CHECKS runs self_check() so the two can't drift):
 - gpt_longseq_train_step — GPT-124M s4096 causal train step (flash
                       fwd+bwd custom calls — the long-context regime the
                       kernel exists for)
-- gpt_decode_step   — one GPT-124M StaticKVCache decode step at the
-                      bench decode config (decode custom call), lowered
+- gpt_decode_step   — one GPT-124M StaticKVCache decode step at
+                      DECODE_CFG (decode custom call), lowered
                       twice: kernel on vs FLAGS_use_decode_attention=0
                       (_sdpa full-cache path) for the cost comparison.
 
@@ -54,14 +54,11 @@ REPO = os.path.dirname(TOOLS_DIR)
 if REPO not in sys.path:  # `python tools/hlo_evidence.py` from anywhere
     sys.path.insert(0, REPO)
 
-# ---- canonical bench configs (self_check() lints these against bench.py) --
+# ---- canonical configs (self_check() runs the kernel gates at them) -------
 BERT_CFG = {"batch": 32, "seq": 128, "dtype": "bfloat16"}
 DECODE_CFG = {"batch": 8, "prompt": 32, "new": 128, "max_seq_len": 1024}
 LONGSEQ_CFG = {"batch": 1, "seq": 4096}
-# train-mode pipeline scan-megastep config. Deliberately an INDEPENDENT
-# literal: tools/pipeline_lint.py (a TOOL_CROSS_CHECKS sibling) compares
-# it against its own canonical copy and bench.py's env defaults, so a
-# drift in any one of the three actually fires the lint.
+# train-mode pipeline scan-megastep config (self_check(): FLAGS_executor_*)
 PIPELINE_CFG = {"batch": 256, "hidden": 64, "steps": 200, "scan_k": 8,
                 "inflight": 2}
 TINY_PIPELINE_CFG = {"batch": 8, "hidden": 4, "steps": 8, "scan_k": 4,
@@ -72,8 +69,8 @@ TINY_DECODE_CFG = {"batch": 2, "prompt": 4, "new": 8, "max_seq_len": 64}
 TINY_LONGSEQ_CFG = {"batch": 1, "seq": 128}
 
 # serving-tier fused decode step (inference/serving.py over the paged
-# KV pool): slots/blocks mirror the FLAGS_serve_* defaults and bench.py's
-# BENCH_SERVE_* env defaults (serve_load_test.self_check pins all three)
+# KV pool): slots/blocks mirror the FLAGS_serve_* defaults
+# (serve_load_test.self_check pins the two)
 SERVE_CFG = {"slots": 64, "blocks": 512, "block_size": 128,
              "max_seq_len": 1024, "prompt": 32, "new": 64}
 TINY_SERVE_CFG = {"slots": 2, "blocks": 6, "block_size": 16,
@@ -156,59 +153,49 @@ def _reset_counters():
 # --------------------------------------------------------------------------
 
 def lower_bert_train(cfg):
-    """The bench_bert train step (fused-CE head), lowered for TPU."""
-    import jax
-    import jax.numpy as jnp
+    """A BERT MLM train step (fused-CE head), lowered for TPU."""
+    from paddle_tpu.text.models.bert import Bert, BertConfig
 
-    sys.path.insert(0, REPO)
-    import bench
-    from paddle_tpu.text.models.bert import BertConfig
-
-    bert_cfg = BertConfig.bert_base() if cfg["seq"] >= 128 \
-        else BertConfig.tiny()
-    saved_dtype = bench.DTYPE
-    try:
-        bench.DTYPE = cfg["dtype"]
-        step, params, slots, n_params = bench._build(bert_cfg,
-                                                     use_fused_head=True)
-    finally:
-        bench.DTYPE = saved_dtype
-    ids = jnp.zeros((cfg["batch"], cfg["seq"]), jnp.int32)
-    labels = jnp.zeros((cfg["batch"], cfg["seq"]), jnp.int32)
-    lr = jnp.asarray(1e-4, jnp.float32)
-    t = jnp.asarray(1, jnp.int32)
-    key = jax.random.PRNGKey(0)
-    # step is already jitted; re-trace the underlying function for AOT
-    fn = step.__wrapped__ if hasattr(step, "__wrapped__") else step
-    return _lower_tpu(fn, params, slots, ids, labels, lr, t, key)
+    net = Bert(BertConfig.bert_base() if cfg["seq"] >= 128
+               else BertConfig.tiny())
+    return _lower_train_step(net, "masked_lm_labels", cfg,
+                             cfg["dtype"] == "bfloat16")
 
 
 def lower_gpt_longseq_train(cfg):
-    """The bench_longseq train step (flash attention + fused-CE head)."""
-    import jax
-    import jax.numpy as jnp
-
+    """A long-sequence GPT train step (flash attention + fused-CE head)."""
     import paddle_tpu as paddle
-    from paddle_tpu import optimizer as opt_mod
-    from paddle_tpu.core import rng as _rng
-    from paddle_tpu.core import tape as _tape
-    from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.text.models.gpt import GPT, GPTConfig
 
-    seq, batch = cfg["seq"], cfg["batch"]
+    seq = cfg["seq"]
     gcfg = GPTConfig(max_seq_len=seq, dropout=0.0) if seq >= 1024 else \
         GPTConfig(vocab_size=1024, hidden_size=64, num_layers=2,
                   num_heads=2, intermediate_size=128, max_seq_len=seq,
                   dropout=0.0)
     paddle.seed(0)
-    net = GPT(gcfg)
+    return _lower_train_step(GPT(gcfg), "labels", cfg, True)
+
+
+def _lower_train_step(net, labels_kw, cfg, bf16):
+    """AdamW train step on `net(ids, **{labels_kw: labels})`, lowered for
+    TPU; `bf16`: bf16 params over f32 master weights (the O2 recipe)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import optimizer as opt_mod
+    from paddle_tpu.core import rng as _rng
+    from paddle_tpu.core import tape as _tape
+    from paddle_tpu.core.tensor import Tensor
+
+    seq, batch = cfg["seq"], cfg["batch"]
     net.train()
     optimizer = opt_mod.AdamW(learning_rate=1e-4,
                               parameters=net.parameters(),
-                              multi_precision=True)
+                              multi_precision=bf16)
     params, buffers = net.functional_state()
-    params = {k: v.astype(jnp.bfloat16) if v.dtype == jnp.float32 else v
-              for k, v in params.items()}
+    if bf16:
+        params = {k: v.astype(jnp.bfloat16) if v.dtype == jnp.float32 else v
+                  for k, v in params.items()}
     named = dict(net.named_parameters())
     optimizer._ensure_slots(params)
     slots = dict(optimizer._slots)
@@ -219,7 +206,7 @@ def lower_gpt_longseq_train(cfg):
             def loss_of(p):
                 net.load_functional_state(p, buffers)
                 loss = net(Tensor(ids, _internal=True),
-                           labels=Tensor(labels, _internal=True))
+                           **{labels_kw: Tensor(labels, _internal=True)})
                 return loss._value.mean().astype(jnp.float32)
 
             loss, grads = jax.value_and_grad(loss_of)(params)
@@ -241,7 +228,7 @@ def lower_gpt_longseq_train(cfg):
 
 def lower_gpt_decode_step(cfg, use_kernel):
     """ONE incremental decode step (s=1 against the StaticKVCache) at the
-    bench decode config — the body the generation scan repeats `new`
+    DECODE_CFG shape — the body the generation scan repeats `new`
     times. Lowered with the decode kernel on or forced to the jnp _sdpa
     full-cache path."""
     import jax
@@ -707,46 +694,25 @@ def run(out_path="HLO_EVIDENCE.json", tiny=False):
 # framework_lint cross-check (TOOL_CROSS_CHECKS)
 # --------------------------------------------------------------------------
 
-def _bench_source():
-    with open(os.path.join(REPO, "bench.py")) as f:
-        return f.read()
-
-
 def self_check():
-    """Fast config-drift + gate lint (no lowering): the tool's canonical
-    configs must match bench.py's env-var defaults, and the kernel
-    eligibility gates must pass for every bench shape — otherwise the
-    'evidence' would be for graphs the bench never runs."""
+    """Fast flag + gate lint (no lowering): PIPELINE_CFG must be the
+    pipeline users get by default, and the kernel eligibility gates must
+    pass for every canonical shape — otherwise the 'evidence' would be
+    for graphs in which no kernel engages."""
     problems = []
-    src = _bench_source()
+    # flag DECLARED defaults (not live values — a test may have set them)
+    from paddle_tpu.core.flags import _DEFS
+    for flag, want in (
+            ("FLAGS_executor_max_inflight", PIPELINE_CFG["inflight"]),
+            ("FLAGS_executor_scan_steps", 0)):  # scan fusion is opt-in
+        if int(_DEFS[flag][1]) != want:
+            problems.append(f"hlo_evidence: {flag} default "
+                            f"{_DEFS[flag][1]} != {want} (PIPELINE_CFG)")
+    if PIPELINE_CFG["scan_k"] < 2:
+        problems.append("hlo_evidence: scan_k must be >= 2 — the '>=2x "
+                        "fewer dispatches per K steps' bar is vacuous")
 
-    def bench_default(env, want):
-        m = re.search(r'os\.environ\.get\("%s",\s*([0-9]+)\)' % env, src)
-        if not m:
-            problems.append(f"hlo_evidence: bench.py no longer reads {env}")
-            return
-        if int(m.group(1)) != want:
-            problems.append(
-                f"hlo_evidence: bench.py default {env}={m.group(1)} but "
-                f"tools/hlo_evidence.py assumes {want} — update the "
-                "canonical config")
-
-    bench_default("BENCH_BATCH", BERT_CFG["batch"])
-    bench_default("BENCH_SEQ", BERT_CFG["seq"])
-    bench_default("BENCH_DECODE_BATCH", DECODE_CFG["batch"])
-    bench_default("BENCH_DECODE_PROMPT", DECODE_CFG["prompt"])
-    bench_default("BENCH_DECODE_NEW", DECODE_CFG["new"])
-    bench_default("BENCH_LONGSEQ", LONGSEQ_CFG["seq"])
-    bench_default("BENCH_SERVE_SLOTS", SERVE_CFG["slots"])
-    bench_default("BENCH_SERVE_BLOCKS", SERVE_CFG["blocks"])
-    bench_default("BENCH_SERVE_PROMPT", SERVE_CFG["prompt"])
-    bench_default("BENCH_SERVE_NEW", SERVE_CFG["new"])
-    if f"max_seq_len={DECODE_CFG['max_seq_len']}" not in src:
-        problems.append(
-            "hlo_evidence: bench.py decode config no longer uses "
-            f"max_seq_len={DECODE_CFG['max_seq_len']}")
-
-    # eligibility gates for the bench shapes (pure static predicates).
+    # eligibility gates for the canonical shapes (pure static predicates).
     # importlib by dotted path: the package __init__ shadows the
     # decode_attention/flash_attention module names with the functions
     try:

@@ -28,10 +28,9 @@ Usage:
                                 # timeline with per-process lanes)
 
 `self_check()` is registered in tools/framework_lint.py TOOL_CROSS_CHECKS
-so tier-1 pins the three encodings of the observability config against
-each other: the flight-recorder dump schema this renderer expects, the
-core flag defaults (ring/series sizes), and bench.py's per-mode metrics
-snapshot emission.
+so tier-1 pins the two encodings of the observability config against
+each other: the flight-recorder dump schema this renderer expects and
+the core flag defaults (ring/series sizes).
 """
 from __future__ import annotations
 
@@ -429,15 +428,6 @@ def self_check():
         if not callable(getattr(monitor, fn, None)):
             problems.append(f"obs_report: core.monitor.{fn}() is gone "
                             "but the dump/report format depends on it")
-    # bench must snapshot the counters per mode (BENCH_*.json carries
-    # them); pin the emission the same way pipeline_lint pins env vars
-    with open(os.path.join(REPO, "bench.py")) as f:
-        src = f.read()
-    if "metrics_snapshot" not in src or "monitor.snapshot" not in src:
-        problems.append(
-            "obs_report: bench.py no longer emits the per-mode "
-            "metrics_snapshot line (monitor.snapshot) — BENCH_*.json "
-            "would lose the counters")
     return problems
 
 

@@ -34,9 +34,8 @@ Env knobs (defaults are the CPU-valid tier-1 shape):
   ONLINE_DRILL_CHAOS_PCT=2  per-event %% probability of RESET and DROP
 
 framework_lint TOOL_CROSS_CHECKS runs self_check() here: the
-PADDLE_STREAM_* / PADDLE_ONLINE_* flag defaults, bench.py's
-BENCH_ONLINE_* online-mode knobs, and docs/online_learning.md must
-agree.
+PADDLE_STREAM_* / PADDLE_ONLINE_* flag defaults and
+docs/online_learning.md must agree.
 """
 import json
 import os
@@ -63,15 +62,6 @@ ONLINE_FLAG_DEFAULTS = {
     "PADDLE_STREAM_DEDUPE_WINDOW": 4096,
     "PADDLE_ONLINE_SYNC_EVERY": 1,
     "PADDLE_ONLINE_STALENESS_BATCHES": 4,
-}
-
-# bench.py online-mode env defaults (BENCH_MODE=online); self_check pins
-# them so the bench line and this drill describe the same loop
-BENCH_ONLINE_DEFAULTS = {
-    "BENCH_ONLINE_RECORDS": 512,
-    "BENCH_ONLINE_BATCH": 16,
-    "BENCH_ONLINE_SYNC_EVERY": 4,
-    "BENCH_ONLINE_PUBLISH_EVERY": 8,
 }
 
 FAST = dict(timeout=2.0, max_retries=2, backoff_base=0.01,
@@ -342,8 +332,7 @@ def run():
 # --------------------------------------------------------------------------
 
 def self_check():
-    """Online-loop knobs <-> flag defaults <-> bench online config <->
-    docs. Returns violations."""
+    """Online-loop knobs <-> flag defaults <-> docs. Returns violations."""
     problems = []
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     try:
@@ -360,26 +349,6 @@ def self_check():
                 f"online_drill: {name} default drifted "
                 f"({defn[1]!r} != {want!r}) — update ONLINE_FLAG_DEFAULTS "
                 "and docs/online_learning.md")
-    # bench.py online-mode env defaults
-    import re
-    with open(os.path.join(repo, "bench.py")) as f:
-        src = f.read()
-    for env, want in BENCH_ONLINE_DEFAULTS.items():
-        m = re.search(r'os\.environ\.get\("%s",\s*([0-9]+)\)' % env, src)
-        if not m:
-            problems.append(
-                f"online_drill: bench.py no longer reads {env}")
-        elif int(m.group(1)) != want:
-            problems.append(
-                f"online_drill: bench.py default {env}={m.group(1)} "
-                f"but this tool assumes {want}")
-    # the bench's flush cadence must stay legal under the default
-    # staleness bound — otherwise BENCH_MODE=online benches a config the
-    # trainer would fail-stop on
-    if BENCH_ONLINE_DEFAULTS["BENCH_ONLINE_SYNC_EVERY"] > \
-            ONLINE_FLAG_DEFAULTS["PADDLE_ONLINE_STALENESS_BATCHES"]:
-        problems.append("online_drill: BENCH_ONLINE_SYNC_EVERY exceeds "
-                        "the PADDLE_ONLINE_STALENESS_BATCHES default")
     # docs
     doc_path = os.path.join(repo, "docs", "online_learning.md")
     try:
@@ -391,11 +360,8 @@ def self_check():
         if name not in doc:
             problems.append(f"online_drill: flag {name} is not "
                             "documented in docs/online_learning.md")
-    for token in ("online_drill", "BENCH_MODE=online"):
-        if token not in doc:
-            problems.append(
-                f"online_drill: docs/online_learning.md no longer "
-                f"mentions `{token}`")
+    if "online_drill" not in doc:
+        problems.append("online_drill: docs/online_learning.md omits it")
     # ttft percentiles must come from the shared core/slo.py estimator
     with open(os.path.abspath(__file__)) as f:
         self_src = f.read()

@@ -26,8 +26,8 @@ Env knobs (defaults are the CPU-valid tier-1 shape):
   SERVE_LOAD_VERIFY=4      streams cross-checked vs sequential generate
 
 framework_lint TOOL_CROSS_CHECKS runs self_check() here: the
-FLAGS_serve_* defaults, bench.py's BENCH_SERVE_* serve-mode knobs,
-tools/hlo_evidence.py's SERVE_CFG, and docs/serving.md must agree.
+FLAGS_serve_* defaults, tools/hlo_evidence.py's SERVE_CFG, and
+docs/serving.md must agree.
 """
 import json
 import os
@@ -55,16 +55,6 @@ SERVE_FLAG_DEFAULTS = {
     "FLAGS_serve_block_size": 0,
     "FLAGS_serve_kv_blocks": 512,
     "FLAGS_serve_max_active": 64,
-}
-
-# bench.py serve-mode env defaults (BENCH_MODE=serve); self_check pins
-# them so the bench line and this drill describe the same tier
-BENCH_SERVE_DEFAULTS = {
-    "BENCH_SERVE_REQUESTS": 256,
-    "BENCH_SERVE_PROMPT": 32,
-    "BENCH_SERVE_NEW": 64,
-    "BENCH_SERVE_SLOTS": 64,
-    "BENCH_SERVE_BLOCKS": 512,
 }
 
 
@@ -176,8 +166,8 @@ def run():
 # --------------------------------------------------------------------------
 
 def self_check():
-    """Serve knobs <-> flag defaults <-> bench serve config <->
-    hlo_evidence serve_decode config <-> docs. Returns violations."""
+    """Serve knobs <-> flag defaults <-> hlo_evidence serve_decode
+    config <-> docs. Returns violations."""
     problems = []
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     try:
@@ -194,29 +184,6 @@ def self_check():
                 f"serve_load_test: {name} default drifted "
                 f"({defn[1]!r} != {want!r}) — update SERVE_FLAG_DEFAULTS "
                 "and docs/serving.md")
-    # bench.py serve-mode env defaults
-    import re
-    with open(os.path.join(repo, "bench.py")) as f:
-        src = f.read()
-    for env, want in BENCH_SERVE_DEFAULTS.items():
-        m = re.search(r'os\.environ\.get\("%s",\s*([0-9]+)\)' % env, src)
-        if not m:
-            problems.append(
-                f"serve_load_test: bench.py no longer reads {env}")
-        elif int(m.group(1)) != want:
-            problems.append(
-                f"serve_load_test: bench.py default {env}={m.group(1)} "
-                f"but this tool assumes {want}")
-    # the bench serve slots/blocks defaults must BE the flag defaults —
-    # one serving shape across bench, flags and the evidence tool
-    if BENCH_SERVE_DEFAULTS["BENCH_SERVE_SLOTS"] != \
-            SERVE_FLAG_DEFAULTS["FLAGS_serve_max_active"]:
-        problems.append("serve_load_test: BENCH_SERVE_SLOTS != "
-                        "FLAGS_serve_max_active default")
-    if BENCH_SERVE_DEFAULTS["BENCH_SERVE_BLOCKS"] != \
-            SERVE_FLAG_DEFAULTS["FLAGS_serve_kv_blocks"]:
-        problems.append("serve_load_test: BENCH_SERVE_BLOCKS != "
-                        "FLAGS_serve_kv_blocks default")
     # hlo_evidence's serve_decode config
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     try:
@@ -244,11 +211,8 @@ def self_check():
         if name not in doc:
             problems.append(f"serve_load_test: flag {name} is not "
                             "documented in docs/serving.md")
-    for token in ("serve_load_test", "BENCH_MODE=serve"):
-        if token not in doc:
-            problems.append(
-                f"serve_load_test: docs/serving.md no longer mentions "
-                f"`{token}`")
+    if "serve_load_test" not in doc:
+        problems.append("serve_load_test: docs/serving.md omits the drill")
     # the p50/p99 lines must come from the shared estimator, and it must
     # round-trip the exact values this report's pins were written against
     try:
