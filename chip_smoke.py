@@ -418,6 +418,41 @@ def arena_relayouts(hlo_text, arena_shape):
     return re.findall(r"= \w+\[%s\]\S* (copy|transpose)\(" % dims, hlo_text)
 
 
+_HLO_ITEM_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2,
+                   "s16": 2, "u16": 2, "f32": 4, "s32": 4, "u32": 4,
+                   "f64": 8, "s64": 8, "u64": 8}
+
+
+def lane_padded_results(hlo_text, min_bytes=64 << 10):
+    """The instructions of a compiled program's optimized HLO whose
+    result is an array with a minor dimension of 1 (by its layout) and
+    more than `min_bytes` as laid out: one element a 128-lane row. The
+    decode step's token writer once asked for one, `bf16[32,25,64,1]
+    {3,2,1,0}`: 13 MB for 102 KB of tokens, a `copy` before every call
+    (PR 34). Instructions inside a fusion are no buffers and are left
+    out."""
+    import re
+    fused = set(re.findall(r" fusion\(.*?calls=%?([\w.\-]+)", hlo_text))
+    found, skip = [], False
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            skip = head.group(1) in fused
+        m = not skip and re.match(
+            r"\s+(?:ROOT )?%?[\w.\-]+ = ((\w+)\[([\d,]+)\]"
+            r"\{([\d,]+)[^}]*\}) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        shape, dtype, dims, minor_to_major, op = m.groups()
+        dims = [int(n) for n in dims.split(",")]
+        if len(dims) < 2 or dims[int(minor_to_major.split(",")[0])] != 1:
+            continue
+        laid_out = int(np.prod(dims)) * 128 * _HLO_ITEM_BYTES.get(dtype, 4)
+        if laid_out > min_bytes:
+            found.append(f"{op} {shape}")
+    return found
+
+
 def _serve_program_memory(loop, bucket):
     """Lower and compile ServeLoop's own decode step and one prefill
     bucket (the persistent cache has both after the run above) and read

@@ -253,10 +253,9 @@ def write_kv(arena, block_tables, lengths, new_kv):
     A loop, not unrolled: a serve program holds two writes per layer."""
     bt = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
-    if new_kv.shape[1] == 1 and _write_kernel_eligible(arena):
-        from ..ops.pallas import run_guarded
-        return run_guarded("paged_write_token",
-                           lambda: _write_token(arena, bt, lens, new_kv))
+    if new_kv.shape[1] == 1 and \
+            _write_kernel_eligible(arena, new_kv.shape[0]):
+        return _write_token(arena, bt, lens, new_kv)
     return _write_blocks(arena, bt, lens, new_kv)
 
 
@@ -274,11 +273,27 @@ def _tokens_to_lanes(arena, new_kv):
 
 
 def _write_token(arena, bt, lens, new_kv):
-    from ..ops.pallas.decode_attention import paged_write_token
-    bs = arena.shape[3]
-    slots = jnp.arange(new_kv.shape[0], dtype=jnp.int32)
-    return paged_write_token(arena, _phys_row(bt, slots, lens // bs),
-                             lens % bs, _tokens_to_lanes(arena, new_kv))
+    """The decode step's write through the Pallas writer. Its tokens go
+    in dense, [h, d, b] with the slots in the lanes: one small transpose
+    (a [b, h, d, 1] operand, one element a 128-lane row, cost a relayout
+    of 128 x the tokens' bytes before every call; PR 34)."""
+    from ..core import monitor
+    from ..ops.pallas import run_guarded
+    from ..ops.pallas.decode_attention import (paged_write_cut,
+                                               paged_write_token)
+    b, bs = new_kv.shape[0], arena.shape[3]
+    slots = jnp.arange(b, dtype=jnp.int32)
+    tokens = jnp.transpose(new_kv[:, 0].astype(arena.dtype), (1, 2, 0))
+    # what a call moves, on the writer's span and as gauges per slot
+    # count for a dump to read (b32 is a decode step of 32 slots)
+    cut = paged_write_cut(tuple(arena.shape), b, arena.dtype.itemsize)
+    monitor.stat_set_many({f"pallas.paged_write_token.{name}.b{b}": value
+                           for name, value in cut.items()})
+    return run_guarded(
+        "paged_write_token",
+        lambda: paged_write_token(arena, _phys_row(bt, slots, lens // bs),
+                                  lens % bs, tokens),
+        **cut)
 
 
 # jitted, like the kernels' calls in ops/pallas/decode_attention.py, so
@@ -406,12 +421,12 @@ def _paged_kernel_eligible(q, k_arena, training):
                                 k_arena.dtype.itemsize))
 
 
-def _write_kernel_eligible(arena):
+def _write_kernel_eligible(arena, slots):
     from ..ops.pallas.decode_attention import paged_write_supported
     return _paged_gate(
         "paged_write_token", False,
         lambda: paged_write_supported(tuple(arena.shape),
-                                      arena.dtype.itemsize))
+                                      arena.dtype.itemsize, slots))
 
 
 def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,

@@ -427,50 +427,98 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
 # The decode step's write: one token per slot into the same arena, as a
 # kernel because XLA's form of it (read the slot's block, select, write it
 # back, in a loop over slots) runs the slots one after another — 0.26 ms a
-# layer at 32 slots on a v5e against 0.08 ms here, where the grid's
-# pipeline fetches slot i+1's block while slot i's is written back (PR 26).
+# layer at 32 slots on a v5e against 0.08 ms here for a layer's two calls,
+# where the grid's pipeline fetches slot i+1's block while slot i's is
+# written back (PR 26). That figure left out what the operand cost: the
+# kernel took a slot's token as a [1, h, d, 1] block, and a 4-d array whose
+# minor dimension is 1 is one element a 128-lane row, so XLA laid the 102 KB
+# of GPT-2 XL's 32 tokens out as 13 MB before every call (`reshape`, 2 x
+# 36.5 us a layer beside the writer's 45.5 + 41.2: 160 us a layer, 7.7 ms of
+# a 15.7 ms decode step). Since PR 34 the tokens come dense, [h, d, slots]
+# with the slots in the lanes, and the kernel brings its slot's lane to the
+# lane it writes: a call measured alone 55 -> 46 us (45 is the blocks' round
+# trip), and GPT-2 XL's decode step 15.7 -> 13.4 ms.
 
-def _paged_write_kernel(row_ref, off_ref, col_ref, blk_ref, out_ref):
+def _paged_write_kernel(row_ref, off_ref, tok_ref, blk_ref, out_ref):
     """Grid (b,): step i holds slot i's block [1, h, d, bs], aliased in
-    and out, and puts the slot's token [1, h, d, 1] in lane off[i]."""
-    off = off_ref[pl.program_id(0)]
+    and out, beside the step's tokens [h, d, slots padded to 128s]
+    (resident: their block index never changes, so they are fetched once
+    a call), and puts column i of the tokens in lane off[i] of the block.
+
+    The column gets there by a lane rotation of the 128 slots around
+    slot i, by off[i] - i: data is moved, never computed with, so
+    whatever a token's bits say (-0.0, inf, a NaN) they arrive as they
+    are, and no other slot's can leak into the block. Mosaic rotates
+    32-bit lanes only, hence through float32 (exact for the arena's
+    narrower floats)."""
+    from jax.experimental.pallas import tpu as pltpu
+    i = pl.program_id(0)
+    off = off_ref[i]
+    lanes, bs = np.int32(128), blk_ref.shape[3]
+    tok = tok_ref[:, :, pl.ds(pl.multiple_of(i // lanes * lanes, 128), 128)]
+    tok = pltpu.roll(tok.astype(jnp.float32),
+                     (off % lanes - i % lanes + lanes) % lanes, 2)
+    tok = tok.astype(tok_ref.dtype)
+    # the block's lanes beside the 128 rotated ones: a prefix of them, or
+    # whole copies (slot i now sits in lane off % 128 of every copy)
+    tok = tok[:, :, :bs] if bs <= 128 else jnp.tile(tok, (1, 1, bs // 128))
     lane = jax.lax.broadcasted_iota(jnp.int32, blk_ref.shape[1:], 2)
-    out_ref[0] = jnp.where(lane == off, col_ref[0], blk_ref[0])
+    out_ref[0] = jnp.where(lane == off, tok, blk_ref[0])
 
 
-# a step holds the block twice in and twice out (double buffering) plus
-# the lane-padded token: keep that well inside Mosaic's scoped VMEM
-_WRITE_BLOCK_BYTES = 2 << 20
+def _write_step_bytes(h, d, bs, slots, itemsize):
+    """VMEM bytes of one grid step of the writer, lanes padded to 128:
+    the slot's block twice in and twice out (double buffering), the
+    resident tokens twice, and the float32 forms of the 128 slots the
+    kernel rotates."""
+    block = h * d * _ceil_to(bs, 128) * itemsize
+    tokens = h * d * _ceil_to(slots, 128) * itemsize
+    return 4 * block + 2 * tokens + 3 * h * d * 128 * 4
 
 
-def paged_write_supported(arena_shape, itemsize) -> bool:
-    """Static predicate: does a slot's whole block fit the writer's VMEM
-    budget? [n_blocks, h, d, block_size] arenas only."""
-    if len(arena_shape) != 4:
+def paged_write_cut(arena_shape, slots, itemsize) -> dict:
+    """What a supported call moves, as laid out on the device:
+    `token_bytes` of the token operand [h, d, slots] (fetched once) and
+    `block_bytes` of the slots' blocks, in and out."""
+    _, h, d, bs = arena_shape
+    return {"token_bytes": h * d * _ceil_to(slots, 128) * itemsize,
+            "block_bytes": 2 * slots * h * d * _ceil_to(bs, 128) * itemsize}
+
+
+def paged_write_supported(arena_shape, itemsize, slots) -> bool:
+    """Static predicate: do a slot's whole block and the tokens of
+    `slots` slots fit the writer's VMEM budget? [n_blocks, h, d,
+    block_size] arenas of at most 32-bit floats only, the block a
+    sublane-tile multiple inside one 128-lane tile or whole tiles."""
+    if len(arena_shape) != 4 or int(itemsize) > 4 or slots < 1:
         return False
     _, h, d, bs = arena_shape
-    return bs % 8 == 0 and \
-        h * d * max(bs, 128) * int(itemsize) <= _WRITE_BLOCK_BYTES
+    if bs % 8 != 0 or (bs > 128 and bs % 128 != 0):
+        return False
+    return _write_step_bytes(h, d, bs, slots,
+                             int(itemsize)) <= _ATTN_VMEM_BYTES
 
 
-def paged_write_token(arena, rows, offsets, cols):
-    """arena [n_blocks, h, d, bs] with cols[i] ([b, h, d, 1], the arena's
-    dtype) written to lane offsets[i] of physical row rows[i] (both [b]
-    i32), in place. Two slots may share a row only if it is the trash
-    block: a step reads its block before the step before it has written
-    its own back."""
-    return _paged_write_once(arena, rows, offsets, cols,
+def paged_write_token(arena, rows, offsets, tokens):
+    """arena [n_blocks, h, d, bs] with tokens[:, :, i] ([h, d, b], the
+    arena's dtype) written to lane offsets[i] of physical row rows[i]
+    (both [b] i32), in place. Two slots may share a row only if it is the
+    trash block: a step reads its block before the step before it has
+    written its own back."""
+    # the kernel rotates whole 128-lane tiles of slots
+    tokens = jnp.pad(tokens, ((0, 0), (0, 0), (0, -tokens.shape[2] % 128)))
+    return _paged_write_once(arena, rows, offsets, tokens,
                              interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_write_once(arena, rows, offsets, cols, *, interpret):
+def _paged_write_once(arena, rows, offsets, tokens, *, interpret):
     from jax.experimental.pallas import tpu as pltpu
     _, h, d, bs = arena.shape
-    b = cols.shape[0]
+    b = rows.shape[0]
 
-    def col_map(i, row_ref, off_ref):
-        return (i, _Z, _Z, _Z)
+    def tok_map(i, row_ref, off_ref):
+        return (_Z, _Z, _Z)
 
     def blk_map(i, row_ref, off_ref):
         return (row_ref[i], _Z, _Z, _Z)
@@ -480,13 +528,13 @@ def _paged_write_once(arena, rows, offsets, cols, *, interpret):
         _paged_write_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b,),
-            in_specs=[pl.BlockSpec((1, h, d, 1), col_map), blk],
+            in_specs=[pl.BlockSpec(tokens.shape, tok_map), blk],
             out_specs=blk),
         out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
-        input_output_aliases={3: 0},    # operands: rows, offsets, cols, arena
+        input_output_aliases={3: 0},  # operands: rows, offsets, tokens, arena
         compiler_params=_cparams("arbitrary"),
         interpret=interpret,
-    )(rows, offsets, cols, arena)
+    )(rows, offsets, tokens, arena)
 
 
 def decode_attention(q, kc, vc, index, scale=None, block_k=None):

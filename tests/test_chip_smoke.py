@@ -147,7 +147,7 @@ def _compile_kernels_for_v5e():
         compile_for_v5e(paged_decode_attention, ((2, 4, 8, 64), bf16),
                         arena, arena, ((2, 4), i32), ((2,), i32))
         compile_for_v5e(paged_write_token, arena, ((2,), i32), ((2,), i32),
-                        ((2, 4, 64, 1), bf16))
+                        ((4, 64, 2), bf16))
     # GPT-2 XL's pool: a block's 25 heads, [1, 25, 64, 128], in one grid
     # step of the decode step; 5 head tiles under a 256-row prefill
     arena = ((225, 25, 64, 128), bf16)
@@ -156,6 +156,12 @@ def _compile_kernels_for_v5e():
                         arena, arena, ((b, 8), i32), ((b,), i32))
         cut = paged_cut((b, 25, s, 64), arena[0], 8, 2)
         assert cut["heads_per_step"] == heads_per_step, (b, s, cut)
+    # the writer picks its slot's lane out of the dense tokens [h, d, b]:
+    # the cells' decode steps, 32 slots over GPT-2 XL's pool and 64 over
+    # the Kimi share's one-head latent arena
+    for b, arena in ((32, arena[0]), (64, (1025, 1, 576, 128))):
+        compile_for_v5e(paged_write_token, (arena, bf16), ((b,), i32),
+                        ((b,), i32), ((*arena[1:3], b), bf16))
     print("MOSAIC-OK")
 
 
@@ -224,6 +230,7 @@ def _compile_paged_steps_for_v5e():
             "s": s,
             "arena_in_hlo": "[%s]" % ",".join(map(str, arena)) in text,
             "relayouts": chip_smoke.arena_relayouts(text, arena),
+            "lane_padded": chip_smoke.lane_padded_results(text),
             "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
             "arena_bytes": int(np.prod(arena)) * 2,
             "attn": hits.get("pallas.hit.paged_decode_attention", 0),
@@ -251,6 +258,10 @@ def test_paged_serve_steps_hold_no_arena_copy_for_v5e():
         assert step["arena_in_hlo"] and step["relayouts"] == [], step
         assert step["temp_bytes"] < step["arena_bytes"] // 10, step
         assert step["attn"] == 2, step
+        # nor an array one element a 128-lane row: the writer's tokens go
+        # in dense (the [32,25,64,1] row-major copy before each of its
+        # calls was 13 MB for 102 KB, 3.6 ms of a 15.7 ms step; PR 34)
+        assert step["lane_padded"] == [], step
     assert (decode["s"], decode["writer"]) == (1, 4)      # the Pallas writer
     assert (prefill["s"], prefill["writer"]) == (256, 0)  # the XLA loop
     # a block's 25 heads in one grid step of the decode step: 32 slots x 8
@@ -318,6 +329,7 @@ def _compile_latent_steps_for_v5e():
             "s": s,
             "arena_in_hlo": "[%s]" % ",".join(map(str, arena)) in text,
             "relayouts": chip_smoke.arena_relayouts(text, arena),
+            "lane_padded": chip_smoke.lane_padded_results(text),
             "temp_bytes": mem.temp_size_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes,
             "arena_bytes": int(np.prod(arena)) * 2,
@@ -344,6 +356,7 @@ def test_latent_serve_steps_hold_no_arena_copy_for_v5e():
         assert step["arena_in_hlo"] and step["relayouts"] == [], step
         assert step["alias_bytes"] >= 2 * step["arena_bytes"], step
         assert step["temp_bytes"] < 1.5e9, step
+        assert step["lane_padded"] == [], step   # 64 dense latents a call
     assert (decode["s"], decode["writer"]) == (1, 2)      # the Pallas writer
     assert (prefill["s"], prefill["writer"]) == (2048, 0)  # the XLA loop
 

@@ -10,6 +10,7 @@ fill levels, pool-exhaustion backpressure then admission-on-retire, and
 an injected kernel crash failing the in-flight requests (no demotion)
 while the serve loop itself survives.
 """
+import importlib
 import os
 import sys
 import threading
@@ -237,14 +238,108 @@ def test_write_kv_matches_numpy_reference(case):
     assert monitor.stat_get("pallas.hit.paged_write_token") == 0
 
 
+# the cells' decode steps through the writer, block 128, bf16: GPT-2 XL's
+# 32 slots x 25 heads x 64 and the Kimi share's 64 slots over its one-head
+# latent arena [n, 1, 576, 128]; (slots, heads, dim, offsets, poisoned)
+_CELL_WRITE_CASES = {
+    f"{cell}_{offsets}": (*shape, offsets, False)
+    for cell, shape in (("gpt2xl_b32", (32, 25, 64)),
+                        ("kimi_b64", (64, 1, 576)))
+    for offsets in ("offset0", "offset127", "mixed")}
+_CELL_WRITE_CASES.update(
+    gpt2xl_b32_isolation=(32, 25, 64, "mixed", True),
+    kimi_b64_isolation=(64, 1, 576, "mixed", True))
+
+
+def _check_cell_write_case(case):
+    """One decode write at a cell's shape: every slot owns one block but
+    two, parked on the trash block (all-zero tables). `poisoned`: one
+    slot's token is all NaN / inf, and every OTHER slot's block has to be
+    the reference's all the same."""
+    b, h, d, offsets, poisoned = _CELL_WRITE_CASES[case]
+    bs, parked, bf16 = 128, (3, b - 2), jnp.bfloat16
+    pool = KVBlockPool(b + 1, bs)
+    bt = np.asarray([[0] if i in parked else pool.alloc(1)
+                     for i in range(b)], np.int32)
+    rng = np.random.RandomState(b)
+    arena = np.asarray(jnp.asarray(
+        rng.randn(*pool.arena_shape(h, d)).astype(np.float32), bf16))
+    new = rng.randn(b, 1, h, d).astype(np.float32)
+    new[1, 0, 0, :2] = [-0.0, 0.0]          # bits, not values, are moved
+    if poisoned:
+        new[5] = np.resize([np.nan, np.inf, -np.inf], new[5].shape)
+    new = np.asarray(jnp.asarray(new, bf16))
+    lens = {"offset0": np.zeros(b), "offset127": np.full(b, bs - 1),
+            "mixed": rng.randint(0, bs, b)}[offsets].astype(np.int32)
+    got = np.asarray(write_kv(jnp.asarray(arena), jnp.asarray(bt),
+                              jnp.asarray(lens), jnp.asarray(new)))
+    want = _write_kv_numpy(arena, bt, lens, new)
+    assert got.dtype == want.dtype == arena.dtype
+    if poisoned:
+        assert not np.isfinite(got[bt[5, 0], :, :, lens[5]]
+                               .astype(np.float32)).any()
+        np.testing.assert_array_equal(got[1:].astype(np.float32),
+                                      want[1:].astype(np.float32))
+        clean = np.delete(np.arange(1, b + 2), bt[5, 0] - 1)
+        got, want = got[clean], want[clean]
+        assert np.isfinite(got.astype(np.float32)).all()
+    else:
+        got, want = got[1:], want[1:]
+    np.testing.assert_array_equal(got.view(np.uint16), want.view(np.uint16))
+    assert not np.array_equal(got, arena[1:][:len(got)])
+
+
 @pytest.mark.parametrize(
-    "case", sorted(c for c in _WRITE_CASES if _WRITE_CASES[c][3] == 1))
+    "case", sorted(c for c in _WRITE_CASES if _WRITE_CASES[c][3] == 1)
+    + sorted(_CELL_WRITE_CASES))
 def test_write_kv_token_kernel_matches_numpy_reference(interpret, case):
     """The decode step's single-token write through the Pallas writer
-    (interpreted), same cases, same reference."""
+    (interpreted), same cases, same reference; then the cells' shapes,
+    bit for bit, and a slot of NaN / inf that stays in its own block."""
     monitor.reset(prefix="pallas.")
-    _check_write_case(case)
+    if case in _WRITE_CASES:
+        _check_write_case(case)
+    else:
+        _check_cell_write_case(case)
     assert monitor.stat_get("pallas.hit.paged_write_token") == 1
+
+
+def test_paged_write_supported_reads_block_and_slots():
+    """The writer's gate is VMEM accounting from the shape: the cells'
+    arenas pass at 32 and 64 slots (and at the small programs' 2 and 4),
+    a block or a slot count that does not fit is refused."""
+    from paddle_tpu.ops.pallas.decode_attention import (
+        _ATTN_VMEM_BYTES, _write_step_bytes, paged_write_cut,
+        paged_write_supported)
+    for arena in ((225, 25, 64, 128), (1025, 1, 576, 128)):
+        for slots in (2, 4, 32, 64):
+            assert paged_write_supported(arena, 2, slots), (arena, slots)
+    assert paged_write_cut((225, 25, 64, 128), 32, 2) == {
+        "token_bytes": 25 * 64 * 128 * 2,               # 410 KB, dense
+        "block_bytes": 2 * 32 * 25 * 64 * 128 * 2}      # 26.2 MB in + out
+    assert paged_write_cut((1025, 1, 576, 128), 64, 2) == {
+        "token_bytes": 576 * 128 * 2, "block_bytes": 2 * 64 * 576 * 128 * 2}
+    assert not paged_write_supported((9, 25, 64, 1024), 2, 32)   # block
+    assert not paged_write_supported((225, 25, 64, 128), 2, 2048)  # slots
+    assert _write_step_bytes(25, 64, 128, 2048, 2) > _ATTN_VMEM_BYTES
+    assert not paged_write_supported((9, 4, 64, 12), 2, 2)      # sublanes
+    assert paged_write_supported((9, 4, 64, 256), 2, 2)         # two tiles
+    assert not paged_write_supported((9, 4, 64, 192), 2, 2)     # 1.5 tiles
+    assert not paged_write_supported((9, 4, 64, 128), 8, 2)     # float64
+    assert not paged_write_supported((9, 64, 128), 2, 2)
+
+
+def test_write_kv_shape_the_writer_refuses_takes_the_xla_loop(
+        interpret, monkeypatch):
+    """A shape over the writer's VMEM budget goes to write_kv's XLA loop,
+    to the same bytes, counted as a gate rejection."""
+    da = importlib.import_module("paddle_tpu.ops.pallas.decode_attention")
+    monkeypatch.setattr(da, "_ATTN_VMEM_BYTES", 1 << 10)
+    monitor.reset(prefix="pallas.")
+    _check_write_case("s1_many_slots")
+    assert monitor.stat_get("pallas.hit.paged_write_token") == 0
+    assert monitor.stat_get(
+        "pallas.gate_reject.paged_write_token.shape") == 1
 
 
 def test_mha_paged_matches_static_cache_bitwise():
@@ -390,7 +485,6 @@ def test_injected_kernel_crash_fails_requests(net, interpret, monkeypatch):
     """With the paged kernel eligible (interpret backend) but crashing,
     nothing demotes to the jnp path: every in-flight request carries the
     error, and the loop itself survives to report them."""
-    import importlib
     # the pallas package __init__ shadows the module name with the
     # function; importlib reaches the module itself
     da = importlib.import_module("paddle_tpu.ops.pallas.decode_attention")
@@ -438,8 +532,22 @@ def test_paged_kernel_engages_in_serve(net, interpret):
     spans = [sp.attrs for sp in trace.recent()
              if sp.name == "pallas/paged_decode_attention"]
     assert any(cut.items() <= attrs.items() for attrs in spans), spans
-    assert f"cut:b2s1={heads}heads/stepx8steps" in obs_report.pallas_rates(
-        {"values": monitor.stats("pallas.")})
+    report = obs_report.pallas_rates({"values": monitor.stats("pallas.")})
+    assert f"cut:b2s1={heads}heads/stepx8steps" in report
+    # and the writer what a call moves: the two slots' tokens as ONE
+    # lane-padded [h, d, 2] operand, not a 128-lane row an element, and
+    # the two blocks [1, h, d, 16] in and out (float32 here)
+    dim = GPTConfig.tiny().hidden_size // heads
+    moved = {"token_bytes": heads * dim * 128 * 4,
+             "block_bytes": 2 * 2 * heads * dim * 128 * 4}
+    for name, value in moved.items():
+        assert monitor.stat_get(
+            f"pallas.paged_write_token.{name}.b2") == value
+    spans = [sp.attrs for sp in trace.recent()
+             if sp.name == "pallas/paged_write_token"]
+    assert any(moved.items() <= attrs.items() for attrs in spans), spans
+    assert (f"write:b2={moved['token_bytes'] / 1e3:.0f}KB"
+            f"/{moved['block_bytes'] / 1e6:.1f}MB") in report
 
 
 def test_serve_spans_and_gauges(net):

@@ -171,10 +171,12 @@ def pallas_rates(metrics) -> str:
     demotion was removed still carry it.) A kernel that says how its
     programs were cut into grid steps (pallas.K.heads_per_step.bMsN and
     .grid_steps.bMsN, M slots of N query rows: the paged kernel) has it
-    in detail."""
+    in detail; so has the token writer, for what a call of M slots moves
+    (pallas.K.token_bytes.bM, the token operand as laid out, and
+    .block_bytes.bM, the slots' blocks in and out)."""
     per = defaultdict(lambda: {"hit": 0.0, "fallback": 0.0,
                                "gate_reject": 0.0, "reasons": []})
-    cuts = defaultdict(dict)
+    cuts, writes = defaultdict(dict), defaultdict(dict)
     for name, v in metrics.get("values", {}).items():
         if not name.startswith("pallas."):
             continue
@@ -189,10 +191,16 @@ def pallas_rates(metrics) -> str:
         elif len(parts) == 4 and parts[2] in ("heads_per_step",
                                               "grid_steps"):
             cuts[kind, parts[3]][parts[2]] = int(v)
+        elif len(parts) == 4 and parts[2] in ("token_bytes", "block_bytes"):
+            writes[kind, parts[3]][parts[2]] = v
     for (k, shape), cut in sorted(cuts.items()):
         per[k]["reasons"].append(
             f"cut:{shape}={cut.get('heads_per_step', '?')}heads/step"
             f"x{cut.get('grid_steps', '?')}steps")
+    for (k, shape), moved in sorted(writes.items()):
+        per[k]["reasons"].append(
+            f"write:{shape}={moved.get('token_bytes', 0) / 1e3:.0f}KB"
+            f"/{moved.get('block_bytes', 0) / 1e6:.1f}MB")
     rows = []
     for k in sorted(per):
         d = per[k]
