@@ -8,7 +8,9 @@ a seed, no network, no git, no child process that needs the chip):
 - train      BERT-base MLM through paddle.Model(...).prepare(amp "O2").fit
 - serve      GPT-2 124M (bf16) behind ServeLoop.start(), ragged greedy
              requests from client threads, plus a teacher-forced
-             paged-kernel vs paged_attention_ref logits check
+             paged-kernel vs paged_attention_ref logits check; one period
+             of the hybrid stack (linear-attention state a slot beside
+             paged keys and values) served, its kernels on against off
 - kernels    each Pallas kernel compiled by Mosaic against its jnp
              reference, fwd and bwd where it has one
 - multichip  (>= 4 devices) the train path through fleet on dp=4 and
@@ -83,12 +85,15 @@ class Sizes:
     multichip_steps: int
     latent: object               # KimiK2Config: the latent-cache programs
     latent_serve: tuple          # (slots, blocks, block, max_seq, bucket)
+    hybrid: object               # OlmoHybridConfig: one period, served
+    hybrid_serve: tuple          # (slots, blocks, block, max_seq, prompts)
 
     @staticmethod
     def full():
         from paddle_tpu.text.models.bert import BertConfig
         from paddle_tpu.text.models.gpt import GPTConfig
         from paddle_tpu.text.models.kimi_k2 import KimiK2Config
+        from paddle_tpu.text.models.olmo_hybrid import OlmoHybridConfig
         return Sizes(
             bert=BertConfig.bert_base(), train_batch=32, train_seq=128,
             train_steps=40, train_lr=1e-4,
@@ -117,13 +122,21 @@ class Sizes:
                     "type": "yarn", "factor": 64, "beta_fast": 32,
                     "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
                     "original_max_position_embeddings": 4096}),
-            latent_serve=(64, 1024, 128, 3072, 2048))
+            latent_serve=(64, 1024, 128, 3072, 2048),
+            # one period of the hybrid stack at its published widths (a
+            # sixteenth of the vocabulary): the gated delta-rule kernels'
+            # first compile on a chip, outside the benchmark
+            hybrid=OlmoHybridConfig(
+                vocab_size=6272, dtype="bfloat16", max_seq_len=1024,
+                layer_types=["linear_attention"] * 3 + ["full_attention"]),
+            hybrid_serve=(4, 32, 128, 1024, (70, 200, 513)))
 
     @staticmethod
     def toy():
         from paddle_tpu.text.models.bert import BertConfig
         from paddle_tpu.text.models.gpt import GPTConfig
         from paddle_tpu.text.models.kimi_k2 import KimiK2Config
+        from paddle_tpu.text.models.olmo_hybrid import OlmoHybridConfig
         return Sizes(
             bert=BertConfig.tiny(), train_batch=8, train_seq=16,
             train_steps=12, train_lr=1e-2,
@@ -137,7 +150,11 @@ class Sizes:
             multichip_layers=1, multichip_steps=3,
             latent=KimiK2Config.tiny(num_layers=2, experts_held=(4, 8),
                                      dtype="bfloat16"),
-            latent_serve=(2, 8, 16, 64, 32))
+            latent_serve=(2, 8, 16, 64, 32),
+            hybrid=OlmoHybridConfig.tiny(
+                dtype="bfloat16", init_std=0.1,
+                layer_types=["linear_attention"] * 3 + ["full_attention"]),
+            hybrid_serve=(2, 8, 16, 64, (5, 14, 23)))
 
 
 # --------------------------------------------------------------------------
@@ -518,6 +535,108 @@ def latent_serve_programs(sizes):
     return _serve_program_memory(loop, bucket)
 
 
+def hybrid_serve(sizes):
+    """A net that keeps one state a decode slot beside its paged keys and
+    values (text/models/olmo_hybrid.py at `sizes.hybrid`, one period)
+    behind a ServeLoop of `sizes.hybrid_serve`: its prompts served
+    greedily, then the same prompts once more with the pool's kernels
+    gated off (`FLAGS_use_paged_attention`: the chunked scan, the state
+    update, the paged pair all on their `jax.numpy` forms). The served
+    tokens are compared position by position through teacher-forced
+    logits, not by equality: -> {"completed", "hits" (the four kernels'
+    engagements with the flag on), "logits_err" (normalized max error of
+    the kernels-on logits against the kernels-off ones over every served
+    position, the kernels-on server fed the kernels-off tokens)}."""
+    import paddle_tpu as paddle
+    from paddle_tpu.core import monitor
+    from paddle_tpu.core import tape
+    from paddle_tpu.inference import ServeConfig, ServeLoop
+    from paddle_tpu.nn.kv_pool import (KVBlockPool, cache_arenas,
+                                       fresh_slot_rows, paged_caches,
+                                       put_slot_rows)
+    from paddle_tpu.text.models.olmo_hybrid import OlmoHybrid
+
+    slots, blocks, block, max_seq, prompt_lens = sizes.hybrid_serve
+    paddle.seed(SEED)
+    net = OlmoHybrid(sizes.hybrid)
+    net.eval()
+    rng = np.random.RandomState(SEED + 2)
+    prompts = [rng.randint(1, sizes.hybrid.vocab_size, n)
+               for n in prompt_lens]
+    before = _pallas_counters()
+    loop = ServeLoop(net, ServeConfig(max_active=slots, kv_blocks=blocks,
+                                      block_size=block, max_seq_len=max_seq))
+    outs = loop.serve(prompts, max_new_tokens=4)
+    hits = {k.rsplit(".", 1)[1]: v - before.get(k, 0)
+            for k, v in _pallas_counters().items()
+            if k.startswith("pallas.hit.")}
+    bucket_of = {n: loop._bucket(n) for n in prompt_lens}
+    del loop
+
+    params, buffers = net.functional_state()
+    spec = net.paged_cache_spec()
+    pool = KVBlockPool(blocks, block)
+    width = -(-max_seq // block)
+
+    def forced(ids, prompt_len):
+        """Logits of positions prompt_len - 1 .. len(ids) - 2 through a
+        bucket-padded prefill of slot 0 and one decode step a token."""
+        table = np.zeros((1, width), np.int32)
+        table[0, :pool.blocks_for(len(ids))] = np.arange(
+            1, pool.blocks_for(len(ids)) + 1)
+        table = jnp.asarray(table)
+
+        def step(params, arenas, tokens, lengths, last_index):
+            with tape.no_grad():
+                net.load_functional_state(params, buffers)
+                view = fresh_slot_rows(spec, arenas) \
+                    if last_index is not None else arenas
+                logits, caches, _ = net._forward_paged(
+                    tokens, paged_caches(spec, view, table, lengths),
+                    last_index=last_index)
+                new = cache_arenas(caches)
+                if last_index is not None:
+                    new = put_slot_rows(spec, arenas, new, jnp.int32(0))
+            return logits, new
+
+        step = jax.jit(step)
+        padded = np.zeros((1, bucket_of[prompt_len]), np.int32)
+        padded[0, :prompt_len] = ids[:prompt_len]
+        arenas = pool.arenas_for(spec, DTYPE, slots=1)
+        try:
+            logits, arenas = step(params, arenas, jnp.asarray(padded),
+                                  jnp.zeros((1,), jnp.int32),
+                                  jnp.asarray([prompt_len - 1], jnp.int32))
+            rows = [logits[0]]
+            for n in range(prompt_len, len(ids) - 1):
+                logits, arenas = step(
+                    params, arenas, jnp.asarray(ids[n:n + 1][None],
+                                                jnp.int32),
+                    jnp.asarray([n], jnp.int32), None)
+                rows.append(logits[0])
+        finally:
+            net.load_functional_state(params, buffers)
+        return np.stack([np.asarray(r, np.float32) for r in rows])
+
+    errs = []
+    for prompt, out in zip(prompts, outs):
+        ids = np.concatenate([prompt, out]).astype(np.int32)
+        on = forced(ids, len(prompt))
+        paddle.set_flags({"FLAGS_use_paged_attention": False})
+        try:
+            off = forced(ids, len(prompt))
+        finally:
+            paddle.set_flags({"FLAGS_use_paged_attention": True})
+        errs.append(_rel_err(on, off))
+    return {"completed": sum(len(o) == 4 for o in outs), "hits": hits,
+            "logits_err": float(f"{max(errs):.3g}"),
+            "state_bytes": int(monitor.stat_get("serve.state_bytes"))}
+
+
+HYBRID_KERNELS = ("gdn_chunk_scan", "gdn_step", "paged_decode_attention",
+                  "paged_write_token")
+
+
 def serve_phase(sizes):
     import paddle_tpu as paddle
     from paddle_tpu.core import monitor
@@ -602,6 +721,19 @@ def serve_phase(sizes):
                 f"arena: {mem['arena_relayouts']} copy/transpose "
                 "instructions of arena shape")
 
+    hybrid = hybrid_serve(sizes)
+    if hybrid["completed"] != len(sizes.hybrid_serve[4]):
+        failures.append(f"hybrid net: {hybrid['completed']} of "
+                        f"{len(sizes.hybrid_serve[4])} requests completed")
+    if not hybrid["logits_err"] <= LOGITS_TOL:
+        failures.append("hybrid net: teacher-forced logits, kernels vs "
+                        f"their jnp forms err {hybrid['logits_err']:.3g} > "
+                        f"{LOGITS_TOL}")
+    if jax.default_backend() == "tpu":
+        for kernel in HYBRID_KERNELS:
+            if not hybrid["hits"].get(kernel):
+                failures.append(f"hybrid net: {kernel} never engaged")
+
     forced = _forced_logits(net, sizes, block_size)
     if not _pallas_counters().get(
             "pallas.gate_reject.paged_decode_attention.flag_off"):
@@ -628,6 +760,7 @@ def serve_phase(sizes):
         "failures": failures, "requests": n, "completed": completed,
         "programs": programs,
         "programs_latent": programs_latent,
+        "hybrid": hybrid,
         "tokens_generated": int(monitor.stat_get("serve.tokens_generated")),
         "preempted": int(monitor.stat_get("serve.preempted")),
         "backpressure_waits":

@@ -58,10 +58,14 @@ nothing to do, and `serve/{retire,evict,hot_swap}`; request spans carry
 timeline of any running `jax.profiler` session (core/trace.py). Stamps
 `ServeRequest.{t_submit,t_admit,t_first,t_tokens,t_done}`. `stats()`
 counts, cumulative: `steps`, `decode_tokens`, `prefill_dispatches`,
-`prefill_tokens`, `admitted`, `queue_wait_s`, and from a net with expert
-layers `MOE_STATS` (mirrored as `serve.*` gauges beside
-`serve.{queue_depth,active_slots,kv_pool_used_blocks,
-kv_pool_free_blocks,model_version}`). Counters `serve.{preempted,
+`prefill_tokens`, `admitted`, `queue_wait_s`, and whatever the served net
+names (`net.serve_counters`: the `moe_*` counts of a net with expert
+layers, the `linear_*` counts of one with recurrent layers), all mirrored
+as `serve.*` gauges beside `serve.{queue_depth,active_slots,
+kv_pool_used_blocks,kv_pool_free_blocks,model_version,state_slots_used,
+state_bytes}` (the last two: decode slots whose per-slot state is owned,
+and the bytes of all of it; 0 for a net that caches by token only).
+Counters `serve.{preempted,
 tokens_generated,requests_completed,requests_errored,hot_swaps,
 completion_log_errors}`, histograms `serve/ttft_ms` and
 `serve/token_ms` — rendered by tools/obs_report.py's serving section
@@ -80,20 +84,17 @@ import numpy as np
 __all__ = ["ServeConfig", "ServeRequest", "ServeLoop",
            "build_decode_step"]
 
-# what the expert layers of a served net counted (nets without any report
-# none): tokens routed, (token, expert) pairs that fell on a held expert,
-# held experts that got at least one pair and the most pairs on one
-# expert, the last two summed over layer-steps; decode beats and prefills
-# apart, `moe_decode_layer_steps` to divide the decode sums by
-MOE_STATS = tuple(f"moe_{kind}_{what}" for kind in ("decode", "prefill")
-                  for what in ("tokens", "pairs_held", "experts_touched",
-                               "peak_pairs")) + ("moe_decode_layer_steps",)
+# The loop's own gauges. What a served net counts of its layers is the
+# net's to name (its `SERVE_STATS`, added up by its `serve_counters`;
+# text/models/kimi_k2.py: `MOE_STATS`, text/models/olmo_hybrid.py:
+# `LINEAR_STATS`): each name is a key of `stats()` and a `serve.<name>`
+# gauge.
 GAUGES = ("serve.queue_depth", "serve.active_slots",
           "serve.kv_pool_used_blocks", "serve.kv_pool_free_blocks",
           "serve.model_version", "serve.decode_tokens",
           "serve.prefill_dispatches", "serve.prefill_tokens",
-          "serve.admitted", "serve.queue_wait_s") \
-    + tuple(f"serve.{name}" for name in MOE_STATS)
+          "serve.admitted", "serve.queue_wait_s",
+          "serve.state_slots_used", "serve.state_bytes")
 COUNTERS = ("serve.preempted", "serve.tokens_generated",
             "serve.requests_completed", "serve.requests_errored",
             "serve.hot_swaps", "serve.completion_log_errors",
@@ -122,10 +123,11 @@ class ServeConfig:
         (and keyed) with it."""
         from ..core import flags as _flags
         cfg = net.config
-        # the widest arena of what the net caches sizes the block
+        # the widest arena of what the net caches BY TOKEN sizes the
+        # block; state indexed by slot (`CacheSpec.slots`) has no blocks
         heads, dim = max((a for layer in net.paged_cache_spec()
                           for a in layer.arenas),
-                         key=lambda a: a[0] * a[1])
+                         key=lambda a: a[0] * a[1], default=(1, 1))
         max_active = int(self.max_active
                          or _flags.flag("FLAGS_serve_max_active"))
         kv_blocks = int(self.kv_blocks
@@ -283,7 +285,8 @@ def _build_prefill(net, temperature, top_k):
     import jax.numpy as jnp
 
     from ..core import tape as _tape
-    from ..nn.kv_pool import cache_arenas, paged_caches
+    from ..nn.kv_pool import (cache_arenas, fresh_slot_rows, paged_caches,
+                              put_slot_rows)
 
     samp = _sampler(temperature, top_k)
     spec = net.paged_cache_spec()
@@ -292,14 +295,19 @@ def _build_prefill(net, temperature, top_k):
                 key, slot):
         with _tape.no_grad():
             net.load_functional_state(params, buffers)
-            caches = paged_caches(spec, arenas, bt_row,
-                                  jnp.zeros((1,), jnp.int32))
+            # state indexed by slot: the net sees one zeroed row (what the
+            # slot's last owner left must not leak into this request) and
+            # what it leaves, the state as of `real_len`, becomes row `slot`
+            caches = paged_caches(spec, fresh_slot_rows(spec, arenas),
+                                  bt_row, jnp.zeros((1,), jnp.int32))
             logits, new_caches, *counted = net._forward_paged(
                 ids, caches, last_index=jnp.reshape(real_len, (1,)) - 1)
             first = samp(logits, key[None], jnp.reshape(real_len,
                                                         (1,)))[0]
             tokens = tokens.at[slot].set(first)
-        return ((cache_arenas(new_caches), tokens), first, *counted)
+            arenas = put_slot_rows(spec, arenas, cache_arenas(new_caches),
+                                   slot)
+        return ((arenas, tokens), first, *counted)
 
     return prefill
 
@@ -376,7 +384,9 @@ class ServeLoop:
         self._prefill_tokens = 0      # prompt tokens sent to prefill
         self._admitted = 0            # first admissions
         self._queue_wait_s = 0.0      # sum of t_admit - t_submit
-        self._moe = {}                # MOE_STATS, once a step reports them
+        # what the net's layers count (`serve_counters`), by the names
+        # the net declares (`SERVE_STATS`); a net that counts nothing: {}
+        self._net_counts = dict.fromkeys(getattr(net, "SERVE_STATS", ()), 0)
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
         self._thread = None
@@ -464,7 +474,9 @@ class ServeLoop:
             "max_active": self._A,
             "model_version": self.model_version,
             "swap_staged": self._staged_swap is not None,
-            **self._moe,
+            "state_slots_used": self._state_slots_used(),
+            "state_bytes": self._state_bytes,
+            **self._net_counts,
         }
 
     def publish_weights(self, version, updates):
@@ -820,16 +832,16 @@ class ServeLoop:
 
     def _count_served(self, kind, handles, n_tokens):
         """Add up what a settled step's program returned past its tokens
-        (a net with expert layers: what they counted). The net names the
-        counters (`serve_counters`); read here, where the tokens have
-        just been read, so the device is not waited for again."""
+        (what the net's layers counted), under the names the net gives
+        them (`serve_counters`); read here, where the tokens have just
+        been read, so the device is not waited for again."""
         if len(handles) < 2:
             return                       # a net that counts nothing
-        moe = self._moe or dict.fromkeys(MOE_STATS, 0)
+        counts = dict(self._net_counts)
         for name, n in self.net.serve_counters(kind, handles[1:],
                                                n_tokens).items():
-            moe[name] += n
-        self._moe = moe
+            counts[name] = counts.get(name, 0) + n
+        self._net_counts = counts        # swapped whole: stats() may read
 
     def _append_token(self, idx, slot, token, now, first=False):
         from ..core import monitor as _monitor
@@ -896,13 +908,23 @@ class ServeLoop:
         import jax.numpy as jnp
 
         from ..static.pipeline_runner import InflightDriver
-        self._arenas = self._pool.arenas_for(self.net.paged_cache_spec(),
-                                             self._dtype)
+        spec = self.net.paged_cache_spec()
+        self._arenas = self._pool.arenas_for(spec, self._dtype,
+                                             slots=self._A)
+        self._state_bytes = sum(
+            x.nbytes for layer, a in zip(spec, self._arenas)
+            for x in a[len(layer.arenas):])
         self._tokens = jnp.zeros((self._A,), jnp.int32)
         self._driver = InflightDriver("serve",
                                       max_inflight=self._max_inflight)
 
     # -- gauges --------------------------------------------------------------
+    def _state_slots_used(self):
+        """Decode slots whose per-slot state (`CacheSpec.slots`) a request
+        owns; 0 for a net that caches by token only."""
+        return sum(s is not None for s in self._slots) \
+            if self._state_bytes else 0
+
     def _publish_gauges(self):
         from ..core import monitor as _monitor
         _monitor.stat_set_many({
@@ -917,5 +939,7 @@ class ServeLoop:
             "serve.prefill_tokens": self._prefill_tokens,
             "serve.admitted": self._admitted,
             "serve.queue_wait_s": self._queue_wait_s,
-            **{f"serve.{k}": v for k, v in self._moe.items()},
+            "serve.state_slots_used": self._state_slots_used(),
+            "serve.state_bytes": self._state_bytes,
+            **{f"serve.{k}": v for k, v in self._net_counts.items()},
         })

@@ -50,8 +50,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PagedKVCache", "PagedLatentCache", "CacheSpec",
-           "KVBlockPool", "paged_caches", "cache_arenas", "paged_attention",
+__all__ = ["PagedKVCache", "PagedLatentCache", "SlotStateCache",
+           "CacheSpec", "KVBlockPool", "paged_caches", "cache_arenas",
+           "fresh_slot_rows", "put_slot_rows", "paged_attention",
            "paged_attention_ref", "latent_paged_attention", "write_kv",
            "pick_block_size"]
 
@@ -92,15 +93,37 @@ class PagedLatentCache(typing.NamedTuple):
         return int(self.kv.shape[3])
 
 
+class SlotStateCache(typing.NamedTuple):
+    """One layer's state that is NOT paged by token: a recurrent
+    (linear-attention) layer keeps one matrix a decode slot and the last
+    inputs of its short convolution, whatever the length of the stream.
+    `state` [slots, dk, heads * dv] float32 (what the recurrence
+    accumulates in; the layout is ops/pallas/gated_delta.py's), `conv`
+    [slots, taps - 1, channels] in the activations' dtype. Row i belongs to
+    decode slot i from admission to retirement: no block table, no
+    growth, no free list. `block_tables` and `lengths` ride along as in
+    the paged caches (a layer reads which rows are live from them)."""
+
+    state: object         # [slots, dk, heads * dv] float32
+    conv: object          # [slots, taps - 1, channels]
+    block_tables: object  # [b, max_blocks] i32
+    lengths: object       # [b] i32
+
+
 class CacheSpec(typing.NamedTuple):
     """What one layer of a served net caches, as the net's
     `paged_cache_spec()` says it: `cache`, the type its `_forward_paged`
-    reads (the arenas in order, then `block_tables`, `lengths`), and
-    `arenas`, the (heads, dim) of each arena — ((h, d), (h, d)) for
-    per-head keys and values, ((1, dim),) for a latent."""
+    reads (the arenas in order, then the per-slot arrays, then
+    `block_tables`, `lengths`); `arenas`, the (heads, dim) of each arena
+    paged by token — ((h, d), (h, d)) for per-head keys and values,
+    ((1, dim),) for a latent, () for a layer that caches no token; and
+    `slots`, the (shape of one slot's row, dtype or None for the pool's)
+    of each array indexed by decode slot — a `SlotStateCache`'s state and
+    convolution inputs."""
 
     cache: type
     arenas: tuple
+    slots: tuple = ()
 
 
 def paged_caches(spec, arenas, block_tables, lengths):
@@ -115,6 +138,26 @@ def cache_arenas(caches):
     """The arenas of each layer's cache, as `KVBlockPool.arenas_for`
     lays them out (and as a serve program donates them)."""
     return [tuple(c[:-2]) for c in caches]
+
+
+def fresh_slot_rows(spec, arenas):
+    """What a prefill of ONE slot is given: the arenas as they are, each
+    per-slot array as one zeroed row [1, ...] — an admitted request
+    starts from zero state, whatever the slot's last owner left."""
+    return [a[:len(layer.arenas)]
+            + tuple(jnp.zeros((1,) + x.shape[1:], x.dtype)
+                    for x in a[len(layer.arenas):])
+            for layer, a in zip(spec, arenas)]
+
+
+def put_slot_rows(spec, arenas, written, slot):
+    """The inverse, after the prefill: `written`'s arenas, and its rows
+    [1, ...] put into `arenas`' per-slot arrays at row `slot`."""
+    return [w[:len(layer.arenas)]
+            + tuple(jax.lax.dynamic_update_slice_in_dim(x, row, slot, 0)
+                    for x, row in zip(a[len(layer.arenas):],
+                                      w[len(layer.arenas):]))
+            for layer, a, w in zip(spec, arenas, written)]
 
 
 def pick_block_size(max_seq_len, heads, head_dim, dtype="float32",
@@ -216,13 +259,17 @@ class KVBlockPool:
         return (self.n_blocks + 1, int(heads), int(head_dim),
                 self.block_size)
 
-    def arenas_for(self, spec, dtype=jnp.float32):
-        """Fresh zeroed arenas for a net's `paged_cache_spec()` (one
-        `CacheSpec` a layer): [(arena, ...), ...] each `arena_shape`
-        (row 0 = trash). Zeros, not empty: a fresh pool must attend to
-        nothing."""
+    def arenas_for(self, spec, dtype=jnp.float32, slots=0):
+        """Fresh zeroed device state for a net's `paged_cache_spec()`
+        (one `CacheSpec` a layer): [(arena, ..., per-slot array, ...),
+        ...], each arena `arena_shape` (row 0 = trash), each per-slot
+        array [`slots`, *row shape] (the decode slots of the loop that
+        asks). Zeros, not empty: a fresh pool must attend to nothing."""
         return [tuple(jnp.zeros(self.arena_shape(h, d), dtype)
-                      for h, d in layer.arenas) for layer in spec]
+                      for h, d in layer.arenas)
+                + tuple(jnp.zeros((int(slots),) + tuple(shape),
+                                  own or dtype)
+                        for shape, own in layer.slots) for layer in spec]
 
     def arenas(self, layers, heads, head_dim, dtype=jnp.float32):
         """`arenas_for` k/v pairs of one shape: [(k, v), ...]."""

@@ -44,7 +44,17 @@ from ... import nn
 from ...nn import initializer as I
 from ...nn.layer.experts import _swiglu
 
-__all__ = ["KimiK2", "KimiK2Config", "yarn_inv_freq", "yarn_mscale"]
+__all__ = ["KimiK2", "KimiK2Config", "MOE_STATS", "yarn_inv_freq",
+           "yarn_mscale"]
+
+# what the expert layers count for `ServeLoop.stats()`: tokens routed,
+# (token, expert) pairs that fell on a held expert, held experts that got
+# at least one pair and the most pairs on one expert, the last two summed
+# over layer-steps; decode beats and prefills apart,
+# `moe_decode_layer_steps` to divide the decode sums by
+MOE_STATS = tuple(f"moe_{kind}_{what}" for kind in ("decode", "prefill")
+                  for what in ("tokens", "pairs_held", "experts_touched",
+                               "peak_pairs")) + ("moe_decode_layer_steps",)
 
 
 @dataclass
@@ -311,6 +321,8 @@ class KimiK2Block(_Weights):
 
 
 class KimiK2(_Weights):
+    SERVE_STATS = MOE_STATS
+
     def __init__(self, config: KimiK2Config = None):
         cfg = config or KimiK2Config()
         super().__init__(cfg)

@@ -4,6 +4,7 @@ no-fallback guarantees its chip run leans on — autotune never measures
 under a trace, and run_guarded never swallows a kernel's error."""
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -58,6 +59,12 @@ def test_serve_phase_toy(interpret):
     assert monitor.stat_get("pallas.hit.paged_decode_attention") > 0
     assert all(e <= chip_smoke.LOGITS_TOL
                for e in result["forced_logits_err"].values())
+    # the toy hybrid (one period): served, and with the interpreted
+    # kernels on the same logits as on their jnp forms
+    hybrid = result["hybrid"]
+    assert hybrid["completed"] == 3 and hybrid["state_bytes"] > 0
+    assert all(hybrid["hits"].get(k) for k in chip_smoke.HYBRID_KERNELS)
+    assert hybrid["logits_err"] <= chip_smoke.LOGITS_TOL
 
 
 def test_kernels_phase_toy(interpret):
@@ -359,6 +366,99 @@ def test_latent_serve_steps_hold_no_arena_copy_for_v5e():
         assert step["lane_padded"] == [], step   # 64 dense latents a call
     assert (decode["s"], decode["writer"]) == (1, 2)      # the Pallas writer
     assert (prefill["s"], prefill["writer"]) == (2048, 0)  # the XLA loop
+
+
+def _compile_hybrid_steps_for_v5e():
+    """Child-process body of the test below: ServeLoop's own decode step
+    and bucket-1024 prefill of one period of the hybrid stack at its
+    published widths (`chip_smoke.Sizes.full().hybrid`), compiled for v5e
+    over the benchmark's pool (32 slots, 576 blocks of 128): paged keys
+    and values and one float32 state a slot from one spec; one JSON line
+    a program."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.inference.serving import (_build_prefill,
+                                              build_decode_step)
+    from paddle_tpu.nn import initializer
+    from paddle_tpu.nn.kv_pool import KVBlockPool
+    from paddle_tpu.text.models import OlmoHybrid
+    try:
+        device = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
+    except Exception as e:  # environment without a usable libtpu
+        print(f"NO-TOPOLOGY {type(e).__name__}: {e}")
+        return
+    sharding = SingleDeviceSharding(device)
+    # only shapes are compiled: 0.9 B parameters need not be drawn
+    initializer.Normal.__call__ = \
+        lambda self, shape, dtype="float32": jnp.zeros(tuple(shape), dtype)
+    net = OlmoHybrid(chip_smoke.Sizes.full().hybrid)
+    net.eval()
+    params, buffers = net.functional_state()
+    slots, blocks, block, max_seq, bucket = 32, 576, 128, 4736, 1024
+    pool, width = KVBlockPool(blocks, block), -(-max_seq // block)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def like(tree):
+        return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
+
+    i32, u32 = jnp.int32, jnp.uint32
+    arenas = jax.eval_shape(lambda: pool.arenas_for(
+        net.paged_cache_spec(), jnp.bfloat16, slots=slots))
+    state = (like(params), like(buffers), like(arenas))
+    held = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+               for layer in arenas for x in layer)
+    programs = {
+        1: (build_decode_step(net),
+            (spec((slots, width), i32), spec((slots,), i32),
+             spec((slots,), i32), spec((slots, 2), u32))),
+        bucket: (_build_prefill(net, 0.0, None),
+                 (spec((slots,), i32), spec((1, width), i32),
+                  spec((1, bucket), i32), spec((), i32), spec((2,), u32),
+                  spec((), i32)))}
+    paddle.set_flags({"FLAGS_pallas_force_compile": True})
+    for s, (fn, rest) in programs.items():
+        monitor.reset(prefix="pallas.")
+        compiled = jax.jit(fn, donate_argnums=(2,)).trace(
+            *state, *rest).lower(lowering_platforms=("tpu",)).compile()
+        net.load_functional_state(params, buffers)
+        text, mem = compiled.as_text(), compiled.memory_analysis()
+        hits = monitor.stats("pallas.hit.")
+        print("STEP " + json.dumps({
+            "s": s,
+            "state_in_hlo": "f32[32,96,5760]" in text,
+            "state_copies": len(re.findall(
+                r"= f32\[32,96,5760\]\S* (?:copy|transpose)\(", text)),
+            "relayouts": chip_smoke.arena_relayouts(
+                text, pool.arena_shape(30, 128)),
+            "temp_bytes": mem.temp_size_in_bytes,
+            "alias_bytes": mem.alias_size_in_bytes, "held_bytes": held,
+            "hits": {k.rsplit(".", 1)[1]: int(v) for k, v in hits.items()}}))
+    print("HYBRID-STEPS-DONE")
+
+
+def test_hybrid_serve_steps_update_state_and_arenas_in_place_for_v5e():
+    """One pool, two kinds of state: the decode step of one period of the
+    hybrid stack (the state-update kernel three times, the Pallas paged
+    pair once) and its bucket-1024 prefill (the chunked-scan kernel three
+    times, a slot's row put into each state) compile under Mosaic for v5e
+    at the published widths, hold no copy or transpose of the state's
+    [32, 96, 5760] float32 (its lanes are whole tiles: 5760 = 45 x 128)
+    nor of an arena, and give every donated byte back aliased."""
+    out = _run_in_cpu_child("_compile_hybrid_steps_for_v5e",
+                            "HYBRID-STEPS-DONE")
+    decode, prefill = (json.loads(line[5:]) for line in out.splitlines()
+                       if line.startswith("STEP "))
+    for step in (decode, prefill):
+        assert step["state_in_hlo"] and step["state_copies"] == 0, step
+        assert step["relayouts"] == [], step
+        assert step["alias_bytes"] >= step["held_bytes"], step
+    assert decode["hits"] == {"gdn_step": 3, "paged_write_token": 2,
+                              "paged_decode_attention": 1}
+    assert decode["temp_bytes"] < 64e6, decode
+    assert prefill["hits"] == {"gdn_chunk_scan": 3}
+    assert prefill["temp_bytes"] < 1.0e9, prefill
 
 
 def test_autotune_lookup_never_measures_under_trace():

@@ -14,11 +14,11 @@ import paddle_tpu as paddle
 from paddle_tpu import nn
 from paddle_tpu.core import monitor
 from paddle_tpu.inference import ServeConfig, ServeLoop
-from paddle_tpu.inference import serving
 from paddle_tpu.nn.kv_pool import (CacheSpec, KVBlockPool, PagedKVCache,
                                    PagedLatentCache, cache_arenas,
                                    paged_caches)
-from paddle_tpu.text.models import GPT, GPTConfig, KimiK2, KimiK2Config
+from paddle_tpu.text.models import (GPT, GPTConfig, KimiK2, KimiK2Config,
+                                    kimi_k2)
 from paddle_tpu.text.models.kimi_k2 import (LatentAttention, yarn_inv_freq,
                                             yarn_mscale)
 from paddle_tpu.text.models.reference import kimi_k2 as ref
@@ -397,7 +397,7 @@ def test_expert_counters_only_from_a_net_with_expert_layers(net):
     loop.serve([rng.randint(1, 256, n) for n in (5, 11, 19)],
                max_new_tokens=6)
     st = loop.stats()
-    assert set(serving.MOE_STATS) <= set(st)
+    assert set(kimi_k2.MOE_STATS) <= set(st)
     # pad rows of a bucketed prompt and empty decode slots are not routed
     assert st["moe_prefill_tokens"] == st["prefill_tokens"] == 35
     assert st["moe_decode_layer_steps"] == 2 * st["steps"]
@@ -411,4 +411,4 @@ def test_expert_counters_only_from_a_net_with_expert_layers(net):
     plain = ServeLoop(gpt, ServeConfig(max_active=2, kv_blocks=8,
                                        block_size=16, max_seq_len=64))
     plain.serve([rng.randint(1, 1024, 5)], max_new_tokens=3)
-    assert not set(serving.MOE_STATS) & set(plain.stats())
+    assert not set(kimi_k2.MOE_STATS) & set(plain.stats())

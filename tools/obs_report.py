@@ -221,14 +221,18 @@ SERVE_GAUGES = ("serve.queue_depth", "serve.active_slots",
                 "serve.model_version", "serve.decode_tokens",
                 "serve.prefill_dispatches", "serve.prefill_tokens",
                 "serve.admitted", "serve.queue_wait_s",
-                # a net with expert layers only (serving.MOE_STATS)
-                "serve.moe_decode_tokens", "serve.moe_decode_pairs_held",
-                "serve.moe_decode_experts_touched",
-                "serve.moe_decode_peak_pairs", "serve.moe_prefill_tokens",
-                "serve.moe_prefill_pairs_held",
-                "serve.moe_prefill_experts_touched",
-                "serve.moe_prefill_peak_pairs",
-                "serve.moe_decode_layer_steps")
+                "serve.state_slots_used", "serve.state_bytes")
+# what a served net counts of its layers (its `SERVE_STATS`): a net with
+# expert layers (text/models/kimi_k2.MOE_STATS), a net with recurrent
+# layers (text/models/olmo_hybrid.LINEAR_STATS); self_check pins both
+SERVE_NET_GAUGES = (
+    "serve.moe_decode_tokens", "serve.moe_decode_pairs_held",
+    "serve.moe_decode_experts_touched", "serve.moe_decode_peak_pairs",
+    "serve.moe_prefill_tokens", "serve.moe_prefill_pairs_held",
+    "serve.moe_prefill_experts_touched", "serve.moe_prefill_peak_pairs",
+    "serve.moe_decode_layer_steps",
+    "serve.linear_prefill_tokens", "serve.linear_prefill_pad_tokens",
+    "serve.linear_decode_layer_steps")
 SERVE_COUNTERS = ("serve.preempted", "serve.tokens_generated",
                   "serve.requests_completed", "serve.requests_errored",
                   "serve.hot_swaps", "serve.completion_log_errors",
@@ -244,8 +248,8 @@ def serving_section(metrics, spans) -> str:
     counters, TTFT/per-token latency histograms, and the per-phase span
     table (one serve/tick per beat and its phases)."""
     values = metrics.get("values", {})
-    rows = [[k, values[k]] for k in SERVE_GAUGES + SERVE_COUNTERS
-            if k in values]
+    rows = [[k, values[k]] for k in SERVE_GAUGES + SERVE_NET_GAUGES
+            + SERVE_COUNTERS if k in values]
     out = [_fmt_table(["metric", "value"], rows)]
     for hname, label in (("serve/ttft_ms", "ttft"),
                          ("serve/token_ms", "per-token")):
@@ -424,6 +428,13 @@ def self_check():
             problems.append(
                 f"obs_report: serving.GAUGES {serving.GAUGES} != "
                 f"renderer SERVE_GAUGES {SERVE_GAUGES} — update both")
+        from paddle_tpu.text.models import kimi_k2, olmo_hybrid
+        named = tuple(f"serve.{n}" for n in kimi_k2.MOE_STATS
+                      + olmo_hybrid.LINEAR_STATS)
+        if named != SERVE_NET_GAUGES:
+            problems.append(
+                f"obs_report: the served nets' SERVE_STATS {named} != "
+                f"renderer SERVE_NET_GAUGES {SERVE_NET_GAUGES}")
         if tuple(serving.COUNTERS) != SERVE_COUNTERS:
             problems.append(
                 f"obs_report: serving.COUNTERS {serving.COUNTERS} != "
