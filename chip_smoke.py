@@ -522,8 +522,10 @@ def latent_serve_programs(sizes):
     ServeLoop of `sizes.latent_serve`): its decode step and its largest
     prefill bucket. The pool's one layout has to hold for the one-head
     arena too: no copy or transpose of arena shape. The temp is reported
-    and not held to an arena's size: plain-XLA latent attention gathers
-    a slot's blocks, a temp of table width x block bytes a slot."""
+    and not held to an arena's size: these are whole model programs, and
+    their temps are activations (tests/test_chip_smoke.py holds the
+    decode step's to 100 MB ahead of time: the latent kernel reads a
+    slot's blocks where they lie)."""
     from paddle_tpu.inference import ServeConfig, ServeLoop
     from paddle_tpu.text.models.kimi_k2 import KimiK2
     slots, blocks, block, max_seq, bucket = sizes.latent_serve
@@ -902,6 +904,75 @@ def _check_paged(sizes, chunk, block_size=None):
     return {"out": _rel_err(out, out_r), "block_size": block_size}
 
 
+def _check_latent_paged(sizes):
+    """The latent-attention kernel of the decode step at `sizes.latent`'s
+    widths, `sizes.latent_serve`'s slots over tables of its width (the
+    block rounded up to the lane tile the kernel takes): `out`, the
+    kernel against `_latent_attn_paged` in float32 on the same operands,
+    fills from one token to the whole table; `logits`, one decode step
+    of the net's leading layer and head over the same cache, the kernel
+    on against the kernel gated off."""
+    import paddle_tpu as paddle
+    from paddle_tpu.core import tape
+    from paddle_tpu.nn.kv_pool import (KVBlockPool, _latent_attn_paged,
+                                       cache_arenas, paged_caches)
+    from paddle_tpu.ops.pallas.decode_attention import \
+        latent_paged_decode_attention
+    from paddle_tpu.text.models.kimi_k2 import KimiK2
+
+    cfg = dataclasses.replace(sizes.latent, num_layers=1)
+    slots, _, block, max_seq, _ = sizes.latent_serve
+    block = -(-block // 128) * 128
+    width = -(-max_seq // block)
+    h, rank = cfg.num_heads, cfg.kv_lora_rank
+    pool = KVBlockPool(slots * width, block)
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 4), 2)
+    arena = jax.random.normal(
+        ks[0], pool.arena_shape(1, rank + cfg.qk_rope_head_dim),
+        jnp.float32).astype(DTYPE)
+    q = jax.random.normal(ks[1], (slots, h, 1, arena.shape[2]),
+                          jnp.float32).astype(DTYPE)
+    rng = np.random.RandomState(SEED + 4)
+    tables = jnp.asarray((rng.permutation(slots * width) + 1).reshape(
+        slots, width).astype(np.int32))
+    lengths = jnp.asarray(
+        np.linspace(0, width * block - 1, slots).astype(np.int32))
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    out = jax.jit(lambda *a: latent_paged_decode_attention(
+        *a, scale, rank))(q, arena, tables, lengths)
+    out_r = jax.jit(lambda *a: _latent_attn_paged(
+        *a, scale=scale, value_dim=rank))(*_f32(q, arena), tables, lengths)
+
+    paddle.seed(SEED)
+    net = KimiK2(cfg)
+    net.eval()
+    params, buffers = net.functional_state()
+    spec = net.paged_cache_spec()
+    tokens = jnp.asarray(rng.randint(1, cfg.vocab_size, (slots, 1)),
+                         jnp.int32)
+
+    def step(params, arenas):
+        with tape.no_grad():
+            net.load_functional_state(params, buffers)
+            logits, caches, _ = net._forward_paged(
+                tokens, paged_caches(spec, arenas, tables, lengths))
+        return logits, cache_arenas(caches)
+
+    logits = {}
+    try:
+        for kernel_on in (True, False):
+            paddle.set_flags({"FLAGS_use_paged_attention": kernel_on})
+            # the flag is read at trace time: a fresh lambda, a fresh trace
+            logits[kernel_on] = jax.jit(lambda *a: step(*a))(
+                params, [(arena,)])[0]
+    finally:
+        paddle.set_flags({"FLAGS_use_paged_attention": True})
+        net.load_functional_state(params, buffers)
+    return {"out": _rel_err(out, out_r),
+            "logits": _rel_err(logits[True], logits[False]),
+            "block_size": block}
+
+
 def kernel_checks(sizes):
     """name -> thunk returning {tensor: normalized max error}."""
     checks = {
@@ -913,6 +984,7 @@ def kernel_checks(sizes):
     for chunk, block in sizes.paged_checks:
         checks[f"paged_decode_s{chunk}_block{block or 'picked'}"] = \
             lambda c=chunk, b=block: _check_paged(sizes, c, b)
+    checks["latent_paged_decode"] = lambda: _check_latent_paged(sizes)
     return checks
 
 
@@ -946,8 +1018,12 @@ def kernels_phase(sizes):
         errs[name] = {k: float(f"{v:.3g}") for k, v in got.items()}
         if block_size is not None:
             errs[name]["block_size"] = block_size
-        if not max(got.values()) <= KERNEL_TOL:
-            failures.append(f"{name}: err {errs[name]} > {KERNEL_TOL}")
+        # a kernel's result against its jnp form; `logits`, a model's
+        # through the kernel against the same model's without
+        if not all(err <= (LOGITS_TOL if key == "logits" else KERNEL_TOL)
+                   for key, err in got.items()):
+            failures.append(f"{name}: err {errs[name]} > {KERNEL_TOL} "
+                            f"(logits {LOGITS_TOL})")
     return {"failures": failures, "errors_vs_jnp_reference": errs,
             "tolerance": KERNEL_TOL, "default_blocks": _default_blocks(sizes),
             "interpreted": jax.default_backend() != "tpu"}

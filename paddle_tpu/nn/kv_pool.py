@@ -412,9 +412,32 @@ def latent_paged_attention(q, arena, block_tables, lengths, scale,
     `dim`-wide rows, values their first `value_dim` entries (MLA's
     absorbed form: the key and value projections live in q and in what
     is done with the result). Row r of slot i attends logical cols
-    <= lengths[i] + r. Returns [b, h, s, value_dim] in q's dtype. Plain
-    XLA: gather the slot's blocks, two einsums with the tokens left in
-    the lanes, softmax in float32."""
+    <= lengths[i] + r. Returns [b, h, s, value_dim] in q's dtype.
+
+    Gated like `paged_attention`: one query row a slot over a lane-tiled
+    arena, the decode step, goes to the Pallas latent kernel
+    (ops/pallas/decode_attention.latent_paged_decode_attention: a slot's
+    live blocks read once, its heads the rows of one product a block);
+    everything else, and every gate rejection, to `_latent_attn_paged`,
+    the plain-XLA form and the kernel's parity oracle."""
+    if _latent_kernel_eligible(q, arena, value_dim):
+        from ..core import monitor
+        from ..ops.pallas import run_guarded
+        from ..ops.pallas.decode_attention import (
+            latent_paged_cut, latent_paged_decode_attention)
+        # the cut this program compiles with, on the kernel's span and as
+        # gauges per slot count (b64 is a decode step of 64 slots)
+        cut = latent_paged_cut(tuple(q.shape), tuple(arena.shape),
+                               block_tables.shape[1], arena.dtype.itemsize,
+                               value_dim)
+        monitor.stat_set_many({
+            f"pallas.latent_paged_attention.{name}.b{q.shape[0]}": value
+            for name, value in cut.items()})
+        return run_guarded(
+            "latent_paged_attention",
+            lambda: latent_paged_decode_attention(
+                q, arena, block_tables, lengths, scale, value_dim),
+            **cut)
     return _latent_attn_paged(
         q, arena, jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(lengths, jnp.int32), scale=float(scale),
@@ -425,6 +448,8 @@ def latent_paged_attention(q, arena, block_tables, lengths, scale,
 # fusions from the rest of a serve program
 @functools.partial(jax.jit, static_argnames=("scale", "value_dim"))
 def _latent_attn_paged(q, arena, bt, lens, *, scale, value_dim):
+    """Plain XLA: gather the slot's blocks, two einsums with the tokens
+    left in the lanes, softmax in float32 over the whole table width."""
     b, h, s, d = q.shape
     bs, nb = arena.shape[3], bt.shape[1]
     g = jnp.take(arena[:, 0], bt, axis=0)                 # [b, nb, d, bs]
@@ -442,7 +467,7 @@ def _latent_attn_paged(q, arena, bt, lens, *, scale, value_dim):
 
 
 def _paged_gate(kernel, training, supported):
-    """Gate shared by the pool's two Pallas kernels; every rejection
+    """Gate shared by the pool's three Pallas kernels; every rejection
     bumps pallas.gate_reject.{kernel}.{reason} so bench/serve output can
     say why the pool path ran on jnp. `supported` is a thunk."""
     from ..core import flags as _flags
@@ -466,6 +491,14 @@ def _paged_kernel_eligible(q, k_arena, training):
         "paged_decode_attention", training,
         lambda: paged_supported(tuple(q.shape), tuple(k_arena.shape),
                                 k_arena.dtype.itemsize))
+
+
+def _latent_kernel_eligible(q, arena, value_dim):
+    from ..ops.pallas.decode_attention import latent_paged_supported
+    return _paged_gate(
+        "latent_paged_attention", False,
+        lambda: latent_paged_supported(tuple(q.shape), tuple(arena.shape),
+                                       arena.dtype.itemsize, value_dim))
 
 
 def _write_kernel_eligible(arena, slots):

@@ -41,7 +41,8 @@ from .flash_attention import (NEG_INF, _Z, _ceil_to, _cparams, _interpret,
 
 __all__ = ["decode_attention", "supported",
            "paged_decode_attention", "paged_supported",
-           "paged_write_token", "paged_write_supported"]
+           "paged_write_token", "paged_write_supported",
+           "latent_paged_decode_attention", "latent_paged_supported"]
 
 
 def _decode_attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -422,6 +423,292 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
     out = _paged_call(q, k_arena, v_arena, bt, lens, scale)
     out = out.astype(out_dtype)
     return out[:, :, :s] if s_p != s else out
+
+
+# --------------------------------------------------------------------------
+# latent variant: every head over ONE cached vector a token
+# --------------------------------------------------------------------------
+#
+# A latent-attention (MLA) layer in its absorbed form caches one `dim`-wide
+# vector a token, shared by every query head: the arena is [n, 1, dim, bs],
+# a block [dim, bs] with the tokens in the lanes. The keys are the whole
+# block, the values its first `value_dim` rows. So the needs are the
+# reverse of the kernel above: there a head is a batch dimension with its
+# own keys and `s` query rows; here the HEADS are the rows of one product,
+# q [h, dim] @ block [dim, bs], with no transpose, and the values are
+# already in VMEM when the scores are done. A path of its own, which shares
+# the gate, the scalar prefetch and the trick of the index map with the
+# pair above, and no kernel body.
+#
+# The cut. A bf16 block of the Kimi share (576 x 128) is 147 KB, 0.18 us
+# of HBM time, and ~8 of a slot's 24 table blocks are live. A step
+# takes `blocks_per_step` logical blocks of its slot: the arena is passed
+# that many times, operand g under an index map of its own (logical block
+# ik * G + g). What the index maps read is a plan made outside the kernel
+# from tables and lengths (`_latent_block_plan`): a live block's physical
+# id, and for a dead one the id that operand fetched LAST, in grid order,
+# across slots too. A repeated block index is no DMA, so a call reads each
+# live block once and nothing else, however wide the table is; the dead
+# blocks' products are skipped by `pl.when`.
+
+def _latent_paged_attn_kernel(len_ref, plan_ref, q_ref, *rest, scale, bs,
+                              nb, nk, group, value_dim):
+    """Grid (b, nk): step (ib, ik) holds slot ib's q as rows [h, dim] and
+    its logical blocks ik * group + g, g < group, each [dim, bs]. len_ref
+    [b]: columns <= len_ref[ib] are live (the step's own token is
+    written). plan_ref is consumed by the index maps.
+
+    A step is two passes over its live blocks, so that no block waits
+    for the softmax of the one before: the scores of each (kept in
+    sc_scr) and their running maximum element by element; then ONE new
+    row maximum and rescaling of the state; then each block's
+    probabilities, their sum element by element, and its product with
+    the values. The reductions along the lanes are two a step."""
+    blocks, o_ref = rest[:group], rest[group]
+    m_scr, l_scr, acc_scr, sc_scr, red_scr = rest[group + 1:]
+    ib, ik = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(ik == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    # np.int32 / np.float32 scalars: see _decode_attn_kernel
+    length = len_ref[ib]
+    last = jnp.minimum(jnp.maximum(length, np.int32(0)) // np.int32(bs),
+                       np.int32(nb - 1))       # last live logical block
+    first = ik * np.int32(group)               # the step's first block
+    red_scr[:] = jnp.full_like(red_scr, NEG_INF)
+
+    for g, blk_ref in enumerate(blocks):
+        j = first + np.int32(g)
+
+        @pl.when(j <= last)
+        def _scores(g=g, j=j, blk_ref=blk_ref):
+            sc = jax.lax.dot_general(
+                q_ref[0], blk_ref[0, 0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * np.float32(scale)
+            col = j * np.int32(bs) + jax.lax.broadcasted_iota(
+                jnp.int32, sc.shape, 1)
+            sc = jnp.where(col <= length, sc, np.float32(NEG_INF))
+            sc_scr[g] = sc                     # [h, bs] f32
+            red_scr[:] = jnp.maximum(red_scr[:], sc)
+
+    @pl.when(first <= last)
+    def _rescale():
+        m_prev = m_scr[:]                      # [h, 1]
+        m_new = jnp.maximum(
+            m_prev, jnp.max(red_scr[:], axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        m_scr[:] = m_new
+        l_scr[:] = l_scr[:] * alpha
+        acc_scr[:] = acc_scr[:] * alpha
+        red_scr[:] = jnp.zeros_like(red_scr)   # now the sum of p
+
+    for g, blk_ref in enumerate(blocks):
+        j = first + np.int32(g)
+
+        @pl.when(j <= last)
+        def _values(g=g, blk_ref=blk_ref):
+            p = jnp.exp(sc_scr[g] - m_scr[:])  # [h, bs] f32
+            red_scr[:] = red_scr[:] + p
+            # p [h, bs] against the values [value_dim, bs]: both contract
+            # their lanes, the tokens
+            acc_scr[:] = acc_scr[:] + jax.lax.dot_general(
+                p.astype(blk_ref.dtype), blk_ref[0, 0, :value_dim],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+    @pl.when(first <= last)
+    def _sum():
+        l_scr[:] = l_scr[:] + jnp.sum(red_scr[:], axis=-1, keepdims=True)
+
+    @pl.when(ik == nk - 1)
+    def _flush():
+        denom = jnp.maximum(l_scr[:], 1e-30)
+        o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+
+
+# The most logical blocks a grid step of the latent kernel takes. Measured
+# on a v5e at the Kimi share's decode step (64 slots, 24-block tables, ~8
+# live blocks a slot, bf16; PERF.md section 6, PR 38): alone, 1 / 4 / 8 / 12
+# blocks a step all read 0.36-0.38 ms a call, because a step whose blocks
+# are dead fetches nothing and skips its body, and 24 (the whole table: no
+# state carried between steps, a slot's fetches in flight together)
+# 0.30-0.31; in the cell 24 is worth 2 % of its tokens a second over 8.
+_LATENT_BLOCKS_PER_STEP = 24
+
+
+def _latent_step_bytes(group, h, dim, value_dim, bs, itemsize):
+    """VMEM bytes of one grid step over `group` blocks, lanes padded to
+    128: the blocks and q and out double-buffered, the softmax state, the
+    blocks' scores and three more of a block's in float32."""
+    bs_l = _ceil_to(bs, 128)
+    blocks = 2 * group * dim * bs_l * itemsize
+    qo = 2 * h * (_ceil_to(dim, 128) + _ceil_to(value_dim, 128)) * itemsize
+    state = h * (128 + 128 + _ceil_to(value_dim, 128)) * 4
+    scores = (group + 3) * h * bs_l * 4
+    return blocks + qo + state + scores
+
+
+def latent_paged_blocks_per_step(h, dim, value_dim, bs, table_blocks,
+                                 itemsize) -> int:
+    """The latent kernel's cut: how many logical blocks of a slot one
+    grid step takes. As many as fit `_ATTN_VMEM_BYTES` of VMEM, at most
+    `_LATENT_BLOCKS_PER_STEP`, then evened out over the steps a table
+    of `table_blocks` needs (24 blocks: one step; 40: 2 steps of 20); 0
+    where not even one block fits."""
+    fit = max((group for group in range(1, _LATENT_BLOCKS_PER_STEP + 1)
+               if _latent_step_bytes(group, h, dim, value_dim, bs,
+                                     itemsize) <= _ATTN_VMEM_BYTES),
+              default=0)
+    if not fit:
+        return 0
+    nb = max(int(table_blocks), 1)
+    return -(-nb // -(-nb // fit))
+
+
+def latent_paged_supported(q_shape, arena_shape, itemsize,
+                           value_dim) -> bool:
+    """Static predicate: can the latent kernel serve q [b, h, s, dim]
+    over an arena [n_blocks, 1, dim, block_size] of `itemsize`-byte
+    elements? One query row a slot (the decode step), one arena head,
+    the block whole lane tiles, the values a prefix of the keys that ends
+    on a sublane tile, and a step of one block within the VMEM budget."""
+    if len(q_shape) != 4 or len(arena_shape) != 4:
+        return False
+    b, h, s, dim = q_shape
+    nb_phys, heads, dim_a, bs = arena_shape
+    if s != 1 or heads != 1 or dim_a != dim or nb_phys < 1 or b < 1:
+        return False
+    if bs < 128 or bs % 128 != 0:
+        return False
+    sublane = 32 // int(itemsize)              # 8 rows of 32 bits a tile
+    if value_dim < 1 or value_dim > dim or value_dim % sublane != 0:
+        return False
+    return latent_paged_blocks_per_step(h, dim, value_dim, bs, 1,
+                                        itemsize) > 0
+
+
+def latent_paged_cut(q_shape, arena_shape, table_blocks, itemsize,
+                     value_dim) -> dict:
+    """How a supported call is cut: `blocks_per_step` logical blocks of a
+    slot a grid step, `grid_steps` = slots x the steps a table needs, and
+    `live_bytes`, what the call reads from the arena for each live block
+    of a slot (a block as laid out; dead blocks cost no bytes). The Kimi
+    share's decode step (64 slots, 64 heads, 576 wide, 24-block tables
+    of 128, bf16): the table's 24 blocks a step, 64 steps, 147 456 B a
+    live block."""
+    b, h, _, dim = q_shape
+    bs = arena_shape[3]
+    group = latent_paged_blocks_per_step(h, dim, value_dim, bs,
+                                         table_blocks, itemsize)
+    return {"blocks_per_step": group,
+            "grid_steps": b * -(-int(table_blocks) // group),
+            "live_bytes": dim * _ceil_to(bs, 128) * itemsize}
+
+
+def _latent_block_plan(block_tables, lengths, bs, group):
+    """What the latent kernel's index maps read: [group * b * nk] i32,
+    operand-major, the physical block operand g holds at grid step
+    (slot, ik). Where logical block ik * group + g of the slot is live,
+    its id from the table; where it is dead, the id the operand held at
+    the step before (in grid order, the slot before's too): the index
+    repeats and Pallas fetches nothing. Before an operand's first live
+    block: the trash block, one fetch a call."""
+    b, nb = block_tables.shape
+    nk = -(-nb // group)
+    last = jnp.clip(lengths // jnp.int32(bs), 0, nb - 1)          # [b]
+    j = jnp.arange(nk * group, dtype=jnp.int32)                   # logical
+    live = (j[None] <= last[:, None]).reshape(b * nk, group)
+    phys = jnp.take(block_tables, jnp.minimum(j, nb - 1),
+                    axis=1).reshape(b * nk, group)
+    step = jnp.arange(b * nk, dtype=jnp.int32)[:, None]
+    held = jax.lax.cummax(jnp.where(live, step, jnp.int32(-1)), axis=0)
+    plan = jnp.where(held >= 0,
+                     jnp.take_along_axis(phys, jnp.maximum(held, 0), axis=0),
+                     jnp.int32(0))
+    return plan.T.reshape(-1)
+
+
+# jitted under its own name, like the pair's calls above: a program's
+# layers trace it once, and a device trace lists the kernel under it
+@functools.partial(jax.jit, static_argnames=("scale", "value_dim",
+                                             "interpret"))
+def _latent_paged_call_once(q, arena, block_tables, lengths, *, scale,
+                            value_dim, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    b, h, dim = q.shape
+    bs, nb = arena.shape[3], block_tables.shape[1]
+    group = latent_paged_blocks_per_step(h, dim, value_dim, bs, nb,
+                                         arena.dtype.itemsize)
+    nk = -(-nb // group)
+    plan = _latent_block_plan(block_tables, lengths, bs, group)
+
+    def q_map(ib, ik, len_ref, plan_ref):
+        return (ib, _Z, _Z)
+
+    def block_map(g):
+        first = np.int32(g * b * nk)
+
+        def index(ib, ik, len_ref, plan_ref):
+            return (plan_ref[first + ib * np.int32(nk) + ik], _Z, _Z, _Z)
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, nk),
+        in_specs=[pl.BlockSpec((1, h, dim), q_map)]
+        + [pl.BlockSpec((1, 1, dim, bs), block_map(g))
+           for g in range(group)],
+        out_specs=pl.BlockSpec((1, h, value_dim), q_map),
+        scratch_shapes=[
+            _vmem((h, 1), jnp.float32),
+            _vmem((h, 1), jnp.float32),
+            _vmem((h, value_dim), jnp.float32),
+            _vmem((group, h, bs), jnp.float32),
+            _vmem((h, bs), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _latent_paged_attn_kernel, scale=scale, bs=bs, nb=nb, nk=nk,
+        group=group, value_dim=value_dim)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, value_dim), q.dtype),
+        compiler_params=_cparams("parallel", "arbitrary"),
+        interpret=interpret,
+    )(lengths, plan, q, *([arena] * group))
+
+
+def latent_paged_decode_attention(q, arena, block_tables, lengths, scale,
+                                  value_dim):
+    """Attention of q [b, h, 1, dim], every head over the ONE cached
+    vector a token of a paged latent arena [n_blocks, 1, dim,
+    block_size]: keys are a block's whole `dim` rows, values its first
+    `value_dim`. `lengths` [b] is each slot's fill BEFORE this step's
+    token, which must already be written (nn/kv_pool.write_kv): slot i
+    attends logical columns <= lengths[i]. Table entries past the
+    allocation MUST be 0 (the trash block). Operands in the arena's
+    dtype, products accumulated in float32, softmax in float32, the
+    probabilities rounded to the arena's dtype before the second
+    product: `nn/kv_pool._latent_attn_paged`'s arithmetic, its softmax
+    online across the grid steps of a table too wide for one. Eval-only (no vjp); returns [b, h, 1, value_dim] in
+    q's dtype."""
+    b, h, s, dim = q.shape
+    if s != 1 or arena.shape[1] != 1 or arena.shape[2] != dim:
+        raise ValueError(
+            f"latent_paged_decode_attention: q{tuple(q.shape)} needs one "
+            f"query row and a one-head arena {dim} wide, got "
+            f"{tuple(arena.shape)}")
+    out = _latent_paged_call_once(
+        q[:, :, 0].astype(arena.dtype), arena,
+        jnp.asarray(block_tables, jnp.int32),
+        jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1), (b,)),
+        scale=float(scale), value_dim=int(value_dim),
+        interpret=_interpret())
+    return out[:, :, None].astype(q.dtype)
 
 
 # The decode step's write: one token per slot into the same arena, as a

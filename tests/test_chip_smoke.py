@@ -72,7 +72,10 @@ def test_kernels_phase_toy(interpret):
     assert result["interpreted"]
     assert set(result["errors_vs_jnp_reference"]) >= {
         "flash_causal", "flash_padding_bias", "fused_ce", "decode",
-        "paged_decode_s1_blockpicked"}
+        "paged_decode_s1_blockpicked", "latent_paged_decode"}
+    # the toy's latent net through the interpreted kernel and without it
+    assert set(result["errors_vs_jnp_reference"]["latent_paged_decode"]) \
+        == {"out", "logits", "block_size"}
 
 
 @pytest.mark.slow
@@ -110,8 +113,8 @@ def _compile_kernels_for_v5e():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     from paddle_tpu.ops.pallas.decode_attention import (
-        decode_attention, paged_cut, paged_decode_attention,
-        paged_write_token)
+        decode_attention, latent_paged_cut, latent_paged_decode_attention,
+        paged_cut, paged_decode_attention, paged_write_token)
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.ops.pallas.fused_ce import fused_linear_cross_entropy
     try:
@@ -169,6 +172,18 @@ def _compile_kernels_for_v5e():
     for b, arena in ((32, arena[0]), (64, (1025, 1, 576, 128))):
         compile_for_v5e(paged_write_token, (arena, bf16), ((b,), i32),
                         ((b,), i32), ((*arena[1:3], b), bf16))
+    # the Kimi share's decode step over that arena: 64 heads the rows of
+    # one product a block (a 576-row contraction, then p against the
+    # first 512 rows with the lanes contracted), the 24-block table in
+    # one grid step; and a table of 3 blocks
+    for table in (24, 3):
+        compile_for_v5e(
+            lambda q, a, t, n: latent_paged_decode_attention(
+                q, a, t, n, 0.1, 512),
+            ((64, 64, 1, 576), bf16), (arena, bf16), ((64, table), i32),
+            ((64,), i32))
+    assert latent_paged_cut((64, 64, 1, 576), arena, 24, 2, 512) == {
+        "blocks_per_step": 24, "grid_steps": 64, "live_bytes": 147456}
     print("MOSAIC-OK")
 
 
@@ -341,20 +356,29 @@ def _compile_latent_steps_for_v5e():
             "alias_bytes": mem.alias_size_in_bytes,
             "arena_bytes": int(np.prod(arena)) * 2,
             "writer": monitor.stats("pallas.hit.").get(
-                "pallas.hit.paged_write_token", 0)}))
+                "pallas.hit.paged_write_token", 0),
+            "latent_attn": monitor.stats("pallas.hit.").get(
+                "pallas.hit.latent_paged_attention", 0),
+            "rejects": monitor.stats(
+                "pallas.gate_reject.latent_paged_attention."),
+            "cut": monitor.stats("pallas.latent_paged_attention.")}))
     print("LATENT-STEPS-DONE")
 
 
 def test_latent_serve_steps_hold_no_arena_copy_for_v5e():
     """The pool's one layout holds for a one-head latent arena
     [blocks+1, 1, 576, 128] too: the Kimi-K2 share's decode step (the
-    Pallas token writer, once a layer) and its bucket-2048 prefill (the
-    in-place block loop) hold no copy or transpose of arena shape, and
-    the donated arenas come back aliased. Unlike GPT's steps these are
-    whole model programs, and plain-XLA latent attention gathers a
-    slot's blocks, so their temps are activations and that gather: held
-    to what fits beside 9.7 GB of weights (the un-blocked scores of a
-    2048-token prompt alone would be 1.07 GB), not to an arena's size."""
+    Pallas token writer and the Pallas latent-attention kernel, each
+    once a layer) and its bucket-2048 prefill (the in-place block loop,
+    attention within the chunk: no kernel of the pool's) hold no copy or
+    transpose of arena shape, and the donated arenas come back aliased.
+    Unlike GPT's steps these are whole model programs, so their temps
+    are activations. The decode step's: 61 MB, under a block table's
+    worth of one slot's blocks (the plain-XLA latent attention gathered
+    24 blocks for each of 64 slots and scored them in float32: 475 MB
+    before the kernel, PR 38). The prefill's is held to what fits beside
+    9.7 GB of weights (the un-blocked scores of a 2048-token prompt alone
+    would be 1.07 GB), not to an arena's size."""
     out = _run_in_cpu_child("_compile_latent_steps_for_v5e",
                             "LATENT-STEPS-DONE")
     decode, prefill = (json.loads(line[5:]) for line in out.splitlines()
@@ -362,10 +386,19 @@ def test_latent_serve_steps_hold_no_arena_copy_for_v5e():
     for step in (decode, prefill):
         assert step["arena_in_hlo"] and step["relayouts"] == [], step
         assert step["alias_bytes"] >= 2 * step["arena_bytes"], step
-        assert step["temp_bytes"] < 1.5e9, step
         assert step["lane_padded"] == [], step   # 64 dense latents a call
-    assert (decode["s"], decode["writer"]) == (1, 2)      # the Pallas writer
-    assert (prefill["s"], prefill["writer"]) == (2048, 0)  # the XLA loop
+        assert step["rejects"] == {}, step
+    assert decode["temp_bytes"] < 1e8, decode
+    assert prefill["temp_bytes"] < 1.5e9, prefill
+    # the Pallas writer and the latent kernel, one a layer (two layers)
+    assert (decode["s"], decode["writer"], decode["latent_attn"]) == (1, 2, 2)
+    assert decode["cut"] == {
+        "pallas.latent_paged_attention.blocks_per_step.b64": 24,
+        "pallas.latent_paged_attention.grid_steps.b64": 64,
+        "pallas.latent_paged_attention.live_bytes.b64": 147456}
+    # the XLA block loop; attention within the chunk hits nothing new
+    assert (prefill["s"], prefill["writer"], prefill["latent_attn"]) \
+        == (2048, 0, 0)
 
 
 def _compile_hybrid_steps_for_v5e():

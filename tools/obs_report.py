@@ -169,11 +169,14 @@ def pallas_rates(metrics) -> str:
     pallas.gate_reject.K.reason -> hit/fallback/reject counts + rate.
     (Nothing emits pallas.fallback.* any more; dumps recorded before the
     demotion was removed still carry it.) A kernel that says how its
-    programs were cut into grid steps (pallas.K.heads_per_step.bMsN and
-    .grid_steps.bMsN, M slots of N query rows: the paged kernel) has it
-    in detail; so has the token writer, for what a call of M slots moves
-    (pallas.K.token_bytes.bM, the token operand as laid out, and
-    .block_bytes.bM, the slots' blocks in and out)."""
+    programs were cut into grid steps has it in detail: the paged kernel
+    (pallas.K.heads_per_step.bMsN and .grid_steps.bMsN, M slots of N
+    query rows) and the latent kernel (pallas.K.blocks_per_step.bM,
+    .grid_steps.bM and .live_bytes.bM, what a call reads for each live
+    block of a slot); so has the token writer, for what a call of M
+    slots moves (pallas.K.token_bytes.bM, the token operand as laid out,
+    and .block_bytes.bM, the slots' blocks in and out). The pool's three
+    kernels, nn/kv_pool.py."""
     per = defaultdict(lambda: {"hit": 0.0, "fallback": 0.0,
                                "gate_reject": 0.0, "reasons": []})
     cuts, writes = defaultdict(dict), defaultdict(dict)
@@ -188,15 +191,21 @@ def pallas_rates(metrics) -> str:
             per[parts[2]][kind] += v
             per[parts[2]]["reasons"].append(
                 f"{kind}:{'.'.join(parts[3:])}={int(v)}")
-        elif len(parts) == 4 and parts[2] in ("heads_per_step",
-                                              "grid_steps"):
+        elif len(parts) == 4 and parts[2] in (
+                "heads_per_step", "blocks_per_step", "grid_steps",
+                "live_bytes"):
             cuts[kind, parts[3]][parts[2]] = int(v)
         elif len(parts) == 4 and parts[2] in ("token_bytes", "block_bytes"):
             writes[kind, parts[3]][parts[2]] = v
     for (k, shape), cut in sorted(cuts.items()):
+        held = f"{cut['blocks_per_step']}blocks" \
+            if "blocks_per_step" in cut \
+            else f"{cut.get('heads_per_step', '?')}heads"
+        live = f",{cut['live_bytes'] / 1e3:.0f}KB/live block" \
+            if "live_bytes" in cut else ""
         per[k]["reasons"].append(
-            f"cut:{shape}={cut.get('heads_per_step', '?')}heads/step"
-            f"x{cut.get('grid_steps', '?')}steps")
+            f"cut:{shape}={held}/stepx{cut.get('grid_steps', '?')}steps"
+            f"{live}")
     for (k, shape), moved in sorted(writes.items()):
         per[k]["reasons"].append(
             f"write:{shape}={moved.get('token_bytes', 0) / 1e3:.0f}KB"
