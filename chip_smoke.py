@@ -85,6 +85,8 @@ class Sizes:
     multichip_steps: int
     latent: object               # KimiK2Config: the latent-cache programs
     latent_serve: tuple          # (slots, blocks, block, max_seq, bucket)
+    scmoe: object                # LongCatFlashConfig: two caches a layer
+    scmoe_serve: tuple           # (slots, blocks, block, max_seq, bucket)
     hybrid: object               # OlmoHybridConfig: one period, served
     hybrid_serve: tuple          # (slots, blocks, block, max_seq, prompts)
 
@@ -93,6 +95,7 @@ class Sizes:
         from paddle_tpu.text.models.bert import BertConfig
         from paddle_tpu.text.models.gpt import GPTConfig
         from paddle_tpu.text.models.kimi_k2 import KimiK2Config
+        from paddle_tpu.text.models.longcat_flash import LongCatFlashConfig
         from paddle_tpu.text.models.olmo_hybrid import OlmoHybridConfig
         return Sizes(
             bert=BertConfig.bert_base(), train_batch=32, train_seq=128,
@@ -123,6 +126,14 @@ class Sizes:
                     "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
                     "original_max_position_embeddings": 4096}),
             latent_serve=(64, 1024, 128, 3072, 2048),
+            # the benchmark's share of LongCat-Flash-Chat at its published
+            # widths, one shortcut-connected layer (two latent attentions,
+            # two dense FFNs, 16 of 512 routed experts held beside 256
+            # zero-compute experts), its pool and its largest bucket
+            scmoe=LongCatFlashConfig(
+                vocab_size=16384, num_layers=1, experts_held=(0, 16),
+                max_seq_len=3072, dtype="bfloat16"),
+            scmoe_serve=(128, 1536, 128, 3072, 2048),
             # one period of the hybrid stack at its published widths (a
             # sixteenth of the vocabulary): the gated delta-rule kernels'
             # first compile on a chip, outside the benchmark
@@ -136,6 +147,7 @@ class Sizes:
         from paddle_tpu.text.models.bert import BertConfig
         from paddle_tpu.text.models.gpt import GPTConfig
         from paddle_tpu.text.models.kimi_k2 import KimiK2Config
+        from paddle_tpu.text.models.longcat_flash import LongCatFlashConfig
         from paddle_tpu.text.models.olmo_hybrid import OlmoHybridConfig
         return Sizes(
             bert=BertConfig.tiny(), train_batch=8, train_seq=16,
@@ -151,6 +163,9 @@ class Sizes:
             latent=KimiK2Config.tiny(num_layers=2, experts_held=(4, 8),
                                      dtype="bfloat16"),
             latent_serve=(2, 8, 16, 64, 32),
+            scmoe=LongCatFlashConfig.tiny(num_layers=1, experts_held=(4, 8),
+                                          dtype="bfloat16"),
+            scmoe_serve=(2, 8, 16, 64, 32),
             hybrid=OlmoHybridConfig.tiny(
                 dtype="bfloat16", init_std=0.1,
                 layer_types=["linear_attention"] * 3 + ["full_attention"]),
@@ -517,24 +532,34 @@ def _serve_program_memory(loop, bucket):
 
 
 def latent_serve_programs(sizes):
-    """`_serve_program_memory` for a net that caches ONE latent a token
-    (text/models/kimi_k2.py, at `sizes.latent`'s widths behind a
-    ServeLoop of `sizes.latent_serve`): its decode step and its largest
-    prefill bucket. The pool's one layout has to hold for the one-head
-    arena too: no copy or transpose of arena shape. The temp is reported
-    and not held to an arena's size: these are whole model programs, and
-    their temps are activations (tests/test_chip_smoke.py holds the
-    decode step's to 100 MB ahead of time: the latent kernel reads a
-    slot's blocks where they lie)."""
+    """`_serve_program_memory` for the nets that cache latents: ONE a
+    token a layer (text/models/kimi_k2.py, at `sizes.latent`'s widths
+    behind a ServeLoop of `sizes.latent_serve`) and TWO a layer
+    (text/models/longcat_flash.py, `sizes.scmoe` / `sizes.scmoe_serve`,
+    its programs under `scmoe_<name>`): the decode step and the largest
+    prefill bucket of each. The pool's one layout has to hold for the
+    one-head arena too: no copy or transpose of arena shape. The temp is
+    reported and not held to an arena's size: these are whole model
+    programs, and their temps are activations (tests/test_chip_smoke.py
+    holds the decode steps' to a bound ahead of time: the latent kernel
+    reads a slot's blocks where they lie)."""
     from paddle_tpu.inference import ServeConfig, ServeLoop
     from paddle_tpu.text.models.kimi_k2 import KimiK2
-    slots, blocks, block, max_seq, bucket = sizes.latent_serve
-    net = KimiK2(sizes.latent)
-    net.eval()
-    loop = ServeLoop(net, ServeConfig(
-        max_active=slots, kv_blocks=blocks, block_size=block,
-        max_seq_len=max_seq))
-    return _serve_program_memory(loop, bucket)
+    from paddle_tpu.text.models.longcat_flash import LongCatFlash
+    out = {}
+    for prefix, model, config, serve in (
+            ("", KimiK2, sizes.latent, sizes.latent_serve),
+            ("scmoe_", LongCatFlash, sizes.scmoe, sizes.scmoe_serve)):
+        slots, blocks, block, max_seq, bucket = serve
+        net = model(config)
+        net.eval()
+        loop = ServeLoop(net, ServeConfig(
+            max_active=slots, kv_blocks=blocks, block_size=block,
+            max_seq_len=max_seq))
+        out.update((prefix + name, mem) for name, mem
+                   in _serve_program_memory(loop, bucket).items())
+        del net, loop
+    return out
 
 
 def hybrid_serve(sizes):

@@ -63,8 +63,8 @@ names (`net.serve_counters`: the `moe_*` counts of a net with expert
 layers, the `linear_*` counts of one with recurrent layers), all mirrored
 as `serve.*` gauges beside `serve.{queue_depth,active_slots,
 kv_pool_used_blocks,kv_pool_free_blocks,model_version,state_slots_used,
-state_bytes}` (the last two: decode slots whose per-slot state is owned,
-and the bytes of all of it; 0 for a net that caches by token only).
+state_bytes,steps}` (`state_*`: decode slots whose per-slot state is
+owned, and the bytes of all of it; 0 for a net that caches by token only).
 Counters `serve.{preempted,
 tokens_generated,requests_completed,requests_errored,hot_swaps,
 completion_log_errors}`, histograms `serve/ttft_ms` and
@@ -94,7 +94,7 @@ GAUGES = ("serve.queue_depth", "serve.active_slots",
           "serve.model_version", "serve.decode_tokens",
           "serve.prefill_dispatches", "serve.prefill_tokens",
           "serve.admitted", "serve.queue_wait_s",
-          "serve.state_slots_used", "serve.state_bytes")
+          "serve.state_slots_used", "serve.state_bytes", "serve.steps")
 COUNTERS = ("serve.preempted", "serve.tokens_generated",
             "serve.requests_completed", "serve.requests_errored",
             "serve.hot_swaps", "serve.completion_log_errors",
@@ -941,5 +941,6 @@ class ServeLoop:
             "serve.queue_wait_s": self._queue_wait_s,
             "serve.state_slots_used": self._state_slots_used(),
             "serve.state_bytes": self._state_bytes,
+            "serve.steps": self._step_count,
             **{f"serve.{k}": v for k, v in self._net_counts.items()},
         })

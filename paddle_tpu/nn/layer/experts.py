@@ -4,11 +4,21 @@
 a capacity past which tokens are dropped, experts sharded over a mesh
 axis. This is the layer that serving runs (DeepSeek-V3 / Kimi-K2 style):
 
-- **router over every expert**: sigmoid scores, float32, `num_experts`
-  wide; the `top_k` experts of a token are those with the highest
-  `score + bias` (the load-balancing bias, `e_score_correction_bias`),
-  its weights are the scores themselves, normalised over the chosen
-  `top_k` and scaled by `routed_scaling_factor`;
+- **router over every expert**: float32 scores, `num_experts` (+
+  `zero_experts`) wide, sigmoid (DeepSeek-V3 / Kimi-K2) or a softmax over
+  the whole width (LongCat-Flash: `score_func="softmax"`); the `top_k`
+  experts of a token are those with the highest `score + bias` (the
+  load-balancing bias, `e_score_correction_bias`), its weights are the
+  scores themselves, normalised over the chosen `top_k`
+  (`norm_topk_prob`, the default) or left as they are, and scaled by
+  `routed_scaling_factor`;
+- **zero-compute experts**: `zero_experts = n` ids past the routed ones,
+  `num_experts .. num_experts + n - 1`, routed over by the same top-k.
+  Such an expert is the identity on the layer's input: it has no
+  weights, nobody holds it, and what a token's zero experts add is one
+  multiply-add, `(sum of their weights) * x`, computed here in full for
+  this chip's tokens (on a deployment a token's home chip computes it).
+  So the products a token costs vary inside one batch;
 - **a held share**: `held = (first, count)` names the contiguous range of
   experts whose weights live here — one chip's share of an
   expert-parallel deployment. Routing, top-k and the normalisation are
@@ -26,8 +36,10 @@ axis. This is the layer that serving runs (DeepSeek-V3 / Kimi-K2 style):
   a whole block, so at most `ceil(P / M) + count` blocks of `M` rows
   exist; the expectation is `T * top_k * count / num_experts` pairs.
 
-`routed` also returns how many pairs each held expert got, which is what
-the serving counters (`serve.moe_*`) are made of.
+`routed` also returns how many pairs each held expert got and, over the
+whole router (held or not), how many of the tokens' pairs fell on routed
+experts and how many on zero-compute experts, which is what the serving
+counters (`serve.moe_*`) are made of.
 """
 from __future__ import annotations
 
@@ -100,18 +112,26 @@ class RoutedExperts(Layer):
     """See the module docstring. `forward(x)` takes [..., hidden] (Tensor
     or array) and returns the same kind; `routed(x, valid)` is the
     array-level call a served model uses: ([T, hidden] in x's dtype,
-    [count] i32 pairs per held expert)."""
+    [count] i32 pairs per held expert, [3] i32: the valid tokens' pairs
+    on routed experts, on zero-compute experts, and the sum over tokens
+    of a token's routed pairs squared)."""
 
     def __init__(self, hidden_size, expert_width, num_experts, top_k,
                  held=None, routed_scaling_factor=1.0, shared_width=0,
-                 dtype="float32", init_std=0.02):
+                 dtype="float32", init_std=0.02, score_func="sigmoid",
+                 norm_topk_prob=True, zero_experts=0):
         super().__init__(dtype=dtype)
         first, count = (0, num_experts) if held is None else held
         if not (0 <= first and count >= 1
                 and first + count <= num_experts and top_k <= num_experts):
             raise ValueError(f"held range {held} outside the router's "
                              f"{num_experts} experts")
+        if score_func not in ("sigmoid", "softmax") or zero_experts < 0:
+            raise ValueError(f"score_func {score_func!r}, zero_experts "
+                             f"{zero_experts}")
         self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.zero_experts = int(zero_experts)
+        self.score_func, self.norm_topk_prob = score_func, bool(norm_topk_prob)
         self.first, self.count = int(first), int(count)
         self.scaling = float(routed_scaling_factor)
         normal = I.Normal(0.0, init_std)
@@ -121,10 +141,11 @@ class RoutedExperts(Layer):
                                          default_initializer=normal)
 
         H, W = int(hidden_size), int(expert_width)
-        self.router_weight = param(H, num_experts)
+        width = self.num_experts + self.zero_experts   # the router's
+        self.router_weight = param(H, width)
         # the selection bias (`e_score_correction_bias`): float32 always
         self.router_bias = self.create_parameter(
-            [num_experts], dtype="float32", is_bias=True)
+            [width], dtype="float32", is_bias=True)
         self.gate, self.up = param(count, H, W), param(count, H, W)
         self.down = param(count, W, H)
         self.shared_width = int(shared_width)
@@ -134,30 +155,45 @@ class RoutedExperts(Layer):
             self.shared_down = param(shared_width, H)
 
     def route(self, x):
-        """x [T, H] -> (expert ids [T, top_k] i32 over all experts,
+        """x [T, H] -> (expert ids [T, top_k] i32 over the router's
+        width, routed experts first and zero-compute experts after them,
         weights [T, top_k] f32). Selection on score + bias; weights from
-        the scores alone, normalised over the chosen, scaled."""
-        scores = jax.nn.sigmoid(jnp.dot(
+        the scores alone, normalised over the chosen or not, scaled."""
+        logits = jnp.dot(
             x.astype(jnp.float32),
             self.router_weight._value.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
+            precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits) if self.score_func == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
         _, idx = jax.lax.top_k(scores + self.router_bias._value, self.top_k)
         chosen = jnp.take_along_axis(scores, idx, axis=-1)
-        weights = self.scaling * chosen / jnp.sum(chosen, axis=-1,
-                                                  keepdims=True)
+        weights = self.scaling * chosen
+        if self.norm_topk_prob:
+            weights = weights / jnp.sum(chosen, axis=-1, keepdims=True)
         return idx.astype(jnp.int32), weights
 
     def routed(self, x, valid=None):
         if valid is None:
             valid = jnp.ones((x.shape[0],), bool)
         idx, weights = self.route(x)
+        # a zero-compute id lies past every held range: `_routed_expert_
+        # ffn` gives it no row and no block, like an absent expert
         y, counts = _routed_expert_ffn(
             x, idx, weights, valid, self.gate._value, self.up._value,
             self.down._value, self.first)
+        zero = (idx >= self.num_experts) & valid[:, None]
+        if self.zero_experts:
+            with jax.named_scope("zero_experts"):
+                w_zero = jnp.sum(jnp.where(zero, weights, 0.0), axis=-1)
+                y = y + w_zero[:, None] * x.astype(jnp.float32)
         if self.shared_width:
             y = y + _swiglu(x, self.shared_gate._value,
                             self.shared_up._value, self.shared_down._value)
-        return y.astype(x.dtype), counts
+        real = jnp.sum((idx < self.num_experts) & valid[:, None], axis=-1,
+                       dtype=jnp.int32)                        # a token
+        pairs = jnp.stack([jnp.sum(real), jnp.sum(zero, dtype=jnp.int32),
+                           jnp.sum(real * real)])
+        return y.astype(x.dtype), counts, pairs
 
     def forward(self, x):
         from ...core.tensor import Tensor

@@ -57,6 +57,21 @@ MOE_STATS = tuple(f"moe_{kind}_{what}" for kind in ("decode", "prefill")
                                "peak_pairs")) + ("moe_decode_layer_steps",)
 
 
+def moe_counters(kind, pairs_held, n_tokens):
+    """`MOE_STATS`' increments from one settled serve program's pairs a
+    held expert [expert layers, held]."""
+    import numpy as np
+    pairs = np.asarray(pairs_held)
+    out = {f"moe_{kind}_tokens": int(n_tokens),
+           f"moe_{kind}_pairs_held": int(pairs.sum()),
+           f"moe_{kind}_experts_touched": int((pairs > 0).sum()),
+           f"moe_{kind}_peak_pairs":
+               int(pairs.max(axis=1).sum()) if pairs.size else 0}
+    if kind == "decode":
+        out["moe_decode_layer_steps"] = int(pairs.shape[0])
+    return out
+
+
 @dataclass
 class KimiK2Config:
     vocab_size: int = 163840
@@ -130,11 +145,14 @@ def yarn_inv_freq(dim, theta, scaling):
     return inv_freq, attention_factor
 
 
-def _rms(x, weight, eps):
+def _rms(x, weight, eps, scale=1.0):
+    """RMSNorm in float32; `scale` multiplies the normed value before it
+    is rounded to x's dtype (1.0: nothing is multiplied)."""
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
                             + eps)
-    return (y * weight.astype(jnp.float32)).astype(x.dtype)
+    y = y * weight.astype(jnp.float32)
+    return (y if scale == 1.0 else y * scale).astype(x.dtype)
 
 
 def _rope(x, cos, sin):
@@ -145,6 +163,17 @@ def _rope(x, cos, sin):
     x32 = x.astype(jnp.float32)
     rot = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
     return (x32 * cos + rot * sin).astype(x.dtype)
+
+
+def _cos_sin(cfg, pos):
+    """cos and sin [..., qk_rope_head_dim] of the positions `pos`, for
+    `_rope`'s half-split pairing, under the configuration's scaling."""
+    inv_freq, factor = yarn_inv_freq(cfg.qk_rope_head_dim,
+                                     float(cfg.rope_theta),
+                                     getattr(cfg, "rope_scaling", None))
+    ang = pos.astype(jnp.float32)[..., None] * inv_freq
+    ang = jnp.concatenate([ang, ang], axis=-1)
+    return jnp.cos(ang) * factor, jnp.sin(ang) * factor
 
 
 # jitted under a name of its own, so that a device trace can tell the
@@ -200,10 +229,18 @@ class _Weights(nn.Layer):
 
 
 class LatentAttention(_Weights):
-    def __init__(self, cfg: KimiK2Config):
+    """Multi-head latent attention of any configuration that names
+    KimiK2Config's attention fields (text/models/longcat_flash.py shares
+    it). `q_scale` multiplies the queries and `kv_scale` the normed
+    latent c_kv, which is cached scaled (LongCat-Flash's
+    `mla_scale_q_lora` / `mla_scale_kv_lora`: (hidden / rank)^1/2); at
+    1.0, Kimi-K2's, nothing is multiplied."""
+
+    def __init__(self, cfg, q_scale=1.0, kv_scale=1.0):
         super().__init__(cfg)
         H, h = cfg.hidden_size, cfg.num_heads
         self.heads, self.eps = h, cfg.rms_norm_eps
+        self.q_scale, self.kv_scale = float(q_scale), float(kv_scale)
         self.dn, self.dr, self.dv = (cfg.qk_nope_head_dim,
                                      cfg.qk_rope_head_dim, cfg.v_head_dim)
         self.rank = cfg.kv_lora_rank
@@ -214,7 +251,7 @@ class LatentAttention(_Weights):
         self.kv_norm = self.ones(self.rank)
         self.kv_b = self.matrix(self.rank, h * (self.dn + self.dv))
         self.o = self.matrix(h * self.dv, H)
-        scaling = cfg.rope_scaling or {}
+        scaling = getattr(cfg, "rope_scaling", None) or {}
         m = yarn_mscale(float(scaling.get("factor", 1.0)),
                         scaling.get("mscale_all_dim", 0)) if scaling else 1.0
         self.scale = (self.dn + self.dr) ** -0.5 * m * m
@@ -226,9 +263,12 @@ class LatentAttention(_Weights):
         c_q = _rms(x @ self.q_a._value, self.q_norm._value, self.eps)
         q = (c_q @ self.q_b._value).reshape(b, s, self.heads,
                                             self.dn + self.dr)
+        if self.q_scale != 1.0:
+            q = (q.astype(jnp.float32) * self.q_scale).astype(q.dtype)
         q_r = _rope(q[..., self.dn:], cos[:, :, None], sin[:, :, None])
         kva = x @ self.kv_a._value
-        c_kv = _rms(kva[..., :self.rank], self.kv_norm._value, self.eps)
+        c_kv = _rms(kva[..., :self.rank], self.kv_norm._value, self.eps,
+                    self.kv_scale)
         k_r = _rope(kva[..., self.rank:], cos, sin)
         return q[..., :self.dn], q_r, jnp.concatenate([c_kv, k_r], axis=-1)
 
@@ -277,7 +317,7 @@ class LatentAttention(_Weights):
 
 
 class DenseFFN(_Weights):
-    def __init__(self, cfg: KimiK2Config):
+    def __init__(self, cfg):
         super().__init__(cfg)
         H, W = cfg.hidden_size, cfg.intermediate_size
         self.gate, self.up = self.matrix(H, W), self.matrix(H, W)
@@ -314,68 +354,29 @@ class KimiK2Block(_Weights):
         if not self.sparse:
             return h + self.ffn(f), cache, None
         b, s, H = f.shape
-        y, counts = self.ffn.routed(
+        y, counts, _ = self.ffn.routed(
             f.reshape(b * s, H),
             None if valid is None else valid.reshape(b * s))
         return h + y.reshape(b, s, H), cache, counts
 
 
-class KimiK2(_Weights):
-    SERVE_STATS = MOE_STATS
+class _LatentDecoder(_Weights):
+    """What the latent-attention decoders share (this file's and
+    text/models/longcat_flash.py's): embedding, a stack of blocks, the
+    final norm, an untied head, and `ServeLoop`'s protocol over what a
+    subclass writes beside `paged_cache_spec`: `_block(i)`, layer i of
+    the stack, and `_blocks`: (ids, pos, caches in spec order or None for
+    a pass without a cache, valid) -> (x, new caches, what the expert
+    layers counted: a tuple of arrays)."""
 
-    def __init__(self, config: KimiK2Config = None):
-        cfg = config or KimiK2Config()
+    def __init__(self, cfg):
         super().__init__(cfg)
         self.config = cfg
         self.embed = self.matrix(cfg.vocab_size, cfg.hidden_size)
-        self.blocks = nn.LayerList([KimiK2Block(cfg, i)
-                                    for i in range(cfg.num_layers)])
+        self.blocks = nn.LayerList(
+            [self._block(i) for i in range(cfg.num_layers)])
         self.norm = self.ones(cfg.hidden_size)
         self.head = self.matrix(cfg.hidden_size, cfg.vocab_size)
-
-    def paged_cache_spec(self):
-        """One `CacheSpec` a layer: a `PagedLatentCache` over one arena,
-        kv_lora_rank + qk_rope_head_dim wide."""
-        from ...nn.kv_pool import CacheSpec, PagedLatentCache
-        cfg = self.config
-        latent = (1, cfg.kv_lora_rank + cfg.qk_rope_head_dim)
-        return [CacheSpec(PagedLatentCache, (latent,))] * cfg.num_layers
-
-    def serve_counters(self, kind, counted, n_tokens):
-        """{`ServeLoop.stats()` name: increment} for one settled serve
-        program (`kind` "decode" or "prefill") that ran `n_tokens` live
-        tokens: `counted` is what `_forward_paged` returned past its
-        caches, the pairs each held expert got [expert layers, held]."""
-        import numpy as np
-        pairs = np.asarray(counted[0])
-        out = {f"moe_{kind}_tokens": int(n_tokens),
-               f"moe_{kind}_pairs_held": int(pairs.sum()),
-               f"moe_{kind}_experts_touched": int((pairs > 0).sum()),
-               f"moe_{kind}_peak_pairs":
-                   int(pairs.max(axis=1).sum()) if pairs.size else 0}
-        if kind == "decode":
-            out["moe_decode_layer_steps"] = int(pairs.shape[0])
-        return out
-
-    def _cos_sin(self, pos):
-        cfg = self.config
-        inv_freq, factor = yarn_inv_freq(cfg.qk_rope_head_dim,
-                                         float(cfg.rope_theta),
-                                         cfg.rope_scaling)
-        ang = pos.astype(jnp.float32)[..., None] * inv_freq
-        ang = jnp.concatenate([ang, ang], axis=-1)
-        return jnp.cos(ang) * factor, jnp.sin(ang) * factor
-
-    def _blocks(self, ids, pos, caches, valid):
-        x = jnp.take(self.embed._value, ids, axis=0)
-        cos, sin = self._cos_sin(pos)
-        new_caches, counts = [], []
-        for blk, c in zip(self.blocks, caches):
-            x, c, n = blk(x, cos, sin, c, valid)
-            new_caches.append(c)
-            if n is not None:
-                counts.append(n)
-        return x, new_caches, counts
 
     def _logits(self, h):
         h = _rms(h, self.norm._value, self.config.rms_norm_eps)
@@ -390,18 +391,17 @@ class KimiK2(_Weights):
             else jnp.asarray(input_ids)
         with tape.no_grad():
             pos = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
-            x, _, _ = self._blocks(ids.astype(jnp.int32), pos,
-                                   [None] * len(self.blocks), None)
+            x, *_ = self._blocks(ids.astype(jnp.int32), pos, None, None)
             return Tensor(self._logits(x), _internal=True)
 
     def _forward_paged(self, input_ids, caches, last_index=None):
         """One paged prefill/decode pass, `GPT._forward_paged`'s contract
         over `PagedLatentCache`s, plus what the expert layers counted:
-        -> (logits [b, V] float32, new caches, pairs per held expert
-        [expert layers, held] i32). Rows that no request owns (a slot
-        whose table starts at the trash block, a prompt's padding past
-        `last_index`) are cached into the trash block like GPT's and
-        are routed to no expert."""
+        -> (logits [b, V] float32, new caches, then `_blocks`' counts:
+        first the pairs per held expert [expert layers, held] i32). Rows
+        that no request owns (a slot whose table starts at the trash
+        block, a prompt's padding past `last_index`) are cached into the
+        trash block like GPT's and are routed to no expert."""
         from ...core.tensor import Tensor
         from ...nn.kv_pool import TRASH_BLOCK
         ids = input_ids._value if isinstance(input_ids, Tensor) \
@@ -414,10 +414,46 @@ class KimiK2(_Weights):
         if last_index is not None:
             last = jnp.asarray(last_index, jnp.int32).reshape(-1)
             valid = valid & (step <= last[:, None])
-        x, new_caches, counts = self._blocks(
+        x, new_caches, counted = self._blocks(
             ids.astype(jnp.int32), lens[:, None] + step, caches, valid)
         h = x[:, -1] if last_index is None else jnp.take_along_axis(
             x, last[:, None, None], axis=1)[:, 0]
+        return (self._logits(h), new_caches, *counted)
+
+
+class KimiK2(_LatentDecoder):
+    SERVE_STATS = MOE_STATS
+
+    def __init__(self, config: KimiK2Config = None):
+        super().__init__(config or KimiK2Config())
+
+    def _block(self, i):
+        return KimiK2Block(self.config, i)
+
+    def paged_cache_spec(self):
+        """One `CacheSpec` a layer: a `PagedLatentCache` over one arena,
+        kv_lora_rank + qk_rope_head_dim wide."""
+        from ...nn.kv_pool import CacheSpec, PagedLatentCache
+        cfg = self.config
+        latent = (1, cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+        return [CacheSpec(PagedLatentCache, (latent,))] * cfg.num_layers
+
+    def serve_counters(self, kind, counted, n_tokens):
+        """{`ServeLoop.stats()` name: increment} for one settled serve
+        program (`kind` "decode" or "prefill") that ran `n_tokens` live
+        tokens: `counted` is what `_forward_paged` returned past its
+        caches, the pairs each held expert got [expert layers, held]."""
+        return moe_counters(kind, counted[0], n_tokens)
+
+    def _blocks(self, ids, pos, caches, valid):
+        x = jnp.take(self.embed._value, ids, axis=0)
+        cos, sin = _cos_sin(self.config, pos)
+        new_caches, counts = [], []
+        for blk, c in zip(self.blocks, caches or [None] * len(self.blocks)):
+            x, c, n = blk(x, cos, sin, c, valid)
+            new_caches.append(c)
+            if n is not None:
+                counts.append(n)
         counts = jnp.stack(counts) if counts \
             else jnp.zeros((0, 0), jnp.int32)
-        return self._logits(h), new_caches, counts
+        return x, new_caches, (counts,)

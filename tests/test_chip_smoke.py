@@ -292,31 +292,37 @@ def test_paged_serve_steps_hold_no_arena_copy_for_v5e():
     assert (prefill["heads_per_step"], prefill["grid_steps"]) == (5, 40)
 
 
-def _compile_latent_steps_for_v5e():
-    """Child-process body of the test below: ServeLoop's own decode step
-    and bucket-2048 prefill of the Kimi-K2 share at its published widths
+def _compile_latent_steps_for_v5e(model="KimiK2", config="latent",
+                                  serve="latent_serve", layers=None):
+    """Child-process body of the tests below: ServeLoop's own decode step
+    and bucket-2048 prefill of a latent-cache share at its published
+    widths, compiled for v5e over the benchmark's pool of one-head latent
+    arenas; one JSON line a program. By default the Kimi-K2 share
     (`chip_smoke.Sizes.full().latent`: one dense and one expert layer, 12
-    of 384 experts held), compiled for v5e over the benchmark's pool of
-    one-head latent arenas; one JSON line a program."""
+    of 384 experts held)."""
+    import dataclasses
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     from paddle_tpu.inference.serving import (_build_prefill,
                                               build_decode_step)
     from paddle_tpu.nn import initializer
     from paddle_tpu.nn.kv_pool import KVBlockPool
-    from paddle_tpu.text.models import KimiK2
+    from paddle_tpu.text import models
     try:
         device = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
     except Exception as e:  # environment without a usable libtpu
         print(f"NO-TOPOLOGY {type(e).__name__}: {e}")
         return
     sharding = SingleDeviceSharding(device)
-    # only shapes are compiled: 1.5 B parameters need not be drawn
+    # only shapes are compiled: billions of parameters need not be drawn
     initializer.Normal.__call__ = \
         lambda self, shape, dtype="float32": jnp.zeros(tuple(shape), dtype)
     sizes = chip_smoke.Sizes.full()
-    slots, blocks, block, max_seq, bucket = sizes.latent_serve
-    net = KimiK2(sizes.latent)
+    slots, blocks, block, max_seq, bucket = getattr(sizes, serve)
+    config = getattr(sizes, config)
+    if layers:
+        config = dataclasses.replace(config, num_layers=layers)
+    net = getattr(models, model)(config)
     net.eval()
     params, buffers = net.functional_state()
     pool, width = KVBlockPool(blocks, block), max_seq // block
@@ -354,7 +360,9 @@ def _compile_latent_steps_for_v5e():
             "lane_padded": chip_smoke.lane_padded_results(text),
             "temp_bytes": mem.temp_size_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes,
+            "argument_bytes": mem.argument_size_in_bytes,
             "arena_bytes": int(np.prod(arena)) * 2,
+            "arenas": len(net.paged_cache_spec()),
             "writer": monitor.stats("pallas.hit.").get(
                 "pallas.hit.paged_write_token", 0),
             "latent_attn": monitor.stats("pallas.hit.").get(
@@ -363,6 +371,14 @@ def _compile_latent_steps_for_v5e():
                 "pallas.gate_reject.latent_paged_attention."),
             "cut": monitor.stats("pallas.latent_paged_attention.")}))
     print("LATENT-STEPS-DONE")
+
+
+def _compile_scmoe_steps_for_v5e():
+    """The same for the LongCat-Flash share as the benchmark serves it
+    (`chip_smoke.Sizes.full().scmoe` at FOUR shortcut-connected layers:
+    8 arenas, 128 slots, 1536 blocks of 128)."""
+    _compile_latent_steps_for_v5e("LongCatFlash", "scmoe", "scmoe_serve",
+                                  layers=4)
 
 
 def test_latent_serve_steps_hold_no_arena_copy_for_v5e():
@@ -397,6 +413,40 @@ def test_latent_serve_steps_hold_no_arena_copy_for_v5e():
         "pallas.latent_paged_attention.grid_steps.b64": 64,
         "pallas.latent_paged_attention.live_bytes.b64": 147456}
     # the XLA block loop; attention within the chunk hits nothing new
+    assert (prefill["s"], prefill["writer"], prefill["latent_attn"]) \
+        == (2048, 0, 0)
+
+
+def test_scmoe_serve_steps_hold_no_arena_copy_for_v5e():
+    """The LongCat-Flash share at the benchmark's size: four layers of
+    two latent attentions each over EIGHT arenas [1537, 1, 576, 128], 128
+    decode slots. The decode step (the Pallas token writer and the Pallas
+    latent kernel, each once a sublayer: 8 a trace) and the bucket-2048
+    prefill hold no copy or transpose of arena shape, every donated
+    arena comes back aliased, nothing is rejected. The decode step's
+    temporaries are 71 MB (its activations at 128 rows; held under 150
+    MB: one gather of the tables' blocks would be 128 x 24 x 147 KB =
+    453 MB). What the chip must hold: 12.16 GB of arguments (10.35 GB of
+    weights, 1.81 GB of arenas) and the prefill's 1.02 GB of temporaries:
+    13.2 GB of 16."""
+    out = _run_in_cpu_child("_compile_scmoe_steps_for_v5e",
+                            "LATENT-STEPS-DONE")
+    decode, prefill = (json.loads(line[5:]) for line in out.splitlines()
+                       if line.startswith("STEP "))
+    for step in (decode, prefill):
+        assert step["arenas"] == 8 and step["arena_bytes"] == 226_639_872
+        assert step["arena_in_hlo"] and step["relayouts"] == [], step
+        assert step["alias_bytes"] >= 8 * step["arena_bytes"], step
+        assert step["lane_padded"] == [], step
+        assert step["rejects"] == {}, step
+        assert step["argument_bytes"] + step["temp_bytes"] < 14e9, step
+    assert decode["temp_bytes"] < 1.5e8, decode
+    assert prefill["temp_bytes"] < 1.5e9, prefill
+    assert (decode["s"], decode["writer"], decode["latent_attn"]) == (1, 8, 8)
+    assert decode["cut"] == {
+        "pallas.latent_paged_attention.blocks_per_step.b128": 24,
+        "pallas.latent_paged_attention.grid_steps.b128": 128,
+        "pallas.latent_paged_attention.live_bytes.b128": 147456}
     assert (prefill["s"], prefill["writer"], prefill["latent_attn"]) \
         == (2048, 0, 0)
 

@@ -84,7 +84,7 @@ def forced_logits(net, ids, prompt_len, bucket, block_size=16):
     @jax.jit
     def step(params, arenas, tokens, lengths, last_index):
         net.load_functional_state(params, buffers)
-        logits, caches, _ = net._forward_paged(
+        logits, caches, *_ = net._forward_paged(
             tokens, paged_caches(net.paged_cache_spec(), arenas,
                                  jnp.asarray(table), lengths),
             last_index=last_index)
@@ -201,7 +201,7 @@ def test_32_shares_add_up_to_the_uncut_layer():
                             w["ffn.shared_down"])
     total, pairs = shared, 0
     for rank in range(32):       # every chip computes the shared expert
-        y, counts = held_layer(w, (2 * rank, 2), 8, 64).routed(x)
+        y, counts, _ = held_layer(w, (2 * rank, 2), 8, 64).routed(x)
         total = total + (y - shared)
         pairs += int(counts.sum())
     assert pairs == 50 * 8       # every pair is held by exactly one rank
@@ -244,7 +244,7 @@ def test_partial_share_keeps_the_full_normalisation():
         w_e = jnp.sum(jnp.where(idx == e, weights, 0.0), axis=-1)
         want = want + w_e[:, None] * ref.swiglu(
             x, w["ffn.gate"][e], w["ffn.up"][e], w["ffn.down"][e])
-    y, counts = layer.routed(x)
+    y, counts, _ = layer.routed(x)
     assert rel_err(y, want) <= 1e-5
     assert int(counts.sum()) == int(((idx >= 5) & (idx < 8)).sum())
 
@@ -264,7 +264,7 @@ def test_forced_routing_drops_nothing(favoured, pairs_on_held):
     bias[list(favoured)] = 1.0
     w["ffn.router_bias"] = jnp.asarray(bias)
     x = jnp.asarray(rng.normal(0, 1, (300, 32)), jnp.float32)
-    y, counts = held_layer(w, (4, 4), 4, 16).routed(x)
+    y, counts, _ = held_layer(w, (4, 4), 4, 16).routed(x)
     with jax.default_matmul_precision("highest"):
         want = ref.expert_layer(share(w, (4, 4)),
                                 dict(ROUTER, num_experts_per_tok=4), x,
@@ -279,7 +279,7 @@ def test_pad_rows_route_nowhere():
     layer = held_layer(w, (0, 16), 4, 16)
     x = jnp.asarray(rng.normal(0, 1, (24, 32)), jnp.float32)
     valid = jnp.arange(24) < 10
-    y, counts = layer.routed(x, valid)
+    y, counts, _ = layer.routed(x, valid)
     assert int(counts.sum()) == 10 * 4
     assert rel_err(y[:10], layer.routed(x[:10])[0]) <= 1e-6
 

@@ -230,16 +230,22 @@ SERVE_GAUGES = ("serve.queue_depth", "serve.active_slots",
                 "serve.model_version", "serve.decode_tokens",
                 "serve.prefill_dispatches", "serve.prefill_tokens",
                 "serve.admitted", "serve.queue_wait_s",
-                "serve.state_slots_used", "serve.state_bytes")
+                "serve.state_slots_used", "serve.state_bytes", "serve.steps")
 # what a served net counts of its layers (its `SERVE_STATS`): a net with
-# expert layers (text/models/kimi_k2.MOE_STATS), a net with recurrent
-# layers (text/models/olmo_hybrid.LINEAR_STATS); self_check pins both
+# expert layers (text/models/kimi_k2.MOE_STATS; with zero-compute experts
+# beside them text/models/longcat_flash.SCMOE_STATS, which starts with
+# those), a net with recurrent layers (text/models/olmo_hybrid
+# .LINEAR_STATS); self_check pins all three
 SERVE_NET_GAUGES = (
     "serve.moe_decode_tokens", "serve.moe_decode_pairs_held",
     "serve.moe_decode_experts_touched", "serve.moe_decode_peak_pairs",
     "serve.moe_prefill_tokens", "serve.moe_prefill_pairs_held",
     "serve.moe_prefill_experts_touched", "serve.moe_prefill_peak_pairs",
     "serve.moe_decode_layer_steps",
+    "serve.moe_decode_pairs_real", "serve.moe_decode_pairs_zero",
+    "serve.moe_decode_pairs_real_sq",
+    "serve.moe_prefill_pairs_real", "serve.moe_prefill_pairs_zero",
+    "serve.moe_prefill_pairs_real_sq",
     "serve.linear_prefill_tokens", "serve.linear_prefill_pad_tokens",
     "serve.linear_decode_layer_steps")
 SERVE_COUNTERS = ("serve.preempted", "serve.tokens_generated",
@@ -252,14 +258,46 @@ _SERVE_SPANS = ("serve/tick", "serve/wait_work", "serve/settle",
                 "serve/retire", "serve/evict", "serve/hot_swap")
 
 
+def moe_line(values):
+    """`  moe: ...`: what the decode steps' routing came to in a net with
+    expert layers: pairs on the experts held here and held experts
+    touched, a layer-step; and, where the net has zero-compute experts,
+    how a token's pairs a layer split into real ones (on routed experts,
+    held or not) and zero ones. None for a net that counts no expert
+    layer."""
+    layer_steps = values.get("serve.moe_decode_layer_steps", 0)
+    if not layer_steps:
+        return None
+    held = values.get("serve.moe_decode_pairs_held", 0) / layer_steps
+    touched = values.get("serve.moe_decode_experts_touched", 0) / layer_steps
+    said = (f"  moe: decode: {held:.3f} pairs held and {touched:.3f} "
+            "experts touched a layer-step")
+    real = values.get("serve.moe_decode_pairs_real", 0)
+    zero = values.get("serve.moe_decode_pairs_zero", 0)
+    tokens = values.get("serve.moe_decode_tokens", 0)
+    steps = values.get("serve.steps", 0)
+    if real + zero and tokens:
+        said += (f"; {100.0 * zero / (real + zero):.1f}% of pairs on "
+                 "zero-compute experts")
+        if steps:
+            token_layers = tokens * layer_steps / steps
+            said += (f": {real / token_layers:.3f} real + "
+                     f"{zero / token_layers:.3f} zero a token a layer")
+    return said
+
+
 def serving_section(metrics, spans) -> str:
     """Continuous-batching serve tier: pool/queue gauges, stream
-    counters, TTFT/per-token latency histograms, and the per-phase span
-    table (one serve/tick per beat and its phases)."""
+    counters, the expert layers' line (`moe_line`), TTFT/per-token
+    latency histograms, and the per-phase span table (one serve/tick per
+    beat and its phases)."""
     values = metrics.get("values", {})
     rows = [[k, values[k]] for k in SERVE_GAUGES + SERVE_NET_GAUGES
             + SERVE_COUNTERS if k in values]
     out = [_fmt_table(["metric", "value"], rows)]
+    moe = moe_line(values)
+    if moe:
+        out.append(moe)
     for hname, label in (("serve/ttft_ms", "ttft"),
                          ("serve/token_ms", "per-token")):
         h = metrics.get("histograms", {}).get(hname)
@@ -437,8 +475,13 @@ def self_check():
             problems.append(
                 f"obs_report: serving.GAUGES {serving.GAUGES} != "
                 f"renderer SERVE_GAUGES {SERVE_GAUGES} — update both")
-        from paddle_tpu.text.models import kimi_k2, olmo_hybrid
-        named = tuple(f"serve.{n}" for n in kimi_k2.MOE_STATS
+        from paddle_tpu.text.models import (kimi_k2, longcat_flash,
+                                            olmo_hybrid)
+        if longcat_flash.SCMOE_STATS[:len(kimi_k2.MOE_STATS)] \
+                != kimi_k2.MOE_STATS:
+            problems.append("obs_report: longcat_flash.SCMOE_STATS no "
+                            "longer starts with kimi_k2.MOE_STATS")
+        named = tuple(f"serve.{n}" for n in longcat_flash.SCMOE_STATS
                       + olmo_hybrid.LINEAR_STATS)
         if named != SERVE_NET_GAUGES:
             problems.append(
