@@ -998,6 +998,43 @@ def _check_latent_paged(sizes):
             "block_size": block}
 
 
+def _check_grouped_ffn(sizes):
+    """The grouped expert kernel at the widths of `sizes.latent`'s and
+    `sizes.scmoe`'s expert layers (rounded up to the lane tiles its gate
+    asks for), a decode step of `*_serve`'s slots and a 256-token bucket
+    each, a router's uniform draw over the whole width: the kernel's sum
+    against the plain `while` form's on the same bf16 operands."""
+    from paddle_tpu.nn.layer import experts
+
+    errs = {}
+    for name, cfg, serve in (("share", sizes.latent, sizes.latent_serve),
+                             ("scmoe", sizes.scmoe, sizes.scmoe_serve)):
+        H = -(-cfg.hidden_size // 128) * 128
+        I = -(-cfg.moe_intermediate_size // 128) * 128
+        first, n = cfg.experts_held
+        width = cfg.num_experts + getattr(cfg, "zero_experts", 0)
+        K = cfg.num_experts_per_tok
+        ks = jax.random.split(jax.random.PRNGKey(SEED + 5), 4)
+        gate, up, down = ((jax.random.normal(k, shape, jnp.float32) * 0.05)
+                          .astype(DTYPE) for k, shape in zip(
+            ks, ((n, H, I), (n, H, I), (n, I, H))))
+        rng = np.random.RandomState(SEED + 5)
+        for T in (serve[0], min(256, serve[4])):
+            x = jax.random.normal(ks[3], (T, H), jnp.float32).astype(DTYPE)
+            idx = jnp.asarray(np.stack([rng.permutation(width)[:K]
+                                        for _ in range(T)]), jnp.int32)
+            weights = jnp.asarray(rng.uniform(0.05, 0.5, (T, K)),
+                                  jnp.float32)
+            args = (x, idx, weights, jnp.ones((T,), bool), gate, up, down,
+                    first)
+            got, counts = experts._grouped_expert_ffn(*args)
+            want, counts_r = experts._routed_expert_ffn(*args)
+            if not (np.asarray(counts) == np.asarray(counts_r)).all():
+                raise AssertionError(f"{name} t{T}: pairs an expert differ")
+            errs[f"{name}_t{T}"] = _rel_err(got, want)
+    return errs
+
+
 def kernel_checks(sizes):
     """name -> thunk returning {tensor: normalized max error}."""
     checks = {
@@ -1010,6 +1047,7 @@ def kernel_checks(sizes):
         checks[f"paged_decode_s{chunk}_block{block or 'picked'}"] = \
             lambda c=chunk, b=block: _check_paged(sizes, c, b)
     checks["latent_paged_decode"] = lambda: _check_latent_paged(sizes)
+    checks["grouped_expert_ffn"] = lambda: _check_grouped_ffn(sizes)
     return checks
 
 

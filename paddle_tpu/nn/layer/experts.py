@@ -34,7 +34,29 @@ axis. This is the layer that serving runs (DeepSeek-V3 / Kimi-K2 style):
   for, whatever it asks. The rule that bounds the loop: with `T` tokens,
   `P = T * top_k` pairs at most are held, an expert's rows are padded to
   a whole block, so at most `ceil(P / M) + count` blocks of `M` rows
-  exist; the expectation is `T * top_k * count / num_experts` pairs.
+  exist; the expectation is `T * top_k * count / num_experts` pairs;
+- **two forms of the blocks' products, one arithmetic**. The plain form
+  (`_routed_expert_ffn`) is a `fori_loop` over the blocks in use: a pass
+  slices its expert's three matrices and multiplies, and since XLA
+  pipelines nothing across the passes of a `while`, every pass starts
+  its weight streams from nothing. The grouped form
+  (`_grouped_expert_ffn` -> `ops/pallas/grouped_ffn.py`) is ONE Pallas
+  kernel a layer: grid (blocks, tiles of the expert width), a block's
+  expert's tiles picked through scalar prefetch from the same order of
+  the pairs (made by counting there, not by a sort), so the next tile,
+  of this expert or the next, is fetched under the current product; x
+  and y stay whole in VMEM and a block's rows are gathered and summed
+  back by exact one-hot products on the MXU. bf16 operands, float32
+  accumulation and weights in both; only the order in which a token's
+  pairs are summed differs. The gate (`_grouped_kernel_eligible`) reads
+  what the call shows and nothing else: a backend that runs Pallas (a
+  TPU, or `FLAGS_pallas_interpret` for the CPU tests), bf16, `H` and `I`
+  whole lane tiles, at most 512 tokens (a block's work on y grows with
+  the tokens; past that the loop wins on the chip), and a cut that fits
+  VMEM with x and y whole; what it rejects
+  (`pallas.gate_reject.grouped_expert_ffn.{backend,dtype,shape,tokens,
+  vmem}`) takes the plain form, which is also the kernel's parity
+  oracle.
 
 `routed` also returns how many pairs each held expert got and, over the
 whole router (held or not), how many of the tokens' pairs fell on routed
@@ -73,7 +95,9 @@ def _routed_expert_ffn(x, idx, weights, valid, gate, up, down, first):
     x [T, H]; idx [T, K] i32 expert ids over the router's width; weights
     [T, K] f32; valid [T] bool (a pad row routes nowhere); gate/up
     [n, H, I], down [n, I, H]: experts first..first+n-1.
-    -> (y [T, H] f32, pairs per held expert [n] i32)."""
+    -> (y [T, H] f32, pairs per held expert [n] i32).
+    The plain form: a `while` over the row blocks in use, three slices
+    and three products a pass."""
     T, K = idx.shape
     n = gate.shape[0]
     M = block_rows(T)
@@ -106,6 +130,68 @@ def _routed_expert_ffn(x, idx, weights, valid, gate, up, down, first):
     y = jax.lax.fori_loop(0, last_block[-1], one_block,
                           jnp.zeros(x.shape, jnp.float32))
     return y, counts
+
+
+@jax.jit
+def _grouped_expert_ffn(x, idx, weights, valid, gate, up, down, first):
+    """`_routed_expert_ffn` with the blocks' products as one Pallas kernel
+    (ops/pallas/grouped_ffn.py). Array code here, for the static bound of
+    `ceil(P / M) + n` blocks: where each held pair sits in the blocks'
+    order, by counting (a pair's rank among its expert's pairs, in token
+    order as the stable sort gives it: no sort and no gather), the
+    counts, and every block's expert; the kernel gathers, multiplies and
+    sums."""
+    from ...ops.pallas.grouped_ffn import grouped_ffn, grouped_ffn_blocks
+    T, K = idx.shape
+    n = gate.shape[0]
+    M = block_rows(T)
+    local = idx - jnp.asarray(first, jnp.int32)
+    held = (local >= 0) & (local < n) & valid[:, None]
+    mine = (held[..., None] & (local[..., None] == jnp.arange(
+        n, dtype=jnp.int32))).reshape(T * K, n).astype(jnp.int32)
+    upto = jnp.cumsum(mine, axis=0)            # pairs of expert e so far
+    counts = upto[-1]                                          # [n]
+    blocks = (counts + (M - 1)) // M                       # per expert
+    last_block = jnp.cumsum(blocks)
+    n_live = last_block[-1]
+    # a pair's row: its expert's first block's first row + its rank
+    pair_row = jnp.sum(mine * (upto - 1 + ((last_block - blocks) * M)[None]),
+                       axis=1).reshape(T, K)
+    j = jnp.arange(grouped_ffn_blocks(T, K, n, M), dtype=jnp.int32)
+    # a block not in use names the last live block's expert: its index
+    # repeats and nothing is fetched for it
+    e = jnp.minimum(jnp.searchsorted(
+        last_block, jnp.minimum(j, jnp.maximum(n_live - 1, 0)),
+        side="right"), n - 1).astype(jnp.int32)
+    y = grouped_ffn(x, jnp.where(held, pair_row, -1), weights, e, n_live,
+                    gate, up, down, rows=M)
+    return y, counts
+
+
+def _grouped_kernel_eligible(x, gate):
+    """The grouped kernel's gate, by what the call shows: a backend that
+    runs Pallas, bf16 operands, `H` and `I` whole lane tiles, no more
+    tokens than the kernel wins at, and a cut that fits VMEM with x and y
+    whole. Every rejection is counted
+    (`pallas.gate_reject.grouped_expert_ffn.{reason}`) and the call takes
+    the plain form."""
+    from ...ops.pallas import gate_reject
+    from ...ops.pallas.grouped_ffn import (GROUPED_FFN_MAX_TOKENS,
+                                           grouped_ffn_tile)
+    from .. import functional as F
+    T, H = x.shape
+    I = gate.shape[2]
+    if not F._pallas_backend_ok():
+        return gate_reject("grouped_expert_ffn", "backend")
+    if x.dtype != jnp.bfloat16 or gate.dtype != jnp.bfloat16:
+        return gate_reject("grouped_expert_ffn", "dtype")
+    if H % 128 or I % 128:
+        return gate_reject("grouped_expert_ffn", "shape")
+    if T > GROUPED_FFN_MAX_TOKENS:
+        return gate_reject("grouped_expert_ffn", "tokens")
+    if not grouped_ffn_tile(T, H, I, block_rows(T), x.dtype.itemsize):
+        return gate_reject("grouped_expert_ffn", "vmem")
+    return True
 
 
 class RoutedExperts(Layer):
@@ -178,9 +264,26 @@ class RoutedExperts(Layer):
         idx, weights = self.route(x)
         # a zero-compute id lies past every held range: `_routed_expert_
         # ffn` gives it no row and no block, like an absent expert
-        y, counts = _routed_expert_ffn(
-            x, idx, weights, valid, self.gate._value, self.up._value,
-            self.down._value, self.first)
+        operands = (x, idx, weights, valid, self.gate._value,
+                    self.up._value, self.down._value, self.first)
+        if _grouped_kernel_eligible(x, self.gate._value):
+            from ...core import monitor
+            from ...ops.pallas import run_guarded
+            from ...ops.pallas.grouped_ffn import grouped_ffn_cut
+            # the cut this program compiles with, on the kernel's span and
+            # as gauges per token count (t128 is a decode step of 128 slots)
+            cut = grouped_ffn_cut(
+                x.shape[0], self.top_k, self.count, x.shape[1],
+                self.gate._value.shape[2], block_rows(x.shape[0]),
+                x.dtype.itemsize)
+            monitor.stat_set_many({
+                f"pallas.grouped_expert_ffn.{name}.t{x.shape[0]}": value
+                for name, value in cut.items()})
+            y, counts = run_guarded(
+                "grouped_expert_ffn",
+                lambda: _grouped_expert_ffn(*operands), **cut)
+        else:
+            y, counts = _routed_expert_ffn(*operands)
         zero = (idx >= self.num_experts) & valid[:, None]
         if self.zero_experts:
             with jax.named_scope("zero_experts"):

@@ -72,7 +72,11 @@ def test_kernels_phase_toy(interpret):
     assert result["interpreted"]
     assert set(result["errors_vs_jnp_reference"]) >= {
         "flash_causal", "flash_padding_bias", "fused_ce", "decode",
-        "paged_decode_s1_blockpicked", "latent_paged_decode"}
+        "paged_decode_s1_blockpicked", "latent_paged_decode",
+        "grouped_expert_ffn"}
+    # both nets' expert layers, a decode step and a bucket each
+    assert set(result["errors_vs_jnp_reference"]["grouped_expert_ffn"]) \
+        == {"share_t2", "share_t32", "scmoe_t2", "scmoe_t32"}
     # the toy's latent net through the interpreted kernel and without it
     assert set(result["errors_vs_jnp_reference"]["latent_paged_decode"]) \
         == {"out", "logits", "block_size"}
@@ -117,6 +121,8 @@ def _compile_kernels_for_v5e():
         paged_cut, paged_decode_attention, paged_write_token)
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.ops.pallas.fused_ce import fused_linear_cross_entropy
+    from paddle_tpu.ops.pallas.grouped_ffn import grouped_ffn_cut
+    from paddle_tpu.nn.layer import experts
     try:
         device = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
     except Exception as e:  # environment without a usable libtpu
@@ -184,6 +190,22 @@ def _compile_kernels_for_v5e():
             ((64,), i32))
     assert latent_paged_cut((64, 64, 1, 576), arena, 24, 2, 512) == {
         "blocks_per_step": 24, "grid_steps": 64, "live_bytes": 147456}
+    # the two shares' expert layers, the plan with the kernel: the decode
+    # step and the longest bucket the gate admits; a 1024-token prefill
+    # is the gate's to reject (`tokens`: the loop wins there on the chip)
+    for H, n, K, sizes in ((7168, 12, 8, (64, 512)),
+                           (6144, 16, 12, (128, 512))):
+        for T in sizes:
+            compile_for_v5e(
+                lambda *a: experts._grouped_expert_ffn(*a, 0),
+                ((T, H), bf16), ((T, K), i32), ((T, K), f32),
+                ((T,), jnp.bool_), ((n, H, 2048), bf16),
+                ((n, H, 2048), bf16), ((n, 2048, H), bf16))
+            cut = grouped_ffn_cut(T, K, n, H, 2048, experts.block_rows(T), 2)
+            assert cut["tile_bytes"] == 3 * H * 256 * 2, (T, H, cut)
+        assert not experts._grouped_kernel_eligible(
+            jax.ShapeDtypeStruct((1024, H), bf16),
+            jax.ShapeDtypeStruct((n, H, 2048), bf16))
     print("MOSAIC-OK")
 
 
@@ -369,7 +391,12 @@ def _compile_latent_steps_for_v5e(model="KimiK2", config="latent",
                 "pallas.hit.latent_paged_attention", 0),
             "rejects": monitor.stats(
                 "pallas.gate_reject.latent_paged_attention."),
-            "cut": monitor.stats("pallas.latent_paged_attention.")}))
+            "cut": monitor.stats("pallas.latent_paged_attention."),
+            "experts": monitor.stats("pallas.hit.").get(
+                "pallas.hit.grouped_expert_ffn", 0),
+            "experts_rejects": monitor.stats(
+                "pallas.gate_reject.grouped_expert_ffn."),
+            "experts_cut": monitor.stats("pallas.grouped_expert_ffn.")}))
     print("LATENT-STEPS-DONE")
 
 
@@ -415,6 +442,16 @@ def test_latent_serve_steps_hold_no_arena_copy_for_v5e():
     # the XLA block loop; attention within the chunk hits nothing new
     assert (prefill["s"], prefill["writer"], prefill["latent_attn"]) \
         == (2048, 0, 0)
+    # the expert layer (one of the two layers): the grouped kernel in the
+    # decode step, 20 blocks of 64 rows by 8 tiles of 256 columns; the
+    # 2048-token prefill is past the tokens it wins at and keeps the loop
+    assert (decode["experts"], decode["experts_rejects"]) == (1, {})
+    assert decode["experts_cut"] == {
+        "pallas.grouped_expert_ffn.rows_per_block.t64": 64,
+        "pallas.grouped_expert_ffn.tile_bytes.t64": 3 * 7168 * 256 * 2,
+        "pallas.grouped_expert_ffn.grid_steps.t64": 160}
+    assert (prefill["experts"], prefill["experts_rejects"]) == (
+        0, {"pallas.gate_reject.grouped_expert_ffn.tokens": 1})
 
 
 def test_scmoe_serve_steps_hold_no_arena_copy_for_v5e():
@@ -449,6 +486,15 @@ def test_scmoe_serve_steps_hold_no_arena_copy_for_v5e():
         "pallas.latent_paged_attention.live_bytes.b128": 147456}
     assert (prefill["s"], prefill["writer"], prefill["latent_attn"]) \
         == (2048, 0, 0)
+    # the four expert layers: the grouped kernel in the decode step, 28
+    # blocks of 128 rows by 8 tiles; the 2048-token prefill keeps the loop
+    assert (decode["experts"], decode["experts_rejects"]) == (4, {})
+    assert decode["experts_cut"] == {
+        "pallas.grouped_expert_ffn.rows_per_block.t128": 128,
+        "pallas.grouped_expert_ffn.tile_bytes.t128": 3 * 6144 * 256 * 2,
+        "pallas.grouped_expert_ffn.grid_steps.t128": 224}
+    assert (prefill["experts"], prefill["experts_rejects"]) == (
+        0, {"pallas.gate_reject.grouped_expert_ffn.tokens": 4})
 
 
 def _compile_hybrid_steps_for_v5e():

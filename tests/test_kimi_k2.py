@@ -141,6 +141,28 @@ def test_serve_loop_tokens_are_the_references_greedy(net):
             out, logits[len(prompt) - 1:-1].argmax(-1))
 
 
+def test_decode_step_reaches_the_grouped_expert_kernel_once_a_layer():
+    """With bf16 weights whose widths are whole lane tiles, every serve
+    program's expert layer is the grouped Pallas kernel (ops/pallas/
+    grouped_ffn.py): one hit an expert layer a trace, the prefill's and
+    the decode step's, and nothing is rejected."""
+    net = make_net("bfloat16", hidden_size=128, moe_intermediate_size=128)
+    ids = np.random.RandomState(3).randint(1, 256, 21 + 3)
+    off = forced_logits(net, ids, prompt_len=21, bucket=32)
+    paddle.set_flags({"FLAGS_pallas_interpret": True})
+    monitor.reset(prefix="pallas.")
+    try:
+        got = forced_logits(net, ids, prompt_len=21, bucket=32)
+    finally:
+        paddle.set_flags({"FLAGS_pallas_interpret": False})
+    layers = net.config.num_layers - net.config.first_k_dense_replace
+    assert monitor.stat_get("pallas.hit.grouped_expert_ffn") == 2 * layers
+    assert not monitor.stats("pallas.gate_reject.grouped_expert_ffn.")
+    assert monitor.stat_get("pallas.grouped_expert_ffn.rows_per_block.t1") \
+        == 16
+    assert rel_err(got, off) <= 2e-2
+
+
 def test_uncut_model_matches_uncut_reference():
     paddle.seed(3)
     cfg = KimiK2Config.tiny()            # every expert held

@@ -176,7 +176,10 @@ def pallas_rates(metrics) -> str:
     block of a slot); so has the token writer, for what a call of M
     slots moves (pallas.K.token_bytes.bM, the token operand as laid out,
     and .block_bytes.bM, the slots' blocks in and out). The pool's three
-    kernels, nn/kv_pool.py."""
+    kernels, nn/kv_pool.py. The grouped expert kernel
+    (nn/layer/experts.py) says its cut for a call of M tokens:
+    pallas.K.rows_per_block.tM, .tile_bytes.tM (the three weight tiles a
+    grid step fetches) and .grid_steps.tM."""
     per = defaultdict(lambda: {"hit": 0.0, "fallback": 0.0,
                                "gate_reject": 0.0, "reasons": []})
     cuts, writes = defaultdict(dict), defaultdict(dict)
@@ -193,11 +196,17 @@ def pallas_rates(metrics) -> str:
                 f"{kind}:{'.'.join(parts[3:])}={int(v)}")
         elif len(parts) == 4 and parts[2] in (
                 "heads_per_step", "blocks_per_step", "grid_steps",
-                "live_bytes"):
+                "live_bytes", "rows_per_block", "tile_bytes"):
             cuts[kind, parts[3]][parts[2]] = int(v)
         elif len(parts) == 4 and parts[2] in ("token_bytes", "block_bytes"):
             writes[kind, parts[3]][parts[2]] = v
     for (k, shape), cut in sorted(cuts.items()):
+        if "rows_per_block" in cut:
+            per[k]["reasons"].append(
+                f"cut:{shape}={cut['rows_per_block']}rows/blockx"
+                f"{cut.get('grid_steps', '?')}steps,"
+                f"{cut.get('tile_bytes', 0) / 1e3:.0f}KB/step")
+            continue
         held = f"{cut['blocks_per_step']}blocks" \
             if "blocks_per_step" in cut \
             else f"{cut.get('heads_per_step', '?')}heads"
