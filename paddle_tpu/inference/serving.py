@@ -93,7 +93,7 @@ GAUGES = ("serve.queue_depth", "serve.active_slots",
           "serve.kv_pool_used_blocks", "serve.kv_pool_free_blocks",
           "serve.model_version", "serve.decode_tokens",
           "serve.prefill_dispatches", "serve.prefill_tokens",
-          "serve.admitted", "serve.queue_wait_s",
+          "serve.prefill_rows", "serve.admitted", "serve.queue_wait_s",
           "serve.state_slots_used", "serve.state_bytes", "serve.steps")
 COUNTERS = ("serve.preempted", "serve.tokens_generated",
             "serve.requests_completed", "serve.requests_errored",
@@ -254,6 +254,7 @@ def build_decode_step(net, temperature=0.0, top_k=None):
     layer gets its own tuple. Exposed at module level so
     tools/hlo_evidence.py can AOT-lower the PRODUCTION step — the
     evidence cannot drift from the loop."""
+    import jax
     import jax.numpy as jnp
 
     from ..core import tape as _tape
@@ -269,7 +270,8 @@ def build_decode_step(net, temperature=0.0, top_k=None):
             logits, new_caches, *counted = net._forward_paged(
                 tokens[:, None], paged_caches(spec, arenas, block_tables,
                                               lengths))
-            nxt = samp(logits, keys, lengths + jnp.int32(1))
+            with jax.named_scope("sample"):
+                nxt = samp(logits, keys, lengths + jnp.int32(1))
         return (cache_arenas(new_caches), nxt, *counted)
 
     return decode_step
@@ -282,6 +284,7 @@ def _build_prefill(net, temperature, top_k):
     carry at `slot`. (params, buffers, arenas, tokens, bt_row, ids,
     real_len, key, slot) -> ((new_arenas, new_tokens), first_token),
     then what `_forward_paged` counted, as in `build_decode_step`."""
+    import jax
     import jax.numpy as jnp
 
     from ..core import tape as _tape
@@ -302,9 +305,10 @@ def _build_prefill(net, temperature, top_k):
                                   bt_row, jnp.zeros((1,), jnp.int32))
             logits, new_caches, *counted = net._forward_paged(
                 ids, caches, last_index=jnp.reshape(real_len, (1,)) - 1)
-            first = samp(logits, key[None], jnp.reshape(real_len,
-                                                        (1,)))[0]
-            tokens = tokens.at[slot].set(first)
+            with jax.named_scope("sample"):
+                first = samp(logits, key[None], jnp.reshape(real_len,
+                                                            (1,)))[0]
+                tokens = tokens.at[slot].set(first)
             arenas = put_slot_rows(spec, arenas, cache_arenas(new_caches),
                                    slot)
         return ((arenas, tokens), first, *counted)
@@ -382,6 +386,7 @@ class ServeLoop:
         self._decode_tokens = 0       # tokens appended from decode beats
         self._prefill_dispatches = 0  # re-prefill after preemption too
         self._prefill_tokens = 0      # prompt tokens sent to prefill
+        self._prefill_rows = 0        # rows dispatched: the buckets' sizes
         self._admitted = 0            # first admissions
         self._queue_wait_s = 0.0      # sum of t_admit - t_submit
         # what the net's layers count (`serve_counters`), by the names
@@ -468,6 +473,7 @@ class ServeLoop:
             "decode_tokens": self._decode_tokens,
             "prefill_dispatches": self._prefill_dispatches,
             "prefill_tokens": self._prefill_tokens,
+            "prefill_rows": self._prefill_rows,
             "admitted": self._admitted,
             "queue_wait_s": self._queue_wait_s,
             "block_size": self._bs,
@@ -661,8 +667,16 @@ class ServeLoop:
         GPT._generate_cached)."""
         if key in self._traced:
             return fn(*args)
+        from ..core import program_map
         try:
-            return fn(*args)
+            # the program's map of instruction -> scope, for whoever reads
+            # a device trace: shapes before the call (the arenas are
+            # donated), the executable the call made looked up after it
+            arg_shapes = program_map.shapes(args)
+            out = fn(*args)
+            program_map.note("serve/" + "/".join(map(str, key)), fn,
+                             arg_shapes)
+            return out
         finally:
             self.net.load_functional_state(self._params, self._buffers)
             self._traced.add(key)
@@ -697,6 +711,7 @@ class ServeLoop:
             self._arenas, self._tokens = carry
         self._prefill_dispatches += 1
         self._prefill_tokens += s_real
+        self._prefill_rows += bucket
         slot.length = s_real
         self._pending.append(("prefill", handles, req, idx,
                               slot.version, s_real))
@@ -937,6 +952,7 @@ class ServeLoop:
             "serve.decode_tokens": self._decode_tokens,
             "serve.prefill_dispatches": self._prefill_dispatches,
             "serve.prefill_tokens": self._prefill_tokens,
+            "serve.prefill_rows": self._prefill_rows,
             "serve.admitted": self._admitted,
             "serve.queue_wait_s": self._queue_wait_s,
             "serve.state_slots_used": self._state_slots_used(),

@@ -261,7 +261,31 @@ class RoutedExperts(Layer):
     def routed(self, x, valid=None):
         if valid is None:
             valid = jnp.ones((x.shape[0],), bool)
-        idx, weights = self.route(x)
+        with jax.named_scope("router"):
+            idx, weights = self.route(x)
+        with jax.named_scope("experts"):
+            y, counts = self._held_products(x, idx, weights, valid)
+            zero = (idx >= self.num_experts) & valid[:, None]
+            if self.zero_experts:
+                with jax.named_scope("zero_experts"):
+                    w_zero = jnp.sum(jnp.where(zero, weights, 0.0), axis=-1)
+                    y = y + w_zero[:, None] * x.astype(jnp.float32)
+        if self.shared_width:
+            with jax.named_scope("ffn"):
+                y = y + _swiglu(x, self.shared_gate._value,
+                                self.shared_up._value,
+                                self.shared_down._value)
+        with jax.named_scope("router"):    # what the routing came to
+            real = jnp.sum((idx < self.num_experts) & valid[:, None],
+                           axis=-1, dtype=jnp.int32)           # a token
+            pairs = jnp.stack([jnp.sum(real),
+                               jnp.sum(zero, dtype=jnp.int32),
+                               jnp.sum(real * real)])
+        return y.astype(x.dtype), counts, pairs
+
+    def _held_products(self, x, idx, weights, valid):
+        """Σ over the held experts a token chose, by the form the gate
+        picks -> (y [T, H] f32, pairs per held expert [count] i32)."""
         # a zero-compute id lies past every held range: `_routed_expert_
         # ffn` gives it no row and no block, like an absent expert
         operands = (x, idx, weights, valid, self.gate._value,
@@ -279,24 +303,10 @@ class RoutedExperts(Layer):
             monitor.stat_set_many({
                 f"pallas.grouped_expert_ffn.{name}.t{x.shape[0]}": value
                 for name, value in cut.items()})
-            y, counts = run_guarded(
+            return run_guarded(
                 "grouped_expert_ffn",
                 lambda: _grouped_expert_ffn(*operands), **cut)
-        else:
-            y, counts = _routed_expert_ffn(*operands)
-        zero = (idx >= self.num_experts) & valid[:, None]
-        if self.zero_experts:
-            with jax.named_scope("zero_experts"):
-                w_zero = jnp.sum(jnp.where(zero, weights, 0.0), axis=-1)
-                y = y + w_zero[:, None] * x.astype(jnp.float32)
-        if self.shared_width:
-            y = y + _swiglu(x, self.shared_gate._value,
-                            self.shared_up._value, self.shared_down._value)
-        real = jnp.sum((idx < self.num_experts) & valid[:, None], axis=-1,
-                       dtype=jnp.int32)                        # a token
-        pairs = jnp.stack([jnp.sum(real), jnp.sum(zero, dtype=jnp.int32),
-                           jnp.sum(real * real)])
-        return y.astype(x.dtype), counts, pairs
+        return _routed_expert_ffn(*operands)
 
     def forward(self, x):
         from ...core.tensor import Tensor

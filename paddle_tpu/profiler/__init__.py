@@ -191,12 +191,18 @@ def stop_xplane():
 @contextlib.contextmanager
 def xplane_trace(log_dir: str):
     """Capture an XLA device trace (the CUPTI-correlation analog,
-    reference platform/device_tracer.h:43)."""
+    reference platform/device_tracer.h:43). On exit the compiled serve
+    programs' maps from instruction to scope (core/program_map.py) are
+    written beside it as `program_map.json`, so that another process can
+    tell the timeline's `fusion.123` by the work it belongs to
+    (benchmark/inspect_scopes.py)."""
+    from ..core import program_map
     start_xplane(log_dir)
     try:
         yield
     finally:
         stop_xplane()
+        program_map.dump(log_dir)
 
 
 # -- achieved-FLOPs accounting ---------------------------------------------

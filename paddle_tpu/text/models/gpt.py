@@ -43,19 +43,22 @@ class GPTBlock(nn.Layer):
         self.drop = nn.Dropout(cfg.dropout)
 
     def forward(self, x, cache=None):
-        h = self.ln1(x)
-        if cache is not None:
-            # StaticKVCache path: positions are tracked by the cache index,
-            # masking happens against the cache — no is_causal needed
-            a, cache = self.attn(h, cache=cache)
-            x = x + a
-        else:
-            # is_causal (not a materialized [s,s] mask) keeps the Pallas
-            # flash kernel's in-kernel triangular masking + block skipping
-            # eligible
-            x = x + self.attn(h, is_causal=True)
-        h = self.ln2(x)
-        x = x + self.drop(self.fc2(F.gelu(self.fc1(h))))
+        with jax.named_scope("attn"):
+            h = self.ln1(x)
+            if cache is not None:
+                # StaticKVCache path: positions are tracked by the cache
+                # index, masking happens against the cache — no is_causal
+                # needed
+                a, cache = self.attn(h, cache=cache)
+                x = x + a
+            else:
+                # is_causal (not a materialized [s,s] mask) keeps the
+                # Pallas flash kernel's in-kernel triangular masking +
+                # block skipping eligible
+                x = x + self.attn(h, is_causal=True)
+        with jax.named_scope("ffn"):
+            h = self.ln2(x)
+            x = x + self.drop(self.fc2(F.gelu(self.fc1(h))))
         return x if cache is None else (x, cache)
 
 
@@ -152,23 +155,26 @@ class GPT(nn.Layer):
         # k/v writes already land in the trash block, so the position
         # embedding only needs to stay in range
         pos = jnp.clip(pos, 0, self.config.max_seq_len - 1)
-        x = self.wte(ids) + self.wpe(Tensor(pos, _internal=True))
-        x = self.drop(x)
+        with jax.named_scope("embed"):
+            x = self.wte(ids) + self.wpe(Tensor(pos, _internal=True))
+            x = self.drop(x)
         new_caches = []
-        for blk, c in zip(self.blocks, caches):
-            x, c = blk(x, cache=c)
+        for i, (blk, c) in enumerate(zip(self.blocks, caches)):
+            with jax.named_scope(f"layer{i}"):
+                x, c = blk(x, cache=c)
             new_caches.append(c)
-        x = self.ln_f(x)
-        h = x._value
-        if last_index is not None:
-            idx = jnp.asarray(last_index, jnp.int32).reshape(-1)
-            h = jnp.take_along_axis(
-                h, idx[:, None, None].astype(jnp.int32),
-                axis=1)[:, 0]
-        else:
-            h = h[:, -1]
-        logits = ops.matmul(Tensor(h, _internal=True), self.wte.weight,
-                            transpose_y=True)
+        with jax.named_scope("head"):
+            x = self.ln_f(x)
+            h = x._value
+            if last_index is not None:
+                idx = jnp.asarray(last_index, jnp.int32).reshape(-1)
+                h = jnp.take_along_axis(
+                    h, idx[:, None, None].astype(jnp.int32),
+                    axis=1)[:, 0]
+            else:
+                h = h[:, -1]
+            logits = ops.matmul(Tensor(h, _internal=True), self.wte.weight,
+                                transpose_y=True)
         return logits._value, new_caches
 
     def generate(self, input_ids, max_new_tokens=32, temperature=1.0,

@@ -302,18 +302,20 @@ class LatentAttention(_Weights):
         x. With a `PagedLatentCache`: the chunk's latents are written,
         then s > 1 attends within the chunk (decompressed) and s == 1
         over the slot's cache (absorbed). -> (out, new cache or None)."""
-        q_nope, q_r, latent = self._project(x, cos, sin)
-        if cache is None:
-            return self._chunk(q_nope, q_r, latent), None
         from ...nn.kv_pool import PagedLatentCache, write_kv
-        lens = jnp.asarray(cache.lengths, jnp.int32)
-        latent = latent.astype(cache.kv.dtype)  # attend to what is cached
-        cache = PagedLatentCache(
-            write_kv(cache.kv, cache.block_tables, lens,
-                     latent[:, :, None, :]), cache.block_tables, lens)
-        out = self._chunk(q_nope, q_r, latent) if x.shape[1] > 1 \
-            else self._absorbed(q_nope, q_r, cache)
-        return out, cache._replace(lengths=lens + jnp.int32(x.shape[1]))
+        with jax.named_scope("attn"):
+            q_nope, q_r, latent = self._project(x, cos, sin)
+            if cache is None:
+                return self._chunk(q_nope, q_r, latent), None
+            lens = jnp.asarray(cache.lengths, jnp.int32)
+            latent = latent.astype(cache.kv.dtype)  # attend to what is cached
+            cache = PagedLatentCache(
+                write_kv(cache.kv, cache.block_tables, lens,
+                         latent[:, :, None, :]), cache.block_tables, lens)
+            out = self._chunk(q_nope, q_r, latent) if x.shape[1] > 1 \
+                else self._absorbed(q_nope, q_r, cache)
+            return out, cache._replace(
+                lengths=lens + jnp.int32(x.shape[1]))
 
 
 class DenseFFN(_Weights):
@@ -324,8 +326,9 @@ class DenseFFN(_Weights):
         self.down = self.matrix(W, H)
 
     def forward(self, x):
-        return _swiglu(x, self.gate._value, self.up._value,
-                       self.down._value).astype(x.dtype)
+        with jax.named_scope("ffn"):
+            return _swiglu(x, self.gate._value, self.up._value,
+                           self.down._value).astype(x.dtype)
 
 
 class KimiK2Block(_Weights):
@@ -347,17 +350,19 @@ class KimiK2Block(_Weights):
     def forward(self, x, cos, sin, cache=None, valid=None):
         """-> (y, new cache, pairs per held expert [count] i32, or None
         from a dense layer)."""
-        a, cache = self.attn(_rms(x, self.attn_norm._value, self.eps),
-                             cos, sin, cache)
-        h = x + a
-        f = _rms(h, self.ffn_norm._value, self.eps)
-        if not self.sparse:
-            return h + self.ffn(f), cache, None
-        b, s, H = f.shape
-        y, counts, _ = self.ffn.routed(
-            f.reshape(b * s, H),
-            None if valid is None else valid.reshape(b * s))
-        return h + y.reshape(b, s, H), cache, counts
+        with jax.named_scope("attn"):
+            a, cache = self.attn(_rms(x, self.attn_norm._value, self.eps),
+                                 cos, sin, cache)
+            h = x + a
+        with jax.named_scope("ffn"):   # `routed` names its own parts
+            f = _rms(h, self.ffn_norm._value, self.eps)
+            if not self.sparse:
+                return h + self.ffn(f), cache, None
+            b, s, H = f.shape
+            y, counts, _ = self.ffn.routed(
+                f.reshape(b * s, H),
+                None if valid is None else valid.reshape(b * s))
+            return h + y.reshape(b, s, H), cache, counts
 
 
 class _LatentDecoder(_Weights):
@@ -379,9 +384,10 @@ class _LatentDecoder(_Weights):
         self.head = self.matrix(cfg.hidden_size, cfg.vocab_size)
 
     def _logits(self, h):
-        h = _rms(h, self.norm._value, self.config.rms_norm_eps)
-        return jnp.dot(h, self.head._value,
-                       preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            h = _rms(h, self.norm._value, self.config.rms_norm_eps)
+            return jnp.dot(h, self.head._value,
+                           preferred_element_type=jnp.float32)
 
     def forward(self, input_ids):
         """Logits [b, s, vocab] (float32) of a whole sequence, no cache."""
@@ -446,11 +452,14 @@ class KimiK2(_LatentDecoder):
         return moe_counters(kind, counted[0], n_tokens)
 
     def _blocks(self, ids, pos, caches, valid):
-        x = jnp.take(self.embed._value, ids, axis=0)
-        cos, sin = _cos_sin(self.config, pos)
+        with jax.named_scope("embed"):
+            x = jnp.take(self.embed._value, ids, axis=0)
+            cos, sin = _cos_sin(self.config, pos)
         new_caches, counts = [], []
-        for blk, c in zip(self.blocks, caches or [None] * len(self.blocks)):
-            x, c, n = blk(x, cos, sin, c, valid)
+        for i, (blk, c) in enumerate(zip(
+                self.blocks, caches or [None] * len(self.blocks))):
+            with jax.named_scope(f"layer{i}"):
+                x, c, n = blk(x, cos, sin, c, valid)
             new_caches.append(c)
             if n is not None:
                 counts.append(n)

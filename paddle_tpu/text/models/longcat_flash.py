@@ -128,19 +128,23 @@ class LongCatFlashBlock(_Weights):
         new = []
         for i, (sub, cache) in enumerate(zip(self.sub, caches)):
             with jax.named_scope(f"sublayer{i}"):
-                a, cache = sub.attn(
-                    _rms(x, sub.attn_norm._value, self.eps), cos, sin, cache)
-                h = x + a
-                f = _rms(h, sub.ffn_norm._value, self.eps)
+                with jax.named_scope("attn"):
+                    a, cache = sub.attn(
+                        _rms(x, sub.attn_norm._value, self.eps), cos, sin,
+                        cache)
+                    h = x + a
+                with jax.named_scope("ffn"):
+                    f = _rms(h, sub.ffn_norm._value, self.eps)
             if i == 0:
                 with jax.named_scope("experts"):
                     m, counts, pairs = self.experts.routed(
                         f.reshape(b * s, H),
                         None if valid is None else valid.reshape(b * s))
-            with jax.named_scope(f"sublayer{i}"):
+            with jax.named_scope(f"sublayer{i}"), jax.named_scope("ffn"):
                 x = h + sub.ffn(f)
             new.append(cache)
-        return x + m.reshape(b, s, H), new, counts, pairs
+        with jax.named_scope("experts"):
+            return x + m.reshape(b, s, H), new, counts, pairs
 
 
 class LongCatFlash(_LatentDecoder):
@@ -178,12 +182,14 @@ class LongCatFlash(_LatentDecoder):
         """`caches`: two a layer, in layer order (None: no cache).
         Counted: pairs per held expert [layers, held] i32, and [layers,
         3] i32 (routed pairs, zero pairs, routed pairs squared)."""
-        x = jnp.take(self.embed._value, ids, axis=0)
-        cos, sin = _cos_sin(self.config, pos)
+        with jax.named_scope("embed"):
+            x = jnp.take(self.embed._value, ids, axis=0)
+            cos, sin = _cos_sin(self.config, pos)
         new_caches, counts, pairs = [], [], []
         for i, blk in enumerate(self.blocks):
-            x, c, n, p = blk(x, cos, sin, caches[2 * i:2 * i + 2]
-                             if caches else (None, None), valid)
+            with jax.named_scope(f"layer{i}"):
+                x, c, n, p = blk(x, cos, sin, caches[2 * i:2 * i + 2]
+                                 if caches else (None, None), valid)
             new_caches += c
             counts.append(n)
             pairs.append(p)

@@ -365,15 +365,19 @@ class OlmoHybridBlock(_Weights):
         of rows that hold a token (`_live_rows`), None for all."""
         dtype = self.ffn.gate._value.dtype
         mixer = self.mixer
-        mixed, cache = mixer.mix(*_live_rows(mixer.project, live, x),
-                                 cache, valid, last, live)
+        word = "linear_attn" if self.kind == LINEAR else "attn"
+        with jax.named_scope(word):
+            mixed, cache = mixer.mix(*_live_rows(mixer.project, live, x),
+                                     cache, valid, last, live)
 
         def rest(x, *mixed):
-            h = x + _rms(mixer.output(*mixed), self.mixer_norm._value,
-                         self.eps)
-            f = _swiglu(h.astype(dtype), self.ffn.gate._value,
-                        self.ffn.up._value, self.ffn.down._value)
-            return (h + _rms(f, self.ffn_norm._value, self.eps),)
+            with jax.named_scope(word):
+                h = x + _rms(mixer.output(*mixed), self.mixer_norm._value,
+                             self.eps)
+            with jax.named_scope("ffn"):
+                f = _swiglu(h.astype(dtype), self.ffn.gate._value,
+                            self.ffn.up._value, self.ffn.down._value)
+                return (h + _rms(f, self.ffn_norm._value, self.eps),)
 
         return _live_rows(rest, live, x, *mixed)[0], cache
 
@@ -415,17 +419,20 @@ class OlmoHybrid(_Weights):
                 "linear_prefill_pad_tokens": width - int(n_tokens)}
 
     def _blocks(self, ids, caches, valid, last, live=None):
-        x = jnp.take(self.embed._value, ids, axis=0).astype(F32)
+        with jax.named_scope("embed"):
+            x = jnp.take(self.embed._value, ids, axis=0).astype(F32)
         new_caches = []
-        for blk, c in zip(self.blocks, caches):
-            x, c = blk(x, c, valid, last, live)
+        for i, (blk, c) in enumerate(zip(self.blocks, caches)):
+            with jax.named_scope(f"layer{i}"):
+                x, c = blk(x, c, valid, last, live)
             new_caches.append(c)
         return x, new_caches
 
     def _logits(self, h):
-        h = _rms(h, self.norm._value, self.config.rms_norm_eps)
-        return jnp.dot(h.astype(self.head._value.dtype), self.head._value,
-                       preferred_element_type=F32)
+        with jax.named_scope("head"):
+            h = _rms(h, self.norm._value, self.config.rms_norm_eps)
+            return jnp.dot(h.astype(self.head._value.dtype),
+                           self.head._value, preferred_element_type=F32)
 
     def forward(self, input_ids):
         """Logits [b, s, vocab] (float32) of a whole sequence, no cache."""

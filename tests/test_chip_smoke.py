@@ -224,6 +224,41 @@ def _run_in_cpu_child(body, ok):
     return r.stdout
 
 
+def _scope_summary(text):
+    """What `core/program_map` makes of a program compiled for v5e: of the
+    entry computation's `fusion` / `while` / Pallas custom-call
+    instructions (the events that carry a decode step's device time), how
+    many lie under each vocabulary word ("unscoped": under none), and the
+    words each kernel's calls lie under."""
+    from paddle_tpu.core import program_map
+    ops = program_map.parse(text)["ops"]
+    entry = re.search(r"^ENTRY .*?\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    words, kernels = {}, {}
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?(\S+) = .*? (fusion|while|custom-call)\(",
+                     line)
+        if not m or (m.group(2) == "custom-call"
+                     and "tpu_custom_call" not in line):
+            continue
+        word = program_map.scope_of(ops.get(m.group(1))) or "unscoped"
+        words[word] = words.get(word, 0) + 1
+        if m.group(2) == "custom-call":
+            kernel = re.sub(r"[.\d]+$", "", m.group(1))
+            kernels[kernel] = sorted({word, *kernels.get(kernel, ())})
+    return {"words": words, "kernels": kernels}
+
+
+def _assert_scoped(step, must, kernels):
+    """A decode step compiled for v5e names its work: at least 90 % of its
+    top-level fusions, loops and kernels under a vocabulary word, the
+    words its net must show, each kernel's calls under its word alone."""
+    words = step["scopes"]["words"]
+    total = sum(words.values())
+    assert total - words.get("unscoped", 0) >= 0.9 * total, words
+    assert must <= set(words), (must, words)
+    assert step["scopes"]["kernels"] == kernels, step["scopes"]
+
+
 def test_kernels_compile_under_mosaic_for_v5e():
     """What interpret mode cannot see is Mosaic itself — 64-bit index
     maps, layouts, block shapes, VMEM. Results still need the chip."""
@@ -396,7 +431,8 @@ def _compile_latent_steps_for_v5e(model="KimiK2", config="latent",
                 "pallas.hit.grouped_expert_ffn", 0),
             "experts_rejects": monitor.stats(
                 "pallas.gate_reject.grouped_expert_ffn."),
-            "experts_cut": monitor.stats("pallas.grouped_expert_ffn.")}))
+            "experts_cut": monitor.stats("pallas.grouped_expert_ffn."),
+            "scopes": _scope_summary(text)}))
     print("LATENT-STEPS-DONE")
 
 
@@ -452,6 +488,12 @@ def test_latent_serve_steps_hold_no_arena_copy_for_v5e():
         "pallas.grouped_expert_ffn.grid_steps.t64": 160}
     assert (prefill["experts"], prefill["experts_rejects"]) == (
         0, {"pallas.gate_reject.grouped_expert_ffn.tokens": 1})
+    # the work's own name on the optimized instructions (PR 41): what a
+    # device trace's `fusion.123` is laid against (core/program_map.py)
+    _assert_scoped(decode, {"attn", "ffn", "router", "experts", "head"},
+                   {"_paged_write_once": ["attn"],
+                    "_latent_paged_call_once": ["attn"],
+                    "_grouped_ffn_call": ["experts"]})
 
 
 def test_scmoe_serve_steps_hold_no_arena_copy_for_v5e():
@@ -495,6 +537,10 @@ def test_scmoe_serve_steps_hold_no_arena_copy_for_v5e():
         "pallas.grouped_expert_ffn.grid_steps.t128": 224}
     assert (prefill["experts"], prefill["experts_rejects"]) == (
         0, {"pallas.gate_reject.grouped_expert_ffn.tokens": 4})
+    _assert_scoped(decode, {"attn", "ffn", "router", "experts", "head"},
+                   {"_paged_write_once": ["attn"],
+                    "_latent_paged_call_once": ["attn"],
+                    "_grouped_ffn_call": ["experts"]})
 
 
 def _compile_hybrid_steps_for_v5e():
@@ -563,7 +609,8 @@ def _compile_hybrid_steps_for_v5e():
                 text, pool.arena_shape(30, 128)),
             "temp_bytes": mem.temp_size_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes, "held_bytes": held,
-            "hits": {k.rsplit(".", 1)[1]: int(v) for k, v in hits.items()}}))
+            "hits": {k.rsplit(".", 1)[1]: int(v) for k, v in hits.items()},
+            "scopes": _scope_summary(text)}))
     print("HYBRID-STEPS-DONE")
 
 
@@ -588,6 +635,10 @@ def test_hybrid_serve_steps_update_state_and_arenas_in_place_for_v5e():
     assert decode["temp_bytes"] < 64e6, decode
     assert prefill["hits"] == {"gdn_chunk_scan": 3}
     assert prefill["temp_bytes"] < 1.0e9, prefill
+    _assert_scoped(decode, {"linear_attn", "attn", "ffn", "head"},
+                   {"_gdn_step_call": ["linear_attn"],
+                    "_paged_write_once": ["attn"],
+                    "_paged_call_once": ["attn"]})
 
 
 def test_autotune_lookup_never_measures_under_trace():

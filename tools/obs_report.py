@@ -238,7 +238,7 @@ SERVE_GAUGES = ("serve.queue_depth", "serve.active_slots",
                 "serve.kv_pool_used_blocks", "serve.kv_pool_free_blocks",
                 "serve.model_version", "serve.decode_tokens",
                 "serve.prefill_dispatches", "serve.prefill_tokens",
-                "serve.admitted", "serve.queue_wait_s",
+                "serve.prefill_rows", "serve.admitted", "serve.queue_wait_s",
                 "serve.state_slots_used", "serve.state_bytes", "serve.steps")
 # what a served net counts of its layers (its `SERVE_STATS`): a net with
 # expert layers (text/models/kimi_k2.MOE_STATS; with zero-compute experts
@@ -295,18 +295,30 @@ def moe_line(values):
     return said
 
 
+def prefill_line(values):
+    """`  prefill: ...`: the rows the prefills dispatched (the buckets'
+    sizes) beside the prompts' own tokens, and the share of the rows that
+    was padding. None before the first prefill."""
+    rows = values.get("serve.prefill_rows", 0)
+    if not rows:
+        return None
+    tokens = values.get("serve.prefill_tokens", 0)
+    return (f"  prefill: {tokens} prompt tokens in {rows} rows dispatched: "
+            f"{100.0 * (1.0 - tokens / rows):.1f}% padding")
+
+
 def serving_section(metrics, spans) -> str:
     """Continuous-batching serve tier: pool/queue gauges, stream
-    counters, the expert layers' line (`moe_line`), TTFT/per-token
+    counters, the prefills' rows and padding (`prefill_line`), the expert
+    layers' line (`moe_line`), TTFT/per-token
     latency histograms, and the per-phase span table (one serve/tick per
     beat and its phases)."""
     values = metrics.get("values", {})
     rows = [[k, values[k]] for k in SERVE_GAUGES + SERVE_NET_GAUGES
             + SERVE_COUNTERS if k in values]
     out = [_fmt_table(["metric", "value"], rows)]
-    moe = moe_line(values)
-    if moe:
-        out.append(moe)
+    out += [line for line in (prefill_line(values), moe_line(values))
+            if line]
     for hname, label in (("serve/ttft_ms", "ttft"),
                          ("serve/token_ms", "per-token")):
         h = metrics.get("histograms", {}).get(hname)
