@@ -518,13 +518,15 @@ def _serve_program_memory(loop, bucket):
     try:
         for name, lower in lowered.items():
             compiled = lower().compile()
+            text, mem = compiled.as_text(), compiled.memory_analysis()
             out[name] = {
-                "temp_bytes":
-                    int(compiled.memory_analysis().temp_size_in_bytes),
+                "temp_bytes": int(mem.temp_size_in_bytes),
+                "alias_bytes": int(mem.alias_size_in_bytes),
                 "arena_bytes": int(arena_bytes),
+                "arenas": sum(len(layer) for layer in loop._arenas),
                 "head_bytes": int(head_bytes),
-                "arena_relayouts": len(arena_relayouts(compiled.as_text(),
-                                                       arena.shape)),
+                "arena_relayouts": len(arena_relayouts(text, arena.shape)),
+                "conditionals": text.count(" conditional("),
             }
     finally:  # tracing rebinds the live layers' parameters to tracers
         loop.net.load_functional_state(loop._params, loop._buffers)
@@ -537,8 +539,11 @@ def latent_serve_programs(sizes):
     behind a ServeLoop of `sizes.latent_serve`) and TWO a layer
     (text/models/longcat_flash.py, `sizes.scmoe` / `sizes.scmoe_serve`,
     its programs under `scmoe_<name>`): the decode step and the largest
-    prefill bucket of each. The pool's one layout has to hold for the
-    one-head arena too: no copy or transpose of arena shape. The temp is
+    prefill bucket of each, which at full size works tile by tile
+    (`tiles`: how many the net cuts that bucket into, 0 for a bucket that
+    runs whole; a tile of queries past the first is a conditional). The
+    pool's one layout has to hold for the one-head arena too: no copy or
+    transpose of arena shape, every donated arena aliased. The temp is
     reported and not held to an arena's size: these are whole model
     programs, and their temps are activations (tests/test_chip_smoke.py
     holds the decode steps' to a bound ahead of time: the latent kernel
@@ -556,8 +561,10 @@ def latent_serve_programs(sizes):
         loop = ServeLoop(net, ServeConfig(
             max_active=slots, kv_blocks=blocks, block_size=block,
             max_seq_len=max_seq))
-        out.update((prefix + name, mem) for name, mem
-                   in _serve_program_memory(loop, bucket).items())
+        programs = _serve_program_memory(loop, bucket)
+        tile = net.prefill_tile(bucket)
+        programs[f"prefill{bucket}"]["tiles"] = bucket // tile if tile else 0
+        out.update((prefix + name, mem) for name, mem in programs.items())
         del net, loop
     return out
 
@@ -742,11 +749,23 @@ def serve_phase(sizes):
 
     programs_latent = latent_serve_programs(sizes)
     for name, mem in programs_latent.items():
-        if jax.default_backend() == "tpu" and mem["arena_relayouts"]:
+        if jax.default_backend() != "tpu":
+            continue
+        if mem["arena_relayouts"]:
             failures.append(
                 f"compiled latent-cache {name} program relays out the "
                 f"arena: {mem['arena_relayouts']} copy/transpose "
                 "instructions of arena shape")
+        if mem["alias_bytes"] < mem["arenas"] * mem["arena_bytes"]:
+            failures.append(
+                f"compiled latent-cache {name} program aliases "
+                f"{mem['alias_bytes']} B of {mem['arenas']} donated arenas "
+                f"of {mem['arena_bytes']} B")
+        if mem.get("tiles") and mem["conditionals"] < mem["tiles"] - 1:
+            failures.append(
+                f"compiled latent-cache {name} program is cut into "
+                f"{mem['tiles']} tiles and holds {mem['conditionals']} "
+                "conditionals: its queries' tiles run whole")
 
     hybrid = hybrid_serve(sizes)
     if hybrid["completed"] != len(sizes.hybrid_serve[4]):

@@ -58,7 +58,10 @@ nothing to do, and `serve/{retire,evict,hot_swap}`; request spans carry
 timeline of any running `jax.profiler` session (core/trace.py). Stamps
 `ServeRequest.{t_submit,t_admit,t_first,t_tokens,t_done}`. `stats()`
 counts, cumulative: `steps`, `decode_tokens`, `prefill_dispatches`,
-`prefill_tokens`, `admitted`, `queue_wait_s`, and whatever the served net
+`prefill_tokens`, `prefill_rows` (the buckets dispatched),
+`prefill_live_rows` (the rows computed: whole tiles of a net that cuts
+its buckets into tiles, `net.prefill_tile`, else the bucket),
+`admitted`, `queue_wait_s`, and whatever the served net
 names (`net.serve_counters`: the `moe_*` counts of a net with expert
 layers, the `linear_*` counts of one with recurrent layers), all mirrored
 as `serve.*` gauges beside `serve.{queue_depth,active_slots,
@@ -93,8 +96,9 @@ GAUGES = ("serve.queue_depth", "serve.active_slots",
           "serve.kv_pool_used_blocks", "serve.kv_pool_free_blocks",
           "serve.model_version", "serve.decode_tokens",
           "serve.prefill_dispatches", "serve.prefill_tokens",
-          "serve.prefill_rows", "serve.admitted", "serve.queue_wait_s",
-          "serve.state_slots_used", "serve.state_bytes", "serve.steps")
+          "serve.prefill_rows", "serve.prefill_live_rows", "serve.admitted",
+          "serve.queue_wait_s", "serve.state_slots_used", "serve.state_bytes",
+          "serve.steps")
 COUNTERS = ("serve.preempted", "serve.tokens_generated",
             "serve.requests_completed", "serve.requests_errored",
             "serve.hot_swaps", "serve.completion_log_errors",
@@ -387,6 +391,7 @@ class ServeLoop:
         self._prefill_dispatches = 0  # re-prefill after preemption too
         self._prefill_tokens = 0      # prompt tokens sent to prefill
         self._prefill_rows = 0        # rows dispatched: the buckets' sizes
+        self._prefill_live_rows = 0   # rows computed: the tiles with a token
         self._admitted = 0            # first admissions
         self._queue_wait_s = 0.0      # sum of t_admit - t_submit
         # what the net's layers count (`serve_counters`), by the names
@@ -474,6 +479,7 @@ class ServeLoop:
             "prefill_dispatches": self._prefill_dispatches,
             "prefill_tokens": self._prefill_tokens,
             "prefill_rows": self._prefill_rows,
+            "prefill_live_rows": self._prefill_live_rows,
             "admitted": self._admitted,
             "queue_wait_s": self._queue_wait_s,
             "block_size": self._bs,
@@ -712,6 +718,11 @@ class ServeLoop:
         self._prefill_dispatches += 1
         self._prefill_tokens += s_real
         self._prefill_rows += bucket
+        # what the program works through row by row: a net that cuts this
+        # bucket into tiles (`prefill_tile`) computes those with a token
+        tile = getattr(self.net, "prefill_tile", lambda bucket: None)(bucket)
+        self._prefill_live_rows += -(-s_real // tile) * tile if tile \
+            else bucket
         slot.length = s_real
         self._pending.append(("prefill", handles, req, idx,
                               slot.version, s_real))
@@ -953,6 +964,7 @@ class ServeLoop:
             "serve.prefill_dispatches": self._prefill_dispatches,
             "serve.prefill_tokens": self._prefill_tokens,
             "serve.prefill_rows": self._prefill_rows,
+            "serve.prefill_live_rows": self._prefill_live_rows,
             "serve.admitted": self._admitted,
             "serve.queue_wait_s": self._queue_wait_s,
             "serve.state_slots_used": self._state_slots_used(),

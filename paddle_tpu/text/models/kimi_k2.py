@@ -19,10 +19,16 @@ MLA:    c_q = RMSNorm(x W_qa);  q = c_q W_qb, per head [q_nope | q_rope];
 
 Two computation paths, the same mathematics:
 - a chunk of s > 1 tokens (a prefill) decompresses k and v for the chunk
-  and attends as multi-head attention, the queries in blocks so that the
-  scores of a 2048-token prompt never exist at once. It attends WITHIN
-  the chunk: a prefill starts an empty slot, which is how ServeLoop
-  prefills (a chunk appended to a non-empty cache is not supported);
+  and attends as multi-head attention, a tile of queries against the
+  keys up to its own tile, so that the scores of a 2048-token prompt never
+  exist at once and nothing above the diagonal's tiles is computed. It
+  attends WITHIN the chunk: a prefill starts an empty slot, which is how
+  ServeLoop prefills (a chunk appended to a non-empty cache is not
+  supported). A bucket of more than two tiles of `PREFILL_TILE` rows works
+  tile by tile over the tiles that hold a token (`_live_rows`: projections,
+  decompression, output projection, dense FFN, norms and residuals; the
+  queries' tiles) and leaves the rest of the bucket zero; the expert layer
+  runs once over the bucket, since its cost is its weights';
 - one token a slot (a decode step) absorbs W_kvb's key half into the
   query and its value half into the output, so all heads attend over the
   one cached vector a token: q~_h = q_nope,h W_kvb,h^K (512 wide),
@@ -176,40 +182,79 @@ def _cos_sin(cfg, pos):
     return jnp.cos(ang) * factor, jnp.sin(ang) * factor
 
 
+# rows of one step of a bucketed prefill's row-wise work, and of one tile
+# of queries in its attention: a bucket holds a prompt of any length over
+# its half, and what the rows past the prompt compute is thrown away
+PREFILL_TILE = 256
+
+
+def _tile_of(bucket, tile):
+    """`tile` where a prefill over `bucket` rows works tile by tile, None
+    where it runs whole: a bucket no larger than a tile, or no multiple."""
+    return tile if bucket > tile and bucket % tile == 0 else None
+
+
+def _live_rows(fn, live, tile, *xs):
+    """`fn` over `xs` ([b, s, ...] each; `fn` works row by row and returns
+    a tuple of [b, rows, ...]). `live` None: all of it at once. Else only
+    the first `live` (a traced count) tiles of `tile` rows are computed,
+    a tile a loop step; the rows of the others come out zero."""
+    if live is None:
+        return fn(*xs)
+    s = xs[0].shape[1]
+    like = jax.eval_shape(fn, *(x[:, :tile] for x in xs))
+    outs = tuple(jnp.zeros((y.shape[0], s) + y.shape[2:], y.dtype)
+                 for y in like)
+
+    def one_tile(i, outs):
+        ys = fn(*(jax.lax.dynamic_slice_in_dim(x, i * tile, tile, axis=1)
+                  for x in xs))
+        return tuple(jax.lax.dynamic_update_slice_in_dim(o, y, i * tile,
+                                                         axis=1)
+                     for o, y in zip(outs, ys))
+
+    return jax.lax.fori_loop(0, live, one_tile, outs)
+
+
 # jitted under a name of its own, so that a device trace can tell the
 # latent attention from the rest of a serve program
 @functools.partial(jax.jit, static_argnames=("scale", "q_block"))
-def _mla_chunk_attention(q_nope, q_r, k_nope, k_r, v, *, scale,
-                         q_block=512):
+def _mla_chunk_attention(q_nope, q_r, k_nope, k_r, v, live=None, *, scale,
+                         q_block=PREFILL_TILE):
     """Causal attention within a chunk, decompressed: q_nope/k_nope
     [b, s, h, dn], q_r [b, s, h, dr], k_r [b, s, dr] (one for all heads),
-    v [b, s, h, dv] -> [b, s, h, dv]. Queries go `q_block` at a time, so
-    the float32 scores are [b, h, q_block, s] and never [b, h, s, s]."""
-    b, s, h, _ = q_nope.shape
+    v [b, s, h, dv] -> [b, s, h, dv]. Queries go a tile of `q_block` at a
+    time and tile i reads the keys of tiles 0..i, the others being masked
+    for every one of its rows: the float32 scores are [b, h, q_block,
+    (i + 1) q_block] and never [b, h, s, s]. With `live` (a traced count)
+    only the first `live` tiles of queries are computed and the others
+    come out zero."""
+    s = q_nope.shape[1]
     qb = min(q_block, s)
     if s % qb:
         raise ValueError(f"chunk of {s} tokens is no multiple of {qb}")
-    col = jnp.arange(s, dtype=jnp.int32)
 
-    def one_block(i):
-        start = i * qb
-        qn = jax.lax.dynamic_slice_in_dim(q_nope, start, qb, axis=1)
-        qr = jax.lax.dynamic_slice_in_dim(q_r, start, qb, axis=1)
-        scores = (jnp.einsum("bqhd,bkhd->bhqk", qn, k_nope,
+    def one_tile(i):
+        rows, keys = slice(i * qb, (i + 1) * qb), slice(0, (i + 1) * qb)
+        scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope[:, rows],
+                             k_nope[:, keys],
                              preferred_element_type=jnp.float32)
-                  + jnp.einsum("bqhd,bkd->bhqk", qr, k_r,
+                  + jnp.einsum("bqhd,bkd->bhqk", q_r[:, rows], k_r[:, keys],
                                preferred_element_type=jnp.float32)) * scale
-        row = start + jnp.arange(qb, dtype=jnp.int32)
+        row = jnp.arange(rows.start, rows.stop, dtype=jnp.int32)
+        col = jnp.arange(keys.stop, dtype=jnp.int32)
         scores = jnp.where(col[None, :] <= row[:, None], scores, -1e9)
         p = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, v,
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v[:, keys],
                           preferred_element_type=jnp.float32
                           ).astype(v.dtype)
 
-    if qb == s:
-        return one_block(jnp.int32(0))
-    out = jax.lax.map(one_block, jnp.arange(s // qb, dtype=jnp.int32))
-    return jnp.moveaxis(out, 0, 1).reshape(b, s, h, v.shape[-1])
+    tiles = [one_tile(0)]                  # the first tile holds a token
+    for i in range(1, s // qb):
+        tiles.append(one_tile(i) if live is None else jax.lax.cond(
+            i < live, functools.partial(one_tile, i),
+            lambda: jnp.zeros_like(tiles[0])))
+    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=1)
 
 
 class _Weights(nn.Layer):
@@ -277,45 +322,67 @@ class LatentAttention(_Weights):
         return self.kv_b._value.reshape(self.rank, self.heads,
                                         self.dn + self.dv)
 
-    def _chunk(self, q_nope, q_r, latent):
-        b, s, _ = latent.shape
+    def project(self, x, cos, sin, cache=None):
+        """Row by row: `_project`, the latent rounded to what `cache`
+        keeps, and for a chunk (more rows than one, or no cache) the keys
+        and values it decompresses to, k_nope [b, s, h, dn] and v [b, s,
+        h, dv] -> (q_nope, q_r, latent[, k_nope, v])."""
+        q_nope, q_r, latent = self._project(x, cos, sin)
+        if cache is not None:
+            latent = latent.astype(cache.kv.dtype)  # attend to what is cached
+            if x.shape[1] == 1:
+                return q_nope, q_r, latent
         kv = jnp.einsum("bsc,chd->bshd", latent[..., :self.rank],
                         self._kv_b())
-        out = _mla_chunk_attention(
-            q_nope, q_r, kv[..., :self.dn], latent[..., self.rank:],
-            kv[..., self.dn:], scale=self.scale)
-        return out.reshape(b, s, self.heads * self.dv) @ self.o._value
+        return q_nope, q_r, latent, kv[..., :self.dn], kv[..., self.dn:]
+
+    def mix(self, q_nope, q_r, latent, k_nope=None, v=None, cache=None,
+            live=None):
+        """Across the rows, from `project`'s parts: the latents written
+        to a `PagedLatentCache`, then a chunk attends within itself
+        (decompressed; `live` tiles of queries of it, None: all) and one
+        token over its slot's cache (absorbed) -> (context [b, s, h dv],
+        new cache or None)."""
+        from ...nn.kv_pool import PagedLatentCache, write_kv
+        b, s = q_nope.shape[:2]
+        if cache is not None:
+            lens = jnp.asarray(cache.lengths, jnp.int32)
+            cache = PagedLatentCache(
+                write_kv(cache.kv, cache.block_tables, lens,
+                         latent[:, :, None, :]), cache.block_tables, lens)
+        if k_nope is not None:
+            ctx = _mla_chunk_attention(
+                q_nope, q_r, k_nope, latent[..., self.rank:], v, live,
+                scale=self.scale, q_block=PREFILL_TILE)
+        else:
+            ctx = self._absorbed(q_nope, q_r, cache)
+        if cache is not None:
+            cache = cache._replace(lengths=lens + jnp.int32(s))
+        return ctx.reshape(b, s, self.heads * self.dv), cache
 
     def _absorbed(self, q_nope, q_r, cache):
         from ...nn.kv_pool import latent_paged_attention
-        b, s = q_nope.shape[:2]
         w = self._kv_b()
         q_abs = jnp.einsum("bshd,chd->bhsc", q_nope, w[..., :self.dn])
         q = jnp.concatenate([q_abs, jnp.swapaxes(q_r, 1, 2)], axis=-1)
         ctx = latent_paged_attention(q, cache.kv, cache.block_tables,
                                      cache.lengths, self.scale, self.rank)
-        out = jnp.einsum("bhsc,chd->bshd", ctx, w[..., self.dn:])
-        return out.reshape(b, s, self.heads * self.dv) @ self.o._value
+        return jnp.einsum("bhsc,chd->bshd", ctx, w[..., self.dn:])
+
+    def output(self, ctx):
+        """Row by row: the output projection."""
+        return ctx @ self.o._value
 
     def forward(self, x, cos, sin, cache=None):
-        """Arrays in, arrays out. Without a cache: causal attention over
-        x. With a `PagedLatentCache`: the chunk's latents are written,
-        then s > 1 attends within the chunk (decompressed) and s == 1
-        over the slot's cache (absorbed). -> (out, new cache or None)."""
-        from ...nn.kv_pool import PagedLatentCache, write_kv
+        """Arrays in, arrays out: `project`, `mix` and `output` over all
+        the rows at once. Without a cache: causal attention over x. With a
+        `PagedLatentCache`: the chunk's latents are written, then s > 1
+        attends within the chunk and s == 1 over the slot's cache.
+        -> (out, new cache or None)."""
         with jax.named_scope("attn"):
-            q_nope, q_r, latent = self._project(x, cos, sin)
-            if cache is None:
-                return self._chunk(q_nope, q_r, latent), None
-            lens = jnp.asarray(cache.lengths, jnp.int32)
-            latent = latent.astype(cache.kv.dtype)  # attend to what is cached
-            cache = PagedLatentCache(
-                write_kv(cache.kv, cache.block_tables, lens,
-                         latent[:, :, None, :]), cache.block_tables, lens)
-            out = self._chunk(q_nope, q_r, latent) if x.shape[1] > 1 \
-                else self._absorbed(q_nope, q_r, cache)
-            return out, cache._replace(
-                lengths=lens + jnp.int32(x.shape[1]))
+            ctx, cache = self.mix(*self.project(x, cos, sin, cache),
+                                  cache=cache)
+            return self.output(ctx), cache
 
 
 class DenseFFN(_Weights):
@@ -329,6 +396,32 @@ class DenseFFN(_Weights):
         with jax.named_scope("ffn"):
             return _swiglu(x, self.gate._value, self.up._value,
                            self.down._value).astype(x.dtype)
+
+
+def _sublayer(attn, attn_norm, ffn_norm, ffn, eps, x, cos, sin, cache, live):
+    """A latent attention and what follows it row by row: h = x +
+    MLA(RMSNorm(x)), f = RMSNorm(h) -> (h + ffn(f), or h where `ffn` is
+    None and an expert layer takes f; f; new cache). `live`: the tiles of
+    `PREFILL_TILE` rows that hold a token (`_live_rows`), None for all:
+    the norms, `project` and `output`, the residuals and `ffn` run over
+    those, `mix` across the rows."""
+    def before(x, cos, sin):
+        with jax.named_scope("attn"):
+            return attn.project(_rms(x, attn_norm._value, eps), cos, sin,
+                                cache)
+
+    def after(x, ctx):
+        with jax.named_scope("attn"):
+            h = x + attn.output(ctx)
+        with jax.named_scope("ffn"):
+            f = _rms(h, ffn_norm._value, eps)
+            return (h if ffn is None else h + ffn(f)), f
+
+    parts = _live_rows(before, live, PREFILL_TILE, x, cos, sin)
+    with jax.named_scope("attn"):
+        ctx, cache = attn.mix(*parts, cache=cache, live=live)
+    y, f = _live_rows(after, live, PREFILL_TILE, x, ctx)
+    return y, f, cache
 
 
 class KimiK2Block(_Weights):
@@ -347,22 +440,22 @@ class KimiK2Block(_Weights):
             dtype=cfg.dtype, init_std=cfg.init_std) if self.sparse \
             else DenseFFN(cfg)
 
-    def forward(self, x, cos, sin, cache=None, valid=None):
+    def forward(self, x, cos, sin, cache=None, valid=None, live=None):
         """-> (y, new cache, pairs per held expert [count] i32, or None
-        from a dense layer)."""
-        with jax.named_scope("attn"):
-            a, cache = self.attn(_rms(x, self.attn_norm._value, self.eps),
-                                 cos, sin, cache)
-            h = x + a
+        from a dense layer). `live`: `_sublayer`'s; the expert layer runs
+        once over all the rows, its cost being its weights'."""
+        y, f, cache = _sublayer(
+            self.attn, self.attn_norm, self.ffn_norm,
+            None if self.sparse else self.ffn, self.eps, x, cos, sin, cache,
+            live)
+        if not self.sparse:
+            return y, cache, None
         with jax.named_scope("ffn"):   # `routed` names its own parts
-            f = _rms(h, self.ffn_norm._value, self.eps)
-            if not self.sparse:
-                return h + self.ffn(f), cache, None
             b, s, H = f.shape
-            y, counts, _ = self.ffn.routed(
+            m, counts, _ = self.ffn.routed(
                 f.reshape(b * s, H),
                 None if valid is None else valid.reshape(b * s))
-            return h + y.reshape(b, s, H), cache, counts
+            return y + m.reshape(b, s, H), cache, counts
 
 
 class _LatentDecoder(_Weights):
@@ -371,8 +464,8 @@ class _LatentDecoder(_Weights):
     final norm, an untied head, and `ServeLoop`'s protocol over what a
     subclass writes beside `paged_cache_spec`: `_block(i)`, layer i of
     the stack, and `_blocks`: (ids, pos, caches in spec order or None for
-    a pass without a cache, valid) -> (x, new caches, what the expert
-    layers counted: a tuple of arrays)."""
+    a pass without a cache, valid, live tiles or None) -> (x, new caches,
+    what the expert layers counted: a tuple of arrays)."""
 
     def __init__(self, cfg):
         super().__init__(cfg)
@@ -400,6 +493,15 @@ class _LatentDecoder(_Weights):
             x, *_ = self._blocks(ids.astype(jnp.int32), pos, None, None)
             return Tensor(self._logits(x), _internal=True)
 
+    def prefill_tile(self, bucket):
+        """`_tile_of` this net's tile: what `_forward_paged` cuts a bucket
+        into, and what `ServeLoop` counts the rows computed by. A bucket
+        of two tiles runs whole: it is the smallest that holds its prompt,
+        so both tiles are live, and a loop only fetches every weight
+        twice (4.7 ms of a 27 ms LongCat prefill; PERF.md section 6)."""
+        return _tile_of(bucket, PREFILL_TILE) \
+            if bucket > 2 * PREFILL_TILE else None
+
     def _forward_paged(self, input_ids, caches, last_index=None):
         """One paged prefill/decode pass, `GPT._forward_paged`'s contract
         over `PagedLatentCache`s, plus what the expert layers counted:
@@ -407,7 +509,9 @@ class _LatentDecoder(_Weights):
         first the pairs per held expert [expert layers, held] i32). Rows
         that no request owns (a slot whose table starts at the trash
         block, a prompt's padding past `last_index`) are cached into the
-        trash block like GPT's and are routed to no expert."""
+        trash block like GPT's and are routed to no expert. A bucket that
+        `prefill_tile` cuts into tiles computes those up to the last
+        prompt's end and leaves the rows of the others zero."""
         from ...core.tensor import Tensor
         from ...nn.kv_pool import TRASH_BLOCK
         ids = input_ids._value if isinstance(input_ids, Tensor) \
@@ -417,11 +521,16 @@ class _LatentDecoder(_Weights):
         step = jnp.arange(s, dtype=jnp.int32)[None]
         valid = jnp.broadcast_to(
             (caches[0].block_tables[:, :1] != TRASH_BLOCK), (b, s))
+        live = None
         if last_index is not None:
             last = jnp.asarray(last_index, jnp.int32).reshape(-1)
             valid = valid & (step <= last[:, None])
+            tile = self.prefill_tile(s)
+            if tile:
+                live = jnp.max(last) // tile + 1
         x, new_caches, counted = self._blocks(
-            ids.astype(jnp.int32), lens[:, None] + step, caches, valid)
+            ids.astype(jnp.int32), lens[:, None] + step, caches, valid,
+            live)
         h = x[:, -1] if last_index is None else jnp.take_along_axis(
             x, last[:, None, None], axis=1)[:, 0]
         return (self._logits(h), new_caches, *counted)
@@ -451,7 +560,7 @@ class KimiK2(_LatentDecoder):
         caches, the pairs each held expert got [expert layers, held]."""
         return moe_counters(kind, counted[0], n_tokens)
 
-    def _blocks(self, ids, pos, caches, valid):
+    def _blocks(self, ids, pos, caches, valid, live=None):
         with jax.named_scope("embed"):
             x = jnp.take(self.embed._value, ids, axis=0)
             cos, sin = _cos_sin(self.config, pos)
@@ -459,7 +568,7 @@ class KimiK2(_LatentDecoder):
         for i, (blk, c) in enumerate(zip(
                 self.blocks, caches or [None] * len(self.blocks))):
             with jax.named_scope(f"layer{i}"):
-                x, c, n = blk(x, cos, sin, c, valid)
+                x, c, n = blk(x, cos, sin, c, valid, live)
             new_caches.append(c)
             if n is not None:
                 counts.append(n)

@@ -23,7 +23,10 @@ and the normed latent c_kv times (hidden / kv_lora_rank)^1/2, which is
 cached scaled (`mla_scale_q_lora`, `mla_scale_kv_lora`). Plain RoPE, no
 scaling. A layer caches TWO latents a token: `paged_cache_spec()` yields
 two `PagedLatentCache` entries a layer, in layer order. Embedding, norm,
-head and `ServeLoop`'s protocol are `kimi_k2._LatentDecoder`'s.
+head and `ServeLoop`'s protocol are `kimi_k2._LatentDecoder`'s, and a
+sublayer up to its dense FFN is `kimi_k2._sublayer`: a bucketed prefill
+works tile by tile over the tiles that hold a token, the expert layer
+once over the bucket.
 
 Experts are `nn.RoutedExperts`: a softmax router over the routed experts
 AND `zero_experts` zero-compute experts (the identity on the layer's
@@ -40,7 +43,7 @@ import jax.numpy as jnp
 
 from ... import nn
 from .kimi_k2 import (MOE_STATS, DenseFFN, LatentAttention, _cos_sin,
-                      _LatentDecoder, _rms, _Weights, moe_counters)
+                      _LatentDecoder, _sublayer, _Weights, moe_counters)
 
 __all__ = ["LongCatFlash", "LongCatFlashConfig", "SCMOE_STATS"]
 
@@ -121,27 +124,24 @@ class LongCatFlashBlock(_Weights):
             zero_experts=cfg.zero_experts, dtype=cfg.dtype,
             init_std=cfg.init_std)
 
-    def forward(self, x, cos, sin, caches=(None, None), valid=None):
+    def forward(self, x, cos, sin, caches=(None, None), valid=None,
+                live=None):
         """-> (y, the two new caches, pairs per held expert [count] i32,
-        [3] i32: routed pairs, zero pairs, routed pairs squared)."""
+        [3] i32: routed pairs, zero pairs, routed pairs squared). `live`:
+        `kimi_k2._sublayer`'s tiles of rows that hold a token, None for
+        all; the expert layer runs once over all the rows."""
         b, s, H = x.shape
         new = []
         for i, (sub, cache) in enumerate(zip(self.sub, caches)):
             with jax.named_scope(f"sublayer{i}"):
-                with jax.named_scope("attn"):
-                    a, cache = sub.attn(
-                        _rms(x, sub.attn_norm._value, self.eps), cos, sin,
-                        cache)
-                    h = x + a
-                with jax.named_scope("ffn"):
-                    f = _rms(h, sub.ffn_norm._value, self.eps)
+                x, f, cache = _sublayer(
+                    sub.attn, sub.attn_norm, sub.ffn_norm, sub.ffn, self.eps,
+                    x, cos, sin, cache, live)
             if i == 0:
                 with jax.named_scope("experts"):
                     m, counts, pairs = self.experts.routed(
                         f.reshape(b * s, H),
                         None if valid is None else valid.reshape(b * s))
-            with jax.named_scope(f"sublayer{i}"), jax.named_scope("ffn"):
-                x = h + sub.ffn(f)
             new.append(cache)
         with jax.named_scope("experts"):
             return x + m.reshape(b, s, H), new, counts, pairs
@@ -178,7 +178,7 @@ class LongCatFlash(_LatentDecoder):
                     f"moe_{kind}_pairs_real_sq": int(real_sq)})
         return out
 
-    def _blocks(self, ids, pos, caches, valid):
+    def _blocks(self, ids, pos, caches, valid, live=None):
         """`caches`: two a layer, in layer order (None: no cache).
         Counted: pairs per held expert [layers, held] i32, and [layers,
         3] i32 (routed pairs, zero pairs, routed pairs squared)."""
@@ -189,7 +189,7 @@ class LongCatFlash(_LatentDecoder):
         for i, blk in enumerate(self.blocks):
             with jax.named_scope(f"layer{i}"):
                 x, c, n, p = blk(x, cos, sin, caches[2 * i:2 * i + 2]
-                                 if caches else (None, None), valid)
+                                 if caches else (None, None), valid, live)
             new_caches += c
             counts.append(n)
             pairs.append(p)

@@ -51,7 +51,7 @@ import numpy as np
 from ... import nn
 from ...nn import initializer as I
 from ...nn.layer.experts import _swiglu
-from .kimi_k2 import DenseFFN, _rms, _Weights
+from .kimi_k2 import DenseFFN, _live_rows, _rms, _tile_of, _Weights
 
 __all__ = ["OlmoHybrid", "OlmoHybridConfig", "LINEAR_STATS"]
 
@@ -101,28 +101,6 @@ class OlmoHybridConfig:
 # rows holds a prompt of any length over its half, and what the rows past
 # the prompt compute is thrown away
 PREFILL_TILE = 512
-
-
-def _live_rows(fn, live, *xs):
-    """`fn` over `xs` ([b, s, ...] each; `fn` works row by row and returns
-    a tuple of [b, rows, ...]). `live` None: all of it at once. Else only
-    the first `live` (a traced count) tiles of `PREFILL_TILE` rows are
-    computed, a tile a loop step; the rows of the others come out zero."""
-    if live is None:
-        return fn(*xs)
-    tile, s = PREFILL_TILE, xs[0].shape[1]
-    like = jax.eval_shape(fn, *(x[:, :tile] for x in xs))
-    outs = tuple(jnp.zeros((y.shape[0], s) + y.shape[2:], y.dtype)
-                 for y in like)
-
-    def one_tile(i, outs):
-        ys = fn(*(jax.lax.dynamic_slice_in_dim(x, i * tile, tile, axis=1)
-                  for x in xs))
-        return tuple(jax.lax.dynamic_update_slice_in_dim(o, y, i * tile,
-                                                         axis=1)
-                     for o, y in zip(outs, ys))
-
-    return jax.lax.fori_loop(0, live, one_tile, outs)
 
 
 # jitted under a name of its own, so that a device trace can tell the
@@ -367,8 +345,9 @@ class OlmoHybridBlock(_Weights):
         mixer = self.mixer
         word = "linear_attn" if self.kind == LINEAR else "attn"
         with jax.named_scope(word):
-            mixed, cache = mixer.mix(*_live_rows(mixer.project, live, x),
-                                     cache, valid, last, live)
+            mixed, cache = mixer.mix(
+                *_live_rows(mixer.project, live, PREFILL_TILE, x),
+                cache, valid, last, live)
 
         def rest(x, *mixed):
             with jax.named_scope(word):
@@ -379,7 +358,7 @@ class OlmoHybridBlock(_Weights):
                             self.ffn.up._value, self.ffn.down._value)
                 return (h + _rms(f, self.ffn_norm._value, self.eps),)
 
-        return _live_rows(rest, live, x, *mixed)[0], cache
+        return _live_rows(rest, live, PREFILL_TILE, x, *mixed)[0], cache
 
 
 class OlmoHybrid(_Weights):
@@ -445,6 +424,11 @@ class OlmoHybrid(_Weights):
                                 [None] * len(self.blocks), None, None)
             return Tensor(self._logits(x), _internal=True)
 
+    def prefill_tile(self, bucket):
+        """`kimi_k2._tile_of` this net's tile: what `_forward_paged` cuts
+        a bucket into, and what `ServeLoop` counts the rows computed by."""
+        return _tile_of(bucket, PREFILL_TILE)
+
     def _forward_paged(self, input_ids, caches, last_index=None):
         """One paged prefill/decode pass, `GPT._forward_paged`'s contract
         over the caches `paged_cache_spec` names, plus what
@@ -466,7 +450,7 @@ class OlmoHybrid(_Weights):
             last = jnp.asarray(last_index, jnp.int32).reshape(-1)
             valid = valid & (step <= last[:, None])
         live = None
-        if last is not None and s > PREFILL_TILE and s % PREFILL_TILE == 0:
+        if last is not None and self.prefill_tile(s):
             live = jnp.max(last) // PREFILL_TILE + 1
         x, new_caches = self._blocks(ids.astype(jnp.int32), caches, valid,
                                      last, live)

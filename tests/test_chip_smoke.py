@@ -65,6 +65,15 @@ def test_serve_phase_toy(interpret):
     assert hybrid["completed"] == 3 and hybrid["state_bytes"] > 0
     assert all(hybrid["hits"].get(k) for k in chip_smoke.HYBRID_KERNELS)
     assert hybrid["logits_err"] <= chip_smoke.LOGITS_TOL
+    # both latent nets' programs are reported; the toy's bucket of 32 is
+    # less than a tile and runs whole (the full size's: the v5e compiles)
+    latent = result["programs_latent"]
+    assert set(latent) == {"decode", "prefill32", "scmoe_decode",
+                           "scmoe_prefill32"}
+    assert latent["prefill32"]["tiles"] == latent["scmoe_prefill32"][
+        "tiles"] == 0
+    assert (latent["decode"]["arenas"], latent["scmoe_decode"]["arenas"]) \
+        == (2, 2)
 
 
 def test_kernels_phase_toy(interpret):
@@ -432,6 +441,9 @@ def _compile_latent_steps_for_v5e(model="KimiK2", config="latent",
             "experts_rejects": monitor.stats(
                 "pallas.gate_reject.grouped_expert_ffn."),
             "experts_cut": monitor.stats("pallas.grouped_expert_ffn."),
+            "instructions": len(re.findall(
+                r"^\s+(?:ROOT )?%?[\w.\-]+ = \S+ [\w\-]+\(", text, re.M)),
+            "conditionals": len(re.findall(r" conditional\(", text)),
             "scopes": _scope_summary(text)}))
     print("LATENT-STEPS-DONE")
 
@@ -452,12 +464,15 @@ def test_latent_serve_steps_hold_no_arena_copy_for_v5e():
     attention within the chunk: no kernel of the pool's) hold no copy or
     transpose of arena shape, and the donated arenas come back aliased.
     Unlike GPT's steps these are whole model programs, so their temps
-    are activations. The decode step's: 61 MB, under a block table's
-    worth of one slot's blocks (the plain-XLA latent attention gathered
-    24 blocks for each of 64 slots and scored them in float32: 475 MB
-    before the kernel, PR 38). The prefill's is held to what fits beside
-    9.7 GB of weights (the un-blocked scores of a 2048-token prompt alone
-    would be 1.07 GB), not to an arena's size."""
+    are activations. The decode step's: 3.2 MB (the plain-XLA latent
+    attention gathered 24 blocks for each of 64 slots and scored them in
+    float32: 475 MB before the kernel, PR 38). The prefill works tile by
+    tile over the tiles that hold a token (`kimi_k2._live_rows`) and a
+    tile of queries reads the keys up to its own tile: seven conditionals
+    a layer at 2048 rows in tiles of 256, and 484 MB of temporaries where
+    whole rows against all the keys held 562 MB (PR 42). The decode step
+    knows nothing of tiles: its instructions and its temporaries are
+    counted, and a PR that means to change it changes the counts."""
     out = _run_in_cpu_child("_compile_latent_steps_for_v5e",
                             "LATENT-STEPS-DONE")
     decode, prefill = (json.loads(line[5:]) for line in out.splitlines()
@@ -467,8 +482,10 @@ def test_latent_serve_steps_hold_no_arena_copy_for_v5e():
         assert step["alias_bytes"] >= 2 * step["arena_bytes"], step
         assert step["lane_padded"] == [], step   # 64 dense latents a call
         assert step["rejects"] == {}, step
-    assert decode["temp_bytes"] < 1e8, decode
-    assert prefill["temp_bytes"] < 1.5e9, prefill
+    assert (decode["temp_bytes"], decode["instructions"],
+            decode["conditionals"]) == (3_162_624, 1694, 0), decode
+    assert prefill["temp_bytes"] < 5.5e8, prefill
+    assert prefill["conditionals"] == 2 * (2048 // 256 - 1), prefill
     # the Pallas writer and the latent kernel, one a layer (two layers)
     assert (decode["s"], decode["writer"], decode["latent_attn"]) == (1, 2, 2)
     assert decode["cut"] == {
@@ -503,11 +520,12 @@ def test_scmoe_serve_steps_hold_no_arena_copy_for_v5e():
     latent kernel, each once a sublayer: 8 a trace) and the bucket-2048
     prefill hold no copy or transpose of arena shape, every donated
     arena comes back aliased, nothing is rejected. The decode step's
-    temporaries are 71 MB (its activations at 128 rows; held under 150
-    MB: one gather of the tables' blocks would be 128 x 24 x 147 KB =
-    453 MB). What the chip must hold: 12.16 GB of arguments (10.35 GB of
-    weights, 1.81 GB of arenas) and the prefill's 1.02 GB of temporaries:
-    13.2 GB of 16."""
+    temporaries are 52 MB (its activations at 128 rows; one gather of
+    the tables' blocks would be 128 x 24 x 147 KB = 453 MB), counted with
+    its instructions as the Kimi share's are. What the chip must hold:
+    12.16 GB of arguments (10.35 GB of weights, 1.81 GB of arenas) and the
+    prefill's 0.68 GB of temporaries (1.02 GB before the tiles, PR 42; 56
+    conditionals: seven a latent attention): 12.8 GB of 16."""
     out = _run_in_cpu_child("_compile_scmoe_steps_for_v5e",
                             "LATENT-STEPS-DONE")
     decode, prefill = (json.loads(line[5:]) for line in out.splitlines()
@@ -519,8 +537,10 @@ def test_scmoe_serve_steps_hold_no_arena_copy_for_v5e():
         assert step["lane_padded"] == [], step
         assert step["rejects"] == {}, step
         assert step["argument_bytes"] + step["temp_bytes"] < 14e9, step
-    assert decode["temp_bytes"] < 1.5e8, decode
-    assert prefill["temp_bytes"] < 1.5e9, prefill
+    assert (decode["temp_bytes"], decode["instructions"],
+            decode["conditionals"]) == (52_076_544, 5908, 0), decode
+    assert prefill["temp_bytes"] < 8e8, prefill
+    assert prefill["conditionals"] == 8 * (2048 // 256 - 1), prefill
     assert (decode["s"], decode["writer"], decode["latent_attn"]) == (1, 8, 8)
     assert decode["cut"] == {
         "pallas.latent_paged_attention.blocks_per_step.b128": 24,

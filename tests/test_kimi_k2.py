@@ -22,6 +22,7 @@ from paddle_tpu.text.models import (GPT, GPTConfig, KimiK2, KimiK2Config,
 from paddle_tpu.text.models.kimi_k2 import (LatentAttention, yarn_inv_freq,
                                             yarn_mscale)
 from paddle_tpu.text.models.reference import kimi_k2 as ref
+from test_olmo_hybrid import forced_logits as loop_forced_logits, small_loop
 
 HELD = (4, 8)            # experts 4..11 of the router's 16
 PUBLISHED_YARN = {"type": "yarn", "factor": 64, "beta_fast": 32,
@@ -124,6 +125,103 @@ def test_served_logits_match_reference(dtype, limit):
     assert got.shape == want.shape == (10, 256)
     for step in range(10):     # the prefill's logits, then 9 decode steps
         assert rel_err(got[step], want[step]) <= limit, step
+
+
+# 1, tile, tile + 1, bucket - 1, bucket: prompt lengths at the edges of a
+# 16-row tile and of the bucket of 256
+TILE_EDGES = [1, 16, 17, 255, 256]
+
+
+def check_live_tiles(net, monkeypatch, prompt_len):
+    """With tiles of 16 rows a bucket of 256 runs its row-wise work and
+    its queries' tiles over ceil(prompt_len / 16) tiles (`while`s and
+    conditionals under jit) and leaves the other rows zero; logits and
+    cached latents are those of the exact length computed whole. For both
+    latent nets (tests/test_longcat_flash.py too)."""
+    ids = np.random.RandomState(prompt_len).randint(1, 256, prompt_len)
+    spec = net.paged_cache_spec()
+    arenas = KVBlockPool(16, 16).arenas_for(spec)
+    table = jnp.asarray(np.arange(1, 17, dtype=np.int32)[None])
+    last = jnp.asarray([prompt_len - 1], jnp.int32)
+
+    def caches():
+        return paged_caches(spec, arenas, table, jnp.zeros((1,), jnp.int32))
+
+    def cached(caches):     # [layers, 256 tokens, width] by position
+        return np.stack([np.asarray(c.kv[1:17, 0]).transpose(0, 2, 1)
+                         .reshape(256, -1) for c in caches])
+
+    assert net.prefill_tile(256) is None        # 256 rows: one tile, whole
+    exact, exact_caches, *_ = net._forward_paged(
+        jnp.asarray(ids[None]), caches(), last_index=last)
+    monkeypatch.setattr(kimi_k2, "PREFILL_TILE", 16)
+    # one tile, and two (the smallest bucket that holds its prompt: both
+    # live), run whole
+    assert [net.prefill_tile(b) for b in (16, 32, 64, 256)] \
+        == [None, None, 16, 16]
+    padded = np.zeros((1, 256), np.int32)
+    padded[0, :prompt_len] = ids
+    try:
+        got, got_caches, *_ = jax.jit(
+            lambda ids, last: net._forward_paged(ids, caches(),
+                                                 last_index=last))(
+            jnp.asarray(padded), last)
+    finally:
+        net.load_functional_state(*net.functional_state())
+    assert rel_err(got, exact) < 1e-4
+    assert rel_err(cached(got_caches)[:, :prompt_len],
+                   cached(exact_caches)[:, :prompt_len]) < 1e-4
+    live = (prompt_len - 1) // 16 + 1
+    x, *_ = net._blocks(jnp.asarray(padded), jnp.arange(256)[None], caches(),
+                        jnp.arange(256)[None] < prompt_len, jnp.int32(live))
+    assert float(jnp.abs(x[:, :prompt_len]).max(axis=-1).min()) > 0
+    assert not np.asarray(x[:, live * 16:]).any()
+
+
+@pytest.mark.parametrize("prompt_len", TILE_EDGES)
+def test_a_prefill_computes_only_the_tiles_that_hold_a_token(
+        net, monkeypatch, prompt_len):
+    check_live_tiles(net, monkeypatch, prompt_len)
+
+
+def test_served_logits_match_reference_through_live_tiles(monkeypatch):
+    """ServeLoop's own prefill program over 3 tiles of a bucket of 4."""
+    monkeypatch.setattr(kimi_k2, "PREFILL_TILE", 16)
+    net = make_net()
+    params, _ = net.functional_state()
+    ids = np.random.RandomState(1).randint(1, 256, 35 + 9)
+    got = loop_forced_logits(net, small_loop(net), 1, ids, 35)  # bucket 64
+    want = np.asarray(ref.forward(params, ref_config(net.config, HELD), ids,
+                                  HELD))[34:]
+    assert rel_err(got, want) < 1e-4
+
+
+@pytest.mark.parametrize("live", [None, 1, 3, 4])
+def test_causal_key_tiles_equal_the_whole_key_form(live):
+    """A tile of queries against the keys up to its own tile is the
+    attention over all the keys, masked; under `live` the tiles past it
+    come out zero."""
+    rng = np.random.RandomState(5)
+    b, s, h, dn, dr, dv = 2, 64, 4, 16, 8, 16
+    q_nope, k_nope = rng.randn(2, b, s, h, dn).astype(np.float32)
+    q_r = rng.randn(b, s, h, dr).astype(np.float32)
+    k_r = rng.randn(b, s, dr).astype(np.float32)
+    v = rng.randn(b, s, h, dv).astype(np.float32)
+    scores = (np.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
+              + np.einsum("bqhd,bkd->bhqk", q_r, k_r)) * 0.2
+    scores = np.where(np.tril(np.ones((s, s), bool)), scores, -np.inf)
+    p = np.exp(scores - scores.max(axis=-1, keepdims=True))
+    want = np.einsum("bhqk,bkhd->bqhd", p / p.sum(axis=-1, keepdims=True), v)
+    got = kimi_k2._mla_chunk_attention(
+        *map(jnp.asarray, (q_nope, q_r, k_nope, k_r, v)),
+        None if live is None else jnp.int32(live), scale=0.2, q_block=16)
+    rows = s if live is None else live * 16
+    np.testing.assert_allclose(got[:, :rows], want[:, :rows], atol=1e-5)
+    assert not np.asarray(got[:, rows:]).any()
+    whole = kimi_k2._mla_chunk_attention(       # one tile: the old form
+        *map(jnp.asarray, (q_nope, q_r, k_nope, k_r, v)), scale=0.2,
+        q_block=64)
+    np.testing.assert_allclose(whole, want, atol=1e-5)
 
 
 def test_serve_loop_tokens_are_the_references_greedy(net):

@@ -20,14 +20,17 @@ from paddle_tpu.nn.kv_pool import (CacheSpec, KVBlockPool, PagedLatentCache,
                                    cache_arenas, paged_caches)
 from paddle_tpu.text.models import (GPT, GPTConfig, KimiK2Config,
                                     LongCatFlash, LongCatFlashConfig,
-                                    longcat_flash)
+                                    kimi_k2, longcat_flash)
 from paddle_tpu.text.models.kimi_k2 import (LatentAttention, _rms, _rope,
                                             yarn_inv_freq)
 from paddle_tpu.text.models.reference import longcat_flash as ref
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 import obs_report  # noqa: E402
-from test_kimi_k2 import forced_logits, rel_err  # noqa: E402
+from test_kimi_k2 import (TILE_EDGES, check_live_tiles,  # noqa: E402
+                          forced_logits, rel_err)
+from test_olmo_hybrid import (  # noqa: E402
+    forced_logits as loop_forced_logits, small_loop)
 
 HELD = (4, 8)            # routed experts 4..11 of 16; 8 zero experts after
 ROUTER = {"moe_topk": 12, "routed_scaling_factor": 6.0,
@@ -88,6 +91,26 @@ def test_served_logits_match_reference(dtype, limit):
     assert got.shape == want.shape == (10, 256)
     for step in range(10):     # the prefill's logits, then 9 decode steps
         assert rel_err(got[step], want[step]) <= limit, step
+
+
+@pytest.mark.parametrize("prompt_len", TILE_EDGES)
+def test_a_prefill_computes_only_the_tiles_that_hold_a_token(
+        net, monkeypatch, prompt_len):
+    """Two latent attentions and two dense FFNs a layer under the tiles,
+    the expert layer once over the bucket."""
+    check_live_tiles(net, monkeypatch, prompt_len)
+
+
+def test_served_logits_match_reference_through_live_tiles(monkeypatch):
+    """ServeLoop's own prefill program over 3 tiles of a bucket of 4."""
+    monkeypatch.setattr(kimi_k2, "PREFILL_TILE", 16)
+    net = make_net()
+    params, _ = net.functional_state()
+    ids = np.random.RandomState(1).randint(1, 256, 35 + 9)
+    got = loop_forced_logits(net, small_loop(net), 1, ids, 35)  # bucket 64
+    want = np.asarray(ref.forward(params, ref_config(net.config), ids,
+                                  HELD))[34:]
+    assert rel_err(got, want) < 1e-4
 
 
 def test_serve_loop_tokens_are_the_references_greedy(net):

@@ -1,6 +1,7 @@
 """core/program_map.py: the map from a compiled program's instructions to
 the scope they were made under, the programs `ServeLoop` notes, and the
-rows a prefill dispatches (`stats()["prefill_rows"]`).
+rows a prefill dispatches and computes (`stats()["prefill_rows"]`,
+`["prefill_live_rows"]`).
 
 On the CPU at toy size: what a map holds, that asking for it builds
 nothing, that a beat never touches it. What the compiler keeps of the
@@ -20,6 +21,7 @@ import paddle_tpu as paddle
 from paddle_tpu import profiler
 from paddle_tpu.core import monitor, program_map
 from paddle_tpu.inference import ServeConfig, ServeLoop
+from paddle_tpu.text.models import kimi_k2, olmo_hybrid
 from paddle_tpu.text.models.gpt import GPT, GPTConfig
 from paddle_tpu.text.models.kimi_k2 import KimiK2, KimiK2Config
 from paddle_tpu.text.models.longcat_flash import (LongCatFlash,
@@ -170,17 +172,54 @@ def test_prefill_rows_are_the_buckets_dispatched():
     assert stats["prefill_rows"] == sum(_rows(n) for n in lens) == 136
     assert stats["prefill_rows"] >= stats["prefill_tokens"]
     assert monitor.stat_get("serve.prefill_rows") == 136
+    # a net that cuts no bucket into tiles computes what it dispatches
+    assert stats["prefill_live_rows"] == 136
+    assert monitor.stat_get("serve.prefill_live_rows") == 136
     said = obs_report.serving_section(
         {"values": monitor.stats("serve.")}, [])
-    assert ("  prefill: 80 prompt tokens in 136 rows dispatched: 41.2% "
-            "padding") in said.splitlines()
+    assert ("  prefill: 80 prompt tokens in 136 rows: 41.2% padding "
+            "dispatched, 41.2% computed") in said.splitlines()
     assert obs_report.prefill_line({}) is None
+    # a dump from before the second gauge says the first share alone
+    assert obs_report.prefill_line(
+        {"serve.prefill_tokens": 80, "serve.prefill_rows": 136}) \
+        == "  prefill: 80 prompt tokens in 136 rows: 41.2% padding dispatched"
     # every program the loop traced is in the map under the scheduler's
     # own name for it, a prefill by its bucket
     assert program_map.labels() == ["serve/decode"] + sorted(
         f"serve/prefill/{b}" for b in {_rows(n) for n in lens})
     assert program_map.scopes("serve/decode")["module"] == "jit_decode_step"
     assert program_map.scopes("serve/prefill/64")["module"] == "jit_prefill"
+
+
+@pytest.mark.parametrize("kind, module", [("kimi", kimi_k2),
+                                          ("longcat", kimi_k2),
+                                          ("hybrid", olmo_hybrid)])
+def test_prefill_live_rows_are_the_tiles_that_hold_a_token(
+        kind, module, monkeypatch):
+    """A net that cuts a bucket into tiles (here of 16 rows) computes the
+    tiles up to the prompt's end; a bucket of one tile or less runs
+    whole."""
+    monkeypatch.setattr(module, "PREFILL_TILE", 16)
+    monitor.reset(prefix="serve.")
+    rng = np.random.RandomState(3)
+    lens = (5, 16, 17, 33, 40, 64)
+    loop = ServeLoop(_net(kind), ServeConfig(
+        max_active=2, kv_blocks=32, block_size=16, max_seq_len=128))
+    reqs = [loop.submit(rng.randint(1, 128, (n,)).astype(np.int64),
+                        max_new_tokens=2) for n in lens]
+    loop.run_until_idle()
+    assert all(len(r.out) == 2 for r in reqs)
+    stats = loop.stats()
+    assert stats["prefill_tokens"] == sum(lens) == 175
+    assert stats["prefill_rows"] == 8 + 16 + 32 + 64 + 64 + 64 == 248
+    # buckets 8 and 16 whole (and 32, two tiles both live, in the latent
+    # nets: the same count); 64, 64, 64: ceil(n / 16) * 16
+    assert stats["prefill_live_rows"] == 8 + 16 + 32 + 48 + 48 + 64 == 216
+    assert monitor.stat_get("serve.prefill_live_rows") == 216
+    assert obs_report.prefill_line(monitor.stats("serve.")) == (
+        "  prefill: 175 prompt tokens in 248 rows: 29.4% padding "
+        "dispatched, 19.0% computed")
 
 
 def test_a_beat_does_not_touch_the_map(monkeypatch):

@@ -238,7 +238,8 @@ SERVE_GAUGES = ("serve.queue_depth", "serve.active_slots",
                 "serve.kv_pool_used_blocks", "serve.kv_pool_free_blocks",
                 "serve.model_version", "serve.decode_tokens",
                 "serve.prefill_dispatches", "serve.prefill_tokens",
-                "serve.prefill_rows", "serve.admitted", "serve.queue_wait_s",
+                "serve.prefill_rows", "serve.prefill_live_rows",
+                "serve.admitted", "serve.queue_wait_s",
                 "serve.state_slots_used", "serve.state_bytes", "serve.steps")
 # what a served net counts of its layers (its `SERVE_STATS`): a net with
 # expert layers (text/models/kimi_k2.MOE_STATS; with zero-compute experts
@@ -297,14 +298,19 @@ def moe_line(values):
 
 def prefill_line(values):
     """`  prefill: ...`: the rows the prefills dispatched (the buckets'
-    sizes) beside the prompts' own tokens, and the share of the rows that
-    was padding. None before the first prefill."""
+    sizes) beside the prompts' own tokens, and the share that was padding:
+    of the rows dispatched, and of the rows computed (a net that cuts a
+    bucket into tiles computes those that hold a token; a dump from before
+    that gauge says the first alone). None before the first prefill."""
     rows = values.get("serve.prefill_rows", 0)
     if not rows:
         return None
     tokens = values.get("serve.prefill_tokens", 0)
-    return (f"  prefill: {tokens} prompt tokens in {rows} rows dispatched: "
-            f"{100.0 * (1.0 - tokens / rows):.1f}% padding")
+    said = (f"  prefill: {tokens} prompt tokens in {rows} rows: "
+            f"{100.0 * (1.0 - tokens / rows):.1f}% padding dispatched")
+    live = values.get("serve.prefill_live_rows", 0)
+    return said + f", {100.0 * (1.0 - tokens / live):.1f}% computed" \
+        if live else said
 
 
 def serving_section(metrics, spans) -> str:
