@@ -89,12 +89,15 @@ class Sizes:
     scmoe_serve: tuple           # (slots, blocks, block, max_seq, bucket)
     hybrid: object               # OlmoHybridConfig: one period, served
     hybrid_serve: tuple          # (slots, blocks, block, max_seq, prompts)
+    window: object               # LagunaConfig: a full and a sliding layer
+    window_serve: tuple          # (slots, blocks, block, max_seq)
 
     @staticmethod
     def full():
         from paddle_tpu.text.models.bert import BertConfig
         from paddle_tpu.text.models.gpt import GPTConfig
         from paddle_tpu.text.models.kimi_k2 import KimiK2Config
+        from paddle_tpu.text.models.laguna import LagunaConfig
         from paddle_tpu.text.models.longcat_flash import LongCatFlashConfig
         from paddle_tpu.text.models.olmo_hybrid import OlmoHybridConfig
         return Sizes(
@@ -140,13 +143,21 @@ class Sizes:
             hybrid=OlmoHybridConfig(
                 vocab_size=6272, dtype="bfloat16", max_seq_len=1024,
                 layer_types=["linear_attention"] * 3 + ["full_attention"]),
-            hybrid_serve=(4, 32, 128, 1024, (70, 200, 513)))
+            hybrid_serve=(4, 32, 128, 1024, (70, 200, 513)),
+            # the benchmark's share of Laguna-S-2.1 at its published
+            # widths, the leading full layer (48 query heads over 8) and
+            # one sliding layer (72 over 8, a ring of 512 tokens a slot),
+            # its slots, and a pool that pages one layer
+            window=LagunaConfig(vocab_size=12544, num_layers=2,
+                                experts_held=(0, 16), dtype="bfloat16"),
+            window_serve=(128, 1024, 128, 9216))
 
     @staticmethod
     def toy():
         from paddle_tpu.text.models.bert import BertConfig
         from paddle_tpu.text.models.gpt import GPTConfig
         from paddle_tpu.text.models.kimi_k2 import KimiK2Config
+        from paddle_tpu.text.models.laguna import LagunaConfig
         from paddle_tpu.text.models.longcat_flash import LongCatFlashConfig
         from paddle_tpu.text.models.olmo_hybrid import OlmoHybridConfig
         return Sizes(
@@ -169,7 +180,11 @@ class Sizes:
             hybrid=OlmoHybridConfig.tiny(
                 dtype="bfloat16", init_std=0.1,
                 layer_types=["linear_attention"] * 3 + ["full_attention"]),
-            hybrid_serve=(2, 8, 16, 64, (5, 14, 23)))
+            hybrid_serve=(2, 8, 16, 64, (5, 14, 23)),
+            window=LagunaConfig.tiny(num_layers=2, experts_held=(4, 8),
+                                     dtype="bfloat16", sliding_window=16,
+                                     ring_block=8),
+            window_serve=(2, 16, 8, 64))
 
 
 # --------------------------------------------------------------------------
@@ -1017,6 +1032,59 @@ def _check_latent_paged(sizes):
             "block_size": block}
 
 
+def _check_grouped_paged(sizes):
+    """The grouped-query form of the paged kernel at `sizes.window`'s
+    widths and `sizes.window_serve`'s slots, its two call sites: a full
+    layer's query heads over the pool's arenas under ragged tables
+    (`full`), and a sliding layer's over the slots' rings, some wrapped
+    and some not (`ring`); each against `paged_attention_ref` in float32
+    on the same operands."""
+    from paddle_tpu.nn.kv_pool import (KVBlockPool, _ring_arena,
+                                       _ring_tables, paged_attention_ref,
+                                       window_attention, window_ring_shape)
+    from paddle_tpu.ops.pallas.decode_attention import paged_decode_attention
+
+    cfg = sizes.window
+    slots, _, block, max_seq = sizes.window_serve
+    kv, d = cfg.num_kv_heads, cfg.head_dim
+    heads = dict(zip(cfg.layer_types, cfg.num_attention_heads_per_layer))
+    width = -(-max_seq // block)
+    scale = d ** -0.5
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 6), 6)
+    rng = np.random.RandomState(SEED + 6)
+
+    def draw(key, shape):
+        return jax.random.normal(key, shape, jnp.float32).astype(DTYPE)
+
+    shape = KVBlockPool(slots * width // 4, block).arena_shape(kv, d)
+    ka, va = draw(ks[0], shape), draw(ks[1], shape)
+    q = draw(ks[2], (slots, heads["full_attention"], 1, d))
+    lengths = np.linspace(0, width * block // 4 - 1, slots).astype(np.int32)
+    tables = np.zeros((slots, width), np.int32)
+    free = iter(rng.permutation(shape[0] - 1) + 1)
+    for i, n in enumerate(lengths):          # the blocks a stream owns
+        for j in range(n // block + 1):
+            tables[i, j] = next(free)
+    args = (jnp.asarray(tables), jnp.asarray(lengths))
+    full = jax.jit(lambda *a: paged_decode_attention(*a, scale))(
+        q, ka, va, *args)
+    full_r = jax.jit(lambda *a: paged_attention_ref(*a, scale))(
+        *_f32(q, ka, va), *args)
+
+    ring = (slots,) + window_ring_shape(cfg.sliding_window, cfg.ring_block,
+                                        kv, d)
+    kr, vr = draw(ks[3], ring), draw(ks[4], ring)
+    q = draw(ks[5], (slots, heads["sliding_attention"], 1, d))
+    lengths = jnp.asarray(np.linspace(
+        0, 3 * cfg.sliding_window, slots).astype(np.int32))
+    got = jax.jit(lambda *a: window_attention(*a, scale))(q, kr, vr, lengths)
+    got_r = jax.jit(lambda q, k, v, n: paged_attention_ref(
+        q, _ring_arena(k), _ring_arena(v), _ring_tables(k),
+        jnp.minimum(n, cfg.sliding_window - 1), scale))(
+            *_f32(q, kr, vr), lengths)
+    return {"full": _rel_err(full, full_r), "ring": _rel_err(got, got_r)}
+
+
 def _check_grouped_ffn(sizes):
     """The grouped expert kernel at the widths of `sizes.latent`'s and
     `sizes.scmoe`'s expert layers (rounded up to the lane tiles its gate
@@ -1067,6 +1135,7 @@ def kernel_checks(sizes):
             lambda c=chunk, b=block: _check_paged(sizes, c, b)
     checks["latent_paged_decode"] = lambda: _check_latent_paged(sizes)
     checks["grouped_expert_ffn"] = lambda: _check_grouped_ffn(sizes)
+    checks["grouped_paged_decode"] = lambda: _check_grouped_paged(sizes)
     return checks
 
 

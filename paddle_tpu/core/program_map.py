@@ -44,8 +44,8 @@ import re
 __all__ = ["SCOPES", "shapes", "note", "labels", "parse", "scopes",
            "scope_of", "dump", "reset"]
 
-SCOPES = ("embed", "attn", "linear_attn", "ffn", "router", "experts",
-          "head", "sample")
+SCOPES = ("embed", "attn", "linear_attn", "window_attn", "ffn", "router",
+          "experts", "head", "sample")
 FILE_NAME = "program_map.json"
 
 _WORDS = frozenset(SCOPES)

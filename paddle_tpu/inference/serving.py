@@ -63,7 +63,10 @@ counts, cumulative: `steps`, `decode_tokens`, `prefill_dispatches`,
 its buckets into tiles, `net.prefill_tile`, else the bucket),
 `admitted`, `queue_wait_s`, and whatever the served net
 names (`net.serve_counters`: the `moe_*` counts of a net with expert
-layers, the `linear_*` counts of one with recurrent layers), all mirrored
+layers, the `linear_*` counts of one with recurrent layers, the `attn_*`
+counts and the `window_ring_bytes` gauge of one with sliding-window
+layers; a name in the net's `SERVE_GAUGES` is set, the others are added
+up), all mirrored
 as `serve.*` gauges beside `serve.{queue_depth,active_slots,
 kv_pool_used_blocks,kv_pool_free_blocks,model_version,state_slots_used,
 state_bytes,steps}` (`state_*`: decode slots whose per-slot state is
@@ -864,9 +867,10 @@ class ServeLoop:
         if len(handles) < 2:
             return                       # a net that counts nothing
         counts = dict(self._net_counts)
+        gauges = getattr(self.net, "SERVE_GAUGES", ())  # set, not added up
         for name, n in self.net.serve_counters(kind, handles[1:],
                                                n_tokens).items():
-            counts[name] = counts.get(name, 0) + n
+            counts[name] = n if name in gauges else counts.get(name, 0) + n
         self._net_counts = counts        # swapped whole: stats() may read
 
     def _append_token(self, idx, slot, token, now, first=False):

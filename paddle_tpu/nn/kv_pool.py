@@ -51,9 +51,11 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["PagedKVCache", "PagedLatentCache", "SlotStateCache",
-           "CacheSpec", "KVBlockPool", "paged_caches", "cache_arenas",
-           "fresh_slot_rows", "put_slot_rows", "paged_attention",
-           "paged_attention_ref", "latent_paged_attention", "write_kv",
+           "WindowKVCache", "CacheSpec", "KVBlockPool", "paged_caches",
+           "cache_arenas", "fresh_slot_rows", "put_slot_rows",
+           "paged_attention", "paged_attention_ref",
+           "latent_paged_attention", "write_kv", "window_ring_shape",
+           "window_fill", "window_write", "window_attention",
            "pick_block_size"]
 
 TRASH_BLOCK = 0  # physical row 0 of every arena; never allocated
@@ -110,6 +112,37 @@ class SlotStateCache(typing.NamedTuple):
     lengths: object       # [b] i32
 
 
+class WindowKVCache(typing.NamedTuple):
+    """One sliding-window layer's cache: the keys and values of the last
+    `window` tokens of each decode slot and nothing else, whatever the
+    stream's length. A RING a slot, not pages of the pool: `k`/`v` are
+    [slots, ring_blocks, h, d, block] — `ring_blocks * block` = window
+    tokens in the lanes, a slot's row laid out as `ring_blocks` rows of
+    an arena, so that [slots * ring_blocks, h, d, block] (a reshape of
+    the leading dimensions, no copy) IS an arena and slot i's ring its
+    blocks i * ring_blocks .. under a table of its own
+    (`_ring_tables`). The token at position p sits at ring column
+    p mod window; keys are rotated before they are cached, so the order
+    of a ring's columns does not matter to the softmax. Three rules:
+    - it does not grow: the bytes are `slots * window` tokens' from the
+      first admission to the last, and `KVBlockPool` hands out no block
+      for it (`blocks_for`, admission and the used share count what is
+      paged by token);
+    - row i belongs to decode slot i from admission to retirement: a
+      prefill starts from one zeroed row (`fresh_slot_rows`) and leaves
+      the prompt's last `window` tokens in it (`window_fill`);
+    - a decode row that no request owns writes into its OWN slot's ring,
+      which nobody reads and the next admission replaces: the trash
+      block's rule without a trash block.
+    `block_tables` and `lengths` ride along as in the paged caches
+    (`lengths` is the stream's, not the ring's)."""
+
+    k: object             # [slots, ring_blocks, h, d, block]
+    v: object             # [slots, ring_blocks, h, d, block]
+    block_tables: object  # [b, max_blocks] i32 (the paged layers')
+    lengths: object       # [b] i32
+
+
 class CacheSpec(typing.NamedTuple):
     """What one layer of a served net caches, as the net's
     `paged_cache_spec()` says it: `cache`, the type its `_forward_paged`
@@ -119,7 +152,8 @@ class CacheSpec(typing.NamedTuple):
     ((1, dim),) for a latent, () for a layer that caches no token; and
     `slots`, the (shape of one slot's row, dtype or None for the pool's)
     of each array indexed by decode slot — a `SlotStateCache`'s state and
-    convolution inputs."""
+    convolution inputs, a `WindowKVCache`'s two rings
+    (`window_ring_shape`)."""
 
     cache: type
     arenas: tuple
@@ -380,29 +414,36 @@ def _write_blocks(arena, bt, lens, new_kv):
 def paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
                         scale):
     """jnp fallback / parity oracle: gather each slot's blocks into a
-    contiguous [b, h, max_blocks*bs, d] view and run the same masked
+    contiguous [b, h_kv, max_blocks*bs, d] view and run the same masked
     softmax as _static_cache_attention, with per-row live lengths. Row r
-    of slot i attends logical cols <= lengths[i] + r."""
+    of slot i attends logical cols <= lengths[i] + r. q [b, h, s, d] with
+    h = G x h_kv: query head j reads key-value head j // G (the G query
+    heads of a key-value head are further rows of its product, each at
+    its own position)."""
     b, h, s, d = q.shape
-    bs = k_arena.shape[3]
+    hk, bs = k_arena.shape[1], k_arena.shape[3]
+    group = h // hk
     bt = jnp.asarray(block_tables, jnp.int32)
     nb = bt.shape[1]
     L = nb * bs
 
     def gather(arena):
-        g = jnp.take(arena, bt, axis=0)          # [b, nb, h, d, bs]
-        return jnp.transpose(g, (0, 2, 1, 4, 3)).reshape(b, h, L, d)
+        g = jnp.take(arena, bt, axis=0)          # [b, nb, hk, d, bs]
+        return jnp.transpose(g, (0, 2, 1, 4, 3)).reshape(b, hk, L, d)
 
     kc, vc = gather(k_arena), gather(v_arena)
     lens = jnp.asarray(lengths, jnp.int32)
-    row = (lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None])  # [b, s]
+    step = jnp.tile(jnp.arange(s, dtype=jnp.int32), group)        # [G s]
+    row = lens[:, None] + step[None]                              # [b, G s]
     col = jnp.arange(L, dtype=jnp.int32)                          # [L]
-    live = col[None, None, :] <= row[:, :, None]                  # [b, s, L]
-    scores = jnp.einsum("bhsd,bhld->bhsl", q.astype(kc.dtype), kc,
+    live = col[None, None, :] <= row[:, :, None]                  # [b, G s, L]
+    scores = jnp.einsum("bhsd,bhld->bhsl",
+                        q.reshape(b, hk, group * s, d).astype(kc.dtype), kc,
                         preferred_element_type=jnp.float32) * scale
     scores = jnp.where(live[:, None], scores, -1e9)
     p = jax.nn.softmax(scores, axis=-1).astype(vc.dtype)
-    return jnp.einsum("bhsl,bhld->bhsd", p, vc).astype(q.dtype)
+    return jnp.einsum("bhsl,bhld->bhsd", p, vc).astype(q.dtype) \
+        .reshape(b, h, s, d)
 
 
 def latent_paged_attention(q, arena, block_tables, lengths, scale,
@@ -520,16 +561,98 @@ def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,
                                                    paged_decode_attention)
         # which cut this program compiles with, on the kernel's span and
         # as a pair of gauges per (slots, query rows) for a dump to read:
-        # b32s1 is a decode step of 32 slots, b1s256 a prefill
+        # b32s1 is a decode step of 32 slots, b1s256 a prefill, b128s1g6
+        # a grouped-query step of 6 query heads a key-value head
+        # a pool's block belongs to ONE slot's table (the trash block
+        # apart), so the live (slot, block) pairs of a call are at most
+        # the arena's blocks and a step a slot: what bounds the
+        # grouped-query form's work list
+        max_steps = k_arena.shape[0] - 1 + q.shape[0]
         cut = paged_cut(tuple(q.shape), tuple(k_arena.shape),
-                        block_tables.shape[1], k_arena.dtype.itemsize)
+                        block_tables.shape[1], k_arena.dtype.itemsize,
+                        max_steps)
+        group = q.shape[1] // k_arena.shape[1]
+        key = f"b{q.shape[0]}s{q.shape[2]}" + (f"g{group}" if group > 1
+                                               else "")
         monitor.stat_set_many({
-            f"pallas.paged_decode_attention.{name}.b{q.shape[0]}"
-            f"s{q.shape[2]}": value for name, value in cut.items()})
+            f"pallas.paged_decode_attention.{name}.{key}": value
+            for name, value in cut.items()})
         return run_guarded(
             "paged_decode_attention",
             lambda: paged_decode_attention(q, k_arena, v_arena,
-                                           block_tables, lengths, scale),
+                                           block_tables, lengths, scale,
+                                           max_steps),
             **cut)
     return paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
                                scale)
+
+
+# --------------------------------------------------------------------------
+# the window cache: a ring a decode slot (`WindowKVCache`)
+# --------------------------------------------------------------------------
+
+def window_ring_shape(window, block, heads, head_dim):
+    """One slot's row of a ring of `window` tokens in blocks of `block`:
+    (ring_blocks, heads, head_dim, block), an arena's layout. The window
+    is whole blocks (ROADMAP: other windows wait)."""
+    if window < block or window % block:
+        raise ValueError(f"a window of {window} tokens is no multiple of "
+                         f"its ring's block of {block}")
+    return (int(window) // int(block), int(heads), int(head_dim), int(block))
+
+
+def _ring_arena(ring):
+    """[slots, ring_blocks, h, d, block] as the arena [slots *
+    ring_blocks, h, d, block] (the leading dimensions merged: no copy)."""
+    return ring.reshape((ring.shape[0] * ring.shape[1],) + ring.shape[2:])
+
+
+def _ring_tables(ring):
+    """Slot i's table over `_ring_arena`: its own blocks, in order."""
+    slots, blocks = ring.shape[:2]
+    return (jnp.arange(slots, dtype=jnp.int32)[:, None] * blocks
+            + jnp.arange(blocks, dtype=jnp.int32)[None])
+
+
+def window_fill(ring, new_kv, count):
+    """What a prefill leaves in ONE slot's ring [1, ring_blocks, h, d,
+    block]: of the chunk `new_kv` [1, s, h, d], whose first `count`
+    tokens exist (positions 0..count-1: a prefill starts an empty slot),
+    the last min(count, window), each at column position mod window; the
+    columns no token has are zero. One slice of the chunk and one
+    rotation: no scatter."""
+    _, blocks, h, d, bs = ring.shape
+    window = blocks * bs
+    count = jnp.asarray(count, jnp.int32).reshape(())
+    # positions count - window .. count - 1 (zeros before position 0)
+    padded = jnp.pad(new_kv[0].astype(ring.dtype),
+                     ((window, 0), (0, 0), (0, 0)))
+    last = jax.lax.dynamic_slice_in_dim(padded, count, window, axis=0)
+    # row i of `last` is position count - window + i: column (count + i)
+    # mod window
+    cols = jnp.roll(last, count % window, axis=0)          # [window, h, d]
+    return jnp.transpose(cols.reshape(blocks, bs, h, d), (0, 2, 3, 1))[None]
+
+
+def window_write(ring, lengths, new_kv):
+    """A decode step's write: slot i's token `new_kv[i]` ([b, 1, h, d],
+    b = the ring's slots) at column lengths[i] mod window of its own
+    ring, through `write_kv` (the Pallas token writer where its gate
+    admits) over the ring as an arena."""
+    slots, blocks, _, _, bs = ring.shape
+    lens = jnp.asarray(lengths, jnp.int32) % jnp.int32(blocks * bs)
+    return write_kv(_ring_arena(ring), _ring_tables(ring), lens,
+                    new_kv).reshape(ring.shape)
+
+
+def window_attention(q, k_ring, v_ring, lengths, scale):
+    """One token a slot over its ring, AFTER `window_write`: q [b, h, 1,
+    d] attends the min(lengths[i] + 1, window) columns that hold a token
+    (columns 0..lengths[i] until the ring wraps, all of them after).
+    `paged_attention` over the ring as an arena under the slot's own
+    table: the paged kernel's second call site, and the same gate."""
+    window = k_ring.shape[1] * k_ring.shape[4]
+    lens = jnp.minimum(jnp.asarray(lengths, jnp.int32),
+                       jnp.int32(window - 1))
+    return paged_attention(q, _ring_arena(k_ring), _ring_arena(v_ring),
+                           _ring_tables(k_ring), lens, scale)

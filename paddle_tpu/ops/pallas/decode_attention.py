@@ -40,7 +40,7 @@ from .flash_attention import (NEG_INF, _Z, _ceil_to, _cparams, _interpret,
                               _pick_block, _vmem)
 
 __all__ = ["decode_attention", "supported",
-           "paged_decode_attention", "paged_supported",
+           "paged_decode_attention", "paged_supported", "paged_group",
            "paged_write_token", "paged_write_supported",
            "latent_paged_decode_attention", "latent_paged_supported"]
 
@@ -197,6 +197,31 @@ def _pick_bk(shape, dtype, scale, measure_builder):
 # block_size minor and a multiple of 128 the pool's buffer IS the
 # kernel's operand; nn/kv_pool.write_kv updates it in place.
 
+def _paged_block_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, scale,
+                        live):
+    """One K/V block into the online softmax of a tile of ht heads: q
+    [ht, s, d] against kT [ht, d, bs], the columns `live()` ([s, bs] bool)
+    leaves, the float32 state m, l [ht, s, 1] and acc [ht, s, d] rescaled
+    and added to. Shared by the multi-head and the grouped-query kernel:
+    they differ in which columns a row may see and in how the grid walks
+    the blocks."""
+    sc = jax.lax.dot_general(q_ref[0], k_ref[0],
+                             (((2,), (1,)), ((0,), (0,))),
+                             preferred_element_type=jnp.float32) * scale
+    sc = jnp.where(live()[None], sc, np.float32(NEG_INF))
+    m_prev = m_scr[:]                          # [ht, s, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(sc - m_new)                    # [ht, s, bs] f32
+    l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    # p [ht, s, bs] against vT [ht, d, bs]: both contract their lanes
+    pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
+                             (((2,), (2,)), ((0,), (0,))),
+                             preferred_element_type=jnp.float32)
+    acc_scr[:] = acc_scr[:] * alpha + pv
+    m_scr[:] = m_new
+
+
 def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
                               m_scr, l_scr, acc_scr, *, scale, bs, nb, s):
     """Grid (b, h // ht, nb); nb = logical blocks per request (sequential
@@ -226,24 +251,12 @@ def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(ik <= last)
     def _compute():
-        q = q_ref[0]                           # [ht, s, d]
-        kt = k_ref[0]                          # [ht, d, bs]
-        sc = jax.lax.dot_general(q, kt, (((2,), (1,)), ((0,), (0,))),
-                                 preferred_element_type=jnp.float32) * scale
-        row = jax.lax.broadcasted_iota(jnp.int32, (s, bs), 0)
-        col = ik * bs + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 1)
-        sc = jnp.where((col <= index + row)[None], sc, np.float32(NEG_INF))
-        m_prev = m_scr[:]                      # [ht, s, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sc - m_new)                # [ht, s, bs] f32
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # p [ht, s, bs] against vT [ht, d, bs]: both contract their lanes
-        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
-                                 (((2,), (2,)), ((0,), (0,))),
-                                 preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = m_new
+        def live():
+            row = jax.lax.broadcasted_iota(jnp.int32, (s, bs), 0)
+            col = ik * bs + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 1)
+            return col <= index + row
+        _paged_block_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
+                            scale, live)
 
     @pl.when(ik == nb - 1)
     def _flush():
@@ -283,32 +296,59 @@ def paged_heads_per_step(h, s_p, d, bs, itemsize,
     return 0
 
 
+def paged_group(q_heads, kv_heads) -> int:
+    """Query heads a key-value head: 1 is multi-head attention, G > 1
+    grouped-query attention (query head h reads key-value head h // G),
+    0 where the query heads are no multiple of the arena's."""
+    return q_heads // kv_heads if kv_heads and q_heads % kv_heads == 0 \
+        else 0
+
+
+def _paged_rows(group, s):
+    """The rows a key-value head's product has, padded to the sublane
+    tile: a chunk's s positions, or the G query heads of one token."""
+    return _ceil_to(group if group > 1 else s, 8)
+
+
 def paged_supported(q_shape, arena_shape, itemsize=4) -> bool:
     """Static predicate: can the paged kernel serve q [b, h, s, d] over
-    an arena [n_blocks, h, d, block_size] of `itemsize`-byte elements?
-    block_size is fixed by the pool layout, so it must already be a
-    sublane-tile multiple; one head of one block, with its query rows
-    and softmax state, has to fit the kernel's VMEM budget."""
+    an arena [n_blocks, h_kv, d, block_size] of `itemsize`-byte elements?
+    h = h_kv (multi-head: the rows of a head's product are the chunk's
+    positions), or h = G x h_kv with one token a slot (grouped-query: the
+    rows are the G query heads of a key-value head, all under the same
+    length). block_size is fixed by the pool layout, so it must already
+    be a sublane-tile multiple; one key-value head of one block, with its
+    query rows and softmax state, has to fit the kernel's VMEM budget."""
     if len(q_shape) != 4 or len(arena_shape) != 4:
         return False
     b, h, s, d = q_shape
     nb_phys, hl, dl, bs = arena_shape
-    if (hl, dl) != (h, d):
+    group = paged_group(h, hl)
+    if dl != d or not group or (group > 1 and s != 1):
         return False
-    if d > 256 or s < 1 or s > 256:
+    if d > 256 or s < 1 or s > 256 or group > 256:
         return False
     if bs < 8 or bs % 8 != 0 or nb_phys < 1:
         return False
-    return paged_heads_per_step(h, _ceil_to(s, 8), d, bs, itemsize) > 0
+    return paged_heads_per_step(hl, _paged_rows(group, s), d, bs,
+                                itemsize) > 0
 
 
-def paged_cut(q_shape, arena_shape, table_blocks, itemsize) -> dict:
-    """How a supported call is cut into grid steps: `heads_per_step`, and
-    `grid_steps` = b x head tiles x the table's logical blocks."""
+def paged_cut(q_shape, arena_shape, table_blocks, itemsize,
+              max_steps=None) -> dict:
+    """How a supported call is cut into grid steps: `heads_per_step`
+    (key-value heads; each brings its G query heads as rows), and
+    `grid_steps` = head tiles x (multi-head: b x the table's logical
+    blocks; grouped-query: the work list's length,
+    `paged_grouped_steps`)."""
     b, h, s, d = q_shape
-    ht = paged_heads_per_step(h, _ceil_to(s, 8), d, arena_shape[3], itemsize)
-    return {"heads_per_step": ht,
-            "grid_steps": b * (h // ht) * int(table_blocks)}
+    hl = arena_shape[1]
+    group = paged_group(h, hl)
+    ht = paged_heads_per_step(hl, _paged_rows(group, s), d, arena_shape[3],
+                              itemsize)
+    steps = b * int(table_blocks) if group == 1 else paged_grouped_steps(
+        b, table_blocks, max_steps)
+    return {"heads_per_step": ht, "grid_steps": (hl // ht) * steps}
 
 
 def _paged_call(q, k_arena, v_arena, block_tables, lengths, scale):
@@ -381,21 +421,165 @@ def _paged_call_once(q, k_arena, v_arena, block_tables, lengths, *, scale,
     )(lengths, block_tables, q, k_arena, v_arena)
 
 
+# --------------------------------------------------------------------------
+# grouped-query form over a WORK LIST: one grid step a live block
+# --------------------------------------------------------------------------
+#
+# The grid above has a step for every entry of every slot's table. A table
+# is as wide as the longest stream the loop admits (72 blocks at 9216
+# tokens) and a slot holds ~19 of them: three steps of four are dead, and a
+# dead step still costs grid overhead (~0.15 us measured: a full layer's
+# call 3.13 ms over 9216 steps, 2.32 over the 3200 of the list). The grouped-query
+# form therefore walks a list of the LIVE (slot, logical block) pairs, made
+# outside the kernel from tables and lengths and read through scalar
+# prefetch: item w holds slot `slot[w]`'s block `blk[w]` at physical row
+# `phys[w]`; a slot's items are consecutive, so its softmax state is
+# initialised at its first block and flushed at its last, and the output's
+# block index changes when the slot does. The list is as long as the
+# caller says the live pairs can be (`max_steps`; the pool's invariant, a
+# physical block belongs to one slot, bounds them by the arena's rows plus
+# a step a slot); items past the live ones repeat the last live item
+# (nothing is fetched) and skip their body.
+
+def _paged_work_list(block_tables, lengths, bs, steps):
+    """(slot, blk, phys) [steps] i32 and the live count [1] i32 of the
+    work list: slot i contributes its logical blocks 0..last_i, last_i =
+    min((lengths[i] - 1) // bs, nb - 1) with `lengths` the fill INCLUDING
+    this step's token, an empty slot its block 0 (every slot's output is
+    written)."""
+    b, nb = block_tables.shape
+    counts = jnp.minimum(jnp.maximum(lengths - 1, 0) // jnp.int32(bs),
+                         jnp.int32(nb - 1)) + 1                   # [b]
+    ends = jnp.cumsum(counts)
+    w = jnp.arange(steps, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.searchsorted(ends, w, side="right"),
+                       b - 1).astype(jnp.int32)
+    blk = w - (ends[slot] - counts[slot])
+    live = w < ends[-1]
+    slot = jnp.where(live, slot, b - 1)
+    blk = jnp.where(live, blk, counts[b - 1] - 1).astype(jnp.int32)
+    return (slot, blk, block_tables[slot, blk],
+            jnp.minimum(ends[-1:], steps).astype(jnp.int32))
+
+
+def _paged_grouped_kernel(len_ref, slot_ref, blk_ref, phys_ref, n_ref,
+                          q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                          *, scale, bs, nb, s):
+    """Grid (h_kv // ht, steps): step (ih, w) holds work item w — logical
+    block blk[w] of slot slot[w] for a tile of ht key-value heads, q/out
+    [1, ht, s, d] with the G query heads of a key-value head as rows (one
+    token a slot: every row under the same length), K/V [1, ht, d, bs].
+    The arithmetic of a block, and the blocks' order within a slot, are
+    `_paged_decode_attn_kernel`'s. len_ref [b]: the fill with this step's
+    token; phys_ref is consumed by the index maps."""
+    w = pl.program_id(1)
+    ib, ik = slot_ref[w], blk_ref[w]
+    live = w < n_ref[0]
+    length = len_ref[ib]
+    index = length - np.int32(1)               # this step's token's column
+    last = jnp.minimum(
+        jnp.maximum(length - np.int32(1), np.int32(0)) // np.int32(bs),
+        np.int32(nb - 1))                      # the slot's last live block
+
+    @pl.when(live & (ik == 0))
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(live)
+    def _compute():
+        def seen():
+            col = ik * bs + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 1)
+            return col <= index
+        _paged_block_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
+                            scale, seen)
+
+    @pl.when(live & (ik == last))
+    def _flush():
+        denom = jnp.maximum(l_scr[:], 1e-30)   # padded rows stay finite
+        o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+
+
+def paged_grouped_steps(b, table_blocks, max_steps=None) -> int:
+    """The work list's length for b slots over tables `table_blocks`
+    wide: `max_steps` where the caller bounds the live (slot, block)
+    pairs, never more than every entry of every table."""
+    full = int(b) * int(table_blocks)
+    return full if not max_steps else max(int(b), min(full, int(max_steps)))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "steps"))
+def _paged_grouped_call_once(q, k_arena, v_arena, block_tables, lengths, *,
+                             scale, interpret, steps):
+    """q [b, h_kv, s_p, d] (the groups' query heads as rows, padded),
+    lengths [b] the fill INCLUDING this step's token."""
+    from jax.experimental.pallas import tpu as pltpu
+    b, h, s_p, d = q.shape
+    bs, nb = k_arena.shape[3], block_tables.shape[1]
+    ht = paged_heads_per_step(h, s_p, d, bs, k_arena.dtype.itemsize)
+    slot, blk, phys, n_live = _paged_work_list(block_tables, lengths, bs,
+                                               steps)
+
+    def q_map(ih, w, len_ref, slot_ref, blk_ref, phys_ref, n_ref):
+        return (slot_ref[w], ih, _Z, _Z)
+
+    def kv_map(ih, w, len_ref, slot_ref, blk_ref, phys_ref, n_ref):
+        return (phys_ref[w], ih, _Z, _Z)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(h // ht, steps),
+        in_specs=[
+            pl.BlockSpec((1, ht, s_p, d), q_map),
+            pl.BlockSpec((1, ht, d, bs), kv_map),
+            pl.BlockSpec((1, ht, d, bs), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, ht, s_p, d), q_map),
+        scratch_shapes=[
+            _vmem((ht, s_p, 1), jnp.float32),
+            _vmem((ht, s_p, 1), jnp.float32),
+            _vmem((ht, s_p, d), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_paged_grouped_kernel, scale=scale, bs=bs,
+                               nb=nb, s=s_p)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, s_p, d), q.dtype),
+        compiler_params=_cparams("parallel", "arbitrary"),
+        interpret=interpret,
+    )(lengths, slot, blk, phys, n_live, q, k_arena, v_arena)
+
+
 def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
-                           scale=None):
+                           scale=None, max_steps=None):
     """Attention of q [b, h, s, d] over a PAGED cache: per-request block
     tables [b, max_blocks] of physical block ids into shared arenas
-    k_arena/v_arena [n_blocks, h, d, block_size]. `lengths` [b] is each
+    k_arena/v_arena [n_blocks, h_kv, d, block_size]. `lengths` [b] is each
     request's cache fill count BEFORE this chunk (the chunk's k/v must
     already be written into the arena — nn/kv_pool.write_kv). Row r of
     batch i attends to logical cache cols <= lengths[i] + r. Block-table
     entries past the allocation MUST be 0 (the pool's reserved trash
     block): padded query rows reach past the live end and the index map
     must land on a valid physical row. Eval-only (no vjp); returns
-    [b, h, s, d] in q's dtype."""
+    [b, h, s, d] in q's dtype.
+
+    h = G x h_kv, G > 1, is grouped-query attention and takes one token a
+    slot (s = 1): query head j reads key-value head j // G, the G query
+    heads of a key-value head are the rows of ONE product over that
+    head's block (padded to the sublane tile), each K/V block is fetched
+    once for its whole group, and every row attends cols <= lengths[i].
+    The grouped form walks a work list of the live (slot, block) pairs
+    (`_paged_grouped_kernel`); `max_steps` bounds them where the caller
+    can (nn/kv_pool.paged_attention: a pool's block belongs to one slot),
+    else the list is as long as the tables."""
     b, h, s, d = q.shape
+    hl = k_arena.shape[1]
+    group = paged_group(h, hl)
     if v_arena.shape != k_arena.shape or k_arena.shape[2] != d \
-            or k_arena.shape[1] != h:
+            or not group or (group > 1 and s != 1):
         raise ValueError(
             f"paged_decode_attention: arena shapes k{tuple(k_arena.shape)} "
             f"v{tuple(v_arena.shape)} don't match q{tuple(q.shape)}")
@@ -409,20 +593,32 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
     out_dtype = q.dtype
     if q.dtype != k_arena.dtype:
         q = q.astype(k_arena.dtype)
+    if group > 1:          # [b, G h_kv, 1, d] -> [b, h_kv, G, d]
+        q = q.reshape(b, hl, group, d)
+    rows = q.shape[2]
 
-    s_p = _ceil_to(s, 8)   # sublane tile: pad query rows, slice back below
-    if s_p != s:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, s_p - s), (0, 0)))
+    s_p = _ceil_to(rows, 8)  # sublane tile: pad query rows, slice back below
+    if s_p != rows:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, s_p - rows), (0, 0)))
     # lengths in PADDED-row terms (kernel recovers fill as length - s_p);
     # padded rows attend a few cols past the live end — garbage rows
     # sliced off below, and their block-table lookups land on entry 0
-    # (the trash block) by the pool's table convention
+    # (the trash block) by the pool's table convention. A group's rows
+    # are one position: the fill and this step's token
     lens = jnp.asarray(lengths, jnp.int32)
-    lens = jnp.broadcast_to(lens.reshape(-1), (b,)) + jnp.int32(s_p)
+    lens = jnp.broadcast_to(lens.reshape(-1), (b,)) \
+        + jnp.int32(1 if group > 1 else s_p)
     bt = jnp.asarray(block_tables, jnp.int32)
-    out = _paged_call(q, k_arena, v_arena, bt, lens, scale)
+    if group > 1:
+        out = _paged_grouped_call_once(
+            q, k_arena, v_arena, bt, lens, scale=float(scale),
+            interpret=_interpret(), steps=paged_grouped_steps(
+                b, bt.shape[1], max_steps))
+    else:
+        out = _paged_call(q, k_arena, v_arena, bt, lens, scale)
     out = out.astype(out_dtype)
-    return out[:, :, :s] if s_p != s else out
+    out = out[:, :, :rows] if s_p != rows else out
+    return out.reshape(b, h, s, d) if group > 1 else out
 
 
 # --------------------------------------------------------------------------

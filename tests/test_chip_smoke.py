@@ -82,7 +82,10 @@ def test_kernels_phase_toy(interpret):
     assert set(result["errors_vs_jnp_reference"]) >= {
         "flash_causal", "flash_padding_bias", "fused_ce", "decode",
         "paged_decode_s1_blockpicked", "latent_paged_decode",
-        "grouped_expert_ffn"}
+        "grouped_expert_ffn", "grouped_paged_decode"}
+    # the grouped-query form's two call sites: the pool's pages, the rings
+    assert set(result["errors_vs_jnp_reference"]["grouped_paged_decode"]) \
+        == {"full", "ring"}
     # both nets' expert layers, a decode step and a bucket each
     assert set(result["errors_vs_jnp_reference"]["grouped_expert_ffn"]) \
         == {"share_t2", "share_t32", "scmoe_t2", "scmoe_t32"}
@@ -659,6 +662,126 @@ def test_hybrid_serve_steps_update_state_and_arenas_in_place_for_v5e():
                    {"_gdn_step_call": ["linear_attn"],
                     "_paged_write_once": ["attn"],
                     "_paged_call_once": ["attn"]})
+
+
+def _compile_window_steps_for_v5e():
+    """Child-process body of the test below: ServeLoop's own decode step
+    and bucket-1024 prefill of the Laguna share's leading full layer and
+    one sliding layer at their published widths
+    (`chip_smoke.Sizes.full().window`), compiled for v5e over the
+    benchmark's 128 slots: a full layer's pages and a sliding layer's
+    rings from one spec; one JSON line a program."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.inference.serving import (_build_prefill,
+                                              build_decode_step)
+    from paddle_tpu.nn import initializer
+    from paddle_tpu.nn.kv_pool import KVBlockPool
+    from paddle_tpu.text.models import Laguna
+    try:
+        device = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
+    except Exception as e:  # environment without a usable libtpu
+        print(f"NO-TOPOLOGY {type(e).__name__}: {e}")
+        return
+    sharding = SingleDeviceSharding(device)
+    # only shapes are compiled: 0.46 B parameters need not be drawn
+    initializer.Normal.__call__ = \
+        lambda self, shape, dtype="float32": jnp.zeros(tuple(shape), dtype)
+    sizes = chip_smoke.Sizes.full()
+    net = Laguna(sizes.window)
+    net.eval()
+    params, buffers = net.functional_state()
+    (slots, blocks, block, max_seq), bucket = sizes.window_serve, 1024
+    pool, width = KVBlockPool(blocks, block), -(-max_seq // block)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def like(tree):
+        return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
+
+    i32, u32 = jnp.int32, jnp.uint32
+    arenas = jax.eval_shape(lambda: pool.arenas_for(
+        net.paged_cache_spec(), jnp.bfloat16, slots=slots))
+    state = (like(params), like(buffers), like(arenas))
+    held = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+               for layer in arenas for x in layer)
+    ring = "bf16[128,4,8,128,128]"
+    programs = {
+        1: (build_decode_step(net),
+            (spec((slots, width), i32), spec((slots,), i32),
+             spec((slots,), i32), spec((slots, 2), u32))),
+        bucket: (_build_prefill(net, 0.0, None),
+                 (spec((slots,), i32), spec((1, width), i32),
+                  spec((1, bucket), i32), spec((), i32), spec((2,), u32),
+                  spec((), i32)))}
+    paddle.set_flags({"FLAGS_pallas_force_compile": True})
+    for s, (fn, rest) in programs.items():
+        monitor.reset(prefix="pallas.")
+        compiled = jax.jit(fn, donate_argnums=(2,)).trace(
+            *state, *rest).lower(lowering_platforms=("tpu",)).compile()
+        net.load_functional_state(params, buffers)
+        text, mem = compiled.as_text(), compiled.memory_analysis()
+        hits = monitor.stats("pallas.hit.")
+        print("STEP " + json.dumps({
+            "s": s,
+            "ring_in_hlo": ring in text,
+            "ring_copies": len(re.findall(
+                r"= bf16\[(?:128,4|512),8,128,128\]\S* (?:copy|transpose)\(",
+                text)),
+            "relayouts": chip_smoke.arena_relayouts(
+                text, pool.arena_shape(8, 128)),
+            "lane_padded": chip_smoke.lane_padded_results(text),
+            "temp_bytes": mem.temp_size_in_bytes,
+            "alias_bytes": mem.alias_size_in_bytes, "held_bytes": held,
+            "ring_bytes": 2 * slots * 4 * 8 * 128 * 128 * 2,
+            "hits": {k.rsplit(".", 1)[1]: int(v) for k, v in hits.items()},
+            "rejects": monitor.stats("pallas.gate_reject."),
+            "cut": monitor.stats("pallas.paged_decode_attention."),
+            "scopes": _scope_summary(text)}))
+    print("WINDOW-STEPS-DONE")
+
+
+def test_window_serve_steps_hand_rings_and_arenas_to_the_kernel_for_v5e():
+    """One kernel, two call sites: the decode step of the Laguna share's
+    leading full layer (48 query heads over 8 key-value heads, the pool's
+    pages) and of one sliding layer (72 over 8, a ring of 512 tokens a
+    slot) compiles under Mosaic for v5e at the published widths and 128
+    slots, hands the arenas AND the rings ([128, 4, 8, 128, 128] read as
+    [512, 8, 128, 128]: the leading dimensions merged, no copy) to the
+    grouped-query paged kernel and to the token writer as they are, and
+    gives every donated byte back aliased; its temporaries are far under
+    one ring. The bucket-1024 prefill writes a slot's ring row in place."""
+    out = _run_in_cpu_child("_compile_window_steps_for_v5e",
+                            "WINDOW-STEPS-DONE")
+    decode, prefill = (json.loads(line[5:]) for line in out.splitlines()
+                       if line.startswith("STEP "))
+    for step in (decode, prefill):
+        assert step["ring_in_hlo"] and step["ring_copies"] == 0, step
+        assert step["relayouts"] == [], step
+        assert step["alias_bytes"] >= step["held_bytes"], step
+        assert step["rejects"] in (
+            {}, {"pallas.gate_reject.grouped_expert_ffn.tokens": 1}), step
+    assert decode["lane_padded"] == [], decode
+    assert decode["hits"] == {"paged_write_token": 4,
+                              "paged_decode_attention": 2,
+                              "grouped_expert_ffn": 1}
+    assert decode["temp_bytes"] < decode["ring_bytes"] // 8, decode
+    # a key-value head's 6 or 9 query heads are the rows of one product:
+    # all 8 key-value heads of a block in one grid step; a work list of
+    # the live blocks, at most the pool's 1024 and a step a slot in the
+    # full layer (not 128 x 72 table entries), the rings' 4 a slot in the
+    # sliding layer
+    assert decode["cut"] == {
+        "pallas.paged_decode_attention.heads_per_step.b128s1g6": 8,
+        "pallas.paged_decode_attention.grid_steps.b128s1g6": 1024 + 128,
+        "pallas.paged_decode_attention.heads_per_step.b128s1g9": 8,
+        "pallas.paged_decode_attention.grid_steps.b128s1g9": 128 * 4}
+    assert prefill["hits"] == {} and prefill["temp_bytes"] < 1.0e9, prefill
+    _assert_scoped(decode, {"attn", "window_attn", "ffn", "experts", "head"},
+                   {"_paged_write_once": ["attn", "window_attn"],
+                    "_paged_grouped_call_once": ["attn", "window_attn"],
+                    "_grouped_ffn_call": ["experts"]})
 
 
 def test_autotune_lookup_never_measures_under_trace():

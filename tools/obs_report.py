@@ -245,7 +245,8 @@ SERVE_GAUGES = ("serve.queue_depth", "serve.active_slots",
 # expert layers (text/models/kimi_k2.MOE_STATS; with zero-compute experts
 # beside them text/models/longcat_flash.SCMOE_STATS, which starts with
 # those), a net with recurrent layers (text/models/olmo_hybrid
-# .LINEAR_STATS); self_check pins all three
+# .LINEAR_STATS), a net with sliding-window layers (text/models/laguna
+# .ATTN_STATS); self_check pins all four
 SERVE_NET_GAUGES = (
     "serve.moe_decode_tokens", "serve.moe_decode_pairs_held",
     "serve.moe_decode_experts_touched", "serve.moe_decode_peak_pairs",
@@ -257,7 +258,9 @@ SERVE_NET_GAUGES = (
     "serve.moe_prefill_pairs_real", "serve.moe_prefill_pairs_zero",
     "serve.moe_prefill_pairs_real_sq",
     "serve.linear_prefill_tokens", "serve.linear_prefill_pad_tokens",
-    "serve.linear_decode_layer_steps")
+    "serve.linear_decode_layer_steps",
+    "serve.attn_full_decode_tokens_read",
+    "serve.attn_window_decode_tokens_read", "serve.window_ring_bytes")
 SERVE_COUNTERS = ("serve.preempted", "serve.tokens_generated",
                   "serve.requests_completed", "serve.requests_errored",
                   "serve.hot_swaps", "serve.completion_log_errors",
@@ -296,6 +299,23 @@ def moe_line(values):
     return said
 
 
+def window_line(values):
+    """`  attn: ...`: what the decode steps read of the cache in a net
+    with sliding-window layers: cached tokens a step attended to in its
+    full layers (a slot's whole stream) and in its window layers (at most
+    the window), summed over slots and layers, and the bytes of the
+    rings. None for a net without window layers."""
+    ring = values.get("serve.window_ring_bytes", 0)
+    steps = values.get("serve.steps", 0)
+    if not ring or not steps:
+        return None
+    full = values.get("serve.attn_full_decode_tokens_read", 0) / steps
+    window = values.get("serve.attn_window_decode_tokens_read", 0) / steps
+    return (f"  attn: decode: {full:.1f} cached tokens read a step in the "
+            f"full layers, {window:.1f} in the window layers; rings "
+            f"{ring / 1e6:.3f} MB")
+
+
 def prefill_line(values):
     """`  prefill: ...`: the rows the prefills dispatched (the buckets'
     sizes) beside the prompts' own tokens, and the share that was padding:
@@ -316,15 +336,16 @@ def prefill_line(values):
 def serving_section(metrics, spans) -> str:
     """Continuous-batching serve tier: pool/queue gauges, stream
     counters, the prefills' rows and padding (`prefill_line`), the expert
-    layers' line (`moe_line`), TTFT/per-token
+    layers' line (`moe_line`), the window layers' (`window_line`),
+    TTFT/per-token
     latency histograms, and the per-phase span table (one serve/tick per
     beat and its phases)."""
     values = metrics.get("values", {})
     rows = [[k, values[k]] for k in SERVE_GAUGES + SERVE_NET_GAUGES
             + SERVE_COUNTERS if k in values]
     out = [_fmt_table(["metric", "value"], rows)]
-    out += [line for line in (prefill_line(values), moe_line(values))
-            if line]
+    out += [line for line in (prefill_line(values), moe_line(values),
+                              window_line(values)) if line]
     for hname, label in (("serve/ttft_ms", "ttft"),
                          ("serve/token_ms", "per-token")):
         h = metrics.get("histograms", {}).get(hname)
@@ -502,14 +523,14 @@ def self_check():
             problems.append(
                 f"obs_report: serving.GAUGES {serving.GAUGES} != "
                 f"renderer SERVE_GAUGES {SERVE_GAUGES} — update both")
-        from paddle_tpu.text.models import (kimi_k2, longcat_flash,
+        from paddle_tpu.text.models import (kimi_k2, laguna, longcat_flash,
                                             olmo_hybrid)
         if longcat_flash.SCMOE_STATS[:len(kimi_k2.MOE_STATS)] \
                 != kimi_k2.MOE_STATS:
             problems.append("obs_report: longcat_flash.SCMOE_STATS no "
                             "longer starts with kimi_k2.MOE_STATS")
         named = tuple(f"serve.{n}" for n in longcat_flash.SCMOE_STATS
-                      + olmo_hybrid.LINEAR_STATS)
+                      + olmo_hybrid.LINEAR_STATS + laguna.ATTN_STATS)
         if named != SERVE_NET_GAUGES:
             problems.append(
                 f"obs_report: the served nets' SERVE_STATS {named} != "
