@@ -14,8 +14,9 @@ a seed, no network, no git, no child process that needs the chip):
 - kernels    each Pallas kernel compiled by Mosaic against its jnp
              reference, fwd and bwd where it has one
 - multichip  (>= 4 devices) the train path through fleet on dp=4 and
-             dp=2 x tp=2, and the LocalSGD shard_map step; on fewer
-             devices an explicit SKIP
+             dp=2 x tp=2, a dp=4 fit with BERT's dropout on (each chip
+             draws its own rows' bits), and the LocalSGD shard_map step;
+             on fewer devices an explicit SKIP
 
 Every phase prints PASS/FAIL with wall time split into compile and run,
 peak device memory, and the pallas.* counters with reasons. Any FAIL, any
@@ -1184,11 +1185,56 @@ def kernels_phase(sizes):
 # multichip: the train path through fleet, sharded
 # --------------------------------------------------------------------------
 
+DROPOUT_COUNTERS = ("dropout.local_draw",
+                    "dropout.local_draw_fallback.manual",
+                    "dropout.local_draw_fallback.indivisible")
+
+
+def dp4_dropout_fit(sizes, cfg, steps):
+    """One dp=4 fit of `cfg` with BERT's published dropout 0.1 / 0.1 (the
+    caller has declared the mesh). Not compared with one chip: on a `dp`
+    mesh each shard draws the bits of its own rows from
+    fold_in(key, dp index) (ops/norm_ops._keep_mask), so a row's mask
+    depends on the mesh and the losses differ by more than rounding. What
+    must hold: the draw went local at every site, one step variant, no
+    late compile, finite loss. Returns (report keys, failures)."""
+    from paddle_tpu.core import monitor
+
+    def counts():
+        return {k: int(monitor.stat_get(k)) for k in DROPOUT_COUNTERS}
+
+    before = counts()
+    model = _bert_model(dataclasses.replace(
+        cfg, hidden_dropout_prob=0.1, attention_probs_dropout_prob=0.1),
+        sizes)
+    losses, late = _fit(model, sizes, steps)
+    moved = {k: v - before[k] for k, v in counts().items()}
+    variants = model._engine._train_fn._cache_size()
+    failures = []
+    if not moved["dropout.local_draw"]:
+        failures.append("dp4_dropout: a dp mesh was active and no dropout "
+                        f"site drew its rows locally: {moved}")
+    if late or variants != 1:
+        failures.append(f"dp4_dropout: {late} backend compiles after step "
+                        f"1, {variants} step variants")
+    if not np.isfinite(losses).all():
+        failures.append(f"dp4_dropout: non-finite loss: {losses}")
+    report = {"dp4_dropout_loss": round(float(losses[-1]), 4),
+              "dp4_dropout_step_variants": variants}
+    report.update({f"dp4_{k}": v for k, v in moved.items()})
+    return report, failures
+
+
 def multichip_phase(sizes):
     """dp=4 and dp=2 x tp=2 meshes under the hapi sharded step, and fleet's
     LocalSGD shard_map step, against a one-device run of the same global
-    batches (dropout off: the hardware bit generator is not
-    sharding-invariant)."""
+    batches. Those parity runs keep dropout off: a row's dropout bits
+    depend on the mesh (the hardware bit generator is not
+    sharding-invariant, and since PR 44 each `dp` shard draws its own
+    rows from a key folded with its `dp` index), so a sharded loss with
+    dropout on is another sample, not the one-chip loss reordered. One
+    more dp=4 fit runs with dropout on (`dp4_dropout_fit`) and is held
+    to what does not depend on the bits."""
     from paddle_tpu import memory
     from paddle_tpu.distributed import fleet
     from paddle_tpu.distributed import mesh as mesh_mod
@@ -1241,6 +1287,13 @@ def multichip_phase(sizes):
             if not all(b > floor for b in in_use):
                 failures.append(f"{tag}: a device holds under {floor} "
                                 f"bytes: {in_use}")
+
+        print("  multichip: START dp4_dropout", flush=True)
+        mesh_mod.reset_mesh()
+        mesh_mod.init_mesh({"dp": 4})
+        drawn, failed = dp4_dropout_fit(sizes, cfg, steps)
+        report.update(drawn)
+        failures.extend(failed)
 
         # fit's shard_map path (hapi _build_localsgd_fn)
         print("  multichip: START localsgd_shard_map", flush=True)

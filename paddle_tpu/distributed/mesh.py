@@ -90,12 +90,15 @@ def axis_tiers(mesh_or_shape) -> Dict[str, dict]:
     return out
 
 
-def shard_map(f, mesh=None, in_specs=None, out_specs=None, check=False):
+def shard_map(f, mesh=None, in_specs=None, out_specs=None, check=False,
+              axis_names=None):
     """`jax.shard_map` with the varying-manual-axes check defaulting OFF —
     the pipeline/MoE SPMD programs here intermix psum/ppermute/all_to_all
-    in ways the checker rejects spuriously."""
+    in ways the checker rejects spuriously. `axis_names` makes only those
+    axes manual and leaves the rest to GSPMD (default: all of them)."""
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=check)
+                         out_specs=out_specs, check_vma=check,
+                         axis_names=frozenset(axis_names or ()))
 
 
 _lock = threading.Lock()

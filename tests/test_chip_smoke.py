@@ -98,6 +98,32 @@ def test_kernels_phase_toy(interpret):
 def test_multichip_phase_toy():
     result = _run("multichip", chip_smoke.multichip_phase)
     assert result["dp2_tp2_sharded_params"] > 0
+    assert result["dp4_dropout.local_draw"] == 1 + 4 * SIZES.multichip_layers
+    assert result["dp4_dropout_step_variants"] == 1
+
+
+def test_multichip_phase_toy_reports_the_local_draws():
+    """The slow toy run above carries these keys too; here the dropout-on
+    fit alone, fast enough for tier-1."""
+    import dataclasses
+    from paddle_tpu.distributed import mesh as mesh_mod
+    mesh_mod.init_mesh({"dp": 4})
+    cfg = dataclasses.replace(SIZES.bert, num_hidden_layers=1)
+    report, failures = chip_smoke.dp4_dropout_fit(SIZES, cfg, 3)
+    assert not failures, failures
+    assert report["dp4_dropout.local_draw"] == 1 + 4      # sites, 1 layer
+    assert report["dp4_dropout.local_draw_fallback.manual"] == 0
+    assert report["dp4_dropout.local_draw_fallback.indivisible"] == 0
+    assert report["dp4_dropout_step_variants"] == 1
+    assert np.isfinite(report["dp4_dropout_loss"])
+
+
+def test_dropout_fit_without_a_mesh_fails_the_report():
+    import dataclasses
+    cfg = dataclasses.replace(SIZES.bert, num_hidden_layers=1)
+    report, failures = chip_smoke.dp4_dropout_fit(SIZES, cfg, 2)
+    assert report["dp4_dropout.local_draw"] == 0
+    assert len(failures) == 1 and "no dropout site" in failures[0]
 
 
 def test_multichip_phase_states_its_skip(monkeypatch):
