@@ -92,6 +92,8 @@ class Sizes:
     hybrid_serve: tuple          # (slots, blocks, block, max_seq, prompts)
     window: object               # LagunaConfig: a full and a sliding layer
     window_serve: tuple          # (slots, blocks, block, max_seq)
+    sink: object                 # MiMoV2Config: a full and a sliding layer
+    sink_serve: tuple            # (slots, blocks, block, max_seq)
 
     @staticmethod
     def full():
@@ -100,6 +102,7 @@ class Sizes:
         from paddle_tpu.text.models.kimi_k2 import KimiK2Config
         from paddle_tpu.text.models.laguna import LagunaConfig
         from paddle_tpu.text.models.longcat_flash import LongCatFlashConfig
+        from paddle_tpu.text.models.mimo_v2 import MiMoV2Config
         from paddle_tpu.text.models.olmo_hybrid import OlmoHybridConfig
         return Sizes(
             bert=BertConfig.bert_base(), train_batch=32, train_seq=128,
@@ -151,7 +154,15 @@ class Sizes:
             # its slots, and a pool that pages one layer
             window=LagunaConfig(vocab_size=12544, num_layers=2,
                                 experts_held=(0, 16), dtype="bfloat16"),
-            window_serve=(128, 1024, 128, 9216))
+            window_serve=(128, 1024, 128, 9216),
+            # the benchmark's share of MiMo-V2-Flash at its published
+            # widths, the leading full layer (64 query heads over 4, keys
+            # of 192 over values of 128, dense FFN) and one sliding expert
+            # layer (64 over 8, sinks, a ring of ONE 128-token block a
+            # slot), its slots, and a pool that pages one layer
+            sink=MiMoV2Config(vocab_size=19072, num_hidden_layers=2,
+                              experts_held=(0, 16), dtype="bfloat16"),
+            sink_serve=(128, 2048, 128, 14336))
 
     @staticmethod
     def toy():
@@ -160,6 +171,7 @@ class Sizes:
         from paddle_tpu.text.models.kimi_k2 import KimiK2Config
         from paddle_tpu.text.models.laguna import LagunaConfig
         from paddle_tpu.text.models.longcat_flash import LongCatFlashConfig
+        from paddle_tpu.text.models.mimo_v2 import MiMoV2Config
         from paddle_tpu.text.models.olmo_hybrid import OlmoHybridConfig
         return Sizes(
             bert=BertConfig.tiny(), train_batch=8, train_seq=16,
@@ -185,7 +197,10 @@ class Sizes:
             window=LagunaConfig.tiny(num_layers=2, experts_held=(4, 8),
                                      dtype="bfloat16", sliding_window=16,
                                      ring_block=8),
-            window_serve=(2, 16, 8, 64))
+            window_serve=(2, 16, 8, 64),
+            sink=MiMoV2Config.tiny(num_hidden_layers=2, experts_held=(4, 8),
+                                   dtype="bfloat16"),
+            sink_serve=(2, 16, 8, 64))
 
 
 # --------------------------------------------------------------------------
@@ -1033,57 +1048,92 @@ def _check_latent_paged(sizes):
             "block_size": block}
 
 
-def _check_grouped_paged(sizes):
-    """The grouped-query form of the paged kernel at `sizes.window`'s
-    widths and `sizes.window_serve`'s slots, its two call sites: a full
-    layer's query heads over the pool's arenas under ragged tables
-    (`full`), and a sliding layer's over the slots' rings, some wrapped
-    and some not (`ring`); each against `paged_attention_ref` in float32
-    on the same operands."""
+def _paged_call_sites(serve, seed, full, ring, window, ring_block,
+                      sink_init=None):
+    """The grouped paged kernel at its two call sites, each against
+    `paged_attention_ref` in float32 on the same operands: `full` =
+    (query heads, key-value heads, key depth, value depth) over the pool's
+    arenas under ragged tables, `ring` the same of a sliding layer over
+    the slots' rings of `window` tokens in blocks of `ring_block`, some
+    wrapped and some not, with a sink logit a head drawn N(*sink_init)
+    where given. `serve` = (slots, blocks, block, max_seq)."""
     from paddle_tpu.nn.kv_pool import (KVBlockPool, _ring_arena,
                                        _ring_tables, paged_attention_ref,
                                        window_attention, window_ring_shape)
     from paddle_tpu.ops.pallas.decode_attention import paged_decode_attention
 
-    cfg = sizes.window
-    slots, _, block, max_seq = sizes.window_serve
-    kv, d = cfg.num_kv_heads, cfg.head_dim
-    heads = dict(zip(cfg.layer_types, cfg.num_attention_heads_per_layer))
+    slots, _, block, max_seq = serve
     width = -(-max_seq // block)
-    scale = d ** -0.5
-    ks = jax.random.split(jax.random.PRNGKey(SEED + 6), 6)
-    rng = np.random.RandomState(SEED + 6)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    rng = np.random.RandomState(seed)
 
     def draw(key, shape):
         return jax.random.normal(key, shape, jnp.float32).astype(DTYPE)
 
-    shape = KVBlockPool(slots * width // 4, block).arena_shape(kv, d)
-    ka, va = draw(ks[0], shape), draw(ks[1], shape)
-    q = draw(ks[2], (slots, heads["full_attention"], 1, d))
+    heads, kv, d, dv = full
+    scale = d ** -0.5
+    pool = KVBlockPool(slots * width // 4, block)
+    ka, va = (draw(ks[0], pool.arena_shape(kv, d)),
+              draw(ks[1], pool.arena_shape(kv, dv)))
+    q = draw(ks[2], (slots, heads, 1, d))
     lengths = np.linspace(0, width * block // 4 - 1, slots).astype(np.int32)
     tables = np.zeros((slots, width), np.int32)
-    free = iter(rng.permutation(shape[0] - 1) + 1)
+    free = iter(rng.permutation(ka.shape[0] - 1) + 1)
     for i, n in enumerate(lengths):          # the blocks a stream owns
         for j in range(n // block + 1):
             tables[i, j] = next(free)
     args = (jnp.asarray(tables), jnp.asarray(lengths))
-    full = jax.jit(lambda *a: paged_decode_attention(*a, scale))(
+    got = jax.jit(lambda *a: paged_decode_attention(*a, scale))(
         q, ka, va, *args)
-    full_r = jax.jit(lambda *a: paged_attention_ref(*a, scale))(
+    want = jax.jit(lambda *a: paged_attention_ref(*a, scale))(
         *_f32(q, ka, va), *args)
+    errs = {"full": _rel_err(got, want)}
 
-    ring = (slots,) + window_ring_shape(cfg.sliding_window, cfg.ring_block,
-                                        kv, d)
-    kr, vr = draw(ks[3], ring), draw(ks[4], ring)
-    q = draw(ks[5], (slots, heads["sliding_attention"], 1, d))
-    lengths = jnp.asarray(np.linspace(
-        0, 3 * cfg.sliding_window, slots).astype(np.int32))
-    got = jax.jit(lambda *a: window_attention(*a, scale))(q, kr, vr, lengths)
-    got_r = jax.jit(lambda q, k, v, n: paged_attention_ref(
+    heads, kv, d, dv = ring
+    kr, vr = (draw(key, (slots,) + window_ring_shape(window, ring_block, kv,
+                                                     depth))
+              for key, depth in ((ks[3], d), (ks[4], dv)))
+    q = draw(ks[5], (slots, heads, 1, d))
+    sinks = None if sink_init is None else sink_init[0] + sink_init[1] \
+        * jax.random.normal(ks[6], (heads,), jnp.float32)
+    lengths = jnp.asarray(np.linspace(0, 3 * window, slots).astype(np.int32))
+    got = jax.jit(lambda *a: window_attention(*a, scale, sinks))(
+        q, kr, vr, lengths)
+    want = jax.jit(lambda q, k, v, n: paged_attention_ref(
         q, _ring_arena(k), _ring_arena(v), _ring_tables(k),
-        jnp.minimum(n, cfg.sliding_window - 1), scale))(
-            *_f32(q, kr, vr), lengths)
-    return {"full": _rel_err(full, full_r), "ring": _rel_err(got, got_r)}
+        jnp.minimum(n, window - 1), scale, sinks))(*_f32(q, kr, vr), lengths)
+    errs["ring"] = _rel_err(got, want)
+    if sinks is not None:       # the same call without its sinks must differ
+        bare = jax.jit(lambda *a: window_attention(*a, scale))(
+            q, kr, vr, lengths)
+        if _rel_err(bare, want) <= KERNEL_TOL:
+            raise AssertionError("a call without its sinks agrees with the "
+                                 "reference with them: the sinks do nothing")
+    return errs
+
+
+def _check_grouped_paged(sizes):
+    """The grouped-query form of the paged kernel at `sizes.window`'s
+    widths and `sizes.window_serve`'s slots (`_paged_call_sites`)."""
+    cfg = sizes.window
+    kv, d = cfg.num_kv_heads, cfg.head_dim
+    heads = dict(zip(cfg.layer_types, cfg.num_attention_heads_per_layer))
+    return _paged_call_sites(
+        sizes.window_serve, SEED + 6, (heads["full_attention"], kv, d, d),
+        (heads["sliding_attention"], kv, d, d), cfg.sliding_window,
+        cfg.ring_block)
+
+
+def _check_sink_paged(sizes):
+    """Its sink / two-width form at `sizes.sink`'s widths and
+    `sizes.sink_serve`'s slots: a K arena beside a shallower V arena, a
+    sliding layer's one-block rings with a sink logit a head."""
+    cfg = sizes.sink
+    d, dv = cfg.head_dim, cfg.v_head_dim
+    return _paged_call_sites(
+        sizes.sink_serve, SEED + 7, (*cfg.heads("full_attention"), d, dv),
+        (*cfg.heads("sliding_attention"), d, dv), cfg.sliding_window,
+        cfg.ring_block, cfg.sink_init)
 
 
 def _check_grouped_ffn(sizes):
@@ -1137,6 +1187,7 @@ def kernel_checks(sizes):
     checks["latent_paged_decode"] = lambda: _check_latent_paged(sizes)
     checks["grouped_expert_ffn"] = lambda: _check_grouped_ffn(sizes)
     checks["grouped_paged_decode"] = lambda: _check_grouped_paged(sizes)
+    checks["sink_paged_decode"] = lambda: _check_sink_paged(sizes)
     return checks
 
 

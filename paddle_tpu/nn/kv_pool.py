@@ -63,14 +63,15 @@ TRASH_BLOCK = 0  # physical row 0 of every arena; never allocated
 
 class PagedKVCache(typing.NamedTuple):
     """One layer's paged decode cache. `k`/`v` are the physical arenas
-    [n_blocks + 1, h, d, block_size] (row 0 = trash); `block_tables`
+    [n_blocks + 1, h, d, block_size] (row 0 = trash; `v` may be its own
+    depth, as its `CacheSpec` entry says); `block_tables`
     [b, max_blocks] i32 maps each request-slot's logical blocks to
     physical rows (unallocated entries 0); `lengths` [b] i32 counts the
     tokens already written per slot. A pytree — jit/scan-able, and the
     block_tables/lengths leaves are shared by reference across layers."""
 
     k: object             # [n_blocks + 1, h, d, block_size]
-    v: object             # [n_blocks + 1, h, d, block_size]
+    v: object             # [n_blocks + 1, h, d_v, block_size]
     block_tables: object  # [b, max_blocks] i32
     lengths: object       # [b] i32
 
@@ -116,7 +117,8 @@ class WindowKVCache(typing.NamedTuple):
     """One sliding-window layer's cache: the keys and values of the last
     `window` tokens of each decode slot and nothing else, whatever the
     stream's length. A RING a slot, not pages of the pool: `k`/`v` are
-    [slots, ring_blocks, h, d, block] — `ring_blocks * block` = window
+    [slots, ring_blocks, h, d, block] (`v` its own depth d_v where a net's
+    values are narrower than its keys) — `ring_blocks * block` = window
     tokens in the lanes, a slot's row laid out as `ring_blocks` rows of
     an arena, so that [slots * ring_blocks, h, d, block] (a reshape of
     the leading dimensions, no copy) IS an arena and slot i's ring its
@@ -138,7 +140,7 @@ class WindowKVCache(typing.NamedTuple):
     (`lengths` is the stream's, not the ring's)."""
 
     k: object             # [slots, ring_blocks, h, d, block]
-    v: object             # [slots, ring_blocks, h, d, block]
+    v: object             # [slots, ring_blocks, h, d_v, block]
     block_tables: object  # [b, max_blocks] i32 (the paged layers')
     lengths: object       # [b] i32
 
@@ -412,14 +414,17 @@ def _write_blocks(arena, bt, lens, new_kv):
 
 
 def paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
-                        scale):
+                        scale, sinks=None):
     """jnp fallback / parity oracle: gather each slot's blocks into a
     contiguous [b, h_kv, max_blocks*bs, d] view and run the same masked
     softmax as _static_cache_attention, with per-row live lengths. Row r
     of slot i attends logical cols <= lengths[i] + r. q [b, h, s, d] with
     h = G x h_kv: query head j reads key-value head j // G (the G query
     heads of a key-value head are further rows of its product, each at
-    its own position)."""
+    its own position). The values may be narrower than the keys (the V
+    arena's own depth d_v: -> [b, h, s, d_v]). `sinks` [h] float32: query
+    head j's sink logit is one more term of its softmax's denominator, in
+    float32, and adds no value."""
     b, h, s, d = q.shape
     hk, bs = k_arena.shape[1], k_arena.shape[3]
     group = h // hk
@@ -429,7 +434,8 @@ def paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
 
     def gather(arena):
         g = jnp.take(arena, bt, axis=0)          # [b, nb, hk, d, bs]
-        return jnp.transpose(g, (0, 2, 1, 4, 3)).reshape(b, hk, L, d)
+        return jnp.transpose(g, (0, 2, 1, 4, 3)).reshape(
+            b, hk, L, arena.shape[2])
 
     kc, vc = gather(k_arena), gather(v_arena)
     lens = jnp.asarray(lengths, jnp.int32)
@@ -441,9 +447,17 @@ def paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
                         q.reshape(b, hk, group * s, d).astype(kc.dtype), kc,
                         preferred_element_type=jnp.float32) * scale
     scores = jnp.where(live[:, None], scores, -1e9)
-    p = jax.nn.softmax(scores, axis=-1).astype(vc.dtype)
-    return jnp.einsum("bhsl,bhld->bhsd", p, vc).astype(q.dtype) \
-        .reshape(b, h, s, d)
+    if sinks is None:
+        p = jax.nn.softmax(scores, axis=-1)
+    else:       # the sink: a last column of the softmax, then dropped
+        sink = jnp.repeat(jnp.asarray(sinks, jnp.float32).reshape(hk, group),
+                          s, axis=1)                              # [hk, G s]
+        p = jax.nn.softmax(jnp.concatenate(
+            [scores, jnp.broadcast_to(sink[None, :, :, None],
+                                      scores.shape[:3] + (1,))],
+            axis=-1), axis=-1)[..., :-1]
+    return jnp.einsum("bhsl,bhld->bhsd", p.astype(vc.dtype), vc) \
+        .astype(q.dtype).reshape(b, h, s, vc.shape[-1])
 
 
 def latent_paged_attention(q, arena, block_tables, lengths, scale,
@@ -526,12 +540,30 @@ def _paged_gate(kernel, training, supported):
     return True
 
 
-def _paged_kernel_eligible(q, k_arena, training):
-    from ..ops.pallas.decode_attention import paged_supported
-    return _paged_gate(
-        "paged_decode_attention", training,
-        lambda: paged_supported(tuple(q.shape), tuple(k_arena.shape),
-                                k_arena.dtype.itemsize))
+def _paged_kernel_eligible(q, k_arena, v_arena, training, sinks=None):
+    """The paged kernel's gate over BOTH arenas. Beside `_paged_gate`'s
+    reasons: `value_arena`, a V arena that is not the K arena's blocks
+    and heads in the K arena's dtype (its depth may differ), and
+    `sinks`, sinks where the kernel takes none (one query head a
+    key-value head, or a chunk: only the grouped form starts a slot's
+    softmax from a sink)."""
+    from ..ops.pallas import gate_reject
+    from ..ops.pallas.decode_attention import paged_group, paged_supported
+    kernel = "paged_decode_attention"
+    if not _paged_gate(
+            kernel, training,
+            lambda: paged_supported(tuple(q.shape), tuple(k_arena.shape),
+                                    k_arena.dtype.itemsize,
+                                    d_v=v_arena.shape[2])):
+        return False
+    if v_arena.dtype != k_arena.dtype or v_arena.shape[:2] \
+            + v_arena.shape[3:] != k_arena.shape[:2] + k_arena.shape[3:]:
+        return gate_reject(kernel, "value_arena")
+    if sinks is not None and (
+            paged_group(q.shape[1], k_arena.shape[1]) < 2
+            or tuple(sinks.shape) != (q.shape[1],)):
+        return gate_reject(kernel, "sinks")
+    return True
 
 
 def _latent_kernel_eligible(q, arena, value_dim):
@@ -551,10 +583,13 @@ def _write_kernel_eligible(arena, slots):
 
 
 def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,
-                    training=False):
+                    training=False, sinks=None):
     """Gated paged attention: the Pallas block-table kernel when
-    eligible, `paged_attention_ref` when the gate rejects."""
-    if _paged_kernel_eligible(q, k_arena, training):
+    eligible, `paged_attention_ref` when the gate rejects. The K arena is
+    [n, h_kv, d, bs], the V arena [n, h_kv, d_v, bs] (-> [b, h, s, d_v]);
+    `sinks` [h] float32 or None: a sink logit a query head
+    (`paged_attention_ref`)."""
+    if _paged_kernel_eligible(q, k_arena, v_arena, training, sinks):
         from ..ops.pallas import run_guarded
         from ..core import monitor
         from ..ops.pallas.decode_attention import (paged_cut,
@@ -568,9 +603,14 @@ def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,
         # the arena's blocks and a step a slot: what bounds the
         # grouped-query form's work list
         max_steps = k_arena.shape[0] - 1 + q.shape[0]
+        d_v = int(v_arena.shape[2])
         cut = paged_cut(tuple(q.shape), tuple(k_arena.shape),
                         block_tables.shape[1], k_arena.dtype.itemsize,
-                        max_steps)
+                        max_steps, d_v=d_v)
+        # a call whose values are narrower than its keys, or that starts
+        # its softmax from sinks, says so beside its cut
+        if d_v != q.shape[3] or sinks is not None:
+            cut.update(value_dim=d_v, sinks=int(sinks is not None))
         group = q.shape[1] // k_arena.shape[1]
         key = f"b{q.shape[0]}s{q.shape[2]}" + (f"g{group}" if group > 1
                                                else "")
@@ -581,10 +621,10 @@ def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,
             "paged_decode_attention",
             lambda: paged_decode_attention(q, k_arena, v_arena,
                                            block_tables, lengths, scale,
-                                           max_steps),
+                                           max_steps, sinks),
             **cut)
     return paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
-                               scale)
+                               scale, sinks)
 
 
 # --------------------------------------------------------------------------
@@ -593,8 +633,11 @@ def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,
 
 def window_ring_shape(window, block, heads, head_dim):
     """One slot's row of a ring of `window` tokens in blocks of `block`:
-    (ring_blocks, heads, head_dim, block), an arena's layout. The window
-    is whole blocks (ROADMAP: other windows wait)."""
+    (ring_blocks, heads, head_dim, block), an arena's layout; a layer's K
+    ring and V ring each have their own (`head_dim` may differ). The
+    window is whole blocks (ROADMAP: other windows wait), down to ONE: a
+    window of a block's tokens is a ring of one block a slot, and the
+    kernel's work list then holds one step a slot."""
     if window < block or window % block:
         raise ValueError(f"a window of {window} tokens is no multiple of "
                          f"its ring's block of {block}")
@@ -645,14 +688,15 @@ def window_write(ring, lengths, new_kv):
                     new_kv).reshape(ring.shape)
 
 
-def window_attention(q, k_ring, v_ring, lengths, scale):
+def window_attention(q, k_ring, v_ring, lengths, scale, sinks=None):
     """One token a slot over its ring, AFTER `window_write`: q [b, h, 1,
     d] attends the min(lengths[i] + 1, window) columns that hold a token
     (columns 0..lengths[i] until the ring wraps, all of them after).
     `paged_attention` over the ring as an arena under the slot's own
-    table: the paged kernel's second call site, and the same gate."""
+    table: the paged kernel's second call site, and the same gate, `sinks`
+    and a V ring of its own depth included."""
     window = k_ring.shape[1] * k_ring.shape[4]
     lens = jnp.minimum(jnp.asarray(lengths, jnp.int32),
                        jnp.int32(window - 1))
     return paged_attention(q, _ring_arena(k_ring), _ring_arena(v_ring),
-                           _ring_tables(k_ring), lens, scale)
+                           _ring_tables(k_ring), lens, scale, sinks=sinks)

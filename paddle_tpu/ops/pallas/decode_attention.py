@@ -200,11 +200,12 @@ def _pick_bk(shape, dtype, scale, measure_builder):
 def _paged_block_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, scale,
                         live):
     """One K/V block into the online softmax of a tile of ht heads: q
-    [ht, s, d] against kT [ht, d, bs], the columns `live()` ([s, bs] bool)
-    leaves, the float32 state m, l [ht, s, 1] and acc [ht, s, d] rescaled
-    and added to. Shared by the multi-head and the grouped-query kernel:
-    they differ in which columns a row may see and in how the grid walks
-    the blocks."""
+    [ht, s, d] against kT [ht, d, bs] and vT [ht, d_v, bs] (d_v = d but
+    where a net's values are narrower than its keys), the columns `live()`
+    ([s, bs] bool) leaves, the float32 state m, l [ht, s, 1] and acc
+    [ht, s, d_v] rescaled and added to. Shared by the multi-head and the
+    grouped-query kernel: they differ in which columns a row may see and
+    in how the grid walks the blocks."""
     sc = jax.lax.dot_general(q_ref[0], k_ref[0],
                              (((2,), (1,)), ((0,), (0,))),
                              preferred_element_type=jnp.float32) * scale
@@ -271,19 +272,23 @@ def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
 _ATTN_VMEM_BYTES = 12 << 20
 
 
-def _paged_step_bytes(ht, s_p, d, bs, itemsize):
+def _paged_step_bytes(ht, s_p, d, bs, itemsize, d_v=None):
     """VMEM bytes of one grid step over `ht` heads, as Mosaic lays the
-    blocks out: the minor dimension padded to the 128 lanes."""
-    d_l, bs_l = _ceil_to(d, 128), _ceil_to(bs, 128)
-    kv = 2 * 2 * ht * d * bs_l * itemsize          # K, V, double-buffered
-    qo = 2 * 2 * ht * s_p * d_l * itemsize         # q, out, double-buffered
-    state = ht * s_p * (128 + 128 + d_l) * 4       # m, l, acc in float32
+    blocks out: the minor dimension padded to the 128 lanes. Keys (and
+    queries) `d` deep, values (and outputs) `d_v`, `d` where not given; a
+    group's sinks, where a call has them, are one more column of state
+    (4 KB a head) and are not counted."""
+    d_v = d if d_v is None else d_v
+    d_l, dv_l, bs_l = _ceil_to(d, 128), _ceil_to(d_v, 128), _ceil_to(bs, 128)
+    kv = 2 * ht * (d + d_v) * bs_l * itemsize      # K, V, double-buffered
+    qo = 2 * ht * s_p * (d_l + dv_l) * itemsize    # q, out, double-buffered
+    state = ht * s_p * (128 + 128 + dv_l) * 4      # m, l, acc in float32
     scores = 3 * ht * s_p * bs_l * 4               # sc, p and a temporary
     return kv + qo + state + scores
 
 
 def paged_heads_per_step(h, s_p, d, bs, itemsize,
-                         budget=_ATTN_VMEM_BYTES) -> int:
+                         budget=_ATTN_VMEM_BYTES, d_v=None) -> int:
     """The paged kernel's head tile: the largest divisor of `h` whose
     grid step fits `budget` bytes of VMEM, 0 where not even one head
     does. From the shape alone: GPT-2 XL's decode step (h 25, 8 padded
@@ -291,7 +296,7 @@ def paged_heads_per_step(h, s_p, d, bs, itemsize,
     one step, its 128- and 256-row prefills 5."""
     for ht in range(int(h), 0, -1):
         if h % ht == 0 and \
-                _paged_step_bytes(ht, s_p, d, bs, itemsize) <= budget:
+                _paged_step_bytes(ht, s_p, d, bs, itemsize, d_v) <= budget:
             return ht
     return 0
 
@@ -310,9 +315,11 @@ def _paged_rows(group, s):
     return _ceil_to(group if group > 1 else s, 8)
 
 
-def paged_supported(q_shape, arena_shape, itemsize=4) -> bool:
+def paged_supported(q_shape, arena_shape, itemsize=4, d_v=None) -> bool:
     """Static predicate: can the paged kernel serve q [b, h, s, d] over
-    an arena [n_blocks, h_kv, d, block_size] of `itemsize`-byte elements?
+    a K arena [n_blocks, h_kv, d, block_size] of `itemsize`-byte elements
+    (and a V arena of the same blocks and heads, `d_v` deep: `d` where
+    not given)?
     h = h_kv (multi-head: the rows of a head's product are the chunk's
     positions), or h = G x h_kv with one token a slot (grouped-query: the
     rows are the G query heads of a key-value head, all under the same
@@ -324,18 +331,21 @@ def paged_supported(q_shape, arena_shape, itemsize=4) -> bool:
     b, h, s, d = q_shape
     nb_phys, hl, dl, bs = arena_shape
     group = paged_group(h, hl)
+    d_v = d if d_v is None else int(d_v)
     if dl != d or not group or (group > 1 and s != 1):
         return False
     if d > 256 or s < 1 or s > 256 or group > 256:
         return False
+    if d_v < 1 or d_v > 256 or d_v % 8:        # the values' sublanes
+        return False
     if bs < 8 or bs % 8 != 0 or nb_phys < 1:
         return False
     return paged_heads_per_step(hl, _paged_rows(group, s), d, bs,
-                                itemsize) > 0
+                                itemsize, d_v=d_v) > 0
 
 
 def paged_cut(q_shape, arena_shape, table_blocks, itemsize,
-              max_steps=None) -> dict:
+              max_steps=None, d_v=None) -> dict:
     """How a supported call is cut into grid steps: `heads_per_step`
     (key-value heads; each brings its G query heads as rows), and
     `grid_steps` = head tiles x (multi-head: b x the table's logical
@@ -345,7 +355,7 @@ def paged_cut(q_shape, arena_shape, table_blocks, itemsize,
     hl = arena_shape[1]
     group = paged_group(h, hl)
     ht = paged_heads_per_step(hl, _paged_rows(group, s), d, arena_shape[3],
-                              itemsize)
+                              itemsize, d_v=d_v)
     steps = b * int(table_blocks) if group == 1 else paged_grouped_steps(
         b, table_blocks, max_steps)
     return {"heads_per_step": ht, "grid_steps": (hl // ht) * steps}
@@ -372,13 +382,14 @@ def _paged_call_once(q, k_arena, v_arena, block_tables, lengths, *, scale,
                      interpret):
     from jax.experimental.pallas import tpu as pltpu
     b, h, s_p, d = q.shape
-    bs = k_arena.shape[3]
+    d_v, bs = v_arena.shape[2], k_arena.shape[3]
     nb = block_tables.shape[1]
     # the cut into grid steps: a step pays ~0.3 us whatever it holds, so
     # it holds as many of a block's heads as fit (one head a step made
     # GPT-2 XL's decode 6400 steps a layer of 16 KB each: 1.6 ms, all of
     # it step overhead, against 0.17 ms for 256 steps of 400 KB; PR 31)
-    ht = paged_heads_per_step(h, s_p, d, bs, k_arena.dtype.itemsize)
+    ht = paged_heads_per_step(h, s_p, d, bs, k_arena.dtype.itemsize,
+                              d_v=d_v)
 
     def q_map(ib, ih, ik, len_ref, bt_ref):
         return (ib, ih, _Z, _Z)
@@ -401,13 +412,13 @@ def _paged_call_once(q, k_arena, v_arena, block_tables, lengths, *, scale,
         in_specs=[
             pl.BlockSpec((1, ht, s_p, d), q_map),
             pl.BlockSpec((1, ht, d, bs), kv_map),
-            pl.BlockSpec((1, ht, d, bs), kv_map),
+            pl.BlockSpec((1, ht, d_v, bs), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, ht, s_p, d), q_map),
+        out_specs=pl.BlockSpec((1, ht, s_p, d_v), q_map),
         scratch_shapes=[
             _vmem((ht, s_p, 1), jnp.float32),
             _vmem((ht, s_p, 1), jnp.float32),
-            _vmem((ht, s_p, d), jnp.float32),
+            _vmem((ht, s_p, d_v), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_decode_attn_kernel,
@@ -415,7 +426,7 @@ def _paged_call_once(q, k_arena, v_arena, block_tables, lengths, *, scale,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, s_p, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, s_p, d_v), q.dtype),
         compiler_params=_cparams("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(lengths, block_tables, q, k_arena, v_arena)
@@ -463,15 +474,26 @@ def _paged_work_list(block_tables, lengths, bs, steps):
 
 
 def _paged_grouped_kernel(len_ref, slot_ref, blk_ref, phys_ref, n_ref,
-                          q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                          *, scale, bs, nb, s):
+                          q_ref, k_ref, v_ref, *rest, scale, bs, nb, s,
+                          sinks=False):
     """Grid (h_kv // ht, steps): step (ih, w) holds work item w — logical
-    block blk[w] of slot slot[w] for a tile of ht key-value heads, q/out
-    [1, ht, s, d] with the G query heads of a key-value head as rows (one
-    token a slot: every row under the same length), K/V [1, ht, d, bs].
-    The arithmetic of a block, and the blocks' order within a slot, are
+    block blk[w] of slot slot[w] for a tile of ht key-value heads, q
+    [1, ht, s, d] and out [1, ht, s, d_v] with the G query heads of a
+    key-value head as rows (one token a slot: every row under the same
+    length), K [1, ht, d, bs], V [1, ht, d_v, bs]. The arithmetic of a
+    block, and the blocks' order within a slot, are
     `_paged_decode_attn_kernel`'s. len_ref [b]: the fill with this step's
-    token; phys_ref is consumed by the index maps."""
+    token; phys_ref is consumed by the index maps.
+
+    With `sinks`, one more operand [ht, s, 1] float32 before the output:
+    a learned logit a query head that joins the softmax's denominator and
+    has no value (an attention sink). It is where a slot's state STARTS:
+    m = sink, l = exp(sink - m) = 1, acc = 0, and the blocks are added to
+    that as to any earlier block; a padded row's sink is NEG_INF, the
+    state the others start from but for l, which its first block's alpha
+    of 0 wipes out."""
+    sink_ref = rest[0] if sinks else None
+    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
     w = pl.program_id(1)
     ib, ik = slot_ref[w], blk_ref[w]
     live = w < n_ref[0]
@@ -483,8 +505,12 @@ def _paged_grouped_kernel(len_ref, slot_ref, blk_ref, phys_ref, n_ref,
 
     @pl.when(live & (ik == 0))
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        if sinks:
+            m_scr[:] = sink_ref[:]
+            l_scr[:] = jnp.ones_like(l_scr)
+        else:
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     @pl.when(live)
@@ -510,14 +536,17 @@ def paged_grouped_steps(b, table_blocks, max_steps=None) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "steps"))
-def _paged_grouped_call_once(q, k_arena, v_arena, block_tables, lengths, *,
-                             scale, interpret, steps):
+def _paged_grouped_call_once(q, k_arena, v_arena, block_tables, lengths,
+                             sinks=None, *, scale, interpret, steps):
     """q [b, h_kv, s_p, d] (the groups' query heads as rows, padded),
-    lengths [b] the fill INCLUDING this step's token."""
+    lengths [b] the fill INCLUDING this step's token, sinks None or
+    [h_kv, s_p, 1] float32 (a padded row's NEG_INF)."""
     from jax.experimental.pallas import tpu as pltpu
     b, h, s_p, d = q.shape
+    d_v = v_arena.shape[2]
     bs, nb = k_arena.shape[3], block_tables.shape[1]
-    ht = paged_heads_per_step(h, s_p, d, bs, k_arena.dtype.itemsize)
+    ht = paged_heads_per_step(h, s_p, d, bs, k_arena.dtype.itemsize,
+                              d_v=d_v)
     slot, blk, phys, n_live = _paged_work_list(block_tables, lengths, bs,
                                                steps)
 
@@ -527,44 +556,50 @@ def _paged_grouped_call_once(q, k_arena, v_arena, block_tables, lengths, *,
     def kv_map(ih, w, len_ref, slot_ref, blk_ref, phys_ref, n_ref):
         return (phys_ref[w], ih, _Z, _Z)
 
+    def sink_map(ih, w, len_ref, slot_ref, blk_ref, phys_ref, n_ref):
+        return (ih, _Z, _Z)        # resident across a head tile's steps
+
+    has_sinks = sinks is not None
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(h // ht, steps),
         in_specs=[
             pl.BlockSpec((1, ht, s_p, d), q_map),
             pl.BlockSpec((1, ht, d, bs), kv_map),
-            pl.BlockSpec((1, ht, d, bs), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, ht, s_p, d), q_map),
+            pl.BlockSpec((1, ht, d_v, bs), kv_map),
+        ] + [pl.BlockSpec((ht, s_p, 1), sink_map)] * has_sinks,
+        out_specs=pl.BlockSpec((1, ht, s_p, d_v), q_map),
         scratch_shapes=[
             _vmem((ht, s_p, 1), jnp.float32),
             _vmem((ht, s_p, 1), jnp.float32),
-            _vmem((ht, s_p, d), jnp.float32),
+            _vmem((ht, s_p, d_v), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_grouped_kernel, scale=scale, bs=bs,
-                               nb=nb, s=s_p)
+                               nb=nb, s=s_p, sinks=has_sinks)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, s_p, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, s_p, d_v), q.dtype),
         compiler_params=_cparams("parallel", "arbitrary"),
         interpret=interpret,
-    )(lengths, slot, blk, phys, n_live, q, k_arena, v_arena)
+    )(lengths, slot, blk, phys, n_live, q, k_arena, v_arena,
+      *([sinks] * has_sinks))
 
 
 def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
-                           scale=None, max_steps=None):
+                           scale=None, max_steps=None, sinks=None):
     """Attention of q [b, h, s, d] over a PAGED cache: per-request block
     tables [b, max_blocks] of physical block ids into shared arenas
-    k_arena/v_arena [n_blocks, h_kv, d, block_size]. `lengths` [b] is each
+    k_arena [n_blocks, h_kv, d, block_size] and v_arena [n_blocks, h_kv,
+    d_v, block_size] (d_v = d in most nets). `lengths` [b] is each
     request's cache fill count BEFORE this chunk (the chunk's k/v must
     already be written into the arena — nn/kv_pool.write_kv). Row r of
     batch i attends to logical cache cols <= lengths[i] + r. Block-table
     entries past the allocation MUST be 0 (the pool's reserved trash
     block): padded query rows reach past the live end and the index map
     must land on a valid physical row. Eval-only (no vjp); returns
-    [b, h, s, d] in q's dtype.
+    [b, h, s, d_v] in q's dtype.
 
     h = G x h_kv, G > 1, is grouped-query attention and takes one token a
     slot (s = 1): query head j reads key-value head j // G, the G query
@@ -574,15 +609,23 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
     The grouped form walks a work list of the live (slot, block) pairs
     (`_paged_grouped_kernel`); `max_steps` bounds them where the caller
     can (nn/kv_pool.paged_attention: a pool's block belongs to one slot),
-    else the list is as long as the tables."""
+    else the list is as long as the tables.
+
+    `sinks` [h] float32, the grouped form only: query head j's learned
+    sink logit joins its softmax's denominator and adds no value
+    (`_paged_grouped_kernel`): p_t = exp(s_t) / (exp(sink_j) + sum_t'
+    exp(s_t'))."""
     b, h, s, d = q.shape
-    hl = k_arena.shape[1]
+    hl, d_v = k_arena.shape[1], v_arena.shape[2]
     group = paged_group(h, hl)
-    if v_arena.shape != k_arena.shape or k_arena.shape[2] != d \
-            or not group or (group > 1 and s != 1):
+    if v_arena.shape[:2] + v_arena.shape[3:] \
+            != k_arena.shape[:2] + k_arena.shape[3:] \
+            or k_arena.shape[2] != d or not group or (group > 1 and s != 1) \
+            or (sinks is not None and (group < 2 or sinks.shape != (h,))):
         raise ValueError(
             f"paged_decode_attention: arena shapes k{tuple(k_arena.shape)} "
-            f"v{tuple(v_arena.shape)} don't match q{tuple(q.shape)}")
+            f"v{tuple(v_arena.shape)} don't match q{tuple(q.shape)}"
+            + ("" if sinks is None else f" with sinks{tuple(sinks.shape)}"))
     bs = k_arena.shape[3]
     if bs % 8 != 0 or bs < 8:
         raise ValueError(
@@ -609,16 +652,20 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
     lens = jnp.broadcast_to(lens.reshape(-1), (b,)) \
         + jnp.int32(1 if group > 1 else s_p)
     bt = jnp.asarray(block_tables, jnp.int32)
+    if sinks is not None:      # [h] -> [h_kv, G padded, 1]
+        sinks = jnp.pad(jnp.asarray(sinks, jnp.float32).reshape(hl, group),
+                        ((0, 0), (0, s_p - rows)),
+                        constant_values=NEG_INF)[..., None]
     if group > 1:
         out = _paged_grouped_call_once(
-            q, k_arena, v_arena, bt, lens, scale=float(scale),
+            q, k_arena, v_arena, bt, lens, sinks, scale=float(scale),
             interpret=_interpret(), steps=paged_grouped_steps(
                 b, bt.shape[1], max_steps))
     else:
         out = _paged_call(q, k_arena, v_arena, bt, lens, scale)
     out = out.astype(out_dtype)
     out = out[:, :, :rows] if s_p != rows else out
-    return out.reshape(b, h, s, d) if group > 1 else out
+    return out.reshape(b, h, s, d_v) if group > 1 else out
 
 
 # --------------------------------------------------------------------------

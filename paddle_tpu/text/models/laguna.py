@@ -43,6 +43,12 @@ Two computation paths, the same mathematics:
 Expert layers are `nn.RoutedExperts` (softmax router, the chosen
 renormalised and scaled), told which experts they hold.
 Inference only: the forward passes are array code under no tape.
+
+What another net with window layers shares (`mimo_v2.py`: keys deeper
+than values, head counts by kind, sink logits) is here under its own
+name: `_gqa_chunk_attention` (sinks and a value width as arguments),
+`GroupedAttention.attend`, `WindowBlock`, `WindowDecoder`,
+`window_cache_spec`; `PREFILL_TILE` is both nets' one tile.
 """
 from __future__ import annotations
 
@@ -181,22 +187,35 @@ def _cos_sin(cfg, kind, pos):
 # jitted under a name of its own, so that a device trace can tell the
 # chunk's attention from the rest of a prefill
 @functools.partial(jax.jit, static_argnames=("scale", "window", "q_block"))
-def _gqa_chunk_attention(q, k, v, live=None, *, scale, window=None,
-                         q_block=PREFILL_TILE):
+def _gqa_chunk_attention(q, k, v, live=None, sinks=None, *, scale,
+                         window=None, q_block=PREFILL_TILE):
     """Causal grouped-query attention within a chunk: q [b, s, G hk, d],
-    k, v [b, s, hk, d] -> [b, s, G hk, d]; query head j reads key-value
-    head j // G; with `window` a query sees the last `window` keys, itself
-    included. A tile of `q_block` queries, the G heads of a key-value head
-    folded into its rows, meets the key tiles it may see one at a time
-    under an online softmax: tiles 0..i, or for a window those that meet
-    the band, so the float32 scores are [b, hk, G q_block, q_block] and
-    nothing outside the mask's tiles is computed. With `live` (a traced
-    count) only the first `live` tiles of queries are computed and the
-    others come out zero."""
+    k [b, s, hk, d], v [b, s, hk, d_v] -> [b, s, G hk, d_v]; query head j
+    reads key-value head j // G; with `window` a query sees the last
+    `window` keys, itself included. A tile of `q_block` queries, the G
+    heads of a key-value head folded into its rows, meets the key tiles it
+    may see one at a time under an online softmax: tiles 0..i, or for a
+    window those that meet the band (a window of 128 under tiles of 256:
+    the tile before and its own), so the float32 scores are [b, hk, G
+    q_block, q_block] and nothing outside the mask's tiles is computed.
+    With `live` (a traced count) only the first `live` tiles of queries
+    are computed and the others come out zero. With `sinks` [G hk]
+    float32 a row's softmax starts from its head's sink logit (m = sink,
+    l = 1, no value): one more term of the denominator."""
     b, s, hq, d = q.shape
-    hk = k.shape[2]
+    hk, dv = k.shape[2], v.shape[3]
     g = hq // hk
     qb = q_block if s % q_block == 0 else s
+
+    def start():
+        """(m, l) a tile's rows start from; a row is (head of the group,
+        query of the tile)."""
+        if sinks is None:
+            return (jnp.full((b, hk, g * qb, 1), -1e9, F32),
+                    jnp.zeros((b, hk, g * qb, 1), F32))
+        m = jnp.repeat(sinks.astype(F32).reshape(hk, g), qb, axis=1)
+        return (jnp.broadcast_to(m[None, :, :, None], (b, hk, g * qb, 1)),
+                jnp.ones((b, hk, g * qb, 1), F32))
     qh = jnp.transpose(q.reshape(b, s, hk, g, d), (0, 2, 3, 1, 4))
     kh, vh = (jnp.transpose(t, (0, 2, 1, 3)) for t in (k, v))
     step = jnp.tile(jnp.arange(qb, dtype=jnp.int32), g)         # [g qb]
@@ -235,34 +254,29 @@ def _gqa_chunk_attention(q, k, v, live=None, *, scale, window=None,
         # there, which wipes out what a tile of masked keys left (alpha 0)
         _, l, acc = jax.lax.fori_loop(
             first, i + 1, one_key_tile,
-            (jnp.full((b, hk, g * qb, 1), -1e9, F32),
-             jnp.zeros((b, hk, g * qb, 1), F32),
-             jnp.zeros((b, hk, g * qb, d), F32)))
-        tile = (acc / l).astype(v.dtype).reshape(b, hk, g, qb, d)
+            (*start(), jnp.zeros((b, hk, g * qb, dv), F32)))
+        tile = (acc / l).astype(v.dtype).reshape(b, hk, g, qb, dv)
         return jax.lax.dynamic_update_slice_in_dim(out, tile, i * qb, axis=3)
 
     out = jax.lax.fori_loop(
         0, np.int32(s // qb) if live is None else live, one_tile,
-        jnp.zeros((b, hk, g, s, d), v.dtype))
-    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(b, s, hq, d)
+        jnp.zeros((b, hk, g, s, dv), v.dtype))
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(b, s, hq, dv)
 
 
-class GatedGroupedAttention(_Weights):
-    """Grouped-query attention with a sigmoid gate a query head, in the
-    three stages a block runs: `project` and `output` row by row, `mix`
-    across the rows."""
+class GroupedAttention(_Weights):
+    """What the grouped-query attentions of the nets with window layers
+    share (this file's and `mimo_v2.py`'s): the rotary over a head's
+    first dims, the fused projection's heads (`heads_of`) and `attend`,
+    across the rows. A subclass sets `heads`, `kv`, `head_dim`, `window`
+    (None in a full layer), `rot`, `round_to` and the matrix `qkv`, and
+    brings the row-wise stages `project` and `output` and a `mix` that
+    wraps `attend`."""
 
-    def __init__(self, cfg: LagunaConfig, kind, heads):
-        super().__init__(cfg)
-        H, d = cfg.hidden_size, cfg.head_dim
-        self.heads, self.kv = int(heads), cfg.num_kv_heads
-        self.head_dim = d
-        self.window = cfg.sliding_window if kind == SLIDING else None
-        self.rot = _rotary(cfg, kind)[0]
-        self.round_to = cfg.kv_round_to
-        self.qkv = self.matrix(H, (self.heads + 2 * self.kv) * d)
-        self.g = self.matrix(H, self.heads)
-        self.o = self.matrix(self.heads * d, H)
+    def sink_logits(self):
+        """[heads] float32, a learned sink logit a query head that joins
+        its softmax's denominator, or None: this family has none."""
+        return None
 
     def _rotate(self, x, cos, sin):
         """Rotary over the first `rot` dims of every head of x [b, t, n,
@@ -273,36 +287,38 @@ class GatedGroupedAttention(_Weights):
             [_rope(x[..., :self.rot], cos[:, :, None], sin[:, :, None]),
              x[..., self.rot:]], axis=-1)
 
-    def project(self, a, cos, sin):
-        """Row by row: the normed stream a [b, t, H] float32 -> q [b, t,
-        n, d] and k [b, t, kv, d] rotated, v [b, t, kv, d], all in the
-        parameters' dtype (keys and values through `kv_round_to` where a
-        control sets it), and the gates [b, t, n] float32."""
+    def heads_of(self, a, cos, sin, value_dim=None, value_scale=1.0):
+        """Row by row: a [b, t, H] in the parameters' dtype through the
+        fused `qkv` matrix -> q [b, t, n, d] and k [b, t, kv, d] rotated,
+        v [b, t, kv, `value_dim` or d] times `value_scale`, all in a's
+        dtype (keys and values through `round_to` where a control sets
+        it)."""
         b, t, _ = a.shape
         n, kv, d = self.heads, self.kv, self.head_dim
-        a = a.astype(self.qkv._value.dtype)
         qkv = jnp.dot(a, self.qkv._value, preferred_element_type=F32)
-        gate = jax.nn.sigmoid(jnp.dot(a, self.g._value,
-                                      preferred_element_type=F32))
         q = self._rotate(qkv[..., :n * d].reshape(b, t, n, d), cos, sin)
         k = self._rotate(qkv[..., n * d:(n + kv) * d].reshape(b, t, kv, d),
                          cos, sin)
-        v = qkv[..., (n + kv) * d:].reshape(b, t, kv, d)
+        v = qkv[..., (n + kv) * d:].reshape(b, t, kv, value_dim or d)
+        if value_scale != 1.0:
+            v = v * value_scale
         q, k, v = (x.astype(a.dtype) for x in (q, k, v))
         if self.round_to:
             k, v = (x.astype(self.round_to).astype(a.dtype) for x in (k, v))
-        return q, k, v, gate
+        return q, k, v
 
-    def mix(self, q, k, v, gate, cache=None, last=None, live=None):
+    def attend(self, q, k, v, cache=None, last=None, live=None):
         """Across the rows: keys and values cached (a full layer's in the
         slot's blocks, a sliding layer's in its ring), the attention ->
-        ((out [b, s, n d], gate), new cache or None). A chunk attends
-        within itself, `live` tiles of queries of it (None: all); one
-        token attends over its slot's cache."""
+        (out [b, s, n d_v], new cache or None). q [b, s, n, d], k [b, s,
+        kv, d], v [b, s, kv, d_v]. A chunk attends within itself, `live`
+        tiles of queries of it (None: all); one token attends over its
+        slot's cache."""
         from ...nn.kv_pool import (paged_attention, window_attention,
                                    window_fill, window_write, write_kv)
         b, s, n, d = q.shape
         scale = d ** -0.5
+        sinks = self.sink_logits()
         chunk = s > 1 or cache is None        # a prefill starts an empty slot
         if cache is not None:
             lens = jnp.asarray(cache.lengths, jnp.int32)
@@ -319,19 +335,54 @@ class GatedGroupedAttention(_Weights):
                 cache = cache._replace(k=window_write(cache.k, lens, k),
                                        v=window_write(cache.v, lens, v))
         if chunk:
-            out = _gqa_chunk_attention(q.astype(k.dtype), k, v, live,
+            out = _gqa_chunk_attention(q.astype(k.dtype), k, v, live, sinks,
                                        scale=scale, window=self.window,
                                        q_block=PREFILL_TILE)
         elif self.window is None:
             out = jnp.swapaxes(paged_attention(
                 jnp.swapaxes(q, 1, 2), cache.k, cache.v,
-                cache.block_tables, lens, scale), 1, 2)
+                cache.block_tables, lens, scale, sinks=sinks), 1, 2)
         else:
             out = jnp.swapaxes(window_attention(
-                jnp.swapaxes(q, 1, 2), cache.k, cache.v, lens, scale), 1, 2)
+                jnp.swapaxes(q, 1, 2), cache.k, cache.v, lens, scale,
+                sinks), 1, 2)
         if cache is not None:
             cache = cache._replace(lengths=lens + jnp.int32(s))
-        return (out.reshape(b, s, n * d).astype(q.dtype), gate), cache
+        return out.reshape(b, s, -1).astype(q.dtype), cache
+
+
+class GatedGroupedAttention(GroupedAttention):
+    """Grouped-query attention with a sigmoid gate a query head, in the
+    three stages a block runs: `project` and `output` row by row, `mix`
+    across the rows."""
+
+    def __init__(self, cfg: LagunaConfig, kind, heads):
+        super().__init__(cfg)
+        H, d = cfg.hidden_size, cfg.head_dim
+        self.heads, self.kv = int(heads), cfg.num_kv_heads
+        self.head_dim = d
+        self.window = cfg.sliding_window if kind == SLIDING else None
+        self.rot = _rotary(cfg, kind)[0]
+        self.round_to = cfg.kv_round_to
+        self.qkv = self.matrix(H, (self.heads + 2 * self.kv) * d)
+        self.g = self.matrix(H, self.heads)
+        self.o = self.matrix(self.heads * d, H)
+
+    def project(self, a, cos, sin):
+        """Row by row: the normed stream a [b, t, H] float32 -> q [b, t,
+        n, d] and k [b, t, kv, d] rotated, v [b, t, kv, d], all in the
+        parameters' dtype (keys and values through `kv_round_to` where a
+        control sets it), and the gates [b, t, n] float32."""
+        a = a.astype(self.qkv._value.dtype)
+        gate = jax.nn.sigmoid(jnp.dot(a, self.g._value,
+                                      preferred_element_type=F32))
+        return (*self.heads_of(a, cos, sin), gate)
+
+    def mix(self, q, k, v, gate, cache=None, last=None, live=None):
+        """`attend`, the gates carried beside it to `output` -> ((out,
+        gate), new cache or None)."""
+        out, cache = self.attend(q, k, v, cache, last, live)
+        return (out, gate), cache
 
     def output(self, out, gate):
         """Row by row: each head's output times its gate, the output
@@ -343,24 +394,22 @@ class GatedGroupedAttention(_Weights):
                        self.o._value, preferred_element_type=F32)
 
 
-class LagunaBlock(_Weights):
-    def __init__(self, cfg: LagunaConfig, index):
+class WindowBlock(_Weights):
+    """A pre-norm block of a net with window layers: the three stages of
+    its attention around the rows' tiles, then a dense or a sparse FFN.
+    `__init__` takes what a family builds: `kind` (FULL | SLIDING), `attn`
+    (a `GroupedAttention`), `ffn` (`DenseFFN`, or `nn.RoutedExperts` when
+    `sparse`)."""
+
+    def __init__(self, cfg, kind, attn, ffn, sparse):
         super().__init__(cfg)
         self.eps = cfg.rms_norm_eps
-        self.kind = cfg.layer_types[index]
+        self.kind = kind
         self.attn_norm = self.ones(cfg.hidden_size)
-        self.attn = GatedGroupedAttention(
-            cfg, self.kind, cfg.num_attention_heads_per_layer[index])
+        self.attn = attn
         self.ffn_norm = self.ones(cfg.hidden_size)
-        self.sparse = index not in tuple(cfg.mlp_only_layers)
-        self.ffn = nn.RoutedExperts(
-            cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts,
-            cfg.num_experts_per_tok, held=cfg.experts_held,
-            routed_scaling_factor=cfg.routed_scaling_factor,
-            shared_width=cfg.shared_expert_intermediate_size,
-            dtype=cfg.dtype, init_std=cfg.init_std, score_func="softmax",
-            norm_topk_prob=cfg.norm_topk_prob) if self.sparse \
-            else DenseFFN(cfg)
+        self.sparse = sparse
+        self.ffn = ffn
 
     def forward(self, x, cos, sin, cache=None, valid=None, last=None,
                 live=None):
@@ -379,9 +428,9 @@ class LagunaBlock(_Weights):
                 return attn.project(_rms(x, self.attn_norm._value, self.eps),
                                     cos, sin)
 
-        def after(x, out, gate):
+        def after(x, *mixed):
             with jax.named_scope(word):
-                h = x + attn.output(out, gate)
+                h = x + attn.output(*mixed)
             with jax.named_scope("ffn"):
                 f = _rms(h, self.ffn_norm._value, self.eps).astype(dtype)
                 if self.sparse:
@@ -403,35 +452,57 @@ class LagunaBlock(_Weights):
             return y + m.reshape(b, s, H).astype(F32), cache, counts
 
 
-class Laguna(_Weights):
+class LagunaBlock(WindowBlock):
+    def __init__(self, cfg: LagunaConfig, index):
+        kind = cfg.layer_types[index]
+        sparse = index not in tuple(cfg.mlp_only_layers)
+        super().__init__(
+            cfg, kind, GatedGroupedAttention(
+                cfg, kind, cfg.num_attention_heads_per_layer[index]),
+            nn.RoutedExperts(
+                cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts,
+                cfg.num_experts_per_tok, held=cfg.experts_held,
+                routed_scaling_factor=cfg.routed_scaling_factor,
+                shared_width=cfg.shared_expert_intermediate_size,
+                dtype=cfg.dtype, init_std=cfg.init_std, score_func="softmax",
+                norm_topk_prob=cfg.norm_topk_prob) if sparse
+            else DenseFFN(cfg), sparse)
+
+
+def window_cache_spec(layer_types, window, ring_block, keys, values):
+    """One `CacheSpec` a layer, by its kind: a full layer pages keys and
+    values by token (`PagedKVCache`), a sliding layer keeps a ring of
+    `window` tokens a slot (`WindowKVCache`: two per-slot arrays, no
+    arena, no block of the pool). `keys` and `values` map a kind to the
+    (heads, dim) of what a token caches there."""
+    from ...nn.kv_pool import (CacheSpec, PagedKVCache, WindowKVCache,
+                               window_ring_shape)
+    return [CacheSpec(PagedKVCache, (keys[kind], values[kind]))
+            if kind == FULL else CacheSpec(WindowKVCache, (), tuple(
+                (window_ring_shape(window, ring_block, *per_head), None)
+                for per_head in (keys[kind], values[kind])))
+            for kind in layer_types]
+
+
+class WindowDecoder(_Weights):
+    """A decoder of `WindowBlock`s behind `ServeLoop`: what the nets with
+    window layers share past their blocks. A family's `__init__` names
+    its block (`block(cfg, index)`); its config gives `vocab_size`, `hidden_size`,
+    `num_layers`, `layer_types`, `sliding_window`, `rope_parameters`
+    (`_rotary`), `head_dim`, `rms_norm_eps`; it brings
+    `paged_cache_spec`."""
+
     SERVE_STATS = MOE_STATS + ATTN_STATS
     SERVE_GAUGES = ("window_ring_bytes",)
 
-    def __init__(self, config: LagunaConfig = None):
-        cfg = config or LagunaConfig()
+    def __init__(self, cfg, block):
         super().__init__(cfg)
         self.config = cfg
         self.embed = self.matrix(cfg.vocab_size, cfg.hidden_size)
-        self.blocks = nn.LayerList([LagunaBlock(cfg, i)
+        self.blocks = nn.LayerList([block(cfg, i)
                                     for i in range(cfg.num_layers)])
         self.norm = self.ones(cfg.hidden_size)
         self.head = self.matrix(cfg.hidden_size, cfg.vocab_size)
-
-    def paged_cache_spec(self):
-        """One `CacheSpec` a layer, by its kind: a full layer pages keys
-        and values by token (`PagedKVCache`, a key-value head's width), a
-        sliding layer keeps a ring of `sliding_window` tokens a slot
-        (`WindowKVCache`: two per-slot arrays, no arena, no block of the
-        pool)."""
-        from ...nn.kv_pool import (CacheSpec, PagedKVCache, WindowKVCache,
-                                   window_ring_shape)
-        cfg = self.config
-        per_head = (cfg.num_kv_heads, cfg.head_dim)
-        ring = (window_ring_shape(cfg.sliding_window, cfg.ring_block,
-                                  *per_head), None)
-        return [CacheSpec(PagedKVCache, (per_head, per_head))
-                if kind == FULL else CacheSpec(WindowKVCache, (), (ring, ring))
-                for kind in cfg.layer_types]
 
     def serve_counters(self, kind, counted, n_tokens):
         """{`ServeLoop.stats()` name: increment, or for a name in
@@ -536,3 +607,17 @@ class Laguna(_Weights):
             len(rings) * jnp.sum(jnp.minimum(seen, cfg.sliding_window)),
             jnp.int32(sum(c.k.nbytes + c.v.nbytes for c in rings) // 1024)])
         return (self._logits(h), new_caches, counts, read.astype(jnp.int32))
+
+
+class Laguna(WindowDecoder):
+    def __init__(self, config: LagunaConfig = None):
+        super().__init__(config or LagunaConfig(), LagunaBlock)
+
+    def paged_cache_spec(self):
+        """`window_cache_spec`: keys and values alike, a key-value head's
+        width, in pages and in rings."""
+        cfg = self.config
+        per_head = dict.fromkeys((FULL, SLIDING),
+                                 (cfg.num_kv_heads, cfg.head_dim))
+        return window_cache_spec(cfg.layer_types, cfg.sliding_window,
+                                 cfg.ring_block, per_head, per_head)

@@ -82,10 +82,12 @@ def test_kernels_phase_toy(interpret):
     assert set(result["errors_vs_jnp_reference"]) >= {
         "flash_causal", "flash_padding_bias", "fused_ce", "decode",
         "paged_decode_s1_blockpicked", "latent_paged_decode",
-        "grouped_expert_ffn", "grouped_paged_decode"}
+        "grouped_expert_ffn", "grouped_paged_decode", "sink_paged_decode"}
     # the grouped-query form's two call sites: the pool's pages, the rings
-    assert set(result["errors_vs_jnp_reference"]["grouped_paged_decode"]) \
-        == {"full", "ring"}
+    # (and again with keys deeper than values and sinks in the rings)
+    for name in ("grouped_paged_decode", "sink_paged_decode"):
+        assert set(result["errors_vs_jnp_reference"][name]) \
+            == {"full", "ring"}
     # both nets' expert layers, a decode step and a bucket each
     assert set(result["errors_vs_jnp_reference"]["grouped_expert_ffn"]) \
         == {"share_t2", "share_t32", "scmoe_t2", "scmoe_t32"}
@@ -262,6 +264,13 @@ def _run_in_cpu_child(body, ok):
     return r.stdout
 
 
+def _instruction_count(text):
+    """Every instruction of every computation of a compiled program's
+    text: what a PR that means to leave a program alone leaves alone."""
+    return len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = \S+ [\w\-]+\(",
+                          text, re.M))
+
+
 def _scope_summary(text):
     """What `core/program_map` makes of a program compiled for v5e: of the
     entry computation's `fusion` / `while` / Pallas custom-call
@@ -355,7 +364,8 @@ def _compile_paged_steps_for_v5e():
             "heads_per_step": monitor.stat_get(
                 f"pallas.paged_decode_attention.heads_per_step.b{b}s{s}"),
             "grid_steps": monitor.stat_get(
-                f"pallas.paged_decode_attention.grid_steps.b{b}s{s}")}))
+                f"pallas.paged_decode_attention.grid_steps.b{b}s{s}"),
+            "instructions": _instruction_count(text)}))
     print("PAGED-STEPS-DONE")
 
 
@@ -385,6 +395,12 @@ def test_paged_serve_steps_hold_no_arena_copy_for_v5e():
     # logical blocks; a 256-row prefill fits 5 heads a step
     assert (decode["heads_per_step"], decode["grid_steps"]) == (25, 256)
     assert (prefill["heads_per_step"], prefill["grid_steps"]) == (5, 40)
+    # counted, as the window nets' steps are: `_paged_call_once` learned a
+    # value width and the grouped kernel sinks for another net (PR 45), and
+    # GPT-2 XL's cut stayed what it was (its jaxpr too, letter for letter)
+    assert (decode["instructions"], decode["temp_bytes"]) == (150, 0), decode
+    assert (prefill["instructions"], prefill["temp_bytes"]) \
+        == (468, 290_816), prefill
 
 
 def _compile_latent_steps_for_v5e(model="KimiK2", config="latent",
@@ -470,8 +486,7 @@ def _compile_latent_steps_for_v5e(model="KimiK2", config="latent",
             "experts_rejects": monitor.stats(
                 "pallas.gate_reject.grouped_expert_ffn."),
             "experts_cut": monitor.stats("pallas.grouped_expert_ffn."),
-            "instructions": len(re.findall(
-                r"^\s+(?:ROOT )?%?[\w.\-]+ = \S+ [\w\-]+\(", text, re.M)),
+            "instructions": _instruction_count(text),
             "conditionals": len(re.findall(r" conditional\(", text)),
             "scopes": _scope_summary(text)}))
     print("LATENT-STEPS-DONE")
@@ -659,6 +674,7 @@ def _compile_hybrid_steps_for_v5e():
             "temp_bytes": mem.temp_size_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes, "held_bytes": held,
             "hits": {k.rsplit(".", 1)[1]: int(v) for k, v in hits.items()},
+            "instructions": _instruction_count(text),
             "scopes": _scope_summary(text)}))
     print("HYBRID-STEPS-DONE")
 
@@ -684,17 +700,23 @@ def test_hybrid_serve_steps_update_state_and_arenas_in_place_for_v5e():
     assert decode["temp_bytes"] < 64e6, decode
     assert prefill["hits"] == {"gdn_chunk_scan": 3}
     assert prefill["temp_bytes"] < 1.0e9, prefill
+    # counted at PR 45's parent and unchanged by it (`_paged_call_once`
+    # with a value width that is the key's)
+    assert (decode["instructions"], prefill["instructions"],
+            prefill["temp_bytes"]) == (1885, 4974, 170_335_232), (decode,
+                                                                  prefill)
     _assert_scoped(decode, {"linear_attn", "attn", "ffn", "head"},
                    {"_gdn_step_call": ["linear_attn"],
                     "_paged_write_once": ["attn"],
                     "_paged_call_once": ["attn"]})
 
 
-def _compile_window_steps_for_v5e():
-    """Child-process body of the test below: ServeLoop's own decode step
-    and bucket-1024 prefill of the Laguna share's leading full layer and
-    one sliding layer at their published widths
-    (`chip_smoke.Sizes.full().window`), compiled for v5e over the
+def _compile_window_steps_for_v5e(model="Laguna", field="window",
+                                  bucket=1024):
+    """Child-process body of the tests below: ServeLoop's own decode step
+    and bucket-`bucket` prefill of a net with window layers, a full layer
+    and one sliding layer at their published widths
+    (`chip_smoke.Sizes.full()`'s `field`), compiled for v5e over the
     benchmark's 128 slots: a full layer's pages and a sliding layer's
     rings from one spec; one JSON line a program."""
     from jax.experimental import topologies
@@ -703,21 +725,21 @@ def _compile_window_steps_for_v5e():
                                               build_decode_step)
     from paddle_tpu.nn import initializer
     from paddle_tpu.nn.kv_pool import KVBlockPool
-    from paddle_tpu.text.models import Laguna
+    from paddle_tpu.text import models
     try:
         device = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
     except Exception as e:  # environment without a usable libtpu
         print(f"NO-TOPOLOGY {type(e).__name__}: {e}")
         return
     sharding = SingleDeviceSharding(device)
-    # only shapes are compiled: 0.46 B parameters need not be drawn
+    # only shapes are compiled: half a billion parameters need not be drawn
     initializer.Normal.__call__ = \
         lambda self, shape, dtype="float32": jnp.zeros(tuple(shape), dtype)
     sizes = chip_smoke.Sizes.full()
-    net = Laguna(sizes.window)
+    net = getattr(models, model)(getattr(sizes, field))
     net.eval()
     params, buffers = net.functional_state()
-    (slots, blocks, block, max_seq), bucket = sizes.window_serve, 1024
+    slots, blocks, block, max_seq = getattr(sizes, field + "_serve")
     pool, width = KVBlockPool(blocks, block), -(-max_seq // block)
 
     def spec(shape, dtype):
@@ -727,12 +749,24 @@ def _compile_window_steps_for_v5e():
         return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
 
     i32, u32 = jnp.int32, jnp.uint32
+    cache_spec = net.paged_cache_spec()
     arenas = jax.eval_shape(lambda: pool.arenas_for(
-        net.paged_cache_spec(), jnp.bfloat16, slots=slots))
+        cache_spec, jnp.bfloat16, slots=slots))
     state = (like(params), like(buffers), like(arenas))
     held = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                for layer in arenas for x in layer)
-    ring = "bf16[128,4,8,128,128]"
+    # each ring [slots, ring_blocks, h, d, block] as the program holds it,
+    # and as the kernels read it ([slots * ring_blocks, h, d, block])
+    rings = sorted({x.shape for layer, a in zip(cache_spec, arenas)
+                    for x in a[len(layer.arenas):]})
+    paged = sorted({x.shape for layer, a in zip(cache_spec, arenas)
+                    for x in a[:len(layer.arenas)]})
+
+    def text_of(shape):
+        return "bf16[" + ",".join(str(n) for n in shape) + "]"
+
+    ring_forms = [text_of(form)[:-1] for r in rings
+                  for form in (r, (r[0] * r[1],) + r[2:])]
     programs = {
         1: (build_decode_step(net),
             (spec((slots, width), i32), spec((slots,), i32),
@@ -751,21 +785,32 @@ def _compile_window_steps_for_v5e():
         hits = monitor.stats("pallas.hit.")
         print("STEP " + json.dumps({
             "s": s,
-            "ring_in_hlo": ring in text,
-            "ring_copies": len(re.findall(
-                r"= bf16\[(?:128,4|512),8,128,128\]\S* (?:copy|transpose)\(",
-                text)),
-            "relayouts": chip_smoke.arena_relayouts(
-                text, pool.arena_shape(8, 128)),
+            "ring_in_hlo": all(text_of(r) in text for r in rings),
+            "ring_copies": sum(len(re.findall(
+                "= " + re.escape(form) + r"\]\S* (?:copy|transpose)\(", text))
+                for form in ring_forms),
+            "relayouts": [x for shape in paged
+                          for x in chip_smoke.arena_relayouts(text, shape)],
             "lane_padded": chip_smoke.lane_padded_results(text),
+            "argument_bytes": mem.argument_size_in_bytes,
             "temp_bytes": mem.temp_size_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes, "held_bytes": held,
-            "ring_bytes": 2 * slots * 4 * 8 * 128 * 128 * 2,
+            "ring_bytes": sum(int(np.prod(x.shape)) * 2
+                              for layer, a in zip(cache_spec, arenas)
+                              for x in a[len(layer.arenas):]),
             "hits": {k.rsplit(".", 1)[1]: int(v) for k, v in hits.items()},
             "rejects": monitor.stats("pallas.gate_reject."),
             "cut": monitor.stats("pallas.paged_decode_attention."),
+            "instructions": _instruction_count(text),
             "scopes": _scope_summary(text)}))
     print("WINDOW-STEPS-DONE")
+
+
+def _compile_sink_steps_for_v5e():
+    """The same for the MiMo-V2-Flash share's leading full layer and one
+    sliding expert layer (`chip_smoke.Sizes.full().sink`), and the
+    benchmark's largest prefill bucket."""
+    _compile_window_steps_for_v5e("MiMoV2Flash", "sink", 8192)
 
 
 def test_window_serve_steps_hand_rings_and_arenas_to_the_kernel_for_v5e():
@@ -805,6 +850,63 @@ def test_window_serve_steps_hand_rings_and_arenas_to_the_kernel_for_v5e():
         "pallas.paged_decode_attention.grid_steps.b128s1g9": 128 * 4}
     assert prefill["hits"] == {} and prefill["temp_bytes"] < 1.0e9, prefill
     _assert_scoped(decode, {"attn", "window_attn", "ffn", "experts", "head"},
+                   {"_paged_write_once": ["attn", "window_attn"],
+                    "_paged_grouped_call_once": ["attn", "window_attn"],
+                    "_grouped_ffn_call": ["experts"]})
+    # the programs are counted: a kernel that learns a new operand for
+    # another net (sinks, values narrower than keys: PR 45) leaves these as
+    # they were, instruction for instruction and byte for byte of temporaries
+    assert (decode["instructions"], decode["temp_bytes"]) \
+        == (2031, 2_872_832), decode
+    assert (prefill["instructions"], prefill["temp_bytes"]) \
+        == (2428, 83_691_008), prefill
+
+
+def test_sink_serve_steps_hand_two_widths_and_one_block_rings_to_the_kernel():
+    """The MiMo-V2-Flash share's leading full layer (64 query heads over 4
+    key-value heads, a K arena 192 deep beside a V arena 128 deep, G = 16)
+    and one sliding expert layer (64 over 8, a ring of ONE 128-token block
+    a slot, a sink logit a head, G = 8) at the published widths and 128
+    slots: the decode step compiles under Mosaic for v5e with the 192-deep
+    contraction as laid out (no K arena padded to 256), hands arenas and
+    rings to the kernel and to the token writer as they are (no copy or
+    transpose of arena or ring shape), gives every donated byte back
+    aliased, and names its work; so does the benchmark's largest prefill,
+    the 8192 bucket, whose temporaries (0.71 GB at two layers; a layer's
+    are reused by the next) size the pool: 11.3 GB of arguments at seven
+    layers and 6144 blocks + 0.7 GB = 12.0 of 16 GB."""
+    out = _run_in_cpu_child("_compile_sink_steps_for_v5e",
+                            "WINDOW-STEPS-DONE")
+    decode, prefill = (json.loads(line[5:]) for line in out.splitlines()
+                       if line.startswith("STEP "))
+    for step in (decode, prefill):
+        assert step["ring_in_hlo"] and step["ring_copies"] == 0, step
+        assert step["relayouts"] == [], step
+        assert step["alias_bytes"] >= step["held_bytes"], step
+        assert step["rejects"] in (
+            {}, {"pallas.gate_reject.grouped_expert_ffn.tokens": 1}), step
+        # two layers' weights, 2048 blocks of pages, 128 slots of rings
+        assert 2.6e9 < step["argument_bytes"] < 2.7e9, step
+    assert decode["lane_padded"] == [], decode
+    assert decode["hits"] == {"paged_write_token": 4,
+                              "paged_decode_attention": 2,
+                              "grouped_expert_ffn": 1}
+    assert decode["temp_bytes"] < 4e6, decode
+    # all of a block's key-value heads in one grid step; the full layer's
+    # work list is the pool's 2048 blocks and a step a slot, the sliding
+    # layer's one block a slot: 128 steps
+    assert decode["cut"] == {
+        "pallas.paged_decode_attention.heads_per_step.b128s1g16": 4,
+        "pallas.paged_decode_attention.grid_steps.b128s1g16": 2048 + 128,
+        "pallas.paged_decode_attention.value_dim.b128s1g16": 128,
+        "pallas.paged_decode_attention.sinks.b128s1g16": 0,
+        "pallas.paged_decode_attention.heads_per_step.b128s1g8": 8,
+        "pallas.paged_decode_attention.grid_steps.b128s1g8": 128,
+        "pallas.paged_decode_attention.value_dim.b128s1g8": 128,
+        "pallas.paged_decode_attention.sinks.b128s1g8": 1}
+    assert prefill["hits"] == {} and prefill["temp_bytes"] < 0.8e9, prefill
+    _assert_scoped(decode, {"attn", "window_attn", "ffn", "router",
+                            "experts", "head"},
                    {"_paged_write_once": ["attn", "window_attn"],
                     "_paged_grouped_call_once": ["attn", "window_attn"],
                     "_grouped_ffn_call": ["experts"]})

@@ -171,7 +171,9 @@ def pallas_rates(metrics) -> str:
     demotion was removed still carry it.) A kernel that says how its
     programs were cut into grid steps has it in detail: the paged kernel
     (pallas.K.heads_per_step.bMsN and .grid_steps.bMsN, M slots of N
-    query rows) and the latent kernel (pallas.K.blocks_per_step.bM,
+    query rows, gG for a group of G query heads; .value_dim and .sinks
+    where a call's values are narrower than its keys or its softmax
+    starts from sink logits) and the latent kernel (pallas.K.blocks_per_step.bM,
     .grid_steps.bM and .live_bytes.bM, what a call reads for each live
     block of a slot); so has the token writer, for what a call of M
     slots moves (pallas.K.token_bytes.bM, the token operand as laid out,
@@ -196,7 +198,8 @@ def pallas_rates(metrics) -> str:
                 f"{kind}:{'.'.join(parts[3:])}={int(v)}")
         elif len(parts) == 4 and parts[2] in (
                 "heads_per_step", "blocks_per_step", "grid_steps",
-                "live_bytes", "rows_per_block", "tile_bytes"):
+                "live_bytes", "rows_per_block", "tile_bytes", "value_dim",
+                "sinks"):
             cuts[kind, parts[3]][parts[2]] = int(v)
         elif len(parts) == 4 and parts[2] in ("token_bytes", "block_bytes"):
             writes[kind, parts[3]][parts[2]] = v
@@ -212,6 +215,9 @@ def pallas_rates(metrics) -> str:
             else f"{cut.get('heads_per_step', '?')}heads"
         live = f",{cut['live_bytes'] / 1e3:.0f}KB/live block" \
             if "live_bytes" in cut else ""
+        if "value_dim" in cut:
+            live += f",values {cut['value_dim']} deep" \
+                + (",sinks" if cut.get("sinks") else "")
         per[k]["reasons"].append(
             f"cut:{shape}={held}/stepx{cut.get('grid_steps', '?')}steps"
             f"{live}")
