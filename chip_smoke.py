@@ -114,7 +114,8 @@ class Sizes:
             serve_prompt_bands=((16, 64), (256, 512)),
             serve_new_tokens=(32, 128), serve_clients=4,
             serve_generate_checks=4,
-            serve_expect_hits=("paged_decode_attention",),
+            serve_expect_hits=("paged_decode_attention",
+                               "paged_write_attend"),
             forced_prompts=(37, 120, 200, 256), forced_bucket=256,
             flash_shape=(2, 12, 1024, 64), ce_shape=(4096, 768, 30522),
             decode_shape=(8, 12, 1024, 64),
@@ -698,8 +699,17 @@ def hybrid_serve(sizes):
             "state_bytes": int(monitor.stat_get("serve.state_bytes"))}
 
 
-HYBRID_KERNELS = ("gdn_chunk_scan", "gdn_step", "paged_decode_attention",
-                  "paged_write_token")
+HYBRID_KERNELS = ("gdn_chunk_scan", "gdn_step")
+
+
+def paged_step_engaged(hits) -> bool:
+    """Did a net's decode step write its token and attend through Pallas?
+    One kernel that does both where `nn/kv_pool.paged_write_attend`'s gate
+    admits the shape, else the token writer and the kernel apart (the
+    hybrid at its published widths: 30 heads of 128)."""
+    return bool(hits.get("paged_write_attend")
+                or (hits.get("paged_decode_attention")
+                    and hits.get("paged_write_token")))
 
 
 def serve_phase(sizes):
@@ -810,6 +820,9 @@ def serve_phase(sizes):
         for kernel in HYBRID_KERNELS:
             if not hybrid["hits"].get(kernel):
                 failures.append(f"hybrid net: {kernel} never engaged")
+        if not paged_step_engaged(hybrid["hits"]):
+            failures.append("hybrid net: the paged write and attention "
+                            f"never engaged: {hybrid['hits']}")
 
     forced = _forced_logits(net, sizes, block_size)
     if not _pallas_counters().get(
@@ -948,14 +961,12 @@ def _check_decode(sizes):
     return {"out": _rel_err(out, out_r)}
 
 
-def _check_paged(sizes, chunk, block_size=None):
+def _paged_operands(sizes, chunk, block_size=None):
     """The arenas ServeLoop builds (KVBlockPool.arenas), shared by
     serve_max_active slots of up to gpt.max_seq_len tokens, at the block
-    size ServeLoop's own picker gives (or the one named)."""
-    from paddle_tpu.nn.kv_pool import (KVBlockPool, paged_attention_ref,
-                                       pick_block_size)
-    from paddle_tpu.ops.pallas.decode_attention import paged_decode_attention
-
+    size ServeLoop's own picker gives (or the one named): -> (q [b, h,
+    chunk, d], K arena, V arena, tables, lengths as numpy, scale)."""
+    from paddle_tpu.nn.kv_pool import KVBlockPool, pick_block_size
     cfg = sizes.gpt
     b, h = sizes.serve_max_active, cfg.num_heads
     d = cfg.hidden_size // h
@@ -971,12 +982,63 @@ def _check_paged(sizes, chunk, block_size=None):
     rng = np.random.RandomState(SEED + 3)
     tables = (rng.permutation(b * nb) + 1).reshape(b, nb).astype(np.int32)
     lengths = np.linspace(0, cfg.max_seq_len - chunk, b).astype(np.int32)
-    scale = d ** -0.5
+    return q, ka, va, jnp.asarray(tables), lengths, d ** -0.5
+
+
+def _check_paged(sizes, chunk, block_size=None):
+    """The paged kernel over `_paged_operands` against
+    `paged_attention_ref` in float32."""
+    from paddle_tpu.nn.kv_pool import paged_attention_ref
+    from paddle_tpu.ops.pallas.decode_attention import paged_decode_attention
+
+    q, ka, va, tables, lengths, scale = _paged_operands(sizes, chunk,
+                                                        block_size)
+    lengths = jnp.asarray(lengths)
     out = jax.jit(lambda *a: paged_decode_attention(*a, scale))(
-        q, ka, va, jnp.asarray(tables), jnp.asarray(lengths))
+        q, ka, va, tables, lengths)
     out_r = jax.jit(lambda *a: paged_attention_ref(*a, scale))(
-        *_f32(q, ka, va), jnp.asarray(tables), jnp.asarray(lengths))
-    return {"out": _rel_err(out, out_r), "block_size": block_size}
+        *_f32(q, ka, va), tables, lengths)
+    return {"out": _rel_err(out, out_r), "block_size": ka.shape[3]}
+
+
+def _check_paged_write_attend(sizes):
+    """The decode step's ONE call that writes the slots' tokens and
+    attends (nn/kv_pool.paged_write_attend; PR 47) against the token
+    writer twice and then the kernel, over `_paged_operands`, the first
+    slots' fills at a block's last lane and its first: `out` and `arenas`
+    are the share of elements whose BITS differ (the trash block left
+    out), so anything but 0 fails."""
+    from paddle_tpu.core import monitor
+    from paddle_tpu.nn.kv_pool import (paged_attention, paged_write_attend,
+                                       write_kv)
+    q, ka, va, tables, lengths, scale = _paged_operands(sizes, 1)
+    (b, h, _, d), bs = q.shape, ka.shape[3]
+    nk, nv = (jax.random.normal(kk, (b, 1, h, d), jnp.float32).astype(DTYPE)
+              for kk in jax.random.split(jax.random.PRNGKey(SEED + 4)))
+    edges = (bs - 1, bs, 0)[:b]
+    lengths[:len(edges)] = edges
+    lengths = jnp.asarray(lengths)
+
+    def pair(ka, va):
+        ka = write_kv(ka, tables, lengths, nk)
+        va = write_kv(va, tables, lengths, nv)
+        return paged_attention(q, ka, va, tables, lengths, scale), ka, va
+
+    before = monitor.stat_get("pallas.hit.paged_write_attend")
+    got = jax.jit(lambda ka, va: paged_write_attend(
+        q, ka, va, tables, lengths, nk, nv, scale))(ka, va)
+    if monitor.stat_get("pallas.hit.paged_write_attend") == before:
+        raise RuntimeError("the gate left the serving shape on the pair")
+    want = jax.jit(pair)(ka, va)
+
+    def differ(x, y):
+        x, y = (np.asarray(t).view(np.uint16 if t.dtype.itemsize == 2
+                                   else np.uint32) for t in (x, y))
+        return float(np.mean(x != y))
+
+    return {"out": differ(got[0], want[0]),
+            "arenas": max(differ(g[1:], w[1:])
+                          for g, w in zip(got[1:], want[1:]))}
 
 
 def _check_latent_paged(sizes):
@@ -1184,6 +1246,7 @@ def kernel_checks(sizes):
     for chunk, block in sizes.paged_checks:
         checks[f"paged_decode_s{chunk}_block{block or 'picked'}"] = \
             lambda c=chunk, b=block: _check_paged(sizes, c, b)
+    checks["paged_write_attend"] = lambda: _check_paged_write_attend(sizes)
     checks["latent_paged_decode"] = lambda: _check_latent_paged(sizes)
     checks["grouped_expert_ffn"] = lambda: _check_grouped_ffn(sizes)
     checks["grouped_paged_decode"] = lambda: _check_grouped_paged(sizes)

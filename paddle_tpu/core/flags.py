@@ -224,9 +224,12 @@ define_flag("FLAGS_use_paged_attention", True,
             "scalar-prefetch path next to the ragged lengths, so a "
             "decode step's KV reads scale with each request's LIVE "
             "blocks, not max_seq_len; the decode step's token write "
-            "(paged_write_token) rides the same flag. Off, the serve loop "
-            "runs the jnp gather fallback (nn/kv_pool.paged_attention_ref) "
-            "and write_kv's XLA loop")
+            "rides the same flag in both its forms: the token writer "
+            "(paged_write_token, behind write_kv) and the write inside "
+            "the multi-head kernel (nn/kv_pool.paged_write_attend, one "
+            "call that writes and attends where its gate admits the "
+            "shape). Off, the serve loop runs the jnp gather fallback "
+            "(nn/kv_pool.paged_attention_ref) and write_kv's XLA loop")
 define_flag("FLAGS_serve_block_size", 0,
             "tokens per physical KV-pool block (nn/kv_pool.KVBlockPool); "
             "0 = auto: the paged-decode autotune table on TPU, else the "

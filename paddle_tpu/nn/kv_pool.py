@@ -53,7 +53,7 @@ import numpy as np
 __all__ = ["PagedKVCache", "PagedLatentCache", "SlotStateCache",
            "WindowKVCache", "CacheSpec", "KVBlockPool", "paged_caches",
            "cache_arenas", "fresh_slot_rows", "put_slot_rows",
-           "paged_attention", "paged_attention_ref",
+           "paged_attention", "paged_attention_ref", "paged_write_attend",
            "latent_paged_attention", "write_kv", "window_ring_shape",
            "window_fill", "window_write", "window_attention",
            "pick_block_size"]
@@ -324,6 +324,11 @@ def write_kv(arena, block_tables, lengths, new_kv):
     lengths[i]..lengths[i]+s-1. Positions past a slot's table (or rows
     the scheduler parked with an all-zero table) redirect to the trash
     block, so masked/padded rows can never corrupt another request.
+    `paged_attention` (and `latent_paged_attention`, `window_attention`)
+    attend over what this leaves: write first, then attend. The one
+    caller that does not come here for its decode step is
+    `paged_write_attend`, where the multi-head kernel writes the token
+    itself.
 
     The arena is only ever updated a whole [1, h, d, block_size] block
     at a time, in place (an XLA scatter, or an update one token wide,
@@ -355,18 +360,25 @@ def _tokens_to_lanes(arena, new_kv):
     return jnp.transpose(new_kv.astype(arena.dtype), (0, 2, 3, 1))
 
 
+def _token_lanes(arena, new_kv):
+    """A decode step's tokens [b, 1, h, d] as the kernels take them:
+    dense, [h, d, b] with the slots in the lanes, in the arena's dtype.
+    One small transpose (a [b, h, d, 1] operand, one element a 128-lane
+    row, cost a relayout of 128 x the tokens' bytes before every call;
+    PR 34)."""
+    return jnp.transpose(new_kv[:, 0].astype(arena.dtype), (1, 2, 0))
+
+
 def _write_token(arena, bt, lens, new_kv):
-    """The decode step's write through the Pallas writer. Its tokens go
-    in dense, [h, d, b] with the slots in the lanes: one small transpose
-    (a [b, h, d, 1] operand, one element a 128-lane row, cost a relayout
-    of 128 x the tokens' bytes before every call; PR 34)."""
+    """The decode step's write through the Pallas writer, its tokens
+    dense (`_token_lanes`)."""
     from ..core import monitor
     from ..ops.pallas import run_guarded
     from ..ops.pallas.decode_attention import (paged_write_cut,
                                                paged_write_token)
     b, bs = new_kv.shape[0], arena.shape[3]
     slots = jnp.arange(b, dtype=jnp.int32)
-    tokens = jnp.transpose(new_kv[:, 0].astype(arena.dtype), (1, 2, 0))
+    tokens = _token_lanes(arena, new_kv)
     # what a call moves, on the writer's span and as gauges per slot
     # count for a dump to read (b32 is a decode step of 32 slots)
     cut = paged_write_cut(tuple(arena.shape), b, arena.dtype.itemsize)
@@ -522,7 +534,7 @@ def _latent_attn_paged(q, arena, bt, lens, *, scale, value_dim):
 
 
 def _paged_gate(kernel, training, supported):
-    """Gate shared by the pool's three Pallas kernels; every rejection
+    """Gate shared by the pool's Pallas kernels; every rejection
     bumps pallas.gate_reject.{kernel}.{reason} so bench/serve output can
     say why the pool path ran on jnp. `supported` is a thunk."""
     from ..core import flags as _flags
@@ -538,6 +550,13 @@ def _paged_gate(kernel, training, supported):
     if not supported():
         return gate_reject(kernel, "shape")
     return True
+
+
+def _value_arena_matches(k_arena, v_arena):
+    """Is the V arena the K arena's blocks and heads in the K arena's
+    dtype (its depth may differ)?"""
+    return v_arena.dtype == k_arena.dtype and v_arena.shape[:2] \
+        + v_arena.shape[3:] == k_arena.shape[:2] + k_arena.shape[3:]
 
 
 def _paged_kernel_eligible(q, k_arena, v_arena, training, sinks=None):
@@ -556,8 +575,7 @@ def _paged_kernel_eligible(q, k_arena, v_arena, training, sinks=None):
                                     k_arena.dtype.itemsize,
                                     d_v=v_arena.shape[2])):
         return False
-    if v_arena.dtype != k_arena.dtype or v_arena.shape[:2] \
-            + v_arena.shape[3:] != k_arena.shape[:2] + k_arena.shape[3:]:
+    if not _value_arena_matches(k_arena, v_arena):
         return gate_reject(kernel, "value_arena")
     if sinks is not None and (
             paged_group(q.shape[1], k_arena.shape[1]) < 2
@@ -584,7 +602,9 @@ def _write_kernel_eligible(arena, slots):
 
 def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,
                     training=False, sinks=None):
-    """Gated paged attention: the Pallas block-table kernel when
+    """Gated paged attention over arenas the caller has WRITTEN
+    (`write_kv`; `paged_write_attend` is the entry that does both): the
+    Pallas block-table kernel when
     eligible, `paged_attention_ref` when the gate rejects. The K arena is
     [n, h_kv, d, bs], the V arena [n, h_kv, d_v, bs] (-> [b, h, s, d_v]);
     `sinks` [h] float32 or None: a sink logit a query head
@@ -625,6 +645,76 @@ def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,
             **cut)
     return paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
                                scale, sinks)
+
+
+def _write_attend_cut(q, k_arena, v_arena, table_blocks, training):
+    """How ONE call of the multi-head kernel that writes this step's
+    tokens and attends would be cut (`paged_write_attend_cut`), or None
+    where the step goes to the pair. From the shapes alone. A chunk
+    (s > 1) and a group of query heads a key-value head are not this
+    gate's to judge and go uncounted; a decode step of one query head a
+    key-value head is admitted, or counted under
+    `pallas.gate_reject.paged_write_attend.*`: `_paged_gate`'s reasons
+    (the pair's own gates count theirs where the call goes next), and
+    `shape` where the kernel or the writer would refuse the call, the V
+    arena is not the K arena's blocks, heads and dtype, a block is not
+    whole 128-lane tiles, or the write would shrink the kernel's head
+    tile."""
+    from ..ops.pallas.decode_attention import paged_write_attend_cut
+    if q.shape[2] != 1 or q.shape[1] != k_arena.shape[1]:
+        return None
+    cut = paged_write_attend_cut(
+        tuple(q.shape), tuple(k_arena.shape), tuple(v_arena.shape),
+        table_blocks, k_arena.dtype.itemsize) \
+        if _value_arena_matches(k_arena, v_arena) else None
+    return cut if _paged_gate("paged_write_attend", training,
+                              lambda: cut is not None) else None
+
+
+def paged_write_attend(q, k_arena, v_arena, block_tables, lengths, new_k,
+                       new_v, scale, training=False):
+    """A layer's step over its paged cache, whole: the chunk's keys and
+    values `new_k` [b, s, h, d] and `new_v` [b, s, h, d_v] written at
+    `lengths`.., then q [b, h_q, s, d] attended over the arenas as
+    written -> (out [b, h_q, s, d_v], k_arena, v_arena). The ONE place
+    that chooses how:
+    - one token a slot (s = 1), one query head a key-value head, and a
+      shape `_write_attend_cut` admits: ONE Pallas call, the paged kernel
+      writing each slot's token into the block it holds anyway
+      (ops/pallas/decode_attention.paged_write_attend): no block is read
+      for the write, each written block is stored once;
+    - everything else (a prefill chunk, grouped-query heads, the flag
+      off, the CPU, training, a shape the gate refuses): `write_kv` twice,
+      then `paged_attention`, each behind its own gate as ever.
+    The two give the same output and the same arenas, bit for bit, but
+    for the trash block. Two slots may share a physical row only if it is
+    the trash block (the pool's invariant). The choice reads the shapes
+    and `FLAGS_use_paged_attention`, nothing else."""
+    lens = jnp.asarray(lengths, jnp.int32)
+    cut = _write_attend_cut(q, k_arena, v_arena, block_tables.shape[1],
+                            training)
+    if cut is not None:
+        from ..core import monitor
+        from ..ops.pallas import run_guarded
+        from ..ops.pallas.decode_attention import \
+            paged_write_attend as write_attend
+        # the cut this program compiles with and the bytes a call stores,
+        # on the kernel's span and as gauges per slot count (b32s1 is a
+        # decode step of 32 slots)
+        monitor.stat_set_many({
+            f"pallas.paged_write_attend.{name}.b{q.shape[0]}s1": value
+            for name, value in cut.items()})
+        return run_guarded(
+            "paged_write_attend",
+            lambda: write_attend(
+                q, k_arena, v_arena, block_tables, lens,
+                _token_lanes(k_arena, new_k), _token_lanes(v_arena, new_v),
+                scale),
+            **cut)
+    k_arena = write_kv(k_arena, block_tables, lens, new_k)
+    v_arena = write_kv(v_arena, block_tables, lens, new_v)
+    return (paged_attention(q, k_arena, v_arena, block_tables, lens, scale,
+                            training=training), k_arena, v_arena)
 
 
 # --------------------------------------------------------------------------

@@ -162,21 +162,21 @@ class MultiHeadAttention(Layer):
                 raise ValueError(
                     "attn_mask is not supported with a PagedKVCache: "
                     "causality comes from the per-slot lengths.")
-            from ..kv_pool import paged_attention, write_kv
+            from ..kv_pool import paged_write_attend
             from ...core.tensor import Tensor
             import jax.numpy as jnp
             # the serve programs' `attn` (core/program_map.SCOPES): the
-            # cache write, the attention or its kernel, the projection out
+            # cache write and the attention (one kernel for a decode
+            # step's token where kv_pool's gate admits, else the writer,
+            # then the kernel or its jnp form), the projection out
             with jax.named_scope("attn"):
                 kj = ops.transpose(k, [0, 2, 1, 3])._value  # [b, s, h, d]
                 vj = ops.transpose(v, [0, 2, 1, 3])._value
                 lens = jnp.asarray(cache.lengths, jnp.int32)
-                kc = write_kv(cache.k, cache.block_tables, lens, kj)
-                vc = write_kv(cache.v, cache.block_tables, lens, vj)
                 qv = q._value
-                out = paged_attention(qv, kc, vc, cache.block_tables, lens,
-                                      self.head_dim ** -0.5,
-                                      training=self.training)
+                out, kc, vc = paged_write_attend(
+                    qv, cache.k, cache.v, cache.block_tables, lens, kj, vj,
+                    self.head_dim ** -0.5, training=self.training)
                 out = ops.transpose(Tensor(out, _internal=True),
                                     [0, 2, 1, 3])
                 b, s = out.shape[0], out.shape[1]

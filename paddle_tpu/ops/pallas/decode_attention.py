@@ -42,6 +42,7 @@ from .flash_attention import (NEG_INF, _Z, _ceil_to, _cparams, _interpret,
 __all__ = ["decode_attention", "supported",
            "paged_decode_attention", "paged_supported", "paged_group",
            "paged_write_token", "paged_write_supported",
+           "paged_write_attend", "paged_write_attend_cut",
            "latent_paged_decode_attention", "latent_paged_supported"]
 
 
@@ -197,6 +198,47 @@ def _pick_bk(shape, dtype, scale, measure_builder):
 # block_size minor and a multiple of 128 the pool's buffer IS the
 # kernel's operand; nn/kv_pool.write_kv updates it in place.
 
+def _token_into_block(tok_ref, blk_ref, out_ref, buf, i, at, interpret):
+    """out_ref[buf] = blk_ref[0] ([h, d, bs] both) with column i of the
+    tokens tok_ref [h, d, slots padded to 128s] in lane `at` (no lane
+    where `at` is negative): what the token writer would do to the
+    block, done by the paged kernel to the block it holds.
+
+    The column gets there as in `_paged_write_kernel`, by a lane rotation
+    of the 128 slots around slot i, by at - i: data is moved, never
+    computed with, so whatever a token's bits say (-0.0, inf, a NaN) they
+    arrive as they are, and no other slot's can leak into the block. But
+    on 32-bit WORDS: a block and tokens narrower than 32 bits are read
+    and stored as the words their rows pack into (d x itemsize / 4 rows
+    of them; a view of the buffers, no element converted: which rows
+    share a word is the buffers' layout, the same for the tokens and the
+    block), so a bfloat16 block of 100 vector registers is rotated,
+    selected and stored as 100, with no float32 form of it in VMEM (a
+    step of GPT-2 XL's 25 heads plans 7.8 MiB, 10.6 the writer's way;
+    measured alone on a v5e the two ways cost the same ~7 us a call of
+    32 slots, PR 47). The interpreter stores through no such view and
+    casts the values instead."""
+    from jax.experimental.pallas import tpu as pltpu
+    lanes, bs = np.int32(128), blk_ref.shape[3]
+    tile = pl.ds(pl.multiple_of(i // lanes * lanes, 128), 128)
+    if interpret:
+        tok = pltpu.bitcast(tok_ref[:, :, tile], jnp.int32)
+        blk = pltpu.bitcast(blk_ref[0], jnp.int32)
+    else:
+        tok = tok_ref.bitcast(jnp.int32)[:, :, tile]
+        blk = blk_ref.bitcast(jnp.int32)[0]
+    tok = pltpu.roll(tok, (at % lanes - i % lanes + lanes) % lanes, 2)
+    # the block's lanes beside the 128 rotated ones: a prefix of them, or
+    # whole copies (slot i now sits in lane at % 128 of every copy)
+    tok = tok[:, :, :bs] if bs <= 128 else jnp.tile(tok, (1, 1, bs // 128))
+    lane = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 2)
+    blk = jnp.where(lane == at, tok, blk)
+    if interpret:
+        out_ref[buf] = pltpu.bitcast(blk, out_ref.dtype)
+    else:
+        out_ref.bitcast(jnp.int32)[buf] = blk
+
+
 def _paged_block_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, scale,
                         live):
     """One K/V block into the online softmax of a tile of ht heads: q
@@ -223,8 +265,9 @@ def _paged_block_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, scale,
     m_scr[:] = m_new
 
 
-def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                              m_scr, l_scr, acc_scr, *, scale, bs, nb, s):
+def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
+                              scale, bs, nb, s, write=False,
+                              interpret=False):
     """Grid (b, h // ht, nb); nb = logical blocks per request (sequential
     accumulator dim). One step holds ONE logical block of one request for
     a tile of ht heads — q/out [1, ht, s, d], K/V [1, ht, d, bs] — and
@@ -235,7 +278,33 @@ def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     vector (index + s per batch, like the contiguous kernel); bt_ref
     [b, nb] maps logical to physical arena blocks (consumed by the index
     maps — unused here beyond documentation: logical col ids already
-    encode causality)."""
+    encode causality).
+
+    With `write` (one token a slot, its 8 padded query rows: PR 47) the
+    step's key and value tokens come too, [ht, d, slots] and [ht, d_v,
+    slots] before the output (`_paged_write_kernel`'s dense operand, a
+    head tile of it), and the arenas again as two more outputs, aliased
+    to the inputs and left in HBM: the kernel stores what it writes
+    itself. The step that holds the token's own block — ik = fill // bs,
+    NOT `last`, which the padded rows push to (fill + 7) // bs — puts
+    the block it fetched, with the token at lane fill % bs, into one of
+    two VMEM buffers an arena (`k_buf`, `v_buf` [2, ht, d, bs]), starts
+    their copies to the token's physical row, and attends over THEM:
+    exactly the blocks the writer would have left for a kernel that ran
+    after it. A copy is waited for when its buffer comes round again, two
+    (slot, head tile)s on, and at the last grid step: it is in flight
+    under the next slot's fetches and products. (As pipelined output
+    blocks written back at a slot's end, the same 26 MB of GPT-2 XL's
+    step cost 74 us a call, as much as the writer they replaced.) The
+    grid of a writing call runs in order for that. A token past its
+    slot's table is not in the table: the step of the table's last block
+    stores that block unamended (to the trash block's row) and attends
+    over it as it is."""
+    if write:
+        tk_ref, tv_ref, o_ref, ko_hbm, vo_hbm = rest[:5]
+        m_scr, l_scr, acc_scr, k_buf, v_buf, sems = rest[5:]
+    else:
+        o_ref, m_scr, l_scr, acc_scr = rest
     ib, ik = pl.program_id(0), pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -250,14 +319,70 @@ def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
         jnp.maximum(length - np.int32(1), np.int32(0)) // np.int32(bs),
         np.int32(nb - 1))                      # last live logical block
 
-    @pl.when(ik <= last)
-    def _compute():
-        def live():
-            row = jax.lax.broadcasted_iota(jnp.int32, (s, bs), 0)
-            col = ik * bs + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 1)
-            return col <= index + row
-        _paged_block_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
-                            scale, live)
+    def live():
+        row = jax.lax.broadcasted_iota(jnp.int32, (s, bs), 0)
+        col = ik * bs + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 1)
+        return col <= index + row
+
+    if not write:
+        @pl.when(ik <= last)
+        def _compute():
+            _paged_block_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
+                                scale, live)
+    else:
+        own = index // np.int32(bs)            # the token's logical block
+        held = ik == jnp.minimum(own, np.int32(nb - 1))
+        # the lane the token takes; none where it falls past the table
+        at = jnp.where(own < np.int32(nb), index % np.int32(bs),
+                       np.int32(-1))
+
+        from jax.experimental.pallas import tpu as pltpu
+        ih, tiles = pl.program_id(1), pl.num_programs(1)
+        n = ib * tiles + ih                    # this (slot, head tile)
+        total = pl.num_programs(0) * tiles
+        buf = n % np.int32(2)
+        ht = k_ref.shape[1]
+        # the token's physical row: the trash block past the table
+        # (nn/kv_pool._phys_row)
+        row = jnp.where(own < np.int32(nb),
+                        bt_ref[ib, jnp.minimum(own, np.int32(nb - 1))],
+                        np.int32(0))
+
+        def stores(buf):
+            heads = pl.ds(ih * np.int32(ht), ht)
+            return [pltpu.make_async_copy(src.at[buf], dst.at[row, heads],
+                                          sems.at[buf, np.int32(j)])
+                    for j, (src, dst) in enumerate(((k_buf, ko_hbm),
+                                                    (v_buf, vo_hbm)))]
+
+        @pl.when(held)                         # held implies ik <= last
+        def _write_and_compute():
+            @pl.when(n >= 2)                   # the buffer's last store
+            def _():
+                for store in stores(buf):
+                    store.wait()
+            _token_into_block(tk_ref, k_ref, k_buf, buf, ib, at, interpret)
+            _token_into_block(tv_ref, v_ref, v_buf, buf, ib, at, interpret)
+            for store in stores(buf):
+                store.start()
+            _paged_block_update(q_ref, k_buf.at[pl.ds(buf, 1)],
+                                v_buf.at[pl.ds(buf, 1)], m_scr, l_scr,
+                                acc_scr, scale, live)
+
+        @pl.when((n == total - 1) & (ik == nb - 1))
+        def _drain():                          # what is still in flight
+            for store in stores(buf):
+                store.wait()
+
+            @pl.when(total >= 2)
+            def _():
+                for store in stores(np.int32(1) - buf):
+                    store.wait()
+
+        @pl.when(jnp.logical_not(held) & (ik <= last))
+        def _compute():
+            _paged_block_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
+                                scale, live)
 
     @pl.when(ik == nb - 1)
     def _flush():
@@ -272,31 +397,42 @@ def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
 _ATTN_VMEM_BYTES = 12 << 20
 
 
-def _paged_step_bytes(ht, s_p, d, bs, itemsize, d_v=None):
+def _paged_step_bytes(ht, s_p, d, bs, itemsize, d_v=None, write_slots=0):
     """VMEM bytes of one grid step over `ht` heads, as Mosaic lays the
     blocks out: the minor dimension padded to the 128 lanes. Keys (and
     queries) `d` deep, values (and outputs) `d_v`, `d` where not given; a
     group's sinks, where a call has them, are one more column of state
-    (4 KB a head) and are not counted."""
+    (4 KB a head) and are not counted. A step that also writes the
+    tokens of `write_slots` slots holds, beside that, two buffers of each
+    block it stores and the head tile's tokens, double-buffered, and
+    the words of the 128 slots it rotates and selects
+    (`_token_into_block`)."""
     d_v = d if d_v is None else d_v
     d_l, dv_l, bs_l = _ceil_to(d, 128), _ceil_to(d_v, 128), _ceil_to(bs, 128)
     kv = 2 * ht * (d + d_v) * bs_l * itemsize      # K, V, double-buffered
     qo = 2 * ht * s_p * (d_l + dv_l) * itemsize    # q, out, double-buffered
     state = ht * s_p * (128 + 128 + dv_l) * 4      # m, l, acc in float32
     scores = 3 * ht * s_p * bs_l * 4               # sc, p and a temporary
-    return kv + qo + state + scores
+    if not write_slots:
+        return kv + qo + state + scores
+    tokens = 2 * ht * (d + d_v) * _ceil_to(write_slots, 128) * itemsize
+    words = 3 * ht * (d + d_v) * 128 * itemsize     # read, rotated, selected
+    return 2 * kv + qo + state + scores + tokens + words
 
 
 def paged_heads_per_step(h, s_p, d, bs, itemsize,
-                         budget=_ATTN_VMEM_BYTES, d_v=None) -> int:
+                         budget=_ATTN_VMEM_BYTES, d_v=None,
+                         write_slots=0) -> int:
     """The paged kernel's head tile: the largest divisor of `h` whose
     grid step fits `budget` bytes of VMEM, 0 where not even one head
     does. From the shape alone: GPT-2 XL's decode step (h 25, 8 padded
     query rows, d 64, block 128, bf16) takes all 25 heads of a block in
-    one step, its 128- and 256-row prefills 5."""
+    one step, its 128- and 256-row prefills 5; the same decode step
+    WRITING its 32 slots' tokens (`write_slots`) still all 25, 7.8 of
+    the 12 MiB."""
     for ht in range(int(h), 0, -1):
-        if h % ht == 0 and \
-                _paged_step_bytes(ht, s_p, d, bs, itemsize, d_v) <= budget:
+        if h % ht == 0 and _paged_step_bytes(
+                ht, s_p, d, bs, itemsize, d_v, write_slots) <= budget:
             return ht
     return 0
 
@@ -361,6 +497,49 @@ def paged_cut(q_shape, arena_shape, table_blocks, itemsize,
     return {"heads_per_step": ht, "grid_steps": (hl // ht) * steps}
 
 
+def paged_write_attend_cut(q_shape, k_shape, v_shape, table_blocks,
+                           itemsize):
+    """The writing form's ONE static predicate, and its cut: can one call
+    of the multi-head kernel write a decode step's tokens and attend
+    (q [b, h, 1, d] over arenas k_shape [n, h, d, bs] and v_shape [n, h,
+    d_v, bs])? None where not; else `paged_cut`'s `heads_per_step` and
+    `grid_steps` (the head tile with the write's VMEM counted) and
+    `write_bytes`, the slots' K and V blocks as laid out, each written
+    back once (nothing of them is read for the write: the kernel holds
+    the block already).
+
+    It takes one token a slot, one query head a key-value head, a call
+    and two arenas that the kernel and the token writer each take
+    (`paged_supported`, `paged_write_supported`), rows that fill whole
+    32-bit words as tiled (`_token_into_block`: d and d_v multiples of 8
+    words' rows), a block of whole 128-lane tiles (what the kernel's own
+    store of it moves), and a head tile no smaller than the call gets WITHOUT
+    the write: a grid step that holds the output blocks and the tokens
+    too must not cost the kernel grid steps (GPT-2 XL's 25 heads of 64
+    fit; 30 heads of 128 would halve to 15, and stay on the writer and
+    the kernel apart)."""
+    if len(q_shape) != 4 or len(k_shape) != 4 or len(v_shape) != 4:
+        return None
+    b, h, s, d = q_shape
+    bs, d_v = k_shape[3], v_shape[2]
+    if s != 1 or paged_group(h, k_shape[1]) != 1:
+        return None
+    if not paged_supported(q_shape, k_shape, itemsize, d_v=d_v) or not all(
+            paged_write_supported(a, itemsize, b) for a in (k_shape, v_shape)):
+        return None
+    word_rows = 8 * 4 // int(itemsize)
+    if d % word_rows or d_v % word_rows or bs % 128:
+        return None
+    tile = functools.partial(paged_heads_per_step, h, _paged_rows(1, s), d,
+                             bs, itemsize, d_v=d_v)
+    ht = tile(write_slots=b)
+    if ht != tile():
+        return None
+    return {"heads_per_step": ht,
+            "grid_steps": (h // ht) * b * int(table_blocks),
+            "write_bytes": b * h * (d + d_v) * _ceil_to(bs, 128) * itemsize}
+
+
 def _paged_call(q, k_arena, v_arena, block_tables, lengths, scale):
     """The pallas_call for already-tile-padded q over arenas
     [n_blocks, h, d, block_size]. With block_size a multiple of 128 the
@@ -378,18 +557,26 @@ def _paged_call(q, k_arena, v_arena, block_tables, lengths, scale):
 # flags is a static argument, so a cached trace never outlives a flag.
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _paged_call_once(q, k_arena, v_arena, block_tables, lengths, *, scale,
-                     interpret):
+def _paged_call_once(q, k_arena, v_arena, block_tables, lengths,
+                     new_k=None, new_v=None, *, scale, interpret):
+    """-> out [b, h, s_p, d_v]; with the step's tokens `new_k` [h, d,
+    slots padded to 128s] and `new_v` [h, d_v, the same] (one token a
+    slot, `lengths` = fill + s_p), -> (out, k_arena, v_arena): the
+    arenas aliased in and out, each slot's token written into its block
+    by the grid step that holds it and the block stored by the kernel's
+    own copy (`_paged_decode_attn_kernel`). A device trace names either
+    form after this function."""
     from jax.experimental.pallas import tpu as pltpu
     b, h, s_p, d = q.shape
     d_v, bs = v_arena.shape[2], k_arena.shape[3]
     nb = block_tables.shape[1]
+    write = new_k is not None
     # the cut into grid steps: a step pays ~0.3 us whatever it holds, so
     # it holds as many of a block's heads as fit (one head a step made
     # GPT-2 XL's decode 6400 steps a layer of 16 KB each: 1.6 ms, all of
     # it step overhead, against 0.17 ms for 256 steps of 400 KB; PR 31)
     ht = paged_heads_per_step(h, s_p, d, bs, k_arena.dtype.itemsize,
-                              d_v=d_v)
+                              d_v=d_v, write_slots=b if write else 0)
 
     def q_map(ib, ih, ik, len_ref, bt_ref):
         return (ib, ih, _Z, _Z)
@@ -406,6 +593,12 @@ def _paged_call_once(q, k_arena, v_arena, block_tables, lengths, *, scale,
             np.int32(nb - 1))
         return (bt_ref[ib, jnp.minimum(ik, last)], ih, _Z, _Z)
 
+    def tok_map(ib, ih, ik, len_ref, bt_ref):
+        return (ih, _Z, _Z)        # resident across a head tile's steps
+
+    out_spec = pl.BlockSpec((1, ht, s_p, d_v), q_map)
+    out_shape = jax.ShapeDtypeStruct((b, h, s_p, d_v), q.dtype)
+    tokens = (new_k, new_v) if write else ()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, h // ht, nb),
@@ -413,23 +606,38 @@ def _paged_call_once(q, k_arena, v_arena, block_tables, lengths, *, scale,
             pl.BlockSpec((1, ht, s_p, d), q_map),
             pl.BlockSpec((1, ht, d, bs), kv_map),
             pl.BlockSpec((1, ht, d_v, bs), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, ht, s_p, d_v), q_map),
+        ] + [pl.BlockSpec((ht,) + t.shape[1:], tok_map) for t in tokens],
+        # the arenas as outputs stay in HBM: the kernel stores a slot's
+        # amended blocks itself, from two buffers each, a store in flight
+        # while the next slot's blocks are fetched and attended
+        out_specs=[out_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        if write else out_spec,
         scratch_shapes=[
             _vmem((ht, s_p, 1), jnp.float32),
             _vmem((ht, s_p, 1), jnp.float32),
             _vmem((ht, s_p, d_v), jnp.float32),
-        ],
+        ] + ([_vmem((2, ht, d, bs), k_arena.dtype),
+              _vmem((2, ht, d_v, bs), v_arena.dtype),
+              pltpu.SemaphoreType.DMA((2, 2))] if write else []),
     )
-    kernel = functools.partial(_paged_decode_attn_kernel,
-                               scale=scale, bs=bs, nb=nb, s=s_p)
-    return pl.pallas_call(
+    kernel = functools.partial(_paged_decode_attn_kernel, scale=scale,
+                               bs=bs, nb=nb, s=s_p, write=write,
+                               interpret=bool(interpret))
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, s_p, d_v), q.dtype),
-        compiler_params=_cparams("parallel", "parallel", "arbitrary"),
+        out_shape=[out_shape] + [jax.ShapeDtypeStruct(a.shape, a.dtype)
+                                 for a in (k_arena, v_arena)]
+        if write else out_shape,
+        # operands: lengths, tables, q, k_arena, v_arena, the tokens
+        input_output_aliases={3: 1, 4: 2} if write else {},
+        # a writing call's stores are waited for two (slot, head tile)s
+        # on: its grid runs in order
+        compiler_params=_cparams(*(("arbitrary",) * 3 if write else
+                                   ("parallel", "parallel", "arbitrary"))),
         interpret=interpret,
-    )(lengths, block_tables, q, k_arena, v_arena)
+    )(lengths, block_tables, q, k_arena, v_arena, *tokens)
+    return tuple(out) if write else out
 
 
 # --------------------------------------------------------------------------
@@ -594,7 +802,9 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
     k_arena [n_blocks, h_kv, d, block_size] and v_arena [n_blocks, h_kv,
     d_v, block_size] (d_v = d in most nets). `lengths` [b] is each
     request's cache fill count BEFORE this chunk (the chunk's k/v must
-    already be written into the arena — nn/kv_pool.write_kv). Row r of
+    already be written into the arena — nn/kv_pool.write_kv; the form
+    that writes a decode step's tokens itself is `paged_write_attend`,
+    below). Row r of
     batch i attends to logical cache cols <= lengths[i] + r. Block-table
     entries past the allocation MUST be 0 (the pool's reserved trash
     block): padded query rows reach past the live end and the index map
@@ -666,6 +876,52 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
     out = out.astype(out_dtype)
     out = out[:, :, :rows] if s_p != rows else out
     return out.reshape(b, h, s, d_v) if group > 1 else out
+
+
+def paged_write_attend(q, k_arena, v_arena, block_tables, lengths, new_k,
+                       new_v, scale=None):
+    """`paged_decode_attention` for one token a slot on the multi-head
+    form (q [b, h, 1, d], h the arenas' heads) that ALSO writes the
+    step's tokens: -> (out [b, h, 1, d_v], k_arena, v_arena), the arenas
+    updated in place (PR 47). `new_k` [h, d, b] and `new_v` [h, d_v, b]
+    are the tokens, dense with the slots in the lanes, in the arenas'
+    dtype; slot i's go to lane lengths[i] % block_size of its logical
+    block lengths[i] // block_size, the trash block where that is past
+    its table, put there by the grid step that holds that block
+    (`_paged_decode_attn_kernel` with `write`). What is attended is what
+    `nn/kv_pool.write_kv` would have left: output and arenas are those
+    of the writer twice and then `paged_decode_attention`, bit for bit
+    (but for the trash block, whose content no one may rely on). Two
+    slots may share a physical row only if it is the trash block (the
+    pool's invariant, the token writer's condition too): a slot's block
+    is written back while the next slot's are fetched. The caller asks
+    `paged_write_attend_cut` first; table entries past the allocation
+    are 0, as there."""
+    b, h, s, d = q.shape
+    d_v, bs = v_arena.shape[2], k_arena.shape[3]
+    if s != 1 or k_arena.shape[1:3] != (h, d) \
+            or v_arena.shape[:2] + v_arena.shape[3:] \
+            != k_arena.shape[:2] + k_arena.shape[3:] \
+            or new_k.shape != (h, d, b) or new_v.shape != (h, d_v, b):
+        raise ValueError(
+            f"paged_write_attend: tokens k{tuple(new_k.shape)} "
+            f"v{tuple(new_v.shape)} and arenas k{tuple(k_arena.shape)} "
+            f"v{tuple(v_arena.shape)} are not one token a slot, one query "
+            f"head a key-value head, for q{tuple(q.shape)}")
+    s_p = _paged_rows(1, s)     # the sublane tile of padded query rows
+    q_p = jnp.pad(q.astype(k_arena.dtype),
+                  ((0, 0), (0, 0), (0, s_p - s), (0, 0)))
+    # the kernel recovers a fill as length - s_p, and rotates whole
+    # 128-lane tiles of slots
+    lens = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1),
+                            (b,)) + jnp.int32(s_p)
+    pad = ((0, 0), (0, 0), (0, -b % 128))
+    out, k_arena, v_arena = _paged_call_once(
+        q_p, k_arena, v_arena, jnp.asarray(block_tables, jnp.int32), lens,
+        jnp.pad(new_k, pad), jnp.pad(new_v, pad),
+        scale=float(d ** -0.5 if scale is None else scale),
+        interpret=_interpret())
+    return out.astype(q.dtype)[:, :, :s], k_arena, v_arena
 
 
 # --------------------------------------------------------------------------

@@ -304,9 +304,10 @@ class FullAttention(_Weights):
         b, s, h, d = q.shape
         scale = d ** -0.5
         if cache is not None:
-            from ...nn.kv_pool import paged_attention, write_kv
+            from ...nn.kv_pool import paged_write_attend, write_kv
             lens = jnp.asarray(cache.lengths, jnp.int32)
             k, v = k.astype(cache.k.dtype), v.astype(cache.v.dtype)
+        if s > 1 and cache is not None:
             cache = cache._replace(
                 k=write_kv(cache.k, cache.block_tables, lens, k),
                 v=write_kv(cache.v, cache.block_tables, lens, v),
@@ -314,10 +315,12 @@ class FullAttention(_Weights):
         if s > 1 or cache is None:   # a prefill starts an empty slot
             out = _chunk_attention(q.astype(k.dtype), k, v, live,
                                    scale=scale, q_block=PREFILL_TILE)
-        else:
-            out = jnp.swapaxes(paged_attention(
+        else:       # a token a slot: written and attended by one entry
+            out, kc, vc = paged_write_attend(
                 jnp.swapaxes(q, 1, 2), cache.k, cache.v,
-                cache.block_tables, lens, scale), 1, 2)
+                cache.block_tables, lens, k, v, scale)
+            out = jnp.swapaxes(out, 1, 2)
+            cache = cache._replace(k=kc, v=vc, lengths=lens + jnp.int32(s))
         return (out.reshape(b, s, h * d).astype(q.dtype),), cache
 
     def output(self, out):

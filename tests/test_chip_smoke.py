@@ -56,7 +56,10 @@ def test_serve_phase_toy(interpret):
     result = _run("serve", chip_smoke.serve_phase)
     assert result["completed"] == SIZES.serve_requests == 6
     assert len(result["prefill_buckets"]) >= 2     # both length bands
+    # a prefill's chunk through the kernel, a decode step through the
+    # kernel that also writes its token (PR 47)
     assert monitor.stat_get("pallas.hit.paged_decode_attention") > 0
+    assert monitor.stat_get("pallas.hit.paged_write_attend") > 0
     assert all(e <= chip_smoke.LOGITS_TOL
                for e in result["forced_logits_err"].values())
     # the toy hybrid (one period): served, and with the interpreted
@@ -64,6 +67,11 @@ def test_serve_phase_toy(interpret):
     hybrid = result["hybrid"]
     assert hybrid["completed"] == 3 and hybrid["state_bytes"] > 0
     assert all(hybrid["hits"].get(k) for k in chip_smoke.HYBRID_KERNELS)
+    # one kernel that writes and attends, or the writer and the kernel
+    # apart (the toy's blocks of less than 128 lanes; at the published
+    # widths the head tile decides: the v5e compile below)
+    assert chip_smoke.paged_step_engaged(hybrid["hits"])
+    assert not chip_smoke.paged_step_engaged({"paged_decode_attention": 1})
     assert hybrid["logits_err"] <= chip_smoke.LOGITS_TOL
     # both latent nets' programs are reported; the toy's bucket of 32 is
     # less than a tile and runs whole (the full size's: the v5e compiles)
@@ -81,8 +89,12 @@ def test_kernels_phase_toy(interpret):
     assert result["interpreted"]
     assert set(result["errors_vs_jnp_reference"]) >= {
         "flash_causal", "flash_padding_bias", "fused_ce", "decode",
-        "paged_decode_s1_blockpicked", "latent_paged_decode",
-        "grouped_expert_ffn", "grouped_paged_decode", "sink_paged_decode"}
+        "paged_decode_s1_blockpicked", "paged_write_attend",
+        "latent_paged_decode", "grouped_expert_ffn", "grouped_paged_decode",
+        "sink_paged_decode"}
+    # the fused write against the writer and the kernel apart: bit for bit
+    assert result["errors_vs_jnp_reference"]["paged_write_attend"] \
+        == {"out": 0.0, "arenas": 0.0}
     # the grouped-query form's two call sites: the pool's pages, the rings
     # (and again with keys deeper than values and sinks in the rings)
     for name in ("grouped_paged_decode", "sink_paged_decode"):
@@ -158,7 +170,8 @@ def _compile_kernels_for_v5e():
     from jax.sharding import SingleDeviceSharding
     from paddle_tpu.ops.pallas.decode_attention import (
         decode_attention, latent_paged_cut, latent_paged_decode_attention,
-        paged_cut, paged_decode_attention, paged_write_token)
+        paged_cut, paged_decode_attention, paged_write_attend,
+        paged_write_attend_cut, paged_write_token)
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.ops.pallas.fused_ce import fused_linear_cross_entropy
     from paddle_tpu.ops.pallas.grouped_ffn import grouped_ffn_cut
@@ -204,6 +217,15 @@ def _compile_kernels_for_v5e():
                         arena, arena, ((2, 4), i32), ((2,), i32))
         compile_for_v5e(paged_write_token, arena, ((2,), i32), ((2,), i32),
                         ((4, 64, 2), bf16))
+        # one token a slot, written by the kernel that attends (PR 47):
+        # whole 128-lane tiles only, which its gate holds it to
+        cut = paged_write_attend_cut((2, 4, 1, 64), arena[0], arena[0], 4, 2)
+        assert (cut is not None) == (block == 128), (block, cut)
+        if cut:
+            compile_for_v5e(
+                paged_write_attend, ((2, 4, 1, 64), bf16), arena, arena,
+                ((2, 4), i32), ((2,), i32), ((4, 64, 2), bf16),
+                ((4, 64, 2), bf16))
     # GPT-2 XL's pool: a block's 25 heads, [1, 25, 64, 128], in one grid
     # step of the decode step; 5 head tiles under a 256-row prefill
     arena = ((225, 25, 64, 128), bf16)
@@ -212,6 +234,13 @@ def _compile_kernels_for_v5e():
                         arena, arena, ((b, 8), i32), ((b,), i32))
         cut = paged_cut((b, 25, s, 64), arena[0], 8, 2)
         assert cut["heads_per_step"] == heads_per_step, (b, s, cut)
+    # the decode step that writes its 32 tokens: still 25 heads a step
+    compile_for_v5e(
+        paged_write_attend,
+        ((32, 25, 1, 64), bf16), arena, arena, ((32, 8), i32), ((32,), i32),
+        ((25, 64, 32), bf16), ((25, 64, 32), bf16))
+    assert paged_write_attend_cut((32, 25, 1, 64), arena[0], arena[0], 8,
+                                  2)["heads_per_step"] == 25
     # the writer picks its slot's lane out of the dense tokens [h, d, b]:
     # the cells' decode steps, 32 slots over GPT-2 XL's pool and 64 over
     # the Kimi share's one-head latent arena
@@ -313,14 +342,15 @@ def test_kernels_compile_under_mosaic_for_v5e():
 
 
 def _compile_paged_steps_for_v5e():
-    """Child-process body of the test below: two layers of write-then-
-    attend over donated arenas of the benchmark's pool shape, as a decode
-    step and as a prefill, compiled for v5e; one JSON line a step says
-    what the compiled program does to a buffer of arena shape."""
+    """Child-process body of the test below: two layers of write-and-
+    attend (`paged_write_attend`, as `MultiHeadAttention` calls it) over
+    donated arenas of the benchmark's pool shape, as a decode step and as
+    a prefill, compiled for v5e; one JSON line a step says what the
+    compiled program does to a buffer of arena shape."""
     import numpy as np
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-    from paddle_tpu.nn.kv_pool import KVBlockPool, paged_attention, write_kv
+    from paddle_tpu.nn.kv_pool import KVBlockPool, paged_write_attend
     try:
         device = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
     except Exception as e:  # environment without a usable libtpu
@@ -333,9 +363,8 @@ def _compile_paged_steps_for_v5e():
     def step(arenas, tables, lengths, q, k, v):
         out = []
         for ka, va in arenas:
-            ka = write_kv(ka, tables, lengths, k)
-            va = write_kv(va, tables, lengths, v)
-            q = paged_attention(q, ka, va, tables, lengths, d ** -0.5)
+            q, ka, va = paged_write_attend(q, ka, va, tables, lengths, k, v,
+                                           d ** -0.5)
             out.append((ka, va))
         return out, q
 
@@ -361,10 +390,15 @@ def _compile_paged_steps_for_v5e():
             "arena_bytes": int(np.prod(arena)) * 2,
             "attn": hits.get("pallas.hit.paged_decode_attention", 0),
             "writer": hits.get("pallas.hit.paged_write_token", 0),
-            "heads_per_step": monitor.stat_get(
-                f"pallas.paged_decode_attention.heads_per_step.b{b}s{s}"),
-            "grid_steps": monitor.stat_get(
-                f"pallas.paged_decode_attention.grid_steps.b{b}s{s}"),
+            "write_attend": hits.get("pallas.hit.paged_write_attend", 0),
+            "rejects": monitor.stats("pallas.gate_reject."),
+            "kernels": sorted(set(re.findall(
+                r"%(_paged_\w+?)[.\d]* = [^\n]*custom-call\(", text))),
+            **{name: monitor.stat_get(
+                f"pallas.{kernel}.{name}.b{b}s{s}")
+               for kernel in (("paged_write_attend",) if s == 1
+                              else ("paged_decode_attention",))
+               for name in ("heads_per_step", "grid_steps", "write_bytes")},
             "instructions": _instruction_count(text)}))
     print("PAGED-STEPS-DONE")
 
@@ -384,21 +418,31 @@ def test_paged_serve_steps_hold_no_arena_copy_for_v5e():
     for step in (decode, prefill):
         assert step["arena_in_hlo"] and step["relayouts"] == [], step
         assert step["temp_bytes"] < step["arena_bytes"] // 10, step
-        assert step["attn"] == 2, step
-        # nor an array one element a 128-lane row: the writer's tokens go
-        # in dense (the [32,25,64,1] row-major copy before each of its
+        assert step["rejects"] == {}, step
+        # nor an array one element a 128-lane row: the tokens go in dense
+        # (the [32,25,64,1] row-major copy before each of the writer's
         # calls was 13 MB for 102 KB, 3.6 ms of a 15.7 ms step; PR 34)
         assert step["lane_padded"] == [], step
-    assert (decode["s"], decode["writer"]) == (1, 4)      # the Pallas writer
-    assert (prefill["s"], prefill["writer"]) == (256, 0)  # the XLA loop
-    # a block's 25 heads in one grid step of the decode step: 32 slots x 8
-    # logical blocks; a 256-row prefill fits 5 heads a step
-    assert (decode["heads_per_step"], decode["grid_steps"]) == (25, 256)
+    # the decode step: ONE kernel a layer writes the tokens and attends
+    # (PR 47): no call of the token writer is left in the program
+    assert (decode["s"], decode["write_attend"], decode["attn"],
+            decode["writer"]) == (1, 2, 0, 0), decode
+    assert decode["kernels"] == ["_paged_call_once"], decode
+    # the prefill: `write_kv`'s XLA loop, then the kernel, as ever
+    assert (prefill["s"], prefill["write_attend"], prefill["attn"],
+            prefill["writer"]) == (256, 0, 2, 0), prefill
+    assert prefill["kernels"] == ["_paged_call_once"], prefill
+    # a block's 25 heads in one grid step of the decode step, with the
+    # write's output blocks and tokens in VMEM too: 32 slots x 8 logical
+    # blocks, 26.2 MB of blocks stored a call; a 256-row prefill fits 5
+    # heads a step
+    assert (decode["heads_per_step"], decode["grid_steps"],
+            decode["write_bytes"]) == (25, 256, 32 * 25 * 128 * 128 * 2)
     assert (prefill["heads_per_step"], prefill["grid_steps"]) == (5, 40)
-    # counted, as the window nets' steps are: `_paged_call_once` learned a
-    # value width and the grouped kernel sinks for another net (PR 45), and
-    # GPT-2 XL's cut stayed what it was (its jaxpr too, letter for letter)
-    assert (decode["instructions"], decode["temp_bytes"]) == (150, 0), decode
+    # counted: the decode step was 150 instructions with the writer's four
+    # calls and their operands (PR 45); the prefill is the parent's
+    # program, instruction for instruction and byte for byte of temporaries
+    assert (decode["instructions"], decode["temp_bytes"]) == (40, 0), decode
     assert (prefill["instructions"], prefill["temp_bytes"]) \
         == (468, 290_816), prefill
 
@@ -674,6 +718,7 @@ def _compile_hybrid_steps_for_v5e():
             "temp_bytes": mem.temp_size_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes, "held_bytes": held,
             "hits": {k.rsplit(".", 1)[1]: int(v) for k, v in hits.items()},
+            "rejects": monitor.stats("pallas.gate_reject."),
             "instructions": _instruction_count(text),
             "scopes": _scope_summary(text)}))
     print("HYBRID-STEPS-DONE")
@@ -695,13 +740,19 @@ def test_hybrid_serve_steps_update_state_and_arenas_in_place_for_v5e():
         assert step["state_in_hlo"] and step["state_copies"] == 0, step
         assert step["relayouts"] == [], step
         assert step["alias_bytes"] >= step["held_bytes"], step
+    # 30 heads of 128 with the write's blocks and tokens in VMEM would be
+    # 15 a grid step: the fused form's gate leaves this step on the
+    # writer and the kernel apart (PR 47), counted
     assert decode["hits"] == {"gdn_step": 3, "paged_write_token": 2,
                               "paged_decode_attention": 1}
+    assert decode["rejects"] == {
+        "pallas.gate_reject.paged_write_attend.shape": 1}, decode
+    assert prefill["rejects"] == {}, prefill
     assert decode["temp_bytes"] < 64e6, decode
     assert prefill["hits"] == {"gdn_chunk_scan": 3}
     assert prefill["temp_bytes"] < 1.0e9, prefill
     # counted at PR 45's parent and unchanged by it (`_paged_call_once`
-    # with a value width that is the key's)
+    # with a value width that is the key's) and by PR 47 (the pair)
     assert (decode["instructions"], prefill["instructions"],
             prefill["temp_bytes"]) == (1885, 4974, 170_335_232), (decode,
                                                                   prefill)

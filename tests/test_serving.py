@@ -342,6 +342,172 @@ def test_write_kv_shape_the_writer_refuses_takes_the_xla_loop(
         "pallas.gate_reject.paged_write_token.shape") == 1
 
 
+# the fused call (PR 47): (heads, d, d_v, block, table width, dtype,
+# fills with -1 an idle slot under an all-zero table, the head tile the
+# call must be cut with where that is fewer than all heads, poisoned:
+# slot 1's tokens hold -0.0 / inf / NaN bits)
+_WRITE_ATTEND_CASES = {
+    "lane0_of_a_fresh_block": (4, 16, 16, 128, 3, "float32", [0, 128, 256],
+                               None, False),
+    "last_lane": (4, 16, 16, 128, 3, "float32", [127, 255, 383], None,
+                  False),
+    "past_the_table": (4, 16, 16, 128, 2, "float32", [256, 255, 300], None,
+                       False),
+    "idle_slot": (4, 16, 16, 128, 3, "float32", [150, -1, 7], None, False),
+    "head_tile_of_eight": (16, 128, 128, 128, 2, "float32", [5, 130, 255],
+                           8, False),
+    "bf16_block128": (5, 64, 64, 128, 2, "bfloat16", [0, 127, 128, 200],
+                      None, False),
+    "bf16_poisoned": (5, 64, 64, 128, 2, "bfloat16", [3, 127, 128, 255],
+                      None, True),
+    "f32_poisoned": (4, 16, 16, 128, 3, "float32", [127, 128, 260], None,
+                     True),
+    "values_narrower": (4, 24, 16, 128, 3, "float32", [0, 127, 300], None,
+                        False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WRITE_ATTEND_CASES))
+def test_paged_write_attend_is_the_pair_bit_for_bit(interpret, case):
+    """ONE call of the multi-head kernel that writes the step's token
+    into the block it holds, against `write_kv` twice and then the
+    kernel, on the same inputs: the output AND both arenas, bit for bit
+    (but for the trash block, whose content nobody may rely on: there the
+    test asks only that nothing unreal got in)."""
+    from paddle_tpu.nn.kv_pool import paged_attention, paged_write_attend
+    da = importlib.import_module("paddle_tpu.ops.pallas.decode_attention")
+    h, d, d_v, bs, nb, dtype, fills, tile, poisoned = \
+        _WRITE_ATTEND_CASES[case]
+    if tile:        # a head tile smaller than h: the kernel itself, past
+        # the gate (which would not trade the pair's 16 heads a step)
+        assert da.paged_heads_per_step(h, 8, d, bs, 4, d_v=d_v,
+                                       write_slots=len(fills)) == tile < h
+    b, dt = len(fills), jnp.dtype(dtype)
+    pool = KVBlockPool(b * nb + 1, bs)
+    bt = np.zeros((b, nb), np.int32)
+    for i, n in enumerate(fills):
+        if n >= 0:
+            blocks = pool.alloc(min(n // bs + 1, nb))
+            bt[i, :len(blocks)] = blocks
+    lens = np.maximum(np.asarray(fills, np.int32), 0)
+    rng = np.random.RandomState(len(case))
+    ka = jnp.asarray(rng.randn(*pool.arena_shape(h, d)), dt)
+    va = jnp.asarray(rng.randn(*pool.arena_shape(h, d_v)), dt)
+    q = jnp.asarray(rng.randn(b, h, 1, d), dt)
+    nk = rng.randn(b, 1, h, d).astype(np.float32)
+    nv = rng.randn(b, 1, h, d_v).astype(np.float32)
+    nk[0, 0, 0, :2] = [-0.0, 0.0]              # bits, not values, move
+    if poisoned:
+        nk[1] = np.resize([np.nan, np.inf, -np.inf, -0.0], nk[1].shape)
+        nv[1] = np.resize([np.inf, np.nan, -0.0], nv[1].shape)
+    nk, nv = jnp.asarray(nk, dt), jnp.asarray(nv, dt)
+    monitor.reset(prefix="pallas.")
+    if tile:
+        lanes = [jnp.transpose(t[:, 0], (1, 2, 0)) for t in (nk, nv)]
+        out, k2, v2 = da.paged_write_attend(q, ka, va, bt, lens, *lanes,
+                                            0.25)
+    else:
+        out, k2, v2 = paged_write_attend(q, ka, va, bt, lens, nk, nv, 0.25)
+        assert monitor.stat_get("pallas.hit.paged_write_attend") == 1
+    assert monitor.stat_get("pallas.hit.paged_write_token") == 0
+    assert monitor.stat_get("pallas.hit.paged_decode_attention") == 0
+    k1 = write_kv(ka, bt, lens, nk)
+    v1 = write_kv(va, bt, lens, nv)
+    want = paged_attention(q, k1, v1, bt, lens, 0.25)
+    assert monitor.stat_get("pallas.hit.paged_write_token") == 2
+    bits = np.uint16 if dtype == "bfloat16" else np.uint32
+
+    def same(got, ref):
+        got, ref = np.asarray(got), np.asarray(ref)
+        assert got.dtype == ref.dtype and got.shape == ref.shape
+        np.testing.assert_array_equal(got.view(bits), ref.view(bits))
+
+    same(out, want)
+    same(k2[1:], k1[1:])
+    same(v2[1:], v1[1:])
+    assert not np.array_equal(np.asarray(k2)[1:], np.asarray(ka)[1:]) \
+        or all(n < 0 or n >= nb * bs for n in fills)
+    # a written token is where the numpy writer puts it
+    i = 0 if fills[0] >= 0 and fills[0] < nb * bs else 1
+    np.testing.assert_array_equal(
+        np.asarray(k2)[bt[i, lens[i] // bs], :, :, lens[i] % bs]
+        .view(bits), np.asarray(nk)[i, 0].view(bits))
+    if not poisoned:    # the trash block holds blocks and tokens, no junk
+        assert np.isfinite(np.asarray(k2, np.float32)[0]).all()
+        assert np.isfinite(np.asarray(v2, np.float32)[0]).all()
+    else:               # and a slot's NaN stays in its own block and row
+        clean = [j for j in range(b) if j != 1]
+        assert np.isfinite(np.asarray(out, np.float32)[clean]).all()
+        assert not np.isfinite(np.asarray(out, np.float32)[1]).all()
+
+
+def test_paged_write_attend_gate_reads_the_shapes(interpret):
+    """The fused form's gate is a predicate of shapes: GPT-2 XL's decode
+    step (32 slots, 25 heads of 64, block 128, bf16) writes and attends
+    in one call with all 25 heads a grid step; the hybrid's 30 heads of
+    128 would fall from 30 heads a step to 15, so its step stays on the
+    writer and the kernel apart, counted; a chunk and a group of query
+    heads are not the gate's business and count nothing."""
+    from paddle_tpu.nn.kv_pool import paged_write_attend
+    from paddle_tpu.ops.pallas.decode_attention import (
+        _ATTN_VMEM_BYTES, _paged_step_bytes, paged_heads_per_step,
+        paged_write_attend_cut)
+    chat = ((32, 25, 1, 64), (225, 25, 64, 128), (225, 25, 64, 128))
+    assert paged_write_attend_cut(*chat, 8, 2) == {
+        "heads_per_step": 25, "grid_steps": 256,
+        "write_bytes": 32 * 25 * 128 * 128 * 2}         # 26.2 MB stored
+    assert _paged_step_bytes(25, 8, 64, 128, 2, write_slots=32) \
+        <= _ATTN_VMEM_BYTES
+    hybrid = ((32, 30, 1, 128), (337, 30, 128, 128), (337, 30, 128, 128))
+    assert paged_heads_per_step(30, 8, 128, 128, 2) == 30
+    assert paged_heads_per_step(30, 8, 128, 128, 2, write_slots=32) == 15
+    assert paged_write_attend_cut(*hybrid, 8, 2) is None
+    # the small programs' shapes, and what the form does not take at all:
+    # a chunk, grouped queries, a block the writer refuses, a block too
+    # large for VMEM, rows that do not fill whole 32-bit words as tiled,
+    # a block of less than a 128-lane tile
+    assert paged_write_attend_cut((2, 4, 1, 64), (9, 4, 64, 128),
+                                  (9, 4, 48, 128), 4, 2)["grid_steps"] == 8
+    for q, k, v in (((2, 4, 8, 64), (9, 4, 64, 128), (9, 4, 64, 128)),
+                    ((2, 8, 1, 64), (9, 4, 64, 128), (9, 4, 64, 128)),
+                    ((2, 4, 1, 64), (9, 4, 64, 192), (9, 4, 64, 192)),
+                    ((32, 25, 1, 64), (9, 25, 64, 1024), (9, 25, 64, 1024)),
+                    ((2, 4, 1, 24), (9, 4, 24, 128), (9, 4, 24, 128)),
+                    ((2, 4, 1, 64), (9, 4, 64, 16), (9, 4, 64, 16))):
+        assert paged_write_attend_cut(q, k, v, 4, 2) is None, (q, k, v)
+
+    def run(b, h_q, s, h, d, bs=128, dtype=jnp.float32):
+        pool = KVBlockPool(8, bs)
+        (ka, va), = pool.arenas(1, h, d, dtype)
+        bt = np.asarray([pool.alloc(2) for _ in range(b)], np.int32)
+        monitor.reset(prefix="pallas.")
+        out, k2, v2 = paged_write_attend(
+            jnp.ones((b, h_q, s, d)), ka, va, bt, np.zeros(b, np.int32),
+            jnp.ones((b, s, h, d)), jnp.ones((b, s, h, d)), 0.25)
+        assert out.shape == (b, h_q, s, d) and k2.shape == ka.shape
+        return monitor.stats("pallas.")
+
+    stats = run(2, 4, 1, 4, 16)
+    assert stats["pallas.hit.paged_write_attend"] == 1
+    for b, h_q, s in ((2, 4, 4), (2, 8, 1)):    # a chunk; grouped queries
+        stats = run(b, h_q, s, 4, 16)
+        assert "pallas.hit.paged_write_attend" not in stats
+        assert not [k for k in stats if "paged_write_attend" in k], stats
+        assert stats["pallas.hit.paged_decode_attention"] == 1
+    stats = run(2, 30, 1, 30, 128, bs=128, dtype=jnp.bfloat16)  # hybrid's
+    assert stats["pallas.gate_reject.paged_write_attend.shape"] == 1
+    assert stats["pallas.hit.paged_write_token"] == 2
+    assert stats["pallas.hit.paged_decode_attention"] == 1
+    paddle.set_flags({"FLAGS_use_paged_attention": False})
+    try:        # the flag off still means the jnp pair
+        stats = run(2, 4, 1, 4, 16)
+    finally:
+        paddle.set_flags({"FLAGS_use_paged_attention": True})
+    assert not [k for k in stats if k.startswith("pallas.hit.")], stats
+    assert stats["pallas.gate_reject.paged_write_attend.flag_off"] == 1
+    assert stats["pallas.gate_reject.paged_decode_attention.flag_off"] == 1
+
+
 def test_mha_paged_matches_static_cache_bitwise():
     """The MHA PagedKVCache branch (jnp path) must be BITWISE equal to
     the StaticKVCache path across a prefill + decode sequence — the
@@ -548,6 +714,36 @@ def test_paged_kernel_engages_in_serve(net, interpret):
     assert any(moved.items() <= attrs.items() for attrs in spans), spans
     assert (f"write:b2={moved['token_bytes'] / 1e3:.0f}KB"
             f"/{moved['block_bytes'] / 1e6:.1f}MB") in report
+    # a block of 16 lanes is less than the 128-lane tile the kernel's own
+    # store moves: the decode step's gate left it on the pair, counted
+    assert monitor.stat_get("pallas.hit.paged_write_attend") == 0
+    assert monitor.stat_get("pallas.gate_reject.paged_write_attend.shape") > 0
+    # the same net over blocks of 128 (the pool's size on the chip): the
+    # decode step is ONE kernel that writes its token and attends (PR 47),
+    # the prefill's chunk `write_kv`'s loop and the kernel as ever. It
+    # says how it was cut and what it stores: every head of a block in one
+    # step, 2 slots x 1 logical block, the two slots' K and V blocks
+    # [1, h, d, 128] (float32 here)
+    monitor.reset(prefix="pallas.")
+    trace.reset()
+    loop = ServeLoop(net, ServeConfig(max_active=2, kv_blocks=4,
+                                      block_size=128, max_seq_len=64))
+    np.testing.assert_array_equal(loop.serve([p], max_new_tokens=4)[0], out)
+    assert monitor.stat_get("pallas.hit.paged_decode_attention") > 0
+    assert monitor.stat_get("pallas.hit.paged_write_attend") > 0
+    assert monitor.stat_get("pallas.hit.paged_write_token") == 0
+    assert not monitor.stats("pallas.gate_reject.paged_write_attend.")
+    cut = {"heads_per_step": heads, "grid_steps": 2 * 1,
+           "write_bytes": 2 * 2 * heads * dim * 128 * 4}
+    for name, value in cut.items():
+        assert monitor.stat_get(
+            f"pallas.paged_write_attend.{name}.b2s1") == value
+    spans = [sp.attrs for sp in trace.recent()
+             if sp.name == "pallas/paged_write_attend"]
+    assert any(cut.items() <= attrs.items() for attrs in spans), spans
+    report = obs_report.pallas_rates({"values": monitor.stats("pallas.")})
+    assert (f"cut:b2s1={heads}heads/stepx2steps,"
+            f"{cut['write_bytes'] / 1e6:.1f}MB stored") in report
 
 
 def test_serve_spans_and_gauges(net):

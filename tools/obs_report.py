@@ -173,7 +173,9 @@ def pallas_rates(metrics) -> str:
     (pallas.K.heads_per_step.bMsN and .grid_steps.bMsN, M slots of N
     query rows, gG for a group of G query heads; .value_dim and .sinks
     where a call's values are narrower than its keys or its softmax
-    starts from sink logits) and the latent kernel (pallas.K.blocks_per_step.bM,
+    starts from sink logits; .write_bytes where the call also writes the
+    step's tokens, kernel paged_write_attend: the blocks it stores) and
+    the latent kernel (pallas.K.blocks_per_step.bM,
     .grid_steps.bM and .live_bytes.bM, what a call reads for each live
     block of a slot); so has the token writer, for what a call of M
     slots moves (pallas.K.token_bytes.bM, the token operand as laid out,
@@ -199,7 +201,7 @@ def pallas_rates(metrics) -> str:
         elif len(parts) == 4 and parts[2] in (
                 "heads_per_step", "blocks_per_step", "grid_steps",
                 "live_bytes", "rows_per_block", "tile_bytes", "value_dim",
-                "sinks"):
+                "sinks", "write_bytes"):
             cuts[kind, parts[3]][parts[2]] = int(v)
         elif len(parts) == 4 and parts[2] in ("token_bytes", "block_bytes"):
             writes[kind, parts[3]][parts[2]] = v
@@ -218,6 +220,8 @@ def pallas_rates(metrics) -> str:
         if "value_dim" in cut:
             live += f",values {cut['value_dim']} deep" \
                 + (",sinks" if cut.get("sinks") else "")
+        if "write_bytes" in cut:
+            live += f",{cut['write_bytes'] / 1e6:.1f}MB stored"
         per[k]["reasons"].append(
             f"cut:{shape}={held}/stepx{cut.get('grid_steps', '?')}steps"
             f"{live}")
