@@ -961,14 +961,15 @@ def _check_decode(sizes):
     return {"out": _rel_err(out, out_r)}
 
 
-def _paged_operands(sizes, chunk, block_size=None):
+def _paged_operands(sizes, chunk, block_size=None, slots=None):
     """The arenas ServeLoop builds (KVBlockPool.arenas), shared by
-    serve_max_active slots of up to gpt.max_seq_len tokens, at the block
+    serve_max_active slots (or `slots`) of up to gpt.max_seq_len tokens,
+    at the block
     size ServeLoop's own picker gives (or the one named): -> (q [b, h,
     chunk, d], K arena, V arena, tables, lengths as numpy, scale)."""
     from paddle_tpu.nn.kv_pool import KVBlockPool, pick_block_size
     cfg = sizes.gpt
-    b, h = sizes.serve_max_active, cfg.num_heads
+    b, h = slots or sizes.serve_max_active, cfg.num_heads
     d = cfg.hidden_size // h
     if block_size is None:
         block_size = pick_block_size(cfg.max_seq_len, h, d,
@@ -1008,16 +1009,23 @@ def _check_paged_write_attend(sizes):
     slots' fills at a block's last lane and its first: `out` and `arenas`
     are the share of elements whose BITS differ (the trash block left
     out), so anything but 0 fails."""
+    q, ka, va, tables, lengths, scale = _paged_operands(sizes, 1)
+    bs = ka.shape[3]
+    edges = (bs - 1, bs, 0)[:len(lengths)]
+    lengths[:len(edges)] = edges
+    return _write_attend_bits(q, ka, va, tables, jnp.asarray(lengths), scale)
+
+
+def _write_attend_bits(q, ka, va, tables, lengths, scale, live=slice(None)):
+    """-> the share of elements whose bits differ between
+    `paged_write_attend` and the pair: `out` (the slots `live` names) and
+    `arenas` (the trash block left out)."""
     from paddle_tpu.core import monitor
     from paddle_tpu.nn.kv_pool import (paged_attention, paged_write_attend,
                                        write_kv)
-    q, ka, va, tables, lengths, scale = _paged_operands(sizes, 1)
-    (b, h, _, d), bs = q.shape, ka.shape[3]
+    b, h, _, d = q.shape
     nk, nv = (jax.random.normal(kk, (b, 1, h, d), jnp.float32).astype(DTYPE)
               for kk in jax.random.split(jax.random.PRNGKey(SEED + 4)))
-    edges = (bs - 1, bs, 0)[:b]
-    lengths[:len(edges)] = edges
-    lengths = jnp.asarray(lengths)
 
     def pair(ka, va):
         ka = write_kv(ka, tables, lengths, nk)
@@ -1036,9 +1044,43 @@ def _check_paged_write_attend(sizes):
                                    else np.uint32) for t in (x, y))
         return float(np.mean(x != y))
 
-    return {"out": differ(got[0], want[0]),
+    return {"out": differ(got[0][live], want[0][live]),
             "arenas": max(differ(g[1:], w[1:])
                           for g, w in zip(got[1:], want[1:]))}
+
+
+def _check_paged_work_list(sizes):
+    """The multi-head kernel's grid ends at the live items of its work
+    list (PR 48). Eight slots of `_paged_operands`: fills at a block's
+    last lane and at its first, an idle slot (no token, an all-zero
+    table), a full table, the rest spread. `items`: the list's live count
+    as the device computes it against fill // block + 1 a slot (an idle
+    one its one item); `out`: the kernel over the list against
+    `paged_attention_ref` in float32, the live slots; `write_out` and
+    `write_arenas`: the form that also writes the tokens against the
+    pair, shares of differing bits (0 passes)."""
+    from paddle_tpu.nn.kv_pool import paged_attention_ref
+    from paddle_tpu.ops.pallas.decode_attention import (
+        _paged_live_list, paged_decode_attention)
+    b, idle = 8, 2
+    q, ka, va, tables, lengths, scale = _paged_operands(sizes, 1, slots=b)
+    bs, nb = ka.shape[3], tables.shape[1]
+    tables = np.array(tables)
+    lengths[:4] = bs - 1, bs, 0, nb * bs - 1
+    tables[idle] = 0
+    live = np.arange(b) != idle
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lengths)
+    items = jax.jit(lambda t, n: _paged_live_list(t, n + 1, bs, b * nb)[3])(
+        tables, lengths)
+    out = jax.jit(lambda *a: paged_decode_attention(*a, scale))(
+        q, ka, va, tables, lengths)
+    out_r = jax.jit(lambda *a: paged_attention_ref(*a, scale))(
+        *_f32(q, ka, va), tables, lengths)
+    bits = _write_attend_bits(q, ka, va, tables, lengths, scale, live)
+    want = int(np.sum(np.minimum(np.asarray(lengths) // bs, nb - 1) + 1))
+    return {"items": abs(int(items[0]) - want),
+            "out": _rel_err(out[live], out_r[live]),
+            "write_out": bits["out"], "write_arenas": bits["arenas"]}
 
 
 def _check_latent_paged(sizes):
@@ -1247,6 +1289,7 @@ def kernel_checks(sizes):
         checks[f"paged_decode_s{chunk}_block{block or 'picked'}"] = \
             lambda c=chunk, b=block: _check_paged(sizes, c, b)
     checks["paged_write_attend"] = lambda: _check_paged_write_attend(sizes)
+    checks["paged_work_list"] = lambda: _check_paged_work_list(sizes)
     checks["latent_paged_decode"] = lambda: _check_latent_paged(sizes)
     checks["grouped_expert_ffn"] = lambda: _check_grouped_ffn(sizes)
     checks["grouped_paged_decode"] = lambda: _check_grouped_paged(sizes)

@@ -69,8 +69,11 @@ layers; a name in the net's `SERVE_GAUGES` is set, the others are added
 up), all mirrored
 as `serve.*` gauges beside `serve.{queue_depth,active_slots,
 kv_pool_used_blocks,kv_pool_free_blocks,model_version,state_slots_used,
-state_bytes,steps}` (`state_*`: decode slots whose per-slot state is
-owned, and the bytes of all of it; 0 for a net that caches by token only).
+state_bytes,steps,paged_live_step_share}` (`state_*`: decode slots whose
+per-slot state is owned, and the bytes of all of it; 0 for a net that
+caches by token only; `paged_live_step_share`: the live (slot, block)
+pairs over slots x the table's width, the share of a table-wide grid
+that held a block, `_paged_live_step_share`).
 Counters `serve.{preempted,
 tokens_generated,requests_completed,requests_errored,hot_swaps,
 completion_log_errors}`, histograms `serve/ttft_ms` and
@@ -101,7 +104,7 @@ GAUGES = ("serve.queue_depth", "serve.active_slots",
           "serve.prefill_dispatches", "serve.prefill_tokens",
           "serve.prefill_rows", "serve.prefill_live_rows", "serve.admitted",
           "serve.queue_wait_s", "serve.state_slots_used", "serve.state_bytes",
-          "serve.steps")
+          "serve.steps", "serve.paged_live_step_share")
 COUNTERS = ("serve.preempted", "serve.tokens_generated",
             "serve.requests_completed", "serve.requests_errored",
             "serve.hot_swaps", "serve.completion_log_errors",
@@ -491,6 +494,7 @@ class ServeLoop:
             "swap_staged": self._staged_swap is not None,
             "state_slots_used": self._state_slots_used(),
             "state_bytes": self._state_bytes,
+            "paged_live_step_share": self._paged_live_step_share(),
             **self._net_counts,
         }
 
@@ -955,6 +959,15 @@ class ServeLoop:
         return sum(s is not None for s in self._slots) \
             if self._state_bytes else 0
 
+    def _paged_live_step_share(self):
+        """Of the slots' table entries, the share a decode step's paged
+        kernel has a grid step for: the (slot, block) pairs of its work
+        list, the pool's blocks in use and one item an idle slot, over
+        slots x the table's width (what a grid over every table entry
+        walked, dead steps and all). From the host's own books."""
+        idle = sum(s is None for s in self._slots)
+        return (self._pool.used_blocks + idle) / (self._A * self._MB)
+
     def _publish_gauges(self):
         from ..core import monitor as _monitor
         _monitor.stat_set_many({
@@ -974,5 +987,6 @@ class ServeLoop:
             "serve.state_slots_used": self._state_slots_used(),
             "serve.state_bytes": self._state_bytes,
             "serve.steps": self._step_count,
+            "serve.paged_live_step_share": self._paged_live_step_share(),
             **{f"serve.{k}": v for k, v in self._net_counts.items()},
         })

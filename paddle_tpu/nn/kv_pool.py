@@ -223,10 +223,9 @@ def pick_block_size(max_seq_len, heads, head_dim, dtype="float32",
         ka = jnp.zeros(KVBlockPool(nb, bs_).arena_shape(h, d), dtype)
         q = jnp.zeros((batch, h, 8, d), dtype)
         bt = jnp.tile(jnp.arange(1, nb + 1, dtype=jnp.int32), (batch, 1))
-        lens = jnp.full((batch,), nb * bs_, jnp.int32)
-        from ..ops.pallas.decode_attention import _paged_call
-        fn = jax.jit(lambda a, k_, v_, b_, ln: _paged_call(
-            a, k_, v_, b_, ln, float(d) ** -0.5))
+        lens = jnp.full((batch,), nb * bs_ - 8, jnp.int32)
+        from ..ops.pallas.decode_attention import paged_decode_attention
+        fn = jax.jit(paged_decode_attention)
         return autotune.time_thunk(lambda: fn(q, ka, ka, bt, lens))
 
     cands = [(x,) for x in (256, 128) if L % x == 0]
@@ -618,11 +617,7 @@ def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,
         # as a pair of gauges per (slots, query rows) for a dump to read:
         # b32s1 is a decode step of 32 slots, b1s256 a prefill, b128s1g6
         # a grouped-query step of 6 query heads a key-value head
-        # a pool's block belongs to ONE slot's table (the trash block
-        # apart), so the live (slot, block) pairs of a call are at most
-        # the arena's blocks and a step a slot: what bounds the
-        # grouped-query form's work list
-        max_steps = k_arena.shape[0] - 1 + q.shape[0]
+        max_steps = _max_list_steps(k_arena, q.shape[0])
         d_v = int(v_arena.shape[2])
         cut = paged_cut(tuple(q.shape), tuple(k_arena.shape),
                         block_tables.shape[1], k_arena.dtype.itemsize,
@@ -637,6 +632,8 @@ def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,
         monitor.stat_set_many({
             f"pallas.paged_decode_attention.{name}.{key}": value
             for name, value in cut.items()})
+        if group == 1:     # the grid that ends at the list's live count
+            monitor.stat_add("pallas.hit.paged_work_list")
         return run_guarded(
             "paged_decode_attention",
             lambda: paged_decode_attention(q, k_arena, v_arena,
@@ -645,6 +642,13 @@ def paged_attention(q, k_arena, v_arena, block_tables, lengths, scale,
             **cut)
     return paged_attention_ref(q, k_arena, v_arena, block_tables, lengths,
                                scale, sinks)
+
+
+def _max_list_steps(arena, slots):
+    """What bounds a call's work list: a pool's block belongs to ONE
+    slot's table (the trash block apart), so the live (slot, block) pairs
+    are at most the arena's blocks and a step a slot."""
+    return arena.shape[0] - 1 + slots
 
 
 def _write_attend_cut(q, k_arena, v_arena, table_blocks, training):
@@ -665,7 +669,8 @@ def _write_attend_cut(q, k_arena, v_arena, table_blocks, training):
         return None
     cut = paged_write_attend_cut(
         tuple(q.shape), tuple(k_arena.shape), tuple(v_arena.shape),
-        table_blocks, k_arena.dtype.itemsize) \
+        table_blocks, k_arena.dtype.itemsize,
+        _max_list_steps(k_arena, q.shape[0])) \
         if _value_arena_matches(k_arena, v_arena) else None
     return cut if _paged_gate("paged_write_attend", training,
                               lambda: cut is not None) else None
@@ -704,12 +709,13 @@ def paged_write_attend(q, k_arena, v_arena, block_tables, lengths, new_k,
         monitor.stat_set_many({
             f"pallas.paged_write_attend.{name}.b{q.shape[0]}s1": value
             for name, value in cut.items()})
+        monitor.stat_add("pallas.hit.paged_work_list")
         return run_guarded(
             "paged_write_attend",
             lambda: write_attend(
                 q, k_arena, v_arena, block_tables, lens,
                 _token_lanes(k_arena, new_k), _token_lanes(v_arena, new_v),
-                scale),
+                scale, _max_list_steps(k_arena, q.shape[0])),
             **cut)
     k_arena = write_kv(k_arena, block_tables, lens, new_k)
     v_arena = write_kv(v_arena, block_tables, lens, new_v)

@@ -265,47 +265,54 @@ def _paged_block_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, scale,
     m_scr[:] = m_new
 
 
-def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
-                              scale, bs, nb, s, write=False,
-                              interpret=False):
-    """Grid (b, h // ht, nb); nb = logical blocks per request (sequential
-    accumulator dim). One step holds ONE logical block of one request for
-    a tile of ht heads — q/out [1, ht, s, d], K/V [1, ht, d, bs] — and
-    does the heads as batched products (Mosaic unrolls them into
-    straight-line code; a fori_loop over the same 2-D body was 5x slower
-    at 25 heads, PR 31). The arithmetic of a head, and its order over the
-    blocks, are those of one head a step. len_ref is the [b] live-length
-    vector (index + s per batch, like the contiguous kernel); bt_ref
-    [b, nb] maps logical to physical arena blocks (consumed by the index
-    maps — unused here beyond documentation: logical col ids already
-    encode causality).
+def _paged_decode_attn_kernel(len_ref, slot_ref, blk_ref, phys_ref, q_ref,
+                              k_ref, v_ref, *rest, scale, bs, nb, s, rows,
+                              slots, write=False, interpret=False):
+    """Grid (h // ht, n_live): step (ih, w) holds item w of the work list
+    (`_paged_live_list`) for a tile of ht heads: logical block blk[w] of
+    slot slot[w], q/out [1, ht, s, d], K/V [1, ht, d, bs] fetched from
+    physical row phys[w] (the index maps' business). The grid's second
+    bound is the list's LIVE count, read on the device: no step is dead. A
+    slot's items are consecutive and every slot has one: its softmax
+    state is initialised at its block 0 and flushed at its last. The
+    heads are batched products (Mosaic unrolls them into straight-line
+    code; a fori_loop over the same 2-D body was 5x slower at 25 heads,
+    PR 31). len_ref is the [b] vector of fills INCLUDING the chunk's
+    `rows` tokens (of the s query rows the first `rows` are positions,
+    the rest padding to the sublane tile, sliced off by the caller: they
+    bring no block of their own into the list).
 
-    With `write` (one token a slot, its 8 padded query rows: PR 47) the
-    step's key and value tokens come too, [ht, d, slots] and [ht, d_v,
-    slots] before the output (`_paged_write_kernel`'s dense operand, a
-    head tile of it), and the arenas again as two more outputs, aliased
-    to the inputs and left in HBM: the kernel stores what it writes
-    itself. The step that holds the token's own block — ik = fill // bs,
-    NOT `last`, which the padded rows push to (fill + 7) // bs — puts
-    the block it fetched, with the token at lane fill % bs, into one of
-    two VMEM buffers an arena (`k_buf`, `v_buf` [2, ht, d, bs]), starts
-    their copies to the token's physical row, and attends over THEM:
-    exactly the blocks the writer would have left for a kernel that ran
-    after it. A copy is waited for when its buffer comes round again, two
-    (slot, head tile)s on, and at the last grid step: it is in flight
-    under the next slot's fetches and products. (As pipelined output
-    blocks written back at a slot's end, the same 26 MB of GPT-2 XL's
-    step cost 74 us a call, as much as the writer they replaced.) The
-    grid of a writing call runs in order for that. A token past its
-    slot's table is not in the table: the step of the table's last block
-    stores that block unamended (to the trash block's row) and attends
-    over it as it is."""
+    With `write` (one token a slot, `rows` = 1: PR 47) the step's key and
+    value tokens come too, [ht, d, slots] and [ht, d_v, slots] before the
+    output (`_paged_write_kernel`'s dense operand, a head tile of it),
+    and the arenas again as two more outputs, aliased to the inputs and
+    left in HBM: the kernel stores what it writes itself. A slot's last
+    item holds the token's own block, fill // bs (the table's last block
+    where the token falls past the table): it puts the block it fetched,
+    with the token at lane fill % bs, into one of two VMEM buffers an
+    arena (`k_buf`, `v_buf` [2, ht, d, bs]), starts their copies to the
+    token's physical row, and attends over THEM: exactly the blocks the
+    writer would have left for a kernel that ran after it. A copy is
+    waited for when its buffer comes round again, two (head tile, slot)s
+    on, and at the last grid step: it is in flight under the next slot's
+    fetches and products. (As pipelined output blocks written back at a
+    slot's end, the same 26 MB of GPT-2 XL's step cost 74 us a call, as
+    much as the writer they replaced.) The grid of a writing call runs
+    in order for that. A token past its slot's table is not in the
+    table: the item of the table's last block stores that block
+    unamended (to the trash block's row) and attends over it as it is."""
     if write:
         tk_ref, tv_ref, o_ref, ko_hbm, vo_hbm = rest[:5]
         m_scr, l_scr, acc_scr, k_buf, v_buf, sems = rest[5:]
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
-    ib, ik = pl.program_id(0), pl.program_id(2)
+    ih, w = pl.program_id(0), pl.program_id(1)
+    ib, ik = slot_ref[w], blk_ref[w]
+    length = len_ref[ib]                       # live cols for the LAST row
+    index = length - np.int32(rows)            # cache fill before the chunk
+    last = jnp.minimum(
+        jnp.maximum(length - np.int32(1), np.int32(0)) // np.int32(bs),
+        np.int32(nb - 1))                      # the slot's last listed block
 
     @pl.when(ik == 0)
     def _init():
@@ -313,40 +320,26 @@ def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    length = len_ref[ib]                       # live cols for the LAST row
-    index = length - np.int32(s)               # cache fill before the chunk
-    last = jnp.minimum(
-        jnp.maximum(length - np.int32(1), np.int32(0)) // np.int32(bs),
-        np.int32(nb - 1))                      # last live logical block
-
     def live():
         row = jax.lax.broadcasted_iota(jnp.int32, (s, bs), 0)
         col = ik * bs + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 1)
         return col <= index + row
 
     if not write:
-        @pl.when(ik <= last)
-        def _compute():
-            _paged_block_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
-                                scale, live)
+        _paged_block_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
+                            scale, live)
     else:
-        own = index // np.int32(bs)            # the token's logical block
-        held = ik == jnp.minimum(own, np.int32(nb - 1))
-        # the lane the token takes; none where it falls past the table
-        at = jnp.where(own < np.int32(nb), index % np.int32(bs),
-                       np.int32(-1))
-
         from jax.experimental.pallas import tpu as pltpu
-        ih, tiles = pl.program_id(1), pl.num_programs(1)
-        n = ib * tiles + ih                    # this (slot, head tile)
-        total = pl.num_programs(0) * tiles
-        buf = n % np.int32(2)
-        ht = k_ref.shape[1]
+        held = ik == last                      # the token's own block
+        # the lane the token takes; none where it falls past the table
+        past = index // np.int32(bs) >= np.int32(nb)
+        at = jnp.where(past, np.int32(-1), index % np.int32(bs))
         # the token's physical row: the trash block past the table
         # (nn/kv_pool._phys_row)
-        row = jnp.where(own < np.int32(nb),
-                        bt_ref[ib, jnp.minimum(own, np.int32(nb - 1))],
-                        np.int32(0))
+        row = jnp.where(past, np.int32(0), phys_ref[w])
+        n = ih * np.int32(slots) + ib          # this (head tile, slot)
+        buf = n % np.int32(2)
+        ht = k_ref.shape[1]
 
         def stores(buf):
             heads = pl.ds(ih * np.int32(ht), ht)
@@ -355,7 +348,7 @@ def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
                     for j, (src, dst) in enumerate(((k_buf, ko_hbm),
                                                     (v_buf, vo_hbm)))]
 
-        @pl.when(held)                         # held implies ik <= last
+        @pl.when(held)
         def _write_and_compute():
             @pl.when(n >= 2)                   # the buffer's last store
             def _():
@@ -369,22 +362,24 @@ def _paged_decode_attn_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
                                 v_buf.at[pl.ds(buf, 1)], m_scr, l_scr,
                                 acc_scr, scale, live)
 
-        @pl.when((n == total - 1) & (ik == nb - 1))
+        # the list's last item is the last slot's own block
+        @pl.when((ih == pl.num_programs(0) - 1)
+                 & (w == pl.num_programs(1) - 1))
         def _drain():                          # what is still in flight
             for store in stores(buf):
                 store.wait()
 
-            @pl.when(total >= 2)
+            @pl.when(n >= 1)
             def _():
                 for store in stores(np.int32(1) - buf):
                     store.wait()
 
-        @pl.when(jnp.logical_not(held) & (ik <= last))
+        @pl.when(jnp.logical_not(held))
         def _compute():
             _paged_block_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
                                 scale, live)
 
-    @pl.when(ik == nb - 1)
+    @pl.when(ik == last)
     def _flush():
         denom = jnp.maximum(l_scr[:], 1e-30)   # padded rows stay finite
         o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
@@ -484,27 +479,29 @@ def paged_cut(q_shape, arena_shape, table_blocks, itemsize,
               max_steps=None, d_v=None) -> dict:
     """How a supported call is cut into grid steps: `heads_per_step`
     (key-value heads; each brings its G query heads as rows), and
-    `grid_steps` = head tiles x (multi-head: b x the table's logical
-    blocks; grouped-query: the work list's length,
-    `paged_grouped_steps`)."""
+    `grid_steps` = head tiles x the work list's length
+    (`paged_list_steps`). The multi-head form's grid ENDS at the list's
+    live count, read on the device: its `grid_steps` is the upper bound,
+    and `list_steps` says the list's static length beside it."""
     b, h, s, d = q_shape
     hl = arena_shape[1]
     group = paged_group(h, hl)
     ht = paged_heads_per_step(hl, _paged_rows(group, s), d, arena_shape[3],
                               itemsize, d_v=d_v)
-    steps = b * int(table_blocks) if group == 1 else paged_grouped_steps(
-        b, table_blocks, max_steps)
-    return {"heads_per_step": ht, "grid_steps": (hl // ht) * steps}
+    steps = paged_list_steps(b, table_blocks, max_steps)
+    return {"heads_per_step": ht, "grid_steps": (hl // ht) * steps,
+            **({"list_steps": steps} if group == 1 else {})}
 
 
 def paged_write_attend_cut(q_shape, k_shape, v_shape, table_blocks,
-                           itemsize):
+                           itemsize, max_steps=None):
     """The writing form's ONE static predicate, and its cut: can one call
     of the multi-head kernel write a decode step's tokens and attend
     (q [b, h, 1, d] over arenas k_shape [n, h, d, bs] and v_shape [n, h,
-    d_v, bs])? None where not; else `paged_cut`'s `heads_per_step` and
-    `grid_steps` (the head tile with the write's VMEM counted) and
-    `write_bytes`, the slots' K and V blocks as laid out, each written
+    d_v, bs])? None where not; else `paged_cut`'s `heads_per_step`,
+    `grid_steps` and `list_steps` (the head tile with the write's VMEM
+    counted; `max_steps` bounds the list as there) and `write_bytes`,
+    the slots' K and V blocks as laid out, each written
     back once (nothing of them is read for the write: the kernel holds
     the block already).
 
@@ -535,19 +532,91 @@ def paged_write_attend_cut(q_shape, k_shape, v_shape, table_blocks,
     ht = tile(write_slots=b)
     if ht != tile():
         return None
-    return {"heads_per_step": ht,
-            "grid_steps": (h // ht) * b * int(table_blocks),
+    steps = paged_list_steps(b, table_blocks, max_steps)
+    return {"heads_per_step": ht, "grid_steps": (h // ht) * steps,
+            "list_steps": steps,
             "write_bytes": b * h * (d + d_v) * _ceil_to(bs, 128) * itemsize}
 
 
-def _paged_call(q, k_arena, v_arena, block_tables, lengths, scale):
-    """The pallas_call for already-tile-padded q over arenas
-    [n_blocks, h, d, block_size]. With block_size a multiple of 128 the
-    compiled program hands the pool's buffers to the kernel as they are
-    (tests/test_chip_smoke.py holds that: no copy, no temp of arena
-    size); other multiples of 8 are correct, and XLA relays them out."""
-    return _paged_call_once(q, k_arena, v_arena, block_tables, lengths,
-                            scale=float(scale), interpret=_interpret())
+# --------------------------------------------------------------------------
+# the WORK LIST: one grid step a live block
+# --------------------------------------------------------------------------
+#
+# A grid over every entry of every slot's table has mostly dead steps: a
+# table is as wide as the longest stream the loop admits (8 blocks for
+# GPT-2 XL's 1024 tokens, 72 at Laguna's 9216) and a slot holds 2 or 19 of
+# them, and a dead step still costs grid overhead (~0.15-0.3 us measured:
+# Laguna's full layer 3.13 ms over 9216 steps, 2.32 over the 3200 of the
+# list). Both paged forms therefore walk a list of the LIVE (slot, logical
+# block) pairs, made outside the kernel from tables and lengths and read
+# through scalar prefetch: item w holds slot `slot[w]`'s block `blk[w]` at
+# physical row `phys[w]`; a slot's items are consecutive, so its softmax
+# state is initialised at its first block and flushed at its last, and the
+# output's block index changes when the slot does. The list's arrays are
+# as long as the caller says the live pairs can be (`max_steps`; the
+# pool's invariant, a physical block belongs to one slot, bounds them by
+# the arena's rows plus a step a slot). The multi-head form's grid ENDS at
+# the live count, a bound read on the device (PR 48: GPT-2 XL's pool of
+# 224 blocks bounds its 32 x 8 = 256 entries by 256, and ~70 are live);
+# the grouped-query form's grid is the static length, and its items past
+# the live ones repeat the last live item (nothing is fetched) and skip
+# their body.
+
+def _paged_work_list(block_tables, lengths, bs, steps):
+    """(slot, blk, phys) [steps] i32 and the live count [1] i32 of the
+    work list: slot i contributes its logical blocks 0..last_i, last_i =
+    min((lengths[i] - 1) // bs, nb - 1) with `lengths` the fill INCLUDING
+    this step's tokens, an empty slot its block 0 (every slot's output is
+    written)."""
+    b, nb = block_tables.shape
+    counts = jnp.minimum(jnp.maximum(lengths - 1, 0) // jnp.int32(bs),
+                         jnp.int32(nb - 1)) + 1                   # [b]
+    ends = jnp.cumsum(counts)
+    w = jnp.arange(steps, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.searchsorted(ends, w, side="right"),
+                       b - 1).astype(jnp.int32)
+    blk = w - (ends[slot] - counts[slot])
+    live = w < ends[-1]
+    slot = jnp.where(live, slot, b - 1)
+    blk = jnp.where(live, blk, counts[b - 1] - 1).astype(jnp.int32)
+    return (slot, blk, block_tables[slot, blk],
+            jnp.minimum(ends[-1:], steps).astype(jnp.int32))
+
+
+def _paged_live_list(block_tables, lengths, bs, steps):
+    """`_paged_work_list`'s list, item for item, made of comparisons and
+    sums alone. The search and the lookups of small arrays there lower,
+    on a TPU, to hundreds of slices and selects a call; these are a few
+    fusions over [steps, slots] and [steps, table entries], and the
+    layers of a program share them (the multi-head form's; the grouped
+    form keeps the list its compiled programs were counted with until
+    its grid gets the dynamic end too)."""
+    b, nb = block_tables.shape
+    i32 = jnp.int32
+    counts = jnp.minimum(jnp.maximum(lengths - 1, 0) // i32(bs),
+                         i32(nb - 1)) + 1                         # [b]
+    i = jnp.arange(b, dtype=i32)
+    ends = jnp.sum(jnp.where(i[None] <= i[:, None], counts[None], 0),
+                   axis=1, dtype=i32)             # the inclusive cumsum
+    w = jnp.arange(steps, dtype=i32)
+    before = ends[None] <= w[:, None]             # [steps, b]: slots done
+    slot = jnp.minimum(jnp.sum(before, axis=1, dtype=i32), b - 1)
+    blk = w - jnp.sum(jnp.where(before, counts[None], 0), axis=1,
+                      dtype=i32)
+    blk = jnp.where(w < ends[-1], blk, counts[-1] - 1)
+    entry = jnp.arange(b * nb, dtype=i32)
+    phys = jnp.sum(jnp.where((slot * nb + blk)[:, None] == entry[None],
+                             block_tables.reshape(-1)[None], 0),
+                   axis=1, dtype=i32)
+    return slot, blk, phys, jnp.minimum(ends[-1:], steps)
+
+
+def paged_list_steps(b, table_blocks, max_steps=None) -> int:
+    """The work list's length for b slots over tables `table_blocks`
+    wide: `max_steps` where the caller bounds the live (slot, block)
+    pairs, never more than every entry of every table."""
+    full = int(b) * int(table_blocks)
+    return full if not max_steps else max(int(b), min(full, int(max_steps)))
 
 
 # The two calls below are jitted so that a program with many identical
@@ -555,17 +624,29 @@ def _paged_call(q, k_arena, v_arena, block_tables, lengths, scale):
 # un-jitted, each of GPT-2 XL's serve programs spent seconds re-tracing 48
 # identical kernels, all of it set-up time (PR 26). What they read from
 # flags is a static argument, so a cached trace never outlives a flag.
+# The layers of a program give the work list the same tables and lengths:
+# inlined, XLA keeps ONE of their identical computations
+# (tests/test_chip_smoke.py counts the compiled program's).
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "rows", "steps", "interpret"))
 def _paged_call_once(q, k_arena, v_arena, block_tables, lengths,
-                     new_k=None, new_v=None, *, scale, interpret):
-    """-> out [b, h, s_p, d_v]; with the step's tokens `new_k` [h, d,
-    slots padded to 128s] and `new_v` [h, d_v, the same] (one token a
-    slot, `lengths` = fill + s_p), -> (out, k_arena, v_arena): the
-    arenas aliased in and out, each slot's token written into its block
-    by the grid step that holds it and the block stored by the kernel's
-    own copy (`_paged_decode_attn_kernel`). A device trace names either
-    form after this function."""
+                     new_k=None, new_v=None, *, scale, rows, steps,
+                     interpret):
+    """The multi-head pallas_call for tile-padded q [b, h, s_p, d] (its
+    first `rows` query rows are positions) over arenas [n_blocks, h, d,
+    block_size]; `lengths` [b] the fill INCLUDING the chunk's `rows`
+    tokens, `steps` the work list's static length. -> out [b, h, s_p,
+    d_v]; with the step's tokens `new_k` [h, d, slots padded to 128s]
+    and `new_v` [h, d_v, the same] (one token a slot), -> (out, k_arena,
+    v_arena): the arenas aliased in and out, each slot's token written
+    into its block by the grid step that holds it and the block stored
+    by the kernel's own copy (`_paged_decode_attn_kernel`). With
+    block_size a multiple of 128 the compiled program hands the pool's
+    buffers to the kernel as they are (tests/test_chip_smoke.py holds
+    that: no copy, no temp of arena size); other multiples of 8 are
+    correct, and XLA relays them out. A device trace names either form
+    after this function."""
     from jax.experimental.pallas import tpu as pltpu
     b, h, s_p, d = q.shape
     d_v, bs = v_arena.shape[2], k_arena.shape[3]
@@ -574,34 +655,28 @@ def _paged_call_once(q, k_arena, v_arena, block_tables, lengths,
     # the cut into grid steps: a step pays ~0.3 us whatever it holds, so
     # it holds as many of a block's heads as fit (one head a step made
     # GPT-2 XL's decode 6400 steps a layer of 16 KB each: 1.6 ms, all of
-    # it step overhead, against 0.17 ms for 256 steps of 400 KB; PR 31)
+    # it step overhead, against 0.17 ms for 256 steps of 400 KB; PR 31),
+    # and there is a step for a LIVE block only
     ht = paged_heads_per_step(h, s_p, d, bs, k_arena.dtype.itemsize,
                               d_v=d_v, write_slots=b if write else 0)
+    slot, blk, phys, n_live = _paged_live_list(block_tables, lengths, bs,
+                                               steps)
 
-    def q_map(ib, ih, ik, len_ref, bt_ref):
-        return (ib, ih, _Z, _Z)
+    def q_map(ih, w, len_ref, slot_ref, blk_ref, phys_ref):
+        return (slot_ref[w], ih, _Z, _Z)
 
-    def kv_map(ib, ih, ik, len_ref, bt_ref):
-        # gather ONLY live physical blocks: past the last live logical
-        # block the index clamps, the physical id repeats, and Pallas
-        # skips the HBM->VMEM DMA for the revisited block — per-step KV
-        # bytes scale with live blocks, not arena/max_seq_len
-        # (np.int32 scalars: see _decode_attn_kernel)
-        last = jnp.minimum(
-            jnp.maximum(len_ref[ib] - np.int32(1),
-                        np.int32(0)) // np.int32(bs),
-            np.int32(nb - 1))
-        return (bt_ref[ib, jnp.minimum(ik, last)], ih, _Z, _Z)
+    def kv_map(ih, w, len_ref, slot_ref, blk_ref, phys_ref):
+        return (phys_ref[w], ih, _Z, _Z)
 
-    def tok_map(ib, ih, ik, len_ref, bt_ref):
+    def tok_map(ih, w, len_ref, slot_ref, blk_ref, phys_ref):
         return (ih, _Z, _Z)        # resident across a head tile's steps
 
     out_spec = pl.BlockSpec((1, ht, s_p, d_v), q_map)
     out_shape = jax.ShapeDtypeStruct((b, h, s_p, d_v), q.dtype)
     tokens = (new_k, new_v) if write else ()
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, h // ht, nb),
+        num_scalar_prefetch=4,
+        grid=(h // ht, n_live[0]),
         in_specs=[
             pl.BlockSpec((1, ht, s_p, d), q_map),
             pl.BlockSpec((1, ht, d, bs), kv_map),
@@ -621,65 +696,28 @@ def _paged_call_once(q, k_arena, v_arena, block_tables, lengths,
               pltpu.SemaphoreType.DMA((2, 2))] if write else []),
     )
     kernel = functools.partial(_paged_decode_attn_kernel, scale=scale,
-                               bs=bs, nb=nb, s=s_p, write=write,
-                               interpret=bool(interpret))
+                               bs=bs, nb=nb, s=s_p, rows=rows, slots=b,
+                               write=write, interpret=bool(interpret))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[out_shape] + [jax.ShapeDtypeStruct(a.shape, a.dtype)
                                  for a in (k_arena, v_arena)]
         if write else out_shape,
-        # operands: lengths, tables, q, k_arena, v_arena, the tokens
-        input_output_aliases={3: 1, 4: 2} if write else {},
-        # a writing call's stores are waited for two (slot, head tile)s
+        # operands: lengths, slot, blk, phys, q, k_arena, v_arena, tokens
+        input_output_aliases={5: 1, 6: 2} if write else {},
+        # a writing call's stores are waited for two (head tile, slot)s
         # on: its grid runs in order
-        compiler_params=_cparams(*(("arbitrary",) * 3 if write else
-                                   ("parallel", "parallel", "arbitrary"))),
+        compiler_params=_cparams(*(("arbitrary",) * 2 if write else
+                                   ("parallel", "arbitrary"))),
         interpret=interpret,
-    )(lengths, block_tables, q, k_arena, v_arena, *tokens)
+    )(lengths, slot, blk, phys, q, k_arena, v_arena, *tokens)
     return tuple(out) if write else out
 
 
 # --------------------------------------------------------------------------
-# grouped-query form over a WORK LIST: one grid step a live block
+# grouped-query form: the list at its static length
 # --------------------------------------------------------------------------
-#
-# The grid above has a step for every entry of every slot's table. A table
-# is as wide as the longest stream the loop admits (72 blocks at 9216
-# tokens) and a slot holds ~19 of them: three steps of four are dead, and a
-# dead step still costs grid overhead (~0.15 us measured: a full layer's
-# call 3.13 ms over 9216 steps, 2.32 over the 3200 of the list). The grouped-query
-# form therefore walks a list of the LIVE (slot, logical block) pairs, made
-# outside the kernel from tables and lengths and read through scalar
-# prefetch: item w holds slot `slot[w]`'s block `blk[w]` at physical row
-# `phys[w]`; a slot's items are consecutive, so its softmax state is
-# initialised at its first block and flushed at its last, and the output's
-# block index changes when the slot does. The list is as long as the
-# caller says the live pairs can be (`max_steps`; the pool's invariant, a
-# physical block belongs to one slot, bounds them by the arena's rows plus
-# a step a slot); items past the live ones repeat the last live item
-# (nothing is fetched) and skip their body.
-
-def _paged_work_list(block_tables, lengths, bs, steps):
-    """(slot, blk, phys) [steps] i32 and the live count [1] i32 of the
-    work list: slot i contributes its logical blocks 0..last_i, last_i =
-    min((lengths[i] - 1) // bs, nb - 1) with `lengths` the fill INCLUDING
-    this step's token, an empty slot its block 0 (every slot's output is
-    written)."""
-    b, nb = block_tables.shape
-    counts = jnp.minimum(jnp.maximum(lengths - 1, 0) // jnp.int32(bs),
-                         jnp.int32(nb - 1)) + 1                   # [b]
-    ends = jnp.cumsum(counts)
-    w = jnp.arange(steps, dtype=jnp.int32)
-    slot = jnp.minimum(jnp.searchsorted(ends, w, side="right"),
-                       b - 1).astype(jnp.int32)
-    blk = w - (ends[slot] - counts[slot])
-    live = w < ends[-1]
-    slot = jnp.where(live, slot, b - 1)
-    blk = jnp.where(live, blk, counts[b - 1] - 1).astype(jnp.int32)
-    return (slot, blk, block_tables[slot, blk],
-            jnp.minimum(ends[-1:], steps).astype(jnp.int32))
-
 
 def _paged_grouped_kernel(len_ref, slot_ref, blk_ref, phys_ref, n_ref,
                           q_ref, k_ref, v_ref, *rest, scale, bs, nb, s,
@@ -733,14 +771,6 @@ def _paged_grouped_kernel(len_ref, slot_ref, blk_ref, phys_ref, n_ref,
     def _flush():
         denom = jnp.maximum(l_scr[:], 1e-30)   # padded rows stay finite
         o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
-
-
-def paged_grouped_steps(b, table_blocks, max_steps=None) -> int:
-    """The work list's length for b slots over tables `table_blocks`
-    wide: `max_steps` where the caller bounds the live (slot, block)
-    pairs, never more than every entry of every table."""
-    full = int(b) * int(table_blocks)
-    return full if not max_steps else max(int(b), min(full, int(max_steps)))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "steps"))
@@ -807,8 +837,8 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
     below). Row r of
     batch i attends to logical cache cols <= lengths[i] + r. Block-table
     entries past the allocation MUST be 0 (the pool's reserved trash
-    block): padded query rows reach past the live end and the index map
-    must land on a valid physical row. Eval-only (no vjp); returns
+    block): an empty slot's one work item, and a chunk that runs past its
+    table, must land on a valid physical row. Eval-only (no vjp); returns
     [b, h, s, d_v] in q's dtype.
 
     h = G x h_kv, G > 1, is grouped-query attention and takes one token a
@@ -816,9 +846,11 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
     heads of a key-value head are the rows of ONE product over that
     head's block (padded to the sublane tile), each K/V block is fetched
     once for its whole group, and every row attends cols <= lengths[i].
-    The grouped form walks a work list of the live (slot, block) pairs
-    (`_paged_grouped_kernel`); `max_steps` bounds them where the caller
-    can (nn/kv_pool.paged_attention: a pool's block belongs to one slot),
+
+    Both forms walk a work list of the live (slot, block) pairs
+    (`_paged_work_list`; the multi-head form's grid ends at their count);
+    `max_steps` bounds them where the caller can
+    (nn/kv_pool.paged_attention: a pool's block belongs to one slot),
     else the list is as long as the tables.
 
     `sinks` [h] float32, the grouped form only: query head j's learned
@@ -853,33 +885,34 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths,
     s_p = _ceil_to(rows, 8)  # sublane tile: pad query rows, slice back below
     if s_p != rows:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, s_p - rows), (0, 0)))
-    # lengths in PADDED-row terms (kernel recovers fill as length - s_p);
-    # padded rows attend a few cols past the live end — garbage rows
-    # sliced off below, and their block-table lookups land on entry 0
-    # (the trash block) by the pool's table convention. A group's rows
-    # are one position: the fill and this step's token
+    # lengths WITH the chunk's tokens (the kernels recover the fill):
+    # the s positions of a chunk, or the one of a group's rows. Padded
+    # rows attend a few cols past the live end: garbage rows, sliced off
+    # below, that bring no block into the work list
     lens = jnp.asarray(lengths, jnp.int32)
     lens = jnp.broadcast_to(lens.reshape(-1), (b,)) \
-        + jnp.int32(1 if group > 1 else s_p)
+        + jnp.int32(1 if group > 1 else s)
     bt = jnp.asarray(block_tables, jnp.int32)
     if sinks is not None:      # [h] -> [h_kv, G padded, 1]
         sinks = jnp.pad(jnp.asarray(sinks, jnp.float32).reshape(hl, group),
                         ((0, 0), (0, s_p - rows)),
                         constant_values=NEG_INF)[..., None]
+    steps = paged_list_steps(b, bt.shape[1], max_steps)
     if group > 1:
         out = _paged_grouped_call_once(
             q, k_arena, v_arena, bt, lens, sinks, scale=float(scale),
-            interpret=_interpret(), steps=paged_grouped_steps(
-                b, bt.shape[1], max_steps))
+            interpret=_interpret(), steps=steps)
     else:
-        out = _paged_call(q, k_arena, v_arena, bt, lens, scale)
+        out = _paged_call_once(q, k_arena, v_arena, bt, lens,
+                               scale=float(scale), rows=s, steps=steps,
+                               interpret=_interpret())
     out = out.astype(out_dtype)
     out = out[:, :, :rows] if s_p != rows else out
     return out.reshape(b, h, s, d_v) if group > 1 else out
 
 
 def paged_write_attend(q, k_arena, v_arena, block_tables, lengths, new_k,
-                       new_v, scale=None):
+                       new_v, scale=None, max_steps=None):
     """`paged_decode_attention` for one token a slot on the multi-head
     form (q [b, h, 1, d], h the arenas' heads) that ALSO writes the
     step's tokens: -> (out [b, h, 1, d_v], k_arena, v_arena), the arenas
@@ -896,7 +929,7 @@ def paged_write_attend(q, k_arena, v_arena, block_tables, lengths, new_k,
     pool's invariant, the token writer's condition too): a slot's block
     is written back while the next slot's are fetched. The caller asks
     `paged_write_attend_cut` first; table entries past the allocation
-    are 0, as there."""
+    are 0, and `max_steps` bounds the work list, as there."""
     b, h, s, d = q.shape
     d_v, bs = v_arena.shape[2], k_arena.shape[3]
     if s != 1 or k_arena.shape[1:3] != (h, d) \
@@ -911,15 +944,17 @@ def paged_write_attend(q, k_arena, v_arena, block_tables, lengths, new_k,
     s_p = _paged_rows(1, s)     # the sublane tile of padded query rows
     q_p = jnp.pad(q.astype(k_arena.dtype),
                   ((0, 0), (0, 0), (0, s_p - s), (0, 0)))
-    # the kernel recovers a fill as length - s_p, and rotates whole
+    # the kernel takes the fill with the step's token, and rotates whole
     # 128-lane tiles of slots
     lens = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1),
-                            (b,)) + jnp.int32(s_p)
+                            (b,)) + jnp.int32(s)
+    bt = jnp.asarray(block_tables, jnp.int32)
     pad = ((0, 0), (0, 0), (0, -b % 128))
     out, k_arena, v_arena = _paged_call_once(
-        q_p, k_arena, v_arena, jnp.asarray(block_tables, jnp.int32), lens,
+        q_p, k_arena, v_arena, bt, lens,
         jnp.pad(new_k, pad), jnp.pad(new_v, pad),
-        scale=float(d ** -0.5 if scale is None else scale),
+        scale=float(d ** -0.5 if scale is None else scale), rows=s,
+        steps=paged_list_steps(b, bt.shape[1], max_steps),
         interpret=_interpret())
     return out.astype(q.dtype)[:, :, :s], k_arena, v_arena
 

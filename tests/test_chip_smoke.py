@@ -90,11 +90,17 @@ def test_kernels_phase_toy(interpret):
     assert set(result["errors_vs_jnp_reference"]) >= {
         "flash_causal", "flash_padding_bias", "fused_ce", "decode",
         "paged_decode_s1_blockpicked", "paged_write_attend",
-        "latent_paged_decode", "grouped_expert_ffn", "grouped_paged_decode",
+        "paged_work_list", "latent_paged_decode", "grouped_expert_ffn", "grouped_paged_decode",
         "sink_paged_decode"}
     # the fused write against the writer and the kernel apart: bit for bit
     assert result["errors_vs_jnp_reference"]["paged_write_attend"] \
         == {"out": 0.0, "arenas": 0.0}
+    # the list the multi-head kernel's grid ends with: every live pair
+    # counted, the kernel over it against the jnp form, and the writing
+    # form against the pair bit for bit, an idle slot and a full table in
+    errs = result["errors_vs_jnp_reference"]["paged_work_list"]
+    assert (errs["items"], errs["write_out"], errs["write_arenas"]) \
+        == (0.0, 0.0, 0.0) and errs["out"] <= chip_smoke.KERNEL_TOL
     # the grouped-query form's two call sites: the pool's pages, the rings
     # (and again with keys deeper than values and sinks in the rings)
     for name in ("grouped_paged_decode", "sink_paged_decode"):
@@ -300,6 +306,23 @@ def _instruction_count(text):
                           text, re.M))
 
 
+def _paged_list_calls(text):
+    """Of a compiled program's `_paged_call_once` kernels (the multi-head
+    paged kernel over its work list): how many there are, how many take a
+    scalar before their scalar-prefetch operands (a DYNAMIC grid bound:
+    the list's live count, read on the device), and the distinct
+    (bound, lengths, slot, blk, phys) operand tuples: one, where the
+    layers of a program share ONE computation of the list."""
+    calls = re.findall(
+        r"%_paged_call_once[.\d]* = [^\n]*? custom-call\(([^)]*)\)[^\n]*?"
+        r"operand_layout_constraints=\{(s32\[\], )?", text)
+    lists = {tuple(re.sub(r"/\*.*?\*/", "", operands).split(", ")[:5])
+             for operands, _ in calls}
+    return {"calls": len(calls),
+            "dynamic_grid": sum(bool(scalar) for _, scalar in calls),
+            "lists": len(lists)}
+
+
 def _scope_summary(text):
     """What `core/program_map` makes of a program compiled for v5e: of the
     entry computation's `fusion` / `while` / Pallas custom-call
@@ -399,6 +422,7 @@ def _compile_paged_steps_for_v5e():
                for kernel in (("paged_write_attend",) if s == 1
                               else ("paged_decode_attention",))
                for name in ("heads_per_step", "grid_steps", "write_bytes")},
+            "paged_calls": _paged_list_calls(text),
             "instructions": _instruction_count(text)}))
     print("PAGED-STEPS-DONE")
 
@@ -433,18 +457,27 @@ def test_paged_serve_steps_hold_no_arena_copy_for_v5e():
             prefill["writer"]) == (256, 0, 2, 0), prefill
     assert prefill["kernels"] == ["_paged_call_once"], prefill
     # a block's 25 heads in one grid step of the decode step, with the
-    # write's output blocks and tokens in VMEM too: 32 slots x 8 logical
-    # blocks, 26.2 MB of blocks stored a call; a 256-row prefill fits 5
-    # heads a step
+    # write's output blocks and tokens in VMEM too, 26.2 MB of blocks
+    # stored a call; a 256-row prefill fits 5 heads a step. The grid is
+    # head tiles x the LIVE items of a work list (PR 48): 256 and 5 x 8
+    # are its bounds (the pool's 224 blocks and a step a slot do not
+    # bound 32 x 8 further), and in both programs each kernel's grid ends
+    # at a scalar read on the device, and the two layers share ONE list
     assert (decode["heads_per_step"], decode["grid_steps"],
             decode["write_bytes"]) == (25, 256, 32 * 25 * 128 * 128 * 2)
     assert (prefill["heads_per_step"], prefill["grid_steps"]) == (5, 40)
-    # counted: the decode step was 150 instructions with the writer's four
-    # calls and their operands (PR 45); the prefill is the parent's
-    # program, instruction for instruction and byte for byte of temporaries
-    assert (decode["instructions"], decode["temp_bytes"]) == (40, 0), decode
+    for step in (decode, prefill):
+        assert step["paged_calls"] == {"calls": 2, "dynamic_grid": 2,
+                                       "lists": 1}, step
+    # counted. The decode step: 150 instructions with the writer's four
+    # calls and their operands (PR 45), 40 with the writing kernel over
+    # the table-wide grid (PR 47), 138 with the list: five fusions of
+    # comparisons and sums, once for both layers, and still no byte of
+    # temporaries (the same list by a search and lookups was 997 and
+    # 2.6 MB). The prefill: 468 and 290 816 B before the list
+    assert (decode["instructions"], decode["temp_bytes"]) == (138, 0), decode
     assert (prefill["instructions"], prefill["temp_bytes"]) \
-        == (468, 290_816), prefill
+        == (537, 323_072), prefill
 
 
 def _compile_latent_steps_for_v5e(model="KimiK2", config="latent",
@@ -719,6 +752,7 @@ def _compile_hybrid_steps_for_v5e():
             "alias_bytes": mem.alias_size_in_bytes, "held_bytes": held,
             "hits": {k.rsplit(".", 1)[1]: int(v) for k, v in hits.items()},
             "rejects": monitor.stats("pallas.gate_reject."),
+            "paged_calls": _paged_list_calls(text),
             "instructions": _instruction_count(text),
             "scopes": _scope_summary(text)}))
     print("HYBRID-STEPS-DONE")
@@ -744,17 +778,24 @@ def test_hybrid_serve_steps_update_state_and_arenas_in_place_for_v5e():
     # 15 a grid step: the fused form's gate leaves this step on the
     # writer and the kernel apart (PR 47), counted
     assert decode["hits"] == {"gdn_step": 3, "paged_write_token": 2,
-                              "paged_decode_attention": 1}
+                              "paged_decode_attention": 1,
+                              "paged_work_list": 1}
+    # whose grid ends at the live items of its work list (PR 48: ~460 of
+    # the 32 x 37 table entries), a bound read on the device
+    assert decode["paged_calls"] == {"calls": 1, "dynamic_grid": 1,
+                                     "lists": 1}, decode
     assert decode["rejects"] == {
         "pallas.gate_reject.paged_write_attend.shape": 1}, decode
     assert prefill["rejects"] == {}, prefill
     assert decode["temp_bytes"] < 64e6, decode
     assert prefill["hits"] == {"gdn_chunk_scan": 3}
     assert prefill["temp_bytes"] < 1.0e9, prefill
-    # counted at PR 45's parent and unchanged by it (`_paged_call_once`
-    # with a value width that is the key's) and by PR 47 (the pair)
+    # counted: the decode step was 1885 at PR 45's parent, unchanged by it
+    # (`_paged_call_once` with a value width that is the key's) and by PR
+    # 47 (the pair); 1975 with the work list of its one full layer (PR 48).
+    # The prefill meets no paged kernel and is the parent's program
     assert (decode["instructions"], prefill["instructions"],
-            prefill["temp_bytes"]) == (1885, 4974, 170_335_232), (decode,
+            prefill["temp_bytes"]) == (1975, 4974, 170_335_232), (decode,
                                                                   prefill)
     _assert_scoped(decode, {"linear_attn", "attn", "ffn", "head"},
                    {"_gdn_step_call": ["linear_attn"],

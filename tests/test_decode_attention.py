@@ -5,6 +5,8 @@ _static_cache_attention / _sdpa — cache-length masking at several index
 values, ragged per-batch lengths, bf16/f32 tolerances, and the vjp-free
 eval contract (training-time cache attention stays on the jnp path).
 """
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -191,3 +193,129 @@ def test_gpt_generate_cached_kernel_matches_oracle(interpret):
                               use_cache=False)
     np.testing.assert_array_equal(np.asarray(out_cached._value),
                                   np.asarray(out_oracle._value))
+
+
+# --------------------------------------------------------------------------
+# the multi-head paged kernel: a grid that ends at the work list's live items
+# --------------------------------------------------------------------------
+
+BS, NB = 128, 4      # a block of one lane tile, tables of four entries
+
+
+def _paged_case(h, d, fills, s=1, idle=(), seed=0, dtype=jnp.bfloat16):
+    """Arenas of noise, a table a slot that holds its fill and the
+    chunk (none for an idle slot: the trash block), q and the chunk."""
+    from paddle_tpu.nn.kv_pool import KVBlockPool
+    rng = np.random.RandomState(seed)
+    b = len(fills)
+    pool = KVBlockPool(b * NB, BS)
+    bt = np.zeros((b, NB), np.int32)
+    for i, fill in enumerate(fills):
+        if i not in idle:
+            blocks = pool.alloc(min((fill + s - 1) // BS + 1, NB))
+            bt[i, :len(blocks)] = blocks
+    shape = pool.arena_shape(h, d)
+    draw = lambda *dims: jnp.asarray(rng.randn(*dims), dtype)  # noqa: E731
+    return (draw(*shape), draw(*shape), jnp.asarray(bt),
+            jnp.asarray(fills, jnp.int32), draw(b, h, s, d),
+            draw(b, s, h, d), draw(b, s, h, d))
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+# (the list's live items, slots' fills, query rows, idle slots, head tiles)
+LIST_CASES = {
+    "every_slot_idle": (3, [0, 0, 0], 1, (0, 1, 2), 1),
+    "every_table_full": (3 * NB, [NB * BS - 1] * 3, 1, (), 1),
+    "a_blocks_last_lane_and_first": (1 + 2 + 2 + 3, [127, 128, 255, 256], 1,
+                                     (), 1),
+    "a_token_past_its_table": (NB + 1, [NB * BS, 5], 1, (), 1),
+    "mixed_with_an_idle_slot_in_the_middle": (3 + 1 + 1 + 4,
+                                              [300, 0, 17, 400], 1, (1,), 1),
+    "two_head_tiles": (2 + 1 + 3, [130, 0, 290], 1, (1,), 2),
+    "one_slot_of_64_rows": (1, [0], 64, (), 1),
+    "one_slot_of_256_rows": (3, [100], 256, (), 1),
+    "one_slot_of_256_rows_to_the_tables_end": (NB, [NB * BS - 256], 256, (),
+                                               1),
+}
+
+
+@pytest.mark.parametrize("case,writing", [
+    (case, writing) for case, (_, _, s, _, _) in LIST_CASES.items()
+    for writing in (False, True) if s == 1 or not writing],     # a chunk
+    ids=lambda x: {False: "attend", True: "write_attend"}.get(x, x))
+def test_paged_list_form_matches_the_pair(interpret, monkeypatch, case,
+                                          writing):
+    """The multi-head paged kernel walks the LIVE (slot, block) pairs
+    under a dynamic grid bound. Its non-writing form after `write_kv`
+    against `paged_attention_ref`; its writing form (one token a slot)
+    against that pair bit for bit: live slots' outputs and both arenas
+    (the trash block apart). A chunk's rows are `write_kv`'s to write."""
+    from paddle_tpu.nn.kv_pool import paged_attention_ref, write_kv
+    da = importlib.import_module("paddle_tpu.ops.pallas.decode_attention")
+    n_live, fills, s, idle, tiles = LIST_CASES[case]
+    h, d = 2 * tiles, 64
+    if tiles > 1:       # a head tile of h // tiles, by a smaller budget
+        tile = da.paged_heads_per_step
+        monkeypatch.setattr(
+            da, "paged_heads_per_step",
+            lambda heads, *a, **k: min(tile(heads, *a, **k), heads // tiles))
+        jax.clear_caches()
+        assert da.paged_heads_per_step(h, 8, d, BS, 2, write_slots=3) == 2
+    ka, va, bt, lens, q, nk, nv = _paged_case(h, d, fills, s, idle,
+                                              seed=len(case))
+    # the list this call walks: an item a live pair, every slot at least one
+    assert int(da._paged_live_list(bt, lens + s, BS, len(fills) * NB)[3][0]) \
+        == n_live
+    k1, v1 = write_kv(ka, bt, lens, nk), write_kv(va, bt, lens, nv)
+    out = da.paged_decode_attention(q, k1, v1, bt, lens)
+    live = [i for i in range(len(fills)) if i not in idle]
+    want = paged_attention_ref(q, k1, v1, bt, lens, d ** -0.5)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(want, np.float32)[live], atol=2e-2)
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    if writing:
+        tokens = (jnp.transpose(nk[:, 0], (1, 2, 0)),
+                  jnp.transpose(nv[:, 0], (1, 2, 0)))
+        fused, k2, v2 = da.paged_write_attend(q, ka, va, bt, lens, *tokens)
+        np.testing.assert_array_equal(_bits(fused)[live], _bits(out)[live])
+        np.testing.assert_array_equal(_bits(k2)[1:], _bits(k1)[1:])
+        np.testing.assert_array_equal(_bits(v2)[1:], _bits(v1)[1:])
+    if tiles > 1:
+        jax.clear_caches()
+
+
+def test_work_list_counts_a_tokens_own_block_and_no_padded_rows():
+    """One token a slot brings `fill // bs + 1` blocks into the list: the
+    7 query rows that pad it to the sublane tile bring none (a table-wide
+    grid computed up to `(fill + 7) // bs`); a chunk of s rows brings
+    `(fill + s - 1) // bs + 1`. The multi-head form's list (comparisons
+    and sums) is the grouped form's (a search and lookups), item for item
+    over the live ones."""
+    da = importlib.import_module("paddle_tpu.ops.pallas.decode_attention")
+    rng = np.random.RandomState(0)
+    bs, nb, b = 128, 8, 32
+    fills = np.concatenate([[0, 120, 121, 127, 128, 1023, 1024, 2000],
+                            rng.randint(0, 1024, b - 8)]).astype(np.int32)
+    bt = jnp.asarray(rng.randint(1, 225, (b, nb)), jnp.int32)
+    for s in (1, 64, 256):
+        counts = np.minimum((fills + s - 1) // bs, nb - 1) + 1
+        got = da._paged_live_list(bt, jnp.asarray(fills + s), bs, b * nb)
+        was = da._paged_work_list(bt, jnp.asarray(fills + s), bs, b * nb)
+        n = int(got[3][0])
+        assert n == counts.sum() == int(was[3][0])
+        for mine, theirs in zip(got[:3], was[:3]):
+            assert mine.dtype == jnp.int32
+            np.testing.assert_array_equal(np.asarray(mine)[:n],
+                                          np.asarray(theirs)[:n])
+        slot, blk, phys = (np.asarray(x)[:n] for x in got[:3])
+        assert (np.bincount(slot, minlength=b) == counts).all()
+        assert (phys == np.asarray(bt)[slot, blk]).all()
+    padded = np.minimum((fills + 7) // bs, nb - 1) + 1     # as the grid was
+    assert (np.minimum(fills // bs, nb - 1) + 1).sum() < padded.sum()
+    # the pool's bound: the list's arrays end there, and so does the count
+    short = da._paged_live_list(bt, jnp.asarray(fills + 1), bs, 40)
+    assert int(short[3][0]) == 40 and short[0].shape == (40,)

@@ -299,10 +299,10 @@ def test_grouped_paged_kernel_parity_ragged_lengths(interpret, group, hk, d,
     assert paged_supported(shape, tuple(ka.shape), ka.dtype.itemsize)
     # the work list: as long as the tables, or as the caller bounds it
     cut = paged_cut(shape, tuple(ka.shape), 4, ka.dtype.itemsize)
-    assert cut == {"heads_per_step": hk, "grid_steps": b * 4}
+    assert cut == {"heads_per_step": hk, "grid_steps": b * 4,
+                   **({"list_steps": b * 4} if group == 1 else {})}
     assert paged_cut(shape, tuple(ka.shape), 4, ka.dtype.itemsize,
-                     max_steps=14 + b)["grid_steps"] \
-        == (19 if group > 1 else 20)       # multi-head: cut as it was
+                     max_steps=14 + b)["grid_steps"] == 19
     q = jnp.asarray(rng.randn(*shape), jnp.float32)
     lens = jnp.asarray([max(ln - 1, 0) for ln in fills], jnp.int32)
     out = paged_decode_attention(q, ka, va, bt, lens)
@@ -324,12 +324,13 @@ def test_grouped_paged_kernel_parity_ragged_lengths(interpret, group, hk, d,
 
 
 def test_one_query_head_a_group_is_the_kernel_as_it_was(interpret):
-    """G = 1 takes the multi-head path untouched; and the grouped kernel
-    given one row a head (G = 1 forced through its work list) computes
-    the same bits: the arithmetic of a block and the blocks' order within
-    a slot are the multi-head kernel's."""
+    """G = 1 takes the multi-head kernel; and the grouped kernel given
+    one row a head (G = 1 forced through its work list) computes the same
+    bits: the arithmetic of a block and the blocks' order within a slot
+    are the multi-head kernel's, whose grid ends where the list's live
+    items do."""
     from paddle_tpu.ops.pallas.decode_attention import (
-        _paged_call, _paged_grouped_call_once, paged_decode_attention)
+        _paged_call_once, _paged_grouped_call_once, paged_decode_attention)
     rng = np.random.RandomState(3)
     h, d, bs = 5, 64, 128
     fills = [bs // 2 + 1, bs, 2 * bs + 5, 4 * bs, 0]
@@ -338,8 +339,9 @@ def test_one_query_head_a_group_is_the_kernel_as_it_was(interpret):
     lens = jnp.asarray([max(ln - 1, 0) for ln in fills], jnp.int32)
     plain = paged_decode_attention(q, ka, va, bt, lens)
     padded = jnp.pad(q, ((0, 0), (0, 0), (0, 7), (0, 0)))
-    as_was = _paged_call(padded, ka, va, bt, lens + 8, d ** -0.5)[:, :, :1]
-    np.testing.assert_array_equal(np.asarray(plain), np.asarray(as_was))
+    direct = _paged_call_once(padded, ka, va, bt, lens + 1, scale=d ** -0.5,
+                              rows=1, steps=20, interpret=True)[:, :, :1]
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(direct))
     for steps in (20, 15, 12):       # every table entry .. the live pairs
         grouped = _paged_grouped_call_once(
             padded, ka, va, bt, lens + 1, scale=d ** -0.5, interpret=True,
@@ -386,9 +388,11 @@ def test_the_gate_takes_groups_of_one_token_and_nothing_else():
     assert not paged_supported((128, 50, 1, 128), arena, 2)  # no multiple
     assert not paged_supported((1, 48, 256, 128), arena, 2)  # a chunk: XLA
     assert not paged_supported((128, 48, 1, 64), arena, 2)   # other width
-    # multi-head calls are cut as they were (GPT-2 XL's decode step)
+    # a multi-head call's list is as long, and its grid ends at the live
+    # count (GPT-2 XL's decode step over a long table)
     assert paged_cut((32, 25, 1, 64), (1601, 25, 64, 128), 16, 2) \
-        == {"heads_per_step": 25, "grid_steps": 32 * 16}
+        == {"heads_per_step": 25, "grid_steps": 32 * 16,
+            "list_steps": 32 * 16}
 
 
 def test_decode_step_reaches_the_paged_kernel_once_a_layer(interpret):

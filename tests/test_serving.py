@@ -160,10 +160,12 @@ def test_paged_head_tile_comes_from_the_shape(h):
         assert ht == max(fits, default=0)
     assert paged_heads_per_step(h, 8, 64, 128, 2) == h
     assert paged_heads_per_step(25, 256, 64, 128, 2) == 5
+    # the grid's bound: head tiles x the work list's length (the grid
+    # itself ends at the list's live count)
     assert paged_cut((32, 25, 1, 64), (225, 25, 64, 128), 8, 2) == \
-        {"heads_per_step": 25, "grid_steps": 256}
+        {"heads_per_step": 25, "grid_steps": 256, "list_steps": 256}
     assert paged_cut((1, 25, 256, 64), (225, 25, 64, 128), 8, 2) == \
-        {"heads_per_step": 5, "grid_steps": 40}
+        {"heads_per_step": 5, "grid_steps": 40, "list_steps": 8}
     # one head of a 2048-token float32 block at d 256 is over the budget
     assert _paged_step_bytes(1, 256, 256, 2048, 4) > _ATTN_VMEM_BYTES
     assert not paged_supported((1, h, 256, 256), (4, h, 256, 2048), 4)
@@ -454,8 +456,11 @@ def test_paged_write_attend_gate_reads_the_shapes(interpret):
         paged_write_attend_cut)
     chat = ((32, 25, 1, 64), (225, 25, 64, 128), (225, 25, 64, 128))
     assert paged_write_attend_cut(*chat, 8, 2) == {
-        "heads_per_step": 25, "grid_steps": 256,
+        "heads_per_step": 25, "grid_steps": 256, "list_steps": 256,
         "write_bytes": 32 * 25 * 128 * 128 * 2}         # 26.2 MB stored
+    # the pool bounds the list: 100 blocks and a step a slot
+    assert paged_write_attend_cut(*chat, 8, 2, max_steps=100 + 32)[
+        "list_steps"] == 132
     assert _paged_step_bytes(25, 8, 64, 128, 2, write_slots=32) \
         <= _ATTN_VMEM_BYTES
     hybrid = ((32, 30, 1, 128), (337, 30, 128, 128), (337, 30, 128, 128))
@@ -658,7 +663,7 @@ def test_injected_kernel_crash_fails_requests(net, interpret, monkeypatch):
     def boom(*a, **k):
         raise RuntimeError("injected Mosaic crash")
 
-    monkeypatch.setattr(da, "_paged_call", boom)
+    monkeypatch.setattr(da, "_paged_call_once", boom)
     monitor.reset(prefix="pallas.")
     monitor.reset(prefix="serve.")
     rng = np.random.RandomState(5)
@@ -690,16 +695,25 @@ def test_paged_kernel_engages_in_serve(net, interpret):
     # the decode step says how it was cut into grid steps: every head of
     # a block in one step, 2 slots x 4 logical blocks; as gauges, on the
     # kernel's span, and in the report of a dump
+    # a block in one step, a work list of 2 slots x 4 logical blocks whose
+    # live items bound the grid (counted once a traced call); as gauges, on
+    # the kernel's span, and in the report of a dump, where the loop's
+    # share of live table entries stands beside the bound
     heads = GPTConfig.tiny().num_heads
-    cut = {"heads_per_step": heads, "grid_steps": 2 * 4}
+    cut = {"heads_per_step": heads, "grid_steps": 2 * 4, "list_steps": 2 * 4}
     for name, value in cut.items():
         assert monitor.stat_get(
             f"pallas.paged_decode_attention.{name}.b2s1") == value
+    assert monitor.stat_get("pallas.hit.paged_work_list") \
+        == monitor.stat_get("pallas.hit.paged_decode_attention")
     spans = [sp.attrs for sp in trace.recent()
              if sp.name == "pallas/paged_decode_attention"]
     assert any(cut.items() <= attrs.items() for attrs in spans), spans
-    report = obs_report.pallas_rates({"values": monitor.stats("pallas.")})
-    assert f"cut:b2s1={heads}heads/stepx8steps" in report
+    report = obs_report.pallas_rates({"values": {
+        **monitor.stats("pallas."),
+        "serve.paged_live_step_share": loop.stats()["paged_live_step_share"]}})
+    assert (f"cut:b2s1={heads}heads/stepx<=8steps,"
+            "25.0% of table entries live") in report   # idle: 2 of 2 x 4
     # and the writer what a call moves: the two slots' tokens as ONE
     # lane-padded [h, d, 2] operand, not a 128-lane row an element, and
     # the two blocks [1, h, d, 16] in and out (float32 here)
@@ -733,17 +747,38 @@ def test_paged_kernel_engages_in_serve(net, interpret):
     assert monitor.stat_get("pallas.hit.paged_write_attend") > 0
     assert monitor.stat_get("pallas.hit.paged_write_token") == 0
     assert not monitor.stats("pallas.gate_reject.paged_write_attend.")
-    cut = {"heads_per_step": heads, "grid_steps": 2 * 1,
+    cut = {"heads_per_step": heads, "grid_steps": 2 * 1, "list_steps": 2,
            "write_bytes": 2 * 2 * heads * dim * 128 * 4}
     for name, value in cut.items():
         assert monitor.stat_get(
             f"pallas.paged_write_attend.{name}.b2s1") == value
+    assert monitor.stat_get("pallas.hit.paged_work_list") \
+        == monitor.stat_get("pallas.hit.paged_write_attend") \
+        + monitor.stat_get("pallas.hit.paged_decode_attention")
     spans = [sp.attrs for sp in trace.recent()
              if sp.name == "pallas/paged_write_attend"]
     assert any(cut.items() <= attrs.items() for attrs in spans), spans
     report = obs_report.pallas_rates({"values": monitor.stats("pallas.")})
-    assert (f"cut:b2s1={heads}heads/stepx2steps,"
+    assert (f"cut:b2s1={heads}heads/stepx<=2steps,"
             f"{cut['write_bytes'] / 1e6:.1f}MB stored") in report
+
+
+def test_paged_live_step_share_is_the_work_lists_share_of_the_tables(net):
+    """`stats()["paged_live_step_share"]`: the (slot, block) pairs a
+    decode step's work list holds (the pool's blocks in use and one item
+    an idle slot) over slots x the table's width, from the host's books."""
+    loop = ServeLoop(net, ServeConfig(max_active=4, kv_blocks=16,
+                                      block_size=16, max_seq_len=64))
+    assert loop.stats()["paged_live_step_share"] == 4 / (4 * 4)
+    req = loop.submit(np.arange(1, 21).astype(np.int64), max_new_tokens=30)
+    loop._tick()                    # admitted: 20 tokens are two blocks
+    stats = loop.stats()
+    assert (stats["active_slots"], stats["kv_pool_used_blocks"]) == (1, 2)
+    assert stats["paged_live_step_share"] == (2 + 3) / (4 * 4)
+    assert monitor.stat_get("serve.paged_live_step_share") == 5 / 16
+    loop.run_until_idle()
+    assert len(req.result(timeout=0)) == 30
+    assert loop.stats()["paged_live_step_share"] == 4 / 16
 
 
 def test_serve_spans_and_gauges(net):

@@ -174,7 +174,11 @@ def pallas_rates(metrics) -> str:
     query rows, gG for a group of G query heads; .value_dim and .sinks
     where a call's values are narrower than its keys or its softmax
     starts from sink logits; .write_bytes where the call also writes the
-    step's tokens, kernel paged_write_attend: the blocks it stores) and
+    step's tokens, kernel paged_write_attend: the blocks it stores;
+    .list_steps where the grid ENDS at the live items of a work list that
+    long: .grid_steps is then the bound, printed `<=`, with the share of
+    the slots' table entries that are live beside it where a serve loop
+    said it, serve.paged_live_step_share) and
     the latent kernel (pallas.K.blocks_per_step.bM,
     .grid_steps.bM and .live_bytes.bM, what a call reads for each live
     block of a slot); so has the token writer, for what a call of M
@@ -201,7 +205,7 @@ def pallas_rates(metrics) -> str:
         elif len(parts) == 4 and parts[2] in (
                 "heads_per_step", "blocks_per_step", "grid_steps",
                 "live_bytes", "rows_per_block", "tile_bytes", "value_dim",
-                "sinks", "write_bytes"):
+                "sinks", "write_bytes", "list_steps"):
             cuts[kind, parts[3]][parts[2]] = int(v)
         elif len(parts) == 4 and parts[2] in ("token_bytes", "block_bytes"):
             writes[kind, parts[3]][parts[2]] = v
@@ -222,9 +226,12 @@ def pallas_rates(metrics) -> str:
                 + (",sinks" if cut.get("sinks") else "")
         if "write_bytes" in cut:
             live += f",{cut['write_bytes'] / 1e6:.1f}MB stored"
+        share = metrics.get("values", {}).get("serve.paged_live_step_share")
+        if "list_steps" in cut and share is not None:
+            live += f",{100.0 * share:.1f}% of table entries live"
         per[k]["reasons"].append(
-            f"cut:{shape}={held}/stepx{cut.get('grid_steps', '?')}steps"
-            f"{live}")
+            f"cut:{shape}={held}/stepx{'<=' * ('list_steps' in cut)}"
+            f"{cut.get('grid_steps', '?')}steps{live}")
     for (k, shape), moved in sorted(writes.items()):
         per[k]["reasons"].append(
             f"write:{shape}={moved.get('token_bytes', 0) / 1e3:.0f}KB"
@@ -250,7 +257,8 @@ SERVE_GAUGES = ("serve.queue_depth", "serve.active_slots",
                 "serve.prefill_dispatches", "serve.prefill_tokens",
                 "serve.prefill_rows", "serve.prefill_live_rows",
                 "serve.admitted", "serve.queue_wait_s",
-                "serve.state_slots_used", "serve.state_bytes", "serve.steps")
+                "serve.state_slots_used", "serve.state_bytes", "serve.steps",
+                "serve.paged_live_step_share")
 # what a served net counts of its layers (its `SERVE_STATS`): a net with
 # expert layers (text/models/kimi_k2.MOE_STATS; with zero-compute experts
 # beside them text/models/longcat_flash.SCMOE_STATS, which starts with
