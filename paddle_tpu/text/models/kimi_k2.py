@@ -24,7 +24,7 @@ Two computation paths, the same mathematics:
   exist at once and nothing above the diagonal's tiles is computed. It
   attends WITHIN the chunk: a prefill starts an empty slot, which is how
   ServeLoop prefills (a chunk appended to a non-empty cache is not
-  supported). A bucket of more than two tiles of `PREFILL_TILE` rows works
+  supported). A bucket that `decoder.PagedDecoder.prefill_tile` cuts works
   tile by tile over the tiles that hold a token (`_live_rows`: projections,
   decompression, output projection, dense FFN, norms and residuals; the
   queries' tiles) and leaves the rest of the bucket zero; the expert layer
@@ -40,42 +40,17 @@ Inference only: the forward passes are array code under no tape.
 from __future__ import annotations
 
 import functools
-import math
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 
 from ... import nn
-from ...nn import initializer as I
-from ...nn.layer.experts import _swiglu
+from .decoder import (MOE_STATS, DenseFFN, PagedDecoder, Rows, _live_rows,
+                      _rms, _rope, _rotary_tables, _Weights, moe_counters,
+                      yarn_inv_freq, yarn_mscale)
 
-__all__ = ["KimiK2", "KimiK2Config", "MOE_STATS", "yarn_inv_freq",
-           "yarn_mscale"]
-
-# what the expert layers count for `ServeLoop.stats()`: tokens routed,
-# (token, expert) pairs that fell on a held expert, held experts that got
-# at least one pair and the most pairs on one expert, the last two summed
-# over layer-steps; decode beats and prefills apart,
-# `moe_decode_layer_steps` to divide the decode sums by
-MOE_STATS = tuple(f"moe_{kind}_{what}" for kind in ("decode", "prefill")
-                  for what in ("tokens", "pairs_held", "experts_touched",
-                               "peak_pairs")) + ("moe_decode_layer_steps",)
-
-
-def moe_counters(kind, pairs_held, n_tokens):
-    """`MOE_STATS`' increments from one settled serve program's pairs a
-    held expert [expert layers, held]."""
-    import numpy as np
-    pairs = np.asarray(pairs_held)
-    out = {f"moe_{kind}_tokens": int(n_tokens),
-           f"moe_{kind}_pairs_held": int(pairs.sum()),
-           f"moe_{kind}_experts_touched": int((pairs > 0).sum()),
-           f"moe_{kind}_peak_pairs":
-               int(pairs.max(axis=1).sum()) if pairs.size else 0}
-    if kind == "decode":
-        out["moe_decode_layer_steps"] = int(pairs.shape[0])
-    return out
+__all__ = ["KimiK2", "KimiK2Config"]
 
 
 @dataclass
@@ -120,107 +95,19 @@ class KimiK2Config:
         return KimiK2Config(**cfg)
 
 
-def yarn_mscale(factor, mscale=1.0):
-    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
-
-
-def yarn_inv_freq(dim, theta, scaling):
-    """The `dim // 2` rotary frequencies. Without scaling theta^(-2i/dim);
-    with YaRN the blend of those (extrapolation) and the same divided by
-    `factor` (interpolation) along the linear ramp between the correction
-    dimensions of beta_fast and beta_slow. -> (inv_freq [dim/2] f32, the
-    factor cos and sin are scaled by)."""
-    freq = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    if not scaling:
-        return freq, 1.0
-    factor = float(scaling["factor"])
-    orig = float(scaling["original_max_position_embeddings"])
-
-    def correction_dim(rotations):
-        return dim * math.log(orig / (rotations * 2 * math.pi)) \
-            / (2 * math.log(theta))
-
-    low = max(math.floor(correction_dim(float(scaling["beta_fast"]))), 0)
-    high = min(math.ceil(correction_dim(float(scaling["beta_slow"]))),
-               dim - 1)
-    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
-                    / max(high - low, 0.001), 0.0, 1.0)
-    inv_freq = freq / factor * ramp + freq * (1.0 - ramp)
-    attention_factor = yarn_mscale(factor, scaling.get("mscale", 1)) \
-        / yarn_mscale(factor, scaling.get("mscale_all_dim", 0))
-    return inv_freq, attention_factor
-
-
-def _rms(x, weight, eps, scale=1.0):
-    """RMSNorm in float32; `scale` multiplies the normed value before it
-    is rounded to x's dtype (1.0: nothing is multiplied)."""
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
-                            + eps)
-    y = y * weight.astype(jnp.float32)
-    return (y if scale == 1.0 else y * scale).astype(x.dtype)
-
-
-def _rope(x, cos, sin):
-    """Rotate the pairs (i, i + d/2) of the last axis (the half-split
-    pairing; the published checkpoints pair (2i, 2i+1), a fixed
-    permutation of the projections' columns). cos, sin broadcast to x."""
-    half = x.shape[-1] // 2
-    x32 = x.astype(jnp.float32)
-    rot = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
-    return (x32 * cos + rot * sin).astype(x.dtype)
-
-
 def _cos_sin(cfg, pos):
     """cos and sin [..., qk_rope_head_dim] of the positions `pos`, for
     `_rope`'s half-split pairing, under the configuration's scaling."""
-    inv_freq, factor = yarn_inv_freq(cfg.qk_rope_head_dim,
-                                     float(cfg.rope_theta),
-                                     getattr(cfg, "rope_scaling", None))
-    ang = pos.astype(jnp.float32)[..., None] * inv_freq
-    ang = jnp.concatenate([ang, ang], axis=-1)
-    return jnp.cos(ang) * factor, jnp.sin(ang) * factor
-
-
-# rows of one step of a bucketed prefill's row-wise work, and of one tile
-# of queries in its attention: a bucket holds a prompt of any length over
-# its half, and what the rows past the prompt compute is thrown away
-PREFILL_TILE = 256
-
-
-def _tile_of(bucket, tile):
-    """`tile` where a prefill over `bucket` rows works tile by tile, None
-    where it runs whole: a bucket no larger than a tile, or no multiple."""
-    return tile if bucket > tile and bucket % tile == 0 else None
-
-
-def _live_rows(fn, live, tile, *xs):
-    """`fn` over `xs` ([b, s, ...] each; `fn` works row by row and returns
-    a tuple of [b, rows, ...]). `live` None: all of it at once. Else only
-    the first `live` (a traced count) tiles of `tile` rows are computed,
-    a tile a loop step; the rows of the others come out zero."""
-    if live is None:
-        return fn(*xs)
-    s = xs[0].shape[1]
-    like = jax.eval_shape(fn, *(x[:, :tile] for x in xs))
-    outs = tuple(jnp.zeros((y.shape[0], s) + y.shape[2:], y.dtype)
-                 for y in like)
-
-    def one_tile(i, outs):
-        ys = fn(*(jax.lax.dynamic_slice_in_dim(x, i * tile, tile, axis=1)
-                  for x in xs))
-        return tuple(jax.lax.dynamic_update_slice_in_dim(o, y, i * tile,
-                                                         axis=1)
-                     for o, y in zip(outs, ys))
-
-    return jax.lax.fori_loop(0, live, one_tile, outs)
+    return _rotary_tables(pos, *yarn_inv_freq(
+        cfg.qk_rope_head_dim, float(cfg.rope_theta),
+        getattr(cfg, "rope_scaling", None)))
 
 
 # jitted under a name of its own, so that a device trace can tell the
 # latent attention from the rest of a serve program
 @functools.partial(jax.jit, static_argnames=("scale", "q_block"))
 def _mla_chunk_attention(q_nope, q_r, k_nope, k_r, v, live=None, *, scale,
-                         q_block=PREFILL_TILE):
+                         q_block):
     """Causal attention within a chunk, decompressed: q_nope/k_nope
     [b, s, h, dn], q_r [b, s, h, dr], k_r [b, s, dr] (one for all heads),
     v [b, s, h, dv] -> [b, s, h, dv]. Queries go a tile of `q_block` at a
@@ -255,22 +142,6 @@ def _mla_chunk_attention(q_nope, q_r, k_nope, k_r, v, live=None, *, scale,
             i < live, functools.partial(one_tile, i),
             lambda: jnp.zeros_like(tiles[0])))
     return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=1)
-
-
-class _Weights(nn.Layer):
-    """A layer of matrices born in the configuration's dtype."""
-
-    def __init__(self, cfg):
-        super().__init__(dtype=cfg.dtype)
-        self._normal = I.Normal(0.0, cfg.init_std)
-
-    def matrix(self, *shape):
-        return self.create_parameter(list(shape),
-                                     default_initializer=self._normal)
-
-    def ones(self, n):
-        return self.create_parameter([n],
-                                     default_initializer=I.Constant(1.0))
 
 
 class LatentAttention(_Weights):
@@ -337,12 +208,12 @@ class LatentAttention(_Weights):
         return q_nope, q_r, latent, kv[..., :self.dn], kv[..., self.dn:]
 
     def mix(self, q_nope, q_r, latent, k_nope=None, v=None, cache=None,
-            live=None):
+            rows=Rows()):
         """Across the rows, from `project`'s parts: the latents written
         to a `PagedLatentCache`, then a chunk attends within itself
-        (decompressed; `live` tiles of queries of it, None: all) and one
-        token over its slot's cache (absorbed) -> (context [b, s, h dv],
-        new cache or None)."""
+        (decompressed; `rows.live` tiles of queries of it, None: all) and
+        one token over its slot's cache (absorbed) -> (context [b, s,
+        h dv], new cache or None)."""
         from ...nn.kv_pool import PagedLatentCache, write_kv
         b, s = q_nope.shape[:2]
         if cache is not None:
@@ -352,8 +223,8 @@ class LatentAttention(_Weights):
                          latent[:, :, None, :]), cache.block_tables, lens)
         if k_nope is not None:
             ctx = _mla_chunk_attention(
-                q_nope, q_r, k_nope, latent[..., self.rank:], v, live,
-                scale=self.scale, q_block=PREFILL_TILE)
+                q_nope, q_r, k_nope, latent[..., self.rank:], v, rows.live,
+                scale=self.scale, q_block=rows.tile)
         else:
             ctx = self._absorbed(q_nope, q_r, cache)
         if cache is not None:
@@ -385,26 +256,13 @@ class LatentAttention(_Weights):
             return self.output(ctx), cache
 
 
-class DenseFFN(_Weights):
-    def __init__(self, cfg):
-        super().__init__(cfg)
-        H, W = cfg.hidden_size, cfg.intermediate_size
-        self.gate, self.up = self.matrix(H, W), self.matrix(H, W)
-        self.down = self.matrix(W, H)
-
-    def forward(self, x):
-        with jax.named_scope("ffn"):
-            return _swiglu(x, self.gate._value, self.up._value,
-                           self.down._value).astype(x.dtype)
-
-
-def _sublayer(attn, attn_norm, ffn_norm, ffn, eps, x, cos, sin, cache, live):
+def _sublayer(attn, attn_norm, ffn_norm, ffn, eps, x, cos, sin, cache, rows):
     """A latent attention and what follows it row by row: h = x +
     MLA(RMSNorm(x)), f = RMSNorm(h) -> (h + ffn(f), or h where `ffn` is
-    None and an expert layer takes f; f; new cache). `live`: the tiles of
-    `PREFILL_TILE` rows that hold a token (`_live_rows`), None for all:
-    the norms, `project` and `output`, the residuals and `ffn` run over
-    those, `mix` across the rows."""
+    None and an expert layer takes f; f; new cache). `rows.live`: the
+    tiles of `rows.tile` rows that hold a token (`_live_rows`), None for
+    all: the norms, `project` and `output`, the residuals and `ffn` run
+    over those, `mix` across the rows."""
     def before(x, cos, sin):
         with jax.named_scope("attn"):
             return attn.project(_rms(x, attn_norm._value, eps), cos, sin,
@@ -417,10 +275,10 @@ def _sublayer(attn, attn_norm, ffn_norm, ffn, eps, x, cos, sin, cache, live):
             f = _rms(h, ffn_norm._value, eps)
             return (h if ffn is None else h + ffn(f)), f
 
-    parts = _live_rows(before, live, PREFILL_TILE, x, cos, sin)
+    parts = _live_rows(before, rows.live, rows.tile, x, cos, sin)
     with jax.named_scope("attn"):
-        ctx, cache = attn.mix(*parts, cache=cache, live=live)
-    y, f = _live_rows(after, live, PREFILL_TILE, x, ctx)
+        ctx, cache = attn.mix(*parts, cache=cache, rows=rows)
+    y, f = _live_rows(after, rows.live, rows.tile, x, ctx)
     return y, f, cache
 
 
@@ -440,110 +298,41 @@ class KimiK2Block(_Weights):
             dtype=cfg.dtype, init_std=cfg.init_std) if self.sparse \
             else DenseFFN(cfg)
 
-    def forward(self, x, cos, sin, cache=None, valid=None, live=None):
-        """-> (y, new cache, pairs per held expert [count] i32, or None
-        from a dense layer). `live`: `_sublayer`'s; the expert layer runs
-        once over all the rows, its cost being its weights'."""
+    def forward(self, x, rope, cache, rows):
+        """-> (y, (new cache,), (pairs per held expert [count] i32, or
+        None from a dense layer,)). `rows`: `_sublayer`'s; the expert
+        layer runs once over all the rows, its cost being its weights'."""
         y, f, cache = _sublayer(
             self.attn, self.attn_norm, self.ffn_norm,
-            None if self.sparse else self.ffn, self.eps, x, cos, sin, cache,
-            live)
+            None if self.sparse else self.ffn, self.eps, x, *rope, cache,
+            rows)
         if not self.sparse:
-            return y, cache, None
+            return y, (cache,), (None,)
         with jax.named_scope("ffn"):   # `routed` names its own parts
             b, s, H = f.shape
             m, counts, _ = self.ffn.routed(
                 f.reshape(b * s, H),
-                None if valid is None else valid.reshape(b * s))
-            return y + m.reshape(b, s, H), cache, counts
+                None if rows.valid is None else rows.valid.reshape(b * s))
+            return y + m.reshape(b, s, H), (cache,), (counts,)
 
 
-class _LatentDecoder(_Weights):
-    """What the latent-attention decoders share (this file's and
-    text/models/longcat_flash.py's): embedding, a stack of blocks, the
-    final norm, an untied head, and `ServeLoop`'s protocol over what a
-    subclass writes beside `paged_cache_spec`: `_block(i)`, layer i of
-    the stack, and `_blocks`: (ids, pos, caches in spec order or None for
-    a pass without a cache, valid, live tiles or None) -> (x, new caches,
-    what the expert layers counted: a tuple of arrays)."""
+class _LatentDecoder(PagedDecoder):
+    """What the latent-attention decoders share past `PagedDecoder`
+    (this file's and text/models/longcat_flash.py's): the residual stream
+    stays in the parameters' dtype, and one rotary table serves every
+    layer."""
 
-    def __init__(self, cfg):
-        super().__init__(cfg)
-        self.config = cfg
-        self.embed = self.matrix(cfg.vocab_size, cfg.hidden_size)
-        self.blocks = nn.LayerList(
-            [self._block(i) for i in range(cfg.num_layers)])
-        self.norm = self.ones(cfg.hidden_size)
-        self.head = self.matrix(cfg.hidden_size, cfg.vocab_size)
-
-    def _logits(self, h):
-        with jax.named_scope("head"):
-            h = _rms(h, self.norm._value, self.config.rms_norm_eps)
-            return jnp.dot(h, self.head._value,
-                           preferred_element_type=jnp.float32)
-
-    def forward(self, input_ids):
-        """Logits [b, s, vocab] (float32) of a whole sequence, no cache."""
-        from ...core import tape
-        from ...core.tensor import Tensor
-        ids = input_ids._value if isinstance(input_ids, Tensor) \
-            else jnp.asarray(input_ids)
-        with tape.no_grad():
-            pos = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
-            x, *_ = self._blocks(ids.astype(jnp.int32), pos, None, None)
-            return Tensor(self._logits(x), _internal=True)
-
-    def prefill_tile(self, bucket):
-        """`_tile_of` this net's tile: what `_forward_paged` cuts a bucket
-        into, and what `ServeLoop` counts the rows computed by. A bucket
-        of two tiles runs whole: it is the smallest that holds its prompt,
-        so both tiles are live, and a loop only fetches every weight
-        twice (4.7 ms of a 27 ms LongCat prefill; PERF.md section 6)."""
-        return _tile_of(bucket, PREFILL_TILE) \
-            if bucket > 2 * PREFILL_TILE else None
-
-    def _forward_paged(self, input_ids, caches, last_index=None):
-        """One paged prefill/decode pass, `GPT._forward_paged`'s contract
-        over `PagedLatentCache`s, plus what the expert layers counted:
-        -> (logits [b, V] float32, new caches, then `_blocks`' counts:
-        first the pairs per held expert [expert layers, held] i32). Rows
-        that no request owns (a slot whose table starts at the trash
-        block, a prompt's padding past `last_index`) are cached into the
-        trash block like GPT's and are routed to no expert. A bucket that
-        `prefill_tile` cuts into tiles computes those up to the last
-        prompt's end and leaves the rows of the others zero."""
-        from ...core.tensor import Tensor
-        from ...nn.kv_pool import TRASH_BLOCK
-        ids = input_ids._value if isinstance(input_ids, Tensor) \
-            else jnp.asarray(input_ids)
-        b, s = ids.shape
-        lens = jnp.asarray(caches[0].lengths, jnp.int32)
-        step = jnp.arange(s, dtype=jnp.int32)[None]
-        valid = jnp.broadcast_to(
-            (caches[0].block_tables[:, :1] != TRASH_BLOCK), (b, s))
-        live = None
-        if last_index is not None:
-            last = jnp.asarray(last_index, jnp.int32).reshape(-1)
-            valid = valid & (step <= last[:, None])
-            tile = self.prefill_tile(s)
-            if tile:
-                live = jnp.max(last) // tile + 1
-        x, new_caches, counted = self._blocks(
-            ids.astype(jnp.int32), lens[:, None] + step, caches, valid,
-            live)
-        h = x[:, -1] if last_index is None else jnp.take_along_axis(
-            x, last[:, None, None], axis=1)[:, 0]
-        return (self._logits(h), new_caches, *counted)
+    def _embed(self, ids, pos):
+        return (jnp.take(self.embed._value, ids, axis=0),
+                _cos_sin(self.config, pos))
 
 
 class KimiK2(_LatentDecoder):
     SERVE_STATS = MOE_STATS
 
     def __init__(self, config: KimiK2Config = None):
-        super().__init__(config or KimiK2Config())
-
-    def _block(self, i):
-        return KimiK2Block(self.config, i)
+        cfg = config or KimiK2Config()
+        super().__init__(cfg, lambda i: KimiK2Block(cfg, i))
 
     def paged_cache_spec(self):
         """One `CacheSpec` a layer: a `PagedLatentCache` over one arena,
@@ -554,24 +343,6 @@ class KimiK2(_LatentDecoder):
         return [CacheSpec(PagedLatentCache, (latent,))] * cfg.num_layers
 
     def serve_counters(self, kind, counted, n_tokens):
-        """{`ServeLoop.stats()` name: increment} for one settled serve
-        program (`kind` "decode" or "prefill") that ran `n_tokens` live
-        tokens: `counted` is what `_forward_paged` returned past its
-        caches, the pairs each held expert got [expert layers, held]."""
+        """`counted`: the pairs each held expert got [expert layers,
+        held] i32."""
         return moe_counters(kind, counted[0], n_tokens)
-
-    def _blocks(self, ids, pos, caches, valid, live=None):
-        with jax.named_scope("embed"):
-            x = jnp.take(self.embed._value, ids, axis=0)
-            cos, sin = _cos_sin(self.config, pos)
-        new_caches, counts = [], []
-        for i, (blk, c) in enumerate(zip(
-                self.blocks, caches or [None] * len(self.blocks))):
-            with jax.named_scope(f"layer{i}"):
-                x, c, n = blk(x, cos, sin, c, valid, live)
-            new_caches.append(c)
-            if n is not None:
-                counts.append(n)
-        counts = jnp.stack(counts) if counts \
-            else jnp.zeros((0, 0), jnp.int32)
-        return x, new_caches, (counts,)

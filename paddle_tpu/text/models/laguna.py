@@ -29,13 +29,13 @@ Two computation paths, the same mathematics:
 - a chunk of s > 1 tokens (a prefill) starts an EMPTY slot: the full
   layers write the chunk's keys and values into the slot's blocks, the
   sliding layers leave its last `sliding_window` in the ring, and both
-  attend within the chunk, a tile of `PREFILL_TILE` queries (the group's
-  heads folded into the rows) against the key tiles it may see: those up
-  to its own, or for a sliding layer those that meet the band. A bucket
-  of more than two tiles works tile by tile over the tiles that hold a
-  token (`kimi_k2._live_rows`: norms, projections, rotary, gate, output
-  projection, dense FFN, residuals; the queries' tiles) and leaves the
-  rest of the bucket zero; the expert layer runs once over the bucket;
+  attend within the chunk, a tile of queries (the group's heads folded
+  into the rows) against the key tiles it may see: those up to its own,
+  or for a sliding layer those that meet the band. A bucket that
+  `decoder.PagedDecoder.prefill_tile` cuts works tile by tile over the
+  tiles that hold a token (`_live_rows`: norms, projections, rotary,
+  gate, output projection, dense FFN, residuals; the queries' tiles) and
+  leaves the rest zero; the expert layer runs once over the bucket;
 - one token a slot (a decode step): `write_kv` + `paged_attention` over
   the pool's arenas (full) or `window_write` + `window_attention` over
   the slot's ring (sliding) — the grouped-query form of the paged Pallas
@@ -48,7 +48,7 @@ What another net with window layers shares (`mimo_v2.py`: keys deeper
 than values, head counts by kind, sink logits) is here under its own
 name: `_gqa_chunk_attention` (sinks and a value width as arguments),
 `GroupedAttention.attend`, `WindowBlock`, `WindowDecoder`,
-`window_cache_spec`; `PREFILL_TILE` is both nets' one tile.
+`window_cache_spec`.
 """
 from __future__ import annotations
 
@@ -61,13 +61,13 @@ import numpy as np
 
 from ... import nn
 from ...nn.layer.experts import _swiglu
-from .kimi_k2 import (MOE_STATS, DenseFFN, _live_rows, _rms, _rope,
-                      _tile_of, _Weights, moe_counters, yarn_inv_freq)
+from .decoder import (F32, FULL, MOE_STATS, DenseFFN, PagedDecoder,
+                      _live_rows, _rms, _rope, _rotary_tables, _Weights,
+                      moe_counters, yarn_inv_freq)
 
 __all__ = ["Laguna", "LagunaConfig", "ATTN_STATS"]
 
-FULL, SLIDING = "full_attention", "sliding_attention"
-F32 = jnp.float32
+SLIDING = "sliding_attention"
 
 # what the attention layers count for `ServeLoop.stats()`: cached tokens
 # the decode steps attended to, summed over slots and layer-steps (a full
@@ -158,15 +158,10 @@ class LagunaConfig:
         return LagunaConfig(**cfg)
 
 
-# rows of one step of a bucketed prefill's row-wise work, and of one tile
-# of queries (and of keys) in its attention
-PREFILL_TILE = 256
-
-
 def _rotary(cfg, kind):
     """(r, inv_freq [r/2], the factor cos and sin are scaled by) of the
     layers of `kind`: r = head_dim * partial_rotary_factor rotated dims,
-    YaRN where `rope_type` says so (`kimi_k2.yarn_inv_freq` computes the
+    YaRN where `rope_type` says so (`decoder.yarn_inv_freq` computes the
     frequencies; the published `attention_factor` is taken as given)."""
     p = cfg.rope_parameters[kind]
     r = int(round(cfg.head_dim * float(p.get("partial_rotary_factor", 1))))
@@ -178,17 +173,14 @@ def _rotary(cfg, kind):
 def _cos_sin(cfg, kind, pos):
     """cos and sin [..., r] of the positions `pos` for `_rope`'s
     half-split pairing."""
-    _, inv_freq, factor = _rotary(cfg, kind)
-    ang = pos.astype(F32)[..., None] * inv_freq
-    ang = jnp.concatenate([ang, ang], axis=-1)
-    return jnp.cos(ang) * factor, jnp.sin(ang) * factor
+    return _rotary_tables(pos, *_rotary(cfg, kind)[1:])
 
 
 # jitted under a name of its own, so that a device trace can tell the
 # chunk's attention from the rest of a prefill
 @functools.partial(jax.jit, static_argnames=("scale", "window", "q_block"))
 def _gqa_chunk_attention(q, k, v, live=None, sinks=None, *, scale,
-                         window=None, q_block=PREFILL_TILE):
+                         window=None, q_block):
     """Causal grouped-query attention within a chunk: q [b, s, G hk, d],
     k [b, s, hk, d], v [b, s, hk, d_v] -> [b, s, G hk, d_v]; query head j
     reads key-value head j // G; with `window` a query sees the last
@@ -307,13 +299,13 @@ class GroupedAttention(_Weights):
             k, v = (x.astype(self.round_to).astype(a.dtype) for x in (k, v))
         return q, k, v
 
-    def attend(self, q, k, v, cache=None, last=None, live=None):
+    def attend(self, q, k, v, cache, rows):
         """Across the rows: keys and values cached (a full layer's in the
         slot's blocks, a sliding layer's in its ring), the attention ->
         (out [b, s, n d_v], new cache or None). q [b, s, n, d], k [b, s,
-        kv, d], v [b, s, kv, d_v]. A chunk attends within itself, `live`
-        tiles of queries of it (None: all); one token attends over its
-        slot's cache."""
+        kv, d], v [b, s, kv, d_v]. A chunk attends within itself,
+        `rows.live` tiles of queries of it (None: all); one token attends
+        over its slot's cache."""
         from ...nn.kv_pool import (paged_attention, window_attention,
                                    window_fill, window_write, write_kv)
         b, s, n, d = q.shape
@@ -328,16 +320,16 @@ class GroupedAttention(_Weights):
                     k=write_kv(cache.k, cache.block_tables, lens, k),
                     v=write_kv(cache.v, cache.block_tables, lens, v))
             elif chunk:
-                count = (s if last is None else last[0] + 1)
+                count = (s if rows.last is None else rows.last[0] + 1)
                 cache = cache._replace(k=window_fill(cache.k, k, count),
                                        v=window_fill(cache.v, v, count))
             else:
                 cache = cache._replace(k=window_write(cache.k, lens, k),
                                        v=window_write(cache.v, lens, v))
         if chunk:
-            out = _gqa_chunk_attention(q.astype(k.dtype), k, v, live, sinks,
-                                       scale=scale, window=self.window,
-                                       q_block=PREFILL_TILE)
+            out = _gqa_chunk_attention(q.astype(k.dtype), k, v, rows.live,
+                                       sinks, scale=scale,
+                                       window=self.window, q_block=rows.tile)
         elif self.window is None:
             out = jnp.swapaxes(paged_attention(
                 jnp.swapaxes(q, 1, 2), cache.k, cache.v,
@@ -378,10 +370,10 @@ class GatedGroupedAttention(GroupedAttention):
                                       preferred_element_type=F32))
         return (*self.heads_of(a, cos, sin), gate)
 
-    def mix(self, q, k, v, gate, cache=None, last=None, live=None):
+    def mix(self, q, k, v, gate, cache, rows):
         """`attend`, the gates carried beside it to `output` -> ((out,
         gate), new cache or None)."""
-        out, cache = self.attend(q, k, v, cache, last, live)
+        out, cache = self.attend(q, k, v, cache, rows)
         return (out, gate), cache
 
     def output(self, out, gate):
@@ -411,16 +403,18 @@ class WindowBlock(_Weights):
         self.sparse = sparse
         self.ffn = ffn
 
-    def forward(self, x, cos, sin, cache=None, valid=None, last=None,
-                live=None):
+    def forward(self, x, rope, cache, rows):
         """x [b, s, H] float32: the residual stream stays float32; what a
-        matrix multiplies is rounded to the parameters' dtype. -> (y, new
-        cache, pairs per held expert [count] i32, or None from a dense
-        layer). `live`: the tiles of `PREFILL_TILE` rows that hold a token
-        (`_live_rows`), None for all; the expert layer runs once over all
-        the rows, its cost being its weights'."""
+        matrix multiplies is rounded to the parameters' dtype. `rope`: cos
+        and sin by layer kind. -> (y, (new cache,), (pairs per held expert
+        [count] i32, or None from a dense layer,)). `rows.live`: the tiles of
+        `rows.tile` rows that hold a token (`_live_rows`), None for all;
+        the expert layer runs once over all the rows, its cost being its
+        weights'."""
         dtype = self.attn.o._value.dtype
         attn, ffn = self.attn, self.ffn
+        cos, sin = rope[self.kind]
+        valid, live, tile = rows.valid, rows.live, rows.tile
         word = "attn" if self.kind == FULL else "window_attn"
 
         def before(x, cos, sin):
@@ -438,18 +432,18 @@ class WindowBlock(_Weights):
                 return h + _swiglu(f, ffn.gate._value, ffn.up._value,
                                    ffn.down._value), f
 
-        parts = _live_rows(before, live, PREFILL_TILE, x, cos, sin)
+        parts = _live_rows(before, live, tile, x, cos, sin)
         with jax.named_scope(word):
-            mixed, cache = attn.mix(*parts, cache, last, live)
-        y, f = _live_rows(after, live, PREFILL_TILE, x, *mixed)
+            mixed, cache = attn.mix(*parts, cache, rows)
+        y, f = _live_rows(after, live, tile, x, *mixed)
         if not self.sparse:
-            return y, cache, None
+            return y, (cache,), (None,)
         with jax.named_scope("ffn"):   # `routed` names its own parts
             b, s, H = f.shape
             m, counts, _ = ffn.routed(
                 f.reshape(b * s, H),
                 None if valid is None else valid.reshape(b * s))
-            return y + m.reshape(b, s, H).astype(F32), cache, counts
+            return y + m.reshape(b, s, H).astype(F32), (cache,), (counts,)
 
 
 class LagunaBlock(WindowBlock):
@@ -484,33 +478,48 @@ def window_cache_spec(layer_types, window, ring_block, keys, values):
             for kind in layer_types]
 
 
-class WindowDecoder(_Weights):
-    """A decoder of `WindowBlock`s behind `ServeLoop`: what the nets with
-    window layers share past their blocks. A family's `__init__` names
-    its block (`block(cfg, index)`); its config gives `vocab_size`, `hidden_size`,
-    `num_layers`, `layer_types`, `sliding_window`, `rope_parameters`
-    (`_rotary`), `head_dim`, `rms_norm_eps`; it brings
-    `paged_cache_spec`."""
+class WindowDecoder(PagedDecoder):
+    """What the nets with window layers share past `PagedDecoder` and
+    their blocks: the residual stream in float32, a rotary table a layer
+    kind, and the attention's counts. A family's `__init__` names its
+    block (`block(cfg, index)`); its config gives, beside
+    `PagedDecoder`'s, `layer_types`, `sliding_window`, `rope_parameters`
+    (`_rotary`) and `head_dim`; it brings `paged_cache_spec`."""
 
     SERVE_STATS = MOE_STATS + ATTN_STATS
     SERVE_GAUGES = ("window_ring_bytes",)
+    SLOT_COUNTS = True
 
     def __init__(self, cfg, block):
-        super().__init__(cfg)
-        self.config = cfg
-        self.embed = self.matrix(cfg.vocab_size, cfg.hidden_size)
-        self.blocks = nn.LayerList([block(cfg, i)
-                                    for i in range(cfg.num_layers)])
-        self.norm = self.ones(cfg.hidden_size)
-        self.head = self.matrix(cfg.hidden_size, cfg.vocab_size)
+        super().__init__(cfg, lambda i: block(cfg, i))
+
+    def _embed(self, ids, pos):
+        cfg = self.config
+        # the kinds in the layers' order, not a set's: the order the two
+        # tables are traced in is part of the program's text, which keys
+        # the compile cache, and a set's changes with the hash seed
+        return (jnp.take(self.embed._value, ids, axis=0).astype(F32),
+                {kind: _cos_sin(cfg, kind, pos)
+                 for kind in dict.fromkeys(cfg.layer_types)})
+
+    def _counted(self, caches, rows):
+        """[cached tokens the full layers attended to, the sliding layers,
+        the rings' KiB]."""
+        from ...nn.kv_pool import WindowKVCache
+        cfg = self.config
+        # what one token a slot attends to, this step's token included
+        seen = jnp.where(rows.owned, rows.lens + 1, 0)
+        n_full = sum(kind == FULL for kind in cfg.layer_types)
+        rings = [c for c in caches if isinstance(c, WindowKVCache)]
+        read = jnp.stack([
+            n_full * jnp.sum(seen),
+            len(rings) * jnp.sum(jnp.minimum(seen, cfg.sliding_window)),
+            jnp.int32(sum(c.k.nbytes + c.v.nbytes for c in rings) // 1024)])
+        return (read,)
 
     def serve_counters(self, kind, counted, n_tokens):
-        """{`ServeLoop.stats()` name: increment, or for a name in
-        `SERVE_GAUGES` the value} for one settled serve program (`kind`
-        "decode" or "prefill") that ran `n_tokens` live tokens: `counted`
-        is what `_forward_paged` returned past its caches, the pairs each
-        held expert got [expert layers, held] and [cached tokens the full
-        layers attended to, the sliding layers, the rings' KiB]."""
+        """`counted`: the pairs each held expert got [expert layers, held]
+        and `_counted`'s three."""
         out = moe_counters(kind, counted[0], n_tokens)
         if kind == "decode":
             full, window, ring_kib = (int(x) for x in np.asarray(counted[1]))
@@ -518,95 +527,6 @@ class WindowDecoder(_Weights):
                        attn_window_decode_tokens_read=window,
                        window_ring_bytes=ring_kib * 1024)
         return out
-
-    def _blocks(self, ids, pos, caches, valid, last, live=None):
-        cfg = self.config
-        with jax.named_scope("embed"):
-            x = jnp.take(self.embed._value, ids, axis=0).astype(F32)
-            rope = {kind: _cos_sin(cfg, kind, pos)
-                    for kind in set(cfg.layer_types)}
-        new_caches, counts = [], []
-        for i, (blk, c) in enumerate(zip(
-                self.blocks, caches or [None] * len(self.blocks))):
-            with jax.named_scope(f"layer{i}"):
-                x, c, n = blk(x, *rope[blk.kind], c, valid, last, live)
-            new_caches.append(c)
-            if n is not None:
-                counts.append(n)
-        counts = jnp.stack(counts) if counts \
-            else jnp.zeros((0, 0), jnp.int32)
-        return x, new_caches, counts
-
-    def _logits(self, h):
-        with jax.named_scope("head"):
-            h = _rms(h, self.norm._value, self.config.rms_norm_eps)
-            return jnp.dot(h.astype(self.head._value.dtype),
-                           self.head._value, preferred_element_type=F32)
-
-    def forward(self, input_ids):
-        """Logits [b, s, vocab] (float32) of a whole sequence, no cache."""
-        from ...core import tape
-        from ...core.tensor import Tensor
-        ids = input_ids._value if isinstance(input_ids, Tensor) \
-            else jnp.asarray(input_ids)
-        with tape.no_grad():
-            pos = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
-            x, *_ = self._blocks(ids.astype(jnp.int32), pos, None, None,
-                                 None)
-            return Tensor(self._logits(x), _internal=True)
-
-    def prefill_tile(self, bucket):
-        """`kimi_k2._tile_of` this net's tile: what `_forward_paged` cuts
-        a bucket into, and what `ServeLoop` counts the rows computed by.
-        A bucket of two tiles runs whole, as the latent nets' does: it is
-        the smallest that holds its prompt, so both tiles are live."""
-        return _tile_of(bucket, PREFILL_TILE) \
-            if bucket > 2 * PREFILL_TILE else None
-
-    def _forward_paged(self, input_ids, caches, last_index=None):
-        """One paged prefill/decode pass, `GPT._forward_paged`'s contract
-        over the caches `paged_cache_spec` names, plus what
-        `serve_counters` reads: -> (logits [b, V] float32, new caches,
-        pairs per held expert [expert layers, held] i32, [cached tokens
-        the full layers read, the sliding layers read, the rings' KiB]
-        i32). Rows that no request owns (a slot whose table starts at the
-        trash block, a prompt's padding past `last_index`) write their
-        keys and values to the trash block (full layers) or to their own
-        slot's ring, which nobody reads (sliding layers), and are routed
-        to no expert. A bucket that `prefill_tile` cuts into tiles
-        computes those up to the prompt's end and leaves the rows of the
-        others zero."""
-        from ...core.tensor import Tensor
-        from ...nn.kv_pool import TRASH_BLOCK, WindowKVCache
-        ids = input_ids._value if isinstance(input_ids, Tensor) \
-            else jnp.asarray(input_ids)
-        cfg = self.config
-        b, s = ids.shape
-        lens = jnp.asarray(caches[0].lengths, jnp.int32)
-        step = jnp.arange(s, dtype=jnp.int32)[None]
-        owned = caches[0].block_tables[:, 0] != TRASH_BLOCK         # [b]
-        valid = jnp.broadcast_to(owned[:, None], (b, s))
-        last = live = None
-        if last_index is not None:
-            last = jnp.asarray(last_index, jnp.int32).reshape(-1)
-            valid = valid & (step <= last[:, None])
-            tile = self.prefill_tile(s)
-            if tile:
-                live = jnp.max(last) // tile + 1
-        x, new_caches, counts = self._blocks(
-            ids.astype(jnp.int32), lens[:, None] + step, caches, valid,
-            last, live)
-        h = x[:, -1] if last is None else jnp.take_along_axis(
-            x, last[:, None, None], axis=1)[:, 0]
-        # what one token a slot attends to, this step's token included
-        seen = jnp.where(owned, lens + 1, 0)
-        n_full = sum(kind == FULL for kind in cfg.layer_types)
-        rings = [c for c in caches if isinstance(c, WindowKVCache)]
-        read = jnp.stack([
-            n_full * jnp.sum(seen),
-            len(rings) * jnp.sum(jnp.minimum(seen, cfg.sliding_window)),
-            jnp.int32(sum(c.k.nbytes + c.v.nbytes for c in rings) // 1024)])
-        return (self._logits(h), new_caches, counts, read.astype(jnp.int32))
 
 
 class Laguna(WindowDecoder):

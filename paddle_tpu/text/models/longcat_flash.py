@@ -23,7 +23,7 @@ and the normed latent c_kv times (hidden / kv_lora_rank)^1/2, which is
 cached scaled (`mla_scale_q_lora`, `mla_scale_kv_lora`). Plain RoPE, no
 scaling. A layer caches TWO latents a token: `paged_cache_spec()` yields
 two `PagedLatentCache` entries a layer, in layer order. Embedding, norm,
-head and `ServeLoop`'s protocol are `kimi_k2._LatentDecoder`'s, and a
+head and `ServeLoop`'s protocol are `decoder.PagedDecoder`'s, and a
 sublayer up to its dense FFN is `kimi_k2._sublayer`: a bucketed prefill
 works tile by tile over the tiles that hold a token, the expert layer
 once over the bucket.
@@ -39,15 +39,14 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import jax
-import jax.numpy as jnp
 
 from ... import nn
-from .kimi_k2 import (MOE_STATS, DenseFFN, LatentAttention, _cos_sin,
-                      _LatentDecoder, _sublayer, _Weights, moe_counters)
+from .decoder import MOE_STATS, DenseFFN, _Weights, moe_counters
+from .kimi_k2 import LatentAttention, _LatentDecoder, _sublayer
 
 __all__ = ["LongCatFlash", "LongCatFlashConfig", "SCMOE_STATS"]
 
-# beside `kimi_k2.MOE_STATS`, under the same names: how many of the
+# beside `decoder.MOE_STATS`, under the same names: how many of the
 # routed tokens' pairs fell on routed experts (held here or not), how
 # many on zero-compute experts, and the sum over (token, layer) of a
 # token's routed pairs squared (the spread of compute a token)
@@ -124,37 +123,35 @@ class LongCatFlashBlock(_Weights):
             zero_experts=cfg.zero_experts, dtype=cfg.dtype,
             init_std=cfg.init_std)
 
-    def forward(self, x, cos, sin, caches=(None, None), valid=None,
-                live=None):
-        """-> (y, the two new caches, pairs per held expert [count] i32,
-        [3] i32: routed pairs, zero pairs, routed pairs squared). `live`:
-        `kimi_k2._sublayer`'s tiles of rows that hold a token, None for
-        all; the expert layer runs once over all the rows."""
+    def forward(self, x, rope, cache0, cache1, rows):
+        """-> (y, the two new caches, (pairs per held expert [count] i32,
+        [3] i32: routed pairs, zero pairs, routed pairs squared)). `rows`:
+        `kimi_k2._sublayer`'s; the expert layer runs once over all the
+        rows."""
         b, s, H = x.shape
         new = []
-        for i, (sub, cache) in enumerate(zip(self.sub, caches)):
+        for i, (sub, cache) in enumerate(zip(self.sub, (cache0, cache1))):
             with jax.named_scope(f"sublayer{i}"):
                 x, f, cache = _sublayer(
                     sub.attn, sub.attn_norm, sub.ffn_norm, sub.ffn, self.eps,
-                    x, cos, sin, cache, live)
+                    x, *rope, cache, rows)
             if i == 0:
                 with jax.named_scope("experts"):
                     m, counts, pairs = self.experts.routed(
-                        f.reshape(b * s, H),
-                        None if valid is None else valid.reshape(b * s))
+                        f.reshape(b * s, H), None if rows.valid is None
+                        else rows.valid.reshape(b * s))
             new.append(cache)
         with jax.named_scope("experts"):
-            return x + m.reshape(b, s, H), new, counts, pairs
+            return x + m.reshape(b, s, H), new, (counts, pairs)
 
 
 class LongCatFlash(_LatentDecoder):
     SERVE_STATS = SCMOE_STATS
+    LAYER_CACHES = 2
 
     def __init__(self, config: LongCatFlashConfig = None):
-        super().__init__(config or LongCatFlashConfig())
-
-    def _block(self, i):
-        return LongCatFlashBlock(self.config)     # every layer alike
+        cfg = config or LongCatFlashConfig()
+        super().__init__(cfg, lambda i: LongCatFlashBlock(cfg))  # all alike
 
     def paged_cache_spec(self):
         """TWO `CacheSpec`s a layer, in layer order: each sublayer's
@@ -166,10 +163,9 @@ class LongCatFlash(_LatentDecoder):
         return [CacheSpec(PagedLatentCache, (latent,))] * (2 * cfg.num_layers)
 
     def serve_counters(self, kind, counted, n_tokens):
-        """{`ServeLoop.stats()` name: increment} for one settled serve
-        program: `counted` is what `_forward_paged` returned past its
-        caches, the pairs each held expert got [layers, held] and
-        [layers, 3]: routed pairs, zero pairs, routed pairs squared."""
+        """`counted` is what the blocks counted, stacked: the pairs each
+        held expert got [layers, held] and [layers, 3]: routed pairs, zero
+        pairs, routed pairs squared."""
         import numpy as np
         out = moe_counters(kind, counted[0], n_tokens)
         real, zero, real_sq = np.asarray(counted[1]).sum(axis=0)
@@ -177,20 +173,3 @@ class LongCatFlash(_LatentDecoder):
                     f"moe_{kind}_pairs_zero": int(zero),
                     f"moe_{kind}_pairs_real_sq": int(real_sq)})
         return out
-
-    def _blocks(self, ids, pos, caches, valid, live=None):
-        """`caches`: two a layer, in layer order (None: no cache).
-        Counted: pairs per held expert [layers, held] i32, and [layers,
-        3] i32 (routed pairs, zero pairs, routed pairs squared)."""
-        with jax.named_scope("embed"):
-            x = jnp.take(self.embed._value, ids, axis=0)
-            cos, sin = _cos_sin(self.config, pos)
-        new_caches, counts, pairs = [], [], []
-        for i, blk in enumerate(self.blocks):
-            with jax.named_scope(f"layer{i}"):
-                x, c, n, p = blk(x, cos, sin, caches[2 * i:2 * i + 2]
-                                 if caches else (None, None), valid, live)
-            new_caches += c
-            counts.append(n)
-            pairs.append(p)
-        return x, new_caches, (jnp.stack(counts), jnp.stack(pairs))

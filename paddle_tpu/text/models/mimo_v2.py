@@ -56,9 +56,9 @@ import jax.numpy as jnp
 
 from ... import nn
 from ...nn import initializer as I
-from .kimi_k2 import DenseFFN
-from .laguna import (F32, FULL, SLIDING, GroupedAttention, WindowBlock,
-                     WindowDecoder, _rotary, window_cache_spec)
+from .decoder import F32, FULL, DenseFFN
+from .laguna import (SLIDING, GroupedAttention, WindowBlock, WindowDecoder,
+                     _rotary, window_cache_spec)
 
 __all__ = ["MiMoV2Flash", "MiMoV2Config"]
 
@@ -216,10 +216,10 @@ class SinkGroupedAttention(GroupedAttention):
         return self.heads_of(a.astype(self.qkv._value.dtype), cos, sin,
                              self.v_head_dim, self.value_scale)
 
-    def mix(self, q, k, v, cache=None, last=None, live=None):
+    def mix(self, q, k, v, cache, rows):
         """`laguna.GroupedAttention.attend` -> ((out [b, s, n d_v],), new
         cache or None)."""
-        out, cache = self.attend(q, k, v, cache, last, live)
+        out, cache = self.attend(q, k, v, cache, rows)
         return (out,), cache
 
     def output(self, out):

@@ -30,9 +30,9 @@ Two computation paths, the same mathematics:
   tokens before it; the full layers write their keys and values and
   attend within the chunk, the queries in blocks. What works row by row
   (the projections, the feed-forward part, the queries' blocks) runs over
-  the tiles of `PREFILL_TILE` rows that hold a token and leaves the rest
-  of the bucket zero (`_live_rows`): a prompt just over a bucket's half
-  pays for its tiles, not for the bucket;
+  the tiles of `OlmoHybrid.PREFILL_TILE` rows that hold a token and leaves
+  the rest of the bucket zero (`_live_rows`): a prompt just over a
+  bucket's half pays for its tiles, not for the bucket;
 - one token a slot (a decode step): the linear layers update every
   slot's state in place (`gdn_step`), the full layers go through
   `write_kv` + `paged_attention` (the Pallas pair).
@@ -48,15 +48,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ... import nn
 from ...nn import initializer as I
 from ...nn.layer.experts import _swiglu
-from .kimi_k2 import DenseFFN, _live_rows, _rms, _tile_of, _Weights
+from .decoder import (F32, FULL, DenseFFN, PagedDecoder, _live_rows, _rms,
+                      _Weights)
 
 __all__ = ["OlmoHybrid", "OlmoHybridConfig", "LINEAR_STATS"]
 
-LINEAR, FULL = "linear_attention", "full_attention"
-F32 = jnp.float32
+LINEAR = "linear_attention"
 
 # what the linear layers count for `ServeLoop.stats()`: prompt tokens
 # their prefills scanned, the padding scanned beside them (bucket less
@@ -97,16 +96,10 @@ class OlmoHybridConfig:
         return OlmoHybridConfig(**cfg)
 
 
-# rows of one step of a prefill's row-wise work: a bucket of up to 4096
-# rows holds a prompt of any length over its half, and what the rows past
-# the prompt compute is thrown away
-PREFILL_TILE = 512
-
-
 # jitted under a name of its own, so that a device trace can tell the
 # chunk's attention from the rest of a prefill
 @functools.partial(jax.jit, static_argnames=("scale", "q_block"))
-def _chunk_attention(q, k, v, live=None, *, scale, q_block=PREFILL_TILE):
+def _chunk_attention(q, k, v, live=None, *, scale, q_block):
     """Causal attention within a chunk: q, k, v [b, s, h, d] -> [b, s, h,
     d]. Queries go `q_block` at a time, so the float32 scores are [b, h,
     q_block, s] and never [b, h, s, s] (2 GB at 30 heads of 4096); with
@@ -223,17 +216,17 @@ class LinearAttention(_Weights):
                                    preferred_element_type=F32))
         return gates, proj, gate
 
-    def mix(self, gates, proj, gate, cache=None, valid=None, last=None,
-            live=None):
+    def mix(self, gates, proj, gate, cache, rows):
         """Across the rows: the convolution and the recurrence, from
         `project`'s parts -> ((o [b, s, n dv] float32, gate), new cache or
-        None). `valid` [b, s] marks the tokens that exist; the others
-        leave the state alone. `last` [b] is the last valid index of a
-        chunk."""
+        None). `rows.valid` [b, s] marks the tokens that exist; the others
+        leave the state alone. `rows.last` [b] is the last valid index of
+        a chunk."""
         from ...ops.pallas.gated_delta import (gdn_chunk_scan, gdn_step,
                                                state_layout)
         b, s, _ = proj.shape
         n, dk, dv = self.heads, self.dk, self.dv
+        valid, last = rows.valid, rows.last
         if last is None:
             last = jnp.full((b,), s - 1, jnp.int32)
         qkv, conv = self._conv(proj, cache, last)
@@ -297,10 +290,10 @@ class FullAttention(_Weights):
         return tuple(t_.reshape(b, t, self.heads, self.head_dim)
                      .astype(x.dtype) for t_ in (q, k, qkv[..., 2 * H:]))
 
-    def mix(self, q, k, v, cache=None, valid=None, last=None, live=None):
+    def mix(self, q, k, v, cache, rows):
         """Across the rows: keys and values written, the attention ->
         ((out [b, s, H],), new cache or None). A chunk attends within
-        itself, `live` blocks of queries of it (None: all)."""
+        itself, `rows.live` blocks of queries of it (None: all)."""
         b, s, h, d = q.shape
         scale = d ** -0.5
         if cache is not None:
@@ -313,8 +306,8 @@ class FullAttention(_Weights):
                 v=write_kv(cache.v, cache.block_tables, lens, v),
                 lengths=lens + jnp.int32(s))
         if s > 1 or cache is None:   # a prefill starts an empty slot
-            out = _chunk_attention(q.astype(k.dtype), k, v, live,
-                                   scale=scale, q_block=PREFILL_TILE)
+            out = _chunk_attention(q.astype(k.dtype), k, v, rows.live,
+                                   scale=scale, q_block=rows.tile)
         else:       # a token a slot: written and attended by one entry
             out, kc, vc = paged_write_attend(
                 jnp.swapaxes(q, 1, 2), cache.k, cache.v,
@@ -339,18 +332,19 @@ class OlmoHybridBlock(_Weights):
         self.ffn = DenseFFN(cfg)
         self.ffn_norm = self.ones(cfg.hidden_size)
 
-    def forward(self, x, cache=None, valid=None, last=None, live=None):
+    def forward(self, x, rope, cache, rows):
         """x [b, s, H] float32: the residual stream, and each sublayer's
         output up to its norm, stay in float32 (a few MB); what a matrix
-        multiplies is rounded to the parameters' dtype. `live`: the tiles
-        of rows that hold a token (`_live_rows`), None for all."""
+        multiplies is rounded to the parameters' dtype. No rotary: `rope`
+        is None. `rows.live`: the tiles of rows that hold a token
+        (`_live_rows`), None for all. -> (y, (new cache,), ())."""
         dtype = self.ffn.gate._value.dtype
         mixer = self.mixer
+        live, tile = rows.live, rows.tile
         word = "linear_attn" if self.kind == LINEAR else "attn"
         with jax.named_scope(word):
             mixed, cache = mixer.mix(
-                *_live_rows(mixer.project, live, PREFILL_TILE, x),
-                cache, valid, last, live)
+                *_live_rows(mixer.project, live, tile, x), cache, rows)
 
         def rest(x, *mixed):
             with jax.named_scope(word):
@@ -361,21 +355,22 @@ class OlmoHybridBlock(_Weights):
                             self.ffn.up._value, self.ffn.down._value)
                 return (h + _rms(f, self.ffn_norm._value, self.eps),)
 
-        return _live_rows(rest, live, PREFILL_TILE, x, *mixed)[0], cache
+        return _live_rows(rest, live, tile, x, *mixed)[0], (cache,), ()
 
 
-class OlmoHybrid(_Weights):
+class OlmoHybrid(PagedDecoder):
     SERVE_STATS = LINEAR_STATS
+    # rows of one step of a prefill's row-wise work: a bucket of up to 4096
+    # rows holds a prompt of any length over its half, and what the rows
+    # past the prompt compute is thrown away. Every bucket of more than a
+    # tile is cut (ROADMAP S13 iv prices the latent nets' rule here)
+    PREFILL_TILE = 512
+    WHOLE_TILES = 1
 
     def __init__(self, config: OlmoHybridConfig = None):
         cfg = config or OlmoHybridConfig()
-        super().__init__(cfg)
-        self.config = cfg
-        self.embed = self.matrix(cfg.vocab_size, cfg.hidden_size)
-        self.blocks = nn.LayerList([OlmoHybridBlock(cfg, kind)
-                                    for kind in cfg.layer_types])
-        self.norm = self.ones(cfg.hidden_size)
-        self.head = self.matrix(cfg.hidden_size, cfg.vocab_size)
+        super().__init__(
+            cfg, lambda i: OlmoHybridBlock(cfg, cfg.layer_types[i]))
 
     def paged_cache_spec(self):
         """One `CacheSpec` a layer, by its kind: a full layer pages keys
@@ -389,76 +384,17 @@ class OlmoHybrid(_Weights):
                 else CacheSpec(PagedKVCache, (per_head, per_head))
                 for blk in self.blocks]
 
+    def _embed(self, ids, pos):
+        return jnp.take(self.embed._value, ids, axis=0).astype(F32), None
+
+    def _counted(self, caches, rows):
+        """[tokens a row of the program, linear layers] i32."""
+        n_linear = sum(blk.kind == LINEAR for blk in self.blocks)
+        return (jnp.asarray([rows.valid.shape[1], n_linear], jnp.int32),)
+
     def serve_counters(self, kind, counted, n_tokens):
-        """{`ServeLoop.stats()` name: increment} for one settled serve
-        program (`kind` "decode" or "prefill") that ran `n_tokens` live
-        tokens: `counted` is what `_forward_paged` returned past its
-        caches, (tokens a row of the program, linear layers)."""
         width, layers = (int(x) for x in np.asarray(counted[0]))
         if kind == "decode":
             return {"linear_decode_layer_steps": layers}
         return {"linear_prefill_tokens": int(n_tokens),
                 "linear_prefill_pad_tokens": width - int(n_tokens)}
-
-    def _blocks(self, ids, caches, valid, last, live=None):
-        with jax.named_scope("embed"):
-            x = jnp.take(self.embed._value, ids, axis=0).astype(F32)
-        new_caches = []
-        for i, (blk, c) in enumerate(zip(self.blocks, caches)):
-            with jax.named_scope(f"layer{i}"):
-                x, c = blk(x, c, valid, last, live)
-            new_caches.append(c)
-        return x, new_caches
-
-    def _logits(self, h):
-        with jax.named_scope("head"):
-            h = _rms(h, self.norm._value, self.config.rms_norm_eps)
-            return jnp.dot(h.astype(self.head._value.dtype),
-                           self.head._value, preferred_element_type=F32)
-
-    def forward(self, input_ids):
-        """Logits [b, s, vocab] (float32) of a whole sequence, no cache."""
-        from ...core import tape
-        from ...core.tensor import Tensor
-        ids = input_ids._value if isinstance(input_ids, Tensor) \
-            else jnp.asarray(input_ids)
-        with tape.no_grad():
-            x, _ = self._blocks(ids.astype(jnp.int32),
-                                [None] * len(self.blocks), None, None)
-            return Tensor(self._logits(x), _internal=True)
-
-    def prefill_tile(self, bucket):
-        """`kimi_k2._tile_of` this net's tile: what `_forward_paged` cuts
-        a bucket into, and what `ServeLoop` counts the rows computed by."""
-        return _tile_of(bucket, PREFILL_TILE)
-
-    def _forward_paged(self, input_ids, caches, last_index=None):
-        """One paged prefill/decode pass, `GPT._forward_paged`'s contract
-        over the caches `paged_cache_spec` names, plus what
-        `serve_counters` reads: -> (logits [b, V] float32, new caches,
-        [tokens a row, linear layers] i32). Rows that no request owns (a
-        slot whose table starts at the trash block, a prompt's padding
-        past `last_index`) write their keys and values to the trash
-        block like GPT's and leave every state as it was."""
-        from ...core.tensor import Tensor
-        from ...nn.kv_pool import TRASH_BLOCK
-        ids = input_ids._value if isinstance(input_ids, Tensor) \
-            else jnp.asarray(input_ids)
-        b, s = ids.shape
-        step = jnp.arange(s, dtype=jnp.int32)[None]
-        valid = jnp.broadcast_to(
-            (caches[0].block_tables[:, :1] != TRASH_BLOCK), (b, s))
-        last = None
-        if last_index is not None:
-            last = jnp.asarray(last_index, jnp.int32).reshape(-1)
-            valid = valid & (step <= last[:, None])
-        live = None
-        if last is not None and self.prefill_tile(s):
-            live = jnp.max(last) // PREFILL_TILE + 1
-        x, new_caches = self._blocks(ids.astype(jnp.int32), caches, valid,
-                                     last, live)
-        h = x[:, -1] if last is None else jnp.take_along_axis(
-            x, last[:, None, None], axis=1)[:, 0]
-        n_linear = sum(blk.kind == LINEAR for blk in self.blocks)
-        return (self._logits(h), new_caches,
-                jnp.asarray([s, n_linear], jnp.int32))

@@ -588,7 +588,7 @@ def test_latent_serve_steps_hold_no_arena_copy_for_v5e():
     are activations. The decode step's: 3.2 MB (the plain-XLA latent
     attention gathered 24 blocks for each of 64 slots and scored them in
     float32: 475 MB before the kernel, PR 38). The prefill works tile by
-    tile over the tiles that hold a token (`kimi_k2._live_rows`) and a
+    tile over the tiles that hold a token (`decoder._live_rows`) and a
     tile of queries reads the keys up to its own tile: seven conditionals
     a layer at 2048 rows in tiles of 256, and 484 MB of temporaries where
     whole rows against all the keys held 562 MB (PR 42). The decode step

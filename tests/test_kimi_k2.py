@@ -18,9 +18,10 @@ from paddle_tpu.nn.kv_pool import (CacheSpec, KVBlockPool, PagedKVCache,
                                    PagedLatentCache, cache_arenas,
                                    paged_caches)
 from paddle_tpu.text.models import (GPT, GPTConfig, KimiK2, KimiK2Config,
-                                    kimi_k2)
-from paddle_tpu.text.models.kimi_k2 import (LatentAttention, yarn_inv_freq,
+                                    decoder, kimi_k2)
+from paddle_tpu.text.models.decoder import (Rows, yarn_inv_freq,
                                             yarn_mscale)
+from paddle_tpu.text.models.kimi_k2 import LatentAttention
 from paddle_tpu.text.models.reference import kimi_k2 as ref
 from test_olmo_hybrid import forced_logits as loop_forced_logits, small_loop
 
@@ -154,7 +155,7 @@ def check_live_tiles(net, monkeypatch, prompt_len):
     assert net.prefill_tile(256) is None        # 256 rows: one tile, whole
     exact, exact_caches, *_ = net._forward_paged(
         jnp.asarray(ids[None]), caches(), last_index=last)
-    monkeypatch.setattr(kimi_k2, "PREFILL_TILE", 16)
+    monkeypatch.setattr(decoder.PagedDecoder, "PREFILL_TILE", 16)
     # one tile, and two (the smallest bucket that holds its prompt: both
     # live), run whole
     assert [net.prefill_tile(b) for b in (16, 32, 64, 256)] \
@@ -172,8 +173,10 @@ def check_live_tiles(net, monkeypatch, prompt_len):
     assert rel_err(cached(got_caches)[:, :prompt_len],
                    cached(exact_caches)[:, :prompt_len]) < 1e-4
     live = (prompt_len - 1) // 16 + 1
-    x, *_ = net._blocks(jnp.asarray(padded), jnp.arange(256)[None], caches(),
-                        jnp.arange(256)[None] < prompt_len, jnp.int32(live))
+    x, *_ = net._blocks(
+        jnp.asarray(padded), jnp.arange(256)[None], caches(),
+        Rows(valid=jnp.arange(256)[None] < prompt_len, live=jnp.int32(live),
+             tile=16))
     assert float(jnp.abs(x[:, :prompt_len]).max(axis=-1).min()) > 0
     assert not np.asarray(x[:, live * 16:]).any()
 
@@ -186,7 +189,7 @@ def test_a_prefill_computes_only_the_tiles_that_hold_a_token(
 
 def test_served_logits_match_reference_through_live_tiles(monkeypatch):
     """ServeLoop's own prefill program over 3 tiles of a bucket of 4."""
-    monkeypatch.setattr(kimi_k2, "PREFILL_TILE", 16)
+    monkeypatch.setattr(decoder.PagedDecoder, "PREFILL_TILE", 16)
     net = make_net()
     params, _ = net.functional_state()
     ids = np.random.RandomState(1).randint(1, 256, 35 + 9)
@@ -517,7 +520,7 @@ def test_expert_counters_only_from_a_net_with_expert_layers(net):
     loop.serve([rng.randint(1, 256, n) for n in (5, 11, 19)],
                max_new_tokens=6)
     st = loop.stats()
-    assert set(kimi_k2.MOE_STATS) <= set(st)
+    assert set(decoder.MOE_STATS) <= set(st)
     # pad rows of a bucketed prompt and empty decode slots are not routed
     assert st["moe_prefill_tokens"] == st["prefill_tokens"] == 35
     assert st["moe_decode_layer_steps"] == 2 * st["steps"]
@@ -531,4 +534,4 @@ def test_expert_counters_only_from_a_net_with_expert_layers(net):
     plain = ServeLoop(gpt, ServeConfig(max_active=2, kv_blocks=8,
                                        block_size=16, max_seq_len=64))
     plain.serve([rng.randint(1, 1024, 5)], max_new_tokens=3)
-    assert not set(kimi_k2.MOE_STATS) & set(plain.stats())
+    assert not set(decoder.MOE_STATS) & set(plain.stats())

@@ -23,7 +23,7 @@ from paddle_tpu.nn.kv_pool import (CacheSpec, KVBlockPool, PagedKVCache,
                                    window_fill, window_ring_shape,
                                    window_write, write_kv)
 from paddle_tpu.text.models import GPT, GPTConfig, Laguna, LagunaConfig
-from paddle_tpu.text.models import laguna
+from paddle_tpu.text.models import decoder, laguna
 from paddle_tpu.text.models.reference import laguna as ref
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
@@ -112,7 +112,7 @@ def test_served_logits_match_reference_through_live_tiles(monkeypatch):
     """ServeLoop's own prefill program over 3 tiles of a bucket of 4, a
     sliding layer's tiles meeting the band only (window 8 in tiles of
     16: the tile before and its own)."""
-    monkeypatch.setattr(laguna, "PREFILL_TILE", 16)
+    monkeypatch.setattr(decoder.PagedDecoder, "PREFILL_TILE", 16)
     net = make_net()
     assert [net.prefill_tile(b) for b in (16, 32, 64, 256)] \
         == [None, None, 16, 16]
@@ -153,7 +153,7 @@ def test_a_prefill_computes_only_the_tiles_that_hold_a_token(
         return out
 
     exact, exact_caches, *_ = run(jnp.asarray(ids[None]))
-    monkeypatch.setattr(laguna, "PREFILL_TILE", 16)
+    monkeypatch.setattr(decoder.PagedDecoder, "PREFILL_TILE", 16)
     padded = np.zeros((1, 256), np.int32)
     padded[0, :prompt_len] = ids
     got, got_caches, *_ = jax.jit(run)(jnp.asarray(padded))
@@ -649,7 +649,7 @@ def test_router_is_a_softmax_renormalised_over_the_chosen_times_2_5(net):
     np.testing.assert_array_equal(
         np.asarray(idx), np.asarray(jax.lax.top_k(scores, 3)[1]))
     assert not np.asarray(ffn.router_bias._value).any()   # no selection bias
-    assert isinstance(net.blocks[0].ffn, laguna.DenseFFN)  # mlp_only_layers
+    assert isinstance(net.blocks[0].ffn, decoder.DenseFFN)  # mlp_only_layers
 
 
 # -- 6. the counters --------------------------------------------------------
@@ -664,7 +664,7 @@ def test_counters_tell_what_the_decode_steps_read(net):
     lens = (5, 11, 19)
     loop.serve([rng.randint(1, VOCAB, n) for n in lens], max_new_tokens=6)
     st = loop.stats()
-    assert set(laguna.ATTN_STATS) | set(laguna.MOE_STATS) <= set(st)
+    assert set(laguna.ATTN_STATS) | set(decoder.MOE_STATS) <= set(st)
     # a decode step reads the stream up to and with its own token: the
     # request's tokens 2..6 are decode steps at lengths n .. n + 4
     seen = [n + j + 1 for n in lens for j in range(5)]

@@ -20,9 +20,9 @@ from paddle_tpu.nn.kv_pool import (CacheSpec, KVBlockPool, PagedLatentCache,
                                    cache_arenas, paged_caches)
 from paddle_tpu.text.models import (GPT, GPTConfig, KimiK2Config,
                                     LongCatFlash, LongCatFlashConfig,
-                                    kimi_k2, longcat_flash)
-from paddle_tpu.text.models.kimi_k2 import (LatentAttention, _rms, _rope,
-                                            yarn_inv_freq)
+                                    decoder, longcat_flash)
+from paddle_tpu.text.models.decoder import _rms, _rope, yarn_inv_freq
+from paddle_tpu.text.models.kimi_k2 import LatentAttention
 from paddle_tpu.text.models.reference import longcat_flash as ref
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
@@ -103,7 +103,7 @@ def test_a_prefill_computes_only_the_tiles_that_hold_a_token(
 
 def test_served_logits_match_reference_through_live_tiles(monkeypatch):
     """ServeLoop's own prefill program over 3 tiles of a bucket of 4."""
-    monkeypatch.setattr(kimi_k2, "PREFILL_TILE", 16)
+    monkeypatch.setattr(decoder.PagedDecoder, "PREFILL_TILE", 16)
     net = make_net()
     params, _ = net.functional_state()
     ids = np.random.RandomState(1).randint(1, 256, 35 + 9)

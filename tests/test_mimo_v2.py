@@ -25,7 +25,7 @@ from paddle_tpu.nn.kv_pool import (CacheSpec, KVBlockPool, PagedKVCache,
                                    window_fill, window_ring_shape,
                                    window_write, write_kv)
 from paddle_tpu.text.models import MiMoV2Config, MiMoV2Flash
-from paddle_tpu.text.models import laguna, mimo_v2
+from paddle_tpu.text.models import decoder, laguna, mimo_v2
 from paddle_tpu.text.models.reference import mimo_v2 as ref
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
@@ -115,7 +115,7 @@ def test_served_logits_match_reference_through_live_tiles(monkeypatch):
     """ServeLoop's own prefill program over 3 tiles of a bucket of 4 (the
     tile is the nets with window layers' one constant), a sliding layer's
     tiles meeting the band only and starting from the sinks."""
-    monkeypatch.setattr(laguna, "PREFILL_TILE", 16)
+    monkeypatch.setattr(decoder.PagedDecoder, "PREFILL_TILE", 16)
     net = make_net()
     assert [net.prefill_tile(b) for b in (16, 32, 64, 256)] \
         == [None, None, 16, 16]
@@ -255,7 +255,7 @@ def test_a_net_without_a_shared_expert_holds_no_shared_leaf(net):
     np.testing.assert_allclose(np.asarray(weights.sum(-1)), 1.0, rtol=1e-6)
     y, counts, _ = ffn.routed(x)
     assert y.shape == (9, 64) and counts.shape == (8,)
-    assert isinstance(net.blocks[0].ffn, mimo_v2.DenseFFN)
+    assert isinstance(net.blocks[0].ffn, decoder.DenseFFN)
     assert [n for n in names if n.endswith("sinks")] \
         == [f"blocks.{i}.attn.sinks" for i in (1, 2, 3, 4, 6)]
     low = make_net("bfloat16")
@@ -748,7 +748,7 @@ def test_counters_tell_what_the_decode_steps_read(net):
     lens = (5, 11, 19)
     loop.serve([rng.randint(1, VOCAB, n) for n in lens], max_new_tokens=6)
     st = loop.stats()
-    assert set(laguna.ATTN_STATS) | set(laguna.MOE_STATS) <= set(st)
+    assert set(laguna.ATTN_STATS) | set(decoder.MOE_STATS) <= set(st)
     seen = [n + j + 1 for n in lens for j in range(5)]
     assert st["attn_full_decode_tokens_read"] == 2 * sum(seen)
     assert st["attn_window_decode_tokens_read"] \

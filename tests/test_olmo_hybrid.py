@@ -18,6 +18,7 @@ from paddle_tpu.nn.kv_pool import (CacheSpec, KVBlockPool, PagedKVCache,
 from paddle_tpu.ops.pallas import gated_delta as gd
 from paddle_tpu.text.models import (GPT, GPTConfig, OlmoHybrid,
                                     OlmoHybridConfig, olmo_hybrid)
+from paddle_tpu.text.models.decoder import Rows
 from paddle_tpu.text.models.reference import olmo_hybrid as ref
 
 VOCAB = 128
@@ -290,7 +291,7 @@ def test_a_prefill_computes_only_the_tiles_that_hold_a_token(
 
     exact, exact_caches, _ = net._forward_paged(
         jnp.asarray(ids[None]), caches(), last_index=last)
-    monkeypatch.setattr(olmo_hybrid, "PREFILL_TILE", 16)
+    monkeypatch.setattr(OlmoHybrid, "PREFILL_TILE", 16)
     padded = np.zeros((1, 256), np.int32)
     padded[0, :prompt_len] = ids
     try:
@@ -305,16 +306,17 @@ def test_a_prefill_computes_only_the_tiles_that_hold_a_token(
     for a, b in zip(got_caches[0][:2], exact_caches[0][:2]):
         np.testing.assert_allclose(a, b, atol=3e-4)   # state, conv inputs
     live = (prompt_len - 1) // 16 + 1
-    x, _ = net._blocks(jnp.asarray(padded), caches(),
-                       jnp.arange(256)[None] < prompt_len, last,
-                       jnp.int32(live))
+    x, *_ = net._blocks(
+        jnp.asarray(padded), None, caches(),
+        Rows(valid=jnp.arange(256)[None] < prompt_len, last=last,
+             live=jnp.int32(live), tile=16))
     assert float(jnp.abs(x[:, :prompt_len]).min()) > 0
     assert not np.asarray(x[:, live * 16:]).any()
 
 
 def test_served_logits_match_reference_through_live_tiles(monkeypatch):
     """ServeLoop's own prefill program over 3 tiles of a bucket of 4."""
-    monkeypatch.setattr(olmo_hybrid, "PREFILL_TILE", 16)
+    monkeypatch.setattr(OlmoHybrid, "PREFILL_TILE", 16)
     net = make_net()
     params, _ = net.functional_state()
     ids = np.random.RandomState(1).randint(1, VOCAB, 35 + 9)

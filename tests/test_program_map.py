@@ -21,7 +21,7 @@ import paddle_tpu as paddle
 from paddle_tpu import profiler
 from paddle_tpu.core import monitor, program_map
 from paddle_tpu.inference import ServeConfig, ServeLoop
-from paddle_tpu.text.models import kimi_k2, olmo_hybrid
+from paddle_tpu.text.models.decoder import PagedDecoder
 from paddle_tpu.text.models.gpt import GPT, GPTConfig
 from paddle_tpu.text.models.kimi_k2 import KimiK2, KimiK2Config
 from paddle_tpu.text.models.longcat_flash import (LongCatFlash,
@@ -192,15 +192,15 @@ def test_prefill_rows_are_the_buckets_dispatched():
     assert program_map.scopes("serve/prefill/64")["module"] == "jit_prefill"
 
 
-@pytest.mark.parametrize("kind, module", [("kimi", kimi_k2),
-                                          ("longcat", kimi_k2),
-                                          ("hybrid", olmo_hybrid)])
+@pytest.mark.parametrize("kind, owner", [("kimi", PagedDecoder),
+                                         ("longcat", PagedDecoder),
+                                         ("hybrid", OlmoHybrid)])
 def test_prefill_live_rows_are_the_tiles_that_hold_a_token(
-        kind, module, monkeypatch):
+        kind, owner, monkeypatch):
     """A net that cuts a bucket into tiles (here of 16 rows) computes the
     tiles up to the prompt's end; a bucket of one tile or less runs
     whole."""
-    monkeypatch.setattr(module, "PREFILL_TILE", 16)
+    monkeypatch.setattr(owner, "PREFILL_TILE", 16)
     monitor.reset(prefix="serve.")
     rng = np.random.RandomState(3)
     lens = (5, 16, 17, 33, 40, 64)

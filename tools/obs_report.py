@@ -260,7 +260,7 @@ SERVE_GAUGES = ("serve.queue_depth", "serve.active_slots",
                 "serve.state_slots_used", "serve.state_bytes", "serve.steps",
                 "serve.paged_live_step_share")
 # what a served net counts of its layers (its `SERVE_STATS`): a net with
-# expert layers (text/models/kimi_k2.MOE_STATS; with zero-compute experts
+# expert layers (text/models/decoder.MOE_STATS; with zero-compute experts
 # beside them text/models/longcat_flash.SCMOE_STATS, which starts with
 # those), a net with recurrent layers (text/models/olmo_hybrid
 # .LINEAR_STATS), a net with sliding-window layers (text/models/laguna
@@ -541,12 +541,12 @@ def self_check():
             problems.append(
                 f"obs_report: serving.GAUGES {serving.GAUGES} != "
                 f"renderer SERVE_GAUGES {SERVE_GAUGES} — update both")
-        from paddle_tpu.text.models import (kimi_k2, laguna, longcat_flash,
+        from paddle_tpu.text.models import (decoder, laguna, longcat_flash,
                                             olmo_hybrid)
-        if longcat_flash.SCMOE_STATS[:len(kimi_k2.MOE_STATS)] \
-                != kimi_k2.MOE_STATS:
+        if longcat_flash.SCMOE_STATS[:len(decoder.MOE_STATS)] \
+                != decoder.MOE_STATS:
             problems.append("obs_report: longcat_flash.SCMOE_STATS no "
-                            "longer starts with kimi_k2.MOE_STATS")
+                            "longer starts with decoder.MOE_STATS")
         named = tuple(f"serve.{n}" for n in longcat_flash.SCMOE_STATS
                       + olmo_hybrid.LINEAR_STATS + laguna.ATTN_STATS)
         if named != SERVE_NET_GAUGES:
